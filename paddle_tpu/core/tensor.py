@@ -467,8 +467,11 @@ def _resolve_device(d):
     idx = int(s.split(":")[1]) if ":" in s else 0
     if plat in ("gpu", "cuda", "tpu", "xpu"):  # any accelerator alias
         accel = [x for x in jax.devices() if x.platform != "cpu"]
-        pool = accel or jax.devices()
-        return pool[min(idx, len(pool) - 1)]
+        if not accel:
+            raise RuntimeError(
+                f"device {d!r} names an accelerator but jax.devices() "
+                f"= {jax.devices()} has none")
+        return accel[min(idx, len(accel) - 1)]
     if plat == "cpu":
         return jax.devices("cpu")[0] if any(
             x.platform == "cpu" for x in jax.devices()) else jax.devices()[0]

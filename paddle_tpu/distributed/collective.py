@@ -20,12 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
-try:
-    from jax import shard_map  # jax >= 0.8
-    _SM_KW = {"check_vma": False}
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map  # type: ignore
-    _SM_KW = {"check_rep": False}
+from jax import shard_map
 
 from ..core.tensor import Tensor, to_tensor
 from .mesh import ProcessMesh
@@ -88,7 +83,7 @@ class Group:
             else PartitionSpec(self.axis)
         mapped = shard_map(fn, mesh=self.mesh.jax_mesh,
                            in_specs=(in_specs,), out_specs=out_specs,
-                           **_SM_KW)
+                           check_vma=False)
         return Tensor(mapped(v))
 
 

@@ -46,11 +46,8 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
-
-# version-conditional shard_map kwargs (check_vma vs check_rep) live in
-# collective.py; reuse them so the older-jax fallback actually works here
-from ..collective import _SM_KW, shard_map as _shard_map
 
 from ..mesh import ProcessMesh
 
@@ -263,7 +260,7 @@ def pipeline_forward(stage_fn: Callable, stacked_params, x, mesh: ProcessMesh,
                      in_specs=(param_specs, x_spec) + extra_specs
                      + tuple(reduce_arg_specs),
                      out_specs=out_spec,
-                     **_SM_KW)(stacked_params, xs, *extra_args,
+                     check_vma=False)(stacked_params, xs, *extra_args,
                                *reduce_args)
     if reduce_fn is not None:
         return out                      # (M,) per-microbatch scalars
@@ -854,7 +851,7 @@ def pipeline_1f1b(stage_fn: Callable, stacked_params, x, mesh: ProcessMesh,
             local_fn, mesh=mesh.jax_mesh,
             in_specs=(param_specs, xs_spec) + tuple(extra_specs)
             + tuple(reduce_arg_specs),
-            out_specs=out_specs, **_SM_KW)(sp, xv, *extra, *rargs)
+            out_specs=out_specs, check_vma=False)(sp, xv, *extra, *rargs)
 
     from jax import dtypes as _jdt
     import numpy as _np

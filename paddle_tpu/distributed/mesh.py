@@ -307,6 +307,81 @@ def use_mesh(mesh: ProcessMesh):
         _current_mesh = prev
 
 
+# -- Pallas kernels under a mesh ---------------------------------------------
+# Mosaic refuses to partition a kernel ("Mosaic kernels cannot be
+# automatically partitioned. Please wrap the call in a shard_map"): a
+# bare pallas_call inside a jit that spans more than one device does
+# not lower for the TPU. The interpret mode the CPU mesh runs is plain
+# XLA and partitions silently, so only a multi-chip TPU shows it.
+# What a tensor dimension means decides how it may be split; each role
+# names the mesh axes that carry it under `shard_llama`-style placements.
+_KERNEL_ROLE_AXES = {"batch": ("dp", "sharding"), "seq": ("sep",),
+                     "heads": ("mp",)}
+
+
+def _kernel_mesh():
+    """(jax Mesh, {role: mesh axes}) a Pallas call must be split over,
+    or None when it may run bare: no multi-device mesh is active, or
+    the caller is already inside a shard_map (pipeline stages, ring
+    attention, expert dispatch run their kernels per shard)."""
+    if jax.sharding.get_abstract_mesh().manual_axes:
+        return None
+    tp = _serving_tp
+    if tp is not None:
+        # a serving replica's one-axis submesh shards heads only
+        if tp.jax_mesh.size == 1:
+            return None
+        return tp.jax_mesh, {"heads": tuple(tp.jax_mesh.axis_names)}
+    m = _current_mesh
+    if m is None or m.jax_mesh.size == 1:
+        return None
+    return m.jax_mesh, {
+        role: tuple(a for a in axes if a in m.dim_names)
+        for role, axes in _KERNEL_ROLE_AXES.items()}
+
+
+def shard_kernel(fn, args, in_roles, out_roles):
+    """`fn(*args)` for an `fn` whose body is a Pallas TPU kernel, made
+    to compile under the active mesh (training `use_mesh` or a serving
+    replica's TP scope): `shard_map`ped so every device runs the kernel
+    on its own shard. `in_roles` gives, per argument, one role per
+    tensor dimension — "batch", "seq", "heads" or None — and
+    `out_roles` the same for the single output. A role is split over
+    its mesh axes only when EVERY dimension carrying it divides;
+    otherwise, like None, it stays whole on each device (the kernel
+    then repeats that work per device, which is what GSPMD does with a
+    replicated operand). With no multi-device mesh active this is
+    exactly `fn(*args)`. The mesh is read while TRACING and, like
+    `serving_tp_replicate`'s, is not part of jit's cache key: trace a
+    function under the mesh it will run on."""
+    ctx = _kernel_mesh()
+    if ctx is None:
+        return fn(*args)
+    mesh, role_axes = ctx
+
+    def ways(role):
+        return int(np.prod([mesh.shape[a]
+                            for a in role_axes.get(role, ())]))
+
+    split = {}
+    for roles, a in zip(in_roles, args):
+        for n, role in zip(a.shape, roles):
+            if role is not None:
+                split[role] = (split.get(role, True) and ways(role) > 1
+                               and n % ways(role) == 0)
+
+    def spec(roles):
+        return PartitionSpec(*[role_axes[r] if r is not None and split[r]
+                               else None for r in roles])
+
+    # check_vma off: pallas_call cannot annotate varying-mesh-axes on
+    # its outputs; the specs above are exact
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=tuple(spec(r) for r in in_roles),
+                         out_specs=spec(out_roles),
+                         check_vma=False)(*args)
+
+
 # -- placement -> PartitionSpec ---------------------------------------------
 def placements_to_spec(placements: Sequence[Placement],
                        mesh: ProcessMesh) -> PartitionSpec:
@@ -423,10 +498,7 @@ def shard_constraint(value, *axis_names, mesh: ProcessMesh | None = None):
 def local_map(fn, out_placements, in_placements, process_mesh,
               reshard_inputs=False):
     """≙ paddle.distributed.local_map — run fn on local shards via shard_map."""
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     in_specs = tuple(placements_to_spec(p, process_mesh)
                      for p in in_placements)
     out_specs = tuple(placements_to_spec(p, process_mesh)

@@ -100,6 +100,15 @@ def spawn(func, args=(), nprocs=-1, join=True, daemon=False, **options):
         nprocs = int(os.environ.get("PADDLE_TRAINERS_NUM", "1"))
     if nprocs <= 0:
         nprocs = 1
+    if nprocs > 1 and jax.devices()[0].platform == "tpu":
+        # a chip belongs to one process: this parent already holds the
+        # local chips, and each of the N children would want them all
+        # again — they would fail or hang at backend start-up
+        raise RuntimeError(
+            f"spawn(nprocs={nprocs}) on a TPU host: every worker would "
+            "claim the chips this process already holds. One process "
+            "drives all local chips (dist.create_mesh); use "
+            "paddle_tpu.distributed.launch for one process per host")
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
         port = s.getsockname()[1]

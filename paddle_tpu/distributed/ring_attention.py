@@ -27,11 +27,8 @@ from typing import Optional
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
-
-# version-conditional shard_map kwargs (check_vma vs check_rep) live in
-# collective.py; reuse them so the older-jax fallback actually works here
-from .collective import _SM_KW, shard_map as _shard_map
 
 from ..core.tensor import Tensor, apply
 from .mesh import ProcessMesh, get_mesh
@@ -147,7 +144,7 @@ def ring_attention_values(q, k, v, mesh: Optional[ProcessMesh] = None,
     spec = P(None, axis, None, None)
     return _shard_map(local_fn, mesh=mesh.jax_mesh,
                       in_specs=(spec, spec, spec), out_specs=spec,
-                      **_SM_KW)(q, k, v)
+                      check_vma=False)(q, k, v)
 
 
 def _ring_zigzag(q, k, v, mesh, axis, scale, n):
@@ -230,7 +227,7 @@ def _ring_zigzag(q, k, v, mesh, axis, scale, n):
     spec = P(None, axis, None, None)
     oz = _shard_map(local_fn, mesh=mesh.jax_mesh,
                     in_specs=(spec, spec, spec), out_specs=spec,
-                    **_SM_KW)(qz, kz, vz)
+                    check_vma=False)(qz, kz, vz)
     return jnp.take(oz, jnp.asarray(inv_idx), axis=1)
 
 
@@ -274,7 +271,7 @@ def ulysses_attention_values(q, k, v, mesh: Optional[ProcessMesh] = None,
     spec = P(None, axis, None, None)
     return _shard_map(local_fn, mesh=mesh.jax_mesh,
                       in_specs=(spec, spec, spec), out_specs=spec,
-                      **_SM_KW)(q, k, v)
+                      check_vma=False)(q, k, v)
 
 
 def ring_flash_attention(q: Tensor, k: Tensor, v: Tensor,

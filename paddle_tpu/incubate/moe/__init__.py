@@ -140,7 +140,7 @@ def _expert_ffn_rows(xs_in, eid, w_gate, w_up, w_down, e: int):
     runs the grouped matmul (Pallas kernel when block-aligned), unsorts.
     """
     from ...ops import on_tpu
-    from ...ops.grouped_matmul import (DEFAULT_BLOCK, _HAS_PLTPU,
+    from ...ops.grouped_matmul import (DEFAULT_BLOCK,
                                        grouped_matmul_values)
     n, h = xs_in.shape
     i_size = w_gate.shape[2]
@@ -150,7 +150,7 @@ def _expert_ffn_rows(xs_in, eid, w_gate, w_up, w_down, e: int):
     counts = jnp.bincount(eid, length=e)          # (E,)
 
     block_m = DEFAULT_BLOCK
-    block_aligned = (on_tpu() and _HAS_PLTPU and h % block_m == 0
+    block_aligned = (on_tpu() and h % block_m == 0
                      and i_size % block_m == 0)
     if block_aligned:
         # pad each expert's group to a block_m multiple so no m-tile of
@@ -385,7 +385,7 @@ def _ragged_ep_supported() -> bool:
     if ov is not None:
         return ov == "1"
     from ...ops import on_tpu
-    return on_tpu() and hasattr(jax.lax, "ragged_all_to_all")
+    return on_tpu()
 
 
 class MoELayer(Layer):
@@ -466,11 +466,7 @@ class MoELayer(Layer):
                 n_shards = int(np.prod(
                     [mesh.get_dim_size(a) for a in tok_axes]))
                 if t % n_shards == 0 and e % ep_size == 0:
-                    try:
-                        from jax import shard_map as _shard_map
-                    except ImportError:  # pragma: no cover
-                        from jax.experimental.shard_map import \
-                            shard_map as _shard_map
+                    from jax import shard_map as _shard_map
                     from jax.sharding import PartitionSpec as P
                     t_l = t // n_shards
                     use_ragged = False
@@ -493,7 +489,6 @@ class MoELayer(Layer):
                         return moe_ffn_dropless_ep_values(
                             x_l, gw_, wg_l, wu_l, wd_l, top_k, ep_size,
                             ep, list(tok_axes), cap, ragged=use_ragged)
-                    from ...distributed.collective import _SM_KW
                     # check_vma off: the grouped-matmul pallas_call in
                     # _expert_ffn_rows can't annotate vma on its outputs
                     mapped = _shard_map(
@@ -502,7 +497,7 @@ class MoELayer(Layer):
                                   P(ep, None, None), P(ep, None, None),
                                   P(ep, None, None)),
                         out_specs=(P(tok_axes, None), P(), P()),
-                        **_SM_KW)
+                        check_vma=False)
                     out, aux, drops = mapped(x2, gw, wg, wu, wd)
                     return out.reshape(xv.shape), aux, drops
                 # fall through to capacity path on indivisible shapes

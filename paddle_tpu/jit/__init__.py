@@ -357,7 +357,45 @@ class TrainStep:
                     opt.get_lr = old_get_lr
 
         donate = (0, 2, 3) if self.donate else ()
-        return jax.jit(pure, donate_argnums=donate)
+        return jax.jit(pure, donate_argnums=donate,
+                       out_shardings=self._pin_state_to_mesh())
+
+    def _pin_state_to_mesh(self):
+        """The step's `out_shardings`: the state goes back laid out as
+        it came in. Left to itself GSPMD returns some of a sharded
+        model's state with shardings of its own choosing (a replicated
+        norm weight comes back split over `sharding`; the rope tables
+        and the RNG key, fed from one device, come back on the mesh),
+        the next call then sees new input types and the whole step
+        traces and compiles AGAIN — the two steps after the first took
+        41.5 s each at Llama-3.2-1B on four v5e chips (chip run, PR 21).
+        So under a mesh the buffers and the key are first put on it
+        (replicated), and every state output is pinned to its input's
+        sharding. With everything on one device: None, the compiler
+        chooses."""
+        def of(v):
+            s = getattr(v, "sharding", None)
+            return s if s is not None and len(s.device_set) > 1 else None
+
+        on_mesh = next((s for s in (of(p._value) for p in self._params)
+                        if isinstance(s, jax.sharding.NamedSharding)),
+                       None)
+        if on_mesh is None:
+            return None
+        repl = jax.sharding.NamedSharding(on_mesh.mesh,
+                                          jax.sharding.PartitionSpec())
+        for b in self._buffers:
+            if of(b._value) is None:
+                b._value = jax.device_put(b._value, repl)
+        default_generator._key = jax.device_put(default_generator._key,
+                                                repl)
+        acc, master = self._materialize_state()
+        return ([of(p._value) for p in self._params],
+                [of(b._value) for b in self._buffers],
+                {name: {i: of(a) for i, a in store.items()}
+                 for name, store in acc.items()},
+                {i: of(a) for i, a in master.items()},
+                repl, None, None)
 
     def _materialize_state(self):
         """Run one eager warmup step ONLY to create optimizer accumulators
@@ -423,6 +461,24 @@ class TrainStep:
             self.optimizer.ensure_state()
         self._jitted = self._make_pure()
 
+    def lower(self, *args):
+        """The `jax.stages.Lowered` of THIS train step at the given
+        example inputs — the same pure function `__call__` runs, so its
+        text says which kernels the step contains
+        (`ops.mosaic_kernels(step.lower(x, y).as_text())`) and
+        `.compile()` gives its buffer assignment. Runs nothing."""
+        if self._jitted is None:
+            self._warmup(*args)
+        opt = self.optimizer
+        acc, master = self._materialize_state()
+        lr = np.float32(opt.get_lr()) if opt else np.float32(0.0)
+        arg_vals = _tensors_to_values(list(args))
+        return self._jitted.lower(
+            [p._value for p in self._params],
+            [b._value for b in self._buffers],
+            acc, master, default_generator._key, lr,
+            np.int32(opt._step_count if opt else 0), arg_vals)
+
     def memory_analysis(self, *args):
         """XLA buffer-assignment sizes for THIS train step at the given
         example inputs (utils.memory.compiled_memory_stats over the same
@@ -430,19 +486,9 @@ class TrainStep:
         defends remat/ZeRO/pipeline memory claims. ≙ the reference's
         `max_memory_allocated` + StatAllocator observability (SURVEY.md
         §5), but ahead-of-time and exact."""
-        if self._jitted is None:
-            self._warmup(*args)
-        opt = self.optimizer
-        acc, master = self._materialize_state()
-        lr = np.float32(opt.get_lr()) if opt else np.float32(0.0)
-        arg_vals = _tensors_to_values(list(args))
-        lowered = self._jitted.lower(
-            [p._value for p in self._params],
-            [b._value for b in self._buffers],
-            acc, master, default_generator._key, lr,
-            np.int32(opt._step_count if opt else 0), arg_vals)
         from ..utils.memory import analysis_dict
-        return analysis_dict(lowered.compile().memory_analysis())
+        return analysis_dict(
+            self.lower(*args).compile().memory_analysis())
 
 
 def save(layer, path, input_spec=None, **configs):

@@ -137,10 +137,11 @@ def apply_rope(x: Tensor, cos: Tensor, sin: Tensor, position_offset=0):
             return rope_rotate_values(v, cv, sv)
         return _apply("rope_vec_s", fn_vec_s, (x, cos, sin))
 
-    # use_pallas=False: measured on the v5e (round 3), the XLA rotation
-    # fuses into the surrounding projections and beats the standalone
-    # Pallas kernel by ~7% end-to-end step time; the kernel remains for
-    # explicit use (and is required when fusing rope INTO another kernel).
+    # use_pallas=False: the XLA rotation can fuse into the surrounding
+    # projections, where a standalone Pallas call is a fusion barrier
+    # (a hypothesis — not measured on this code, docs/kernels.md); the
+    # kernel remains for explicit use (and is required when fusing rope
+    # INTO another kernel).
     def fn(v, c, s):
         return rope_values(v, c, s, off, use_pallas=False)
     return _apply("rope", fn, (x, cos, sin))

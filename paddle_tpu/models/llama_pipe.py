@@ -58,8 +58,8 @@ def _layer_values(lp, x, cos, sin, cfg, n_heads, n_kv_heads, psum_axis):
     q = (xn @ lp["wq"].astype(dt)).reshape(b, s, n_heads, hd)
     k = (xn @ lp["wk"].astype(dt)).reshape(b, s, n_kv_heads, hd)
     v = (xn @ lp["wv"].astype(dt)).reshape(b, s, n_kv_heads, hd)
-    # XLA rope (use_pallas=False) fuses into the projections — measured
-    # faster than the standalone Pallas rope kernel on the v5e (round 3)
+    # XLA rope (use_pallas=False) can fuse into the projections (see
+    # models/llama.py apply_rope; not measured on this code)
     q = rope_values(q, cos, sin, use_pallas=False)
     k = rope_values(k, cos, sin, use_pallas=False)
     attn = flash_attention_values(q, k, v, causal=True)

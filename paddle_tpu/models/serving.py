@@ -73,6 +73,7 @@ import contextlib
 import hashlib
 import os
 import time
+import traceback
 from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
@@ -82,6 +83,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.tensor import Tensor
+from ..distributed import mesh as mesh_mod
 from ..autograd import no_grad
 from ..utils.faults import (FaultError, fault_point, fault_value,
                             value_armed)
@@ -89,15 +91,13 @@ from .. import observability as telemetry
 from ..observability import profile as _profile
 from .generation import RequestStatus
 
+_NULL_SCOPE = contextlib.nullcontext()
+
 __all__ = ["ContinuousBatchingEngine", "Request", "RequestStatus",
            "SpecConfig", "QuantServingConfig", "EngineOverloaded",
            "PoolExhausted", "EngineInvariantError", "PayloadCorruption",
            "QuantMismatch", "assemble_payload_kv", "payload_checksums",
            "payload_scale_checksums", "verify_payload"]
-
-# nullcontext is stateless — one shared instance serves every non-TP
-# dispatch (`_tp_scope` sits on the per-decode-step hot path)
-_NULL_SCOPE = contextlib.nullcontext()
 
 
 def assemble_payload_kv(payload: dict):
@@ -762,6 +762,10 @@ class ContinuousBatchingEngine:
         self._clock = clock if clock is not None else time.monotonic
         self.num_timeouts = 0
         self.num_failures = 0
+        # formatted traceback of the latest failure the engine healed
+        # (an isolated admission, a retried decode dispatch): the
+        # one-line `Request.error` names it, this says where it rose
+        self.last_failure: Optional[str] = None
         self.num_preemptions = 0
         self.num_decode_retries = 0
         self._consec_decode_faults = 0
@@ -1436,13 +1440,15 @@ class ContinuousBatchingEngine:
                     # handled=True: a speculative round already
                     # committed tokens and finalizations itself
                     handled = self._decode(finished)
-                except FaultError:
+                except FaultError as e:
                     # transient dispatch fault: it fires BEFORE the
                     # compiled step runs, so slot/page state is
                     # consistent and the next step() simply retries —
                     # bounded so an always-on fault cannot livelock
                     # run()
                     self.num_decode_retries += 1
+                    self.last_failure = "".join(
+                        traceback.format_exception(e))
                     _M_DECODE_RETRIES.inc()
                     self._consec_decode_faults += 1
                     if self._consec_decode_faults \
@@ -2454,6 +2460,7 @@ class ContinuousBatchingEngine:
         """Isolate a failed prefill: finalize THIS request, free the
         slot's partial state, keep admitting everything else."""
         self.num_failures += 1
+        self.last_failure = "".join(traceback.format_exception(exc))
         self._finalize(req, RequestStatus.FAILED,
                        f"{type(exc).__name__}: {exc}", finished)
         self._release_slot(slot, register=False)
@@ -2796,10 +2803,16 @@ class ContinuousBatchingEngine:
 
     def _tp_scope(self):
         """Scope every jit DISPATCH in: trace-time reads inside model
-        code (`llama._tp_repl`'s determinism fences) then see this
-        replica's submesh. A no-op nullcontext without TP."""
+        code (`llama._tp_repl`'s determinism fences, the kernels'
+        `mesh.shard_kernel`) then see this replica's submesh. Without
+        TP the scope hides any global TRAINING mesh (`fleet.init`
+        leaves one set): a one-device engine's kernels must not be
+        split over it."""
         if self._tp is None:
-            return _NULL_SCOPE
+            # the common case — no training mesh set — stays the shared
+            # stateless nullcontext (this sits on the per-step hot path)
+            return _NULL_SCOPE if mesh_mod.get_mesh() is None \
+                else mesh_mod.use_mesh(None)
         return self._tp.scope()
 
     def _view_tp(self, draft: bool = False):

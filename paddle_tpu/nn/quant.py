@@ -7,7 +7,7 @@ rows + «python/paddle/nn/quant/») — TPU-native:
 * W8A8 executes on the MXU's native int8 systolic path: int8×int8 →
   int32 via `lax.dot_general(..., preferred_element_type=int32)`, then
   one fp rescale. This is the int8 MXU mode (datasheet 2x-peak;
-  measured 1.22x vs bf16 on v5e — r5 chip-gate slope timing).
+  its speed against bf16 is not measured on this code).
 * weight-only int8/int4 targets decode (HBM-bandwidth-bound): weights
   live in HBM at 1/2 or 1/4 the bytes and dequantize on the fly into
   the bf16 matmul (XLA fuses the dequant into the dot's operand read).

@@ -89,6 +89,12 @@ _M_JIT_COMPILE_SECONDS = histogram(
     "pdt_jit_compile_seconds",
     "Wall seconds of a program's first invocation — trace + compile + "
     "first execute, the honest cold-start bill.", ("family",))
+_M_JIT_KERNELS = counter(
+    "pdt_jit_mosaic_kernels_total",
+    "Mosaic (Pallas TPU) kernel custom calls in the programs compiled "
+    "through the metered seam, read from each program's lowered text — "
+    "zero for a family means its dispatchers took the XLA reference or "
+    "interpret path.", ("family", "kernel"))
 _M_JIT_CACHE = gauge(
     "pdt_jit_cache_entries",
     "Programs resident in a keyed-LRU jit cache, by family.",
@@ -192,11 +198,16 @@ def compile_timed(fn, family: str, key=None):
     """Wrap a freshly built (never-invoked) ``jax.jit`` callable so its
     FIRST invocation — the one that traces and compiles — is metered:
     `pdt_jit_compiles_total{family}` / `pdt_jit_compile_seconds` under
-    a `jit.compile` span, feeding the retrace-storm window. Later
-    invocations pay one boolean check. The engine's `_jit_lru` /
-    `_jit_singleton` seam routes every cached program through here
-    (pdt-lint PDT012 pins that), so compile observability cannot be
-    bypassed."""
+    a `jit.compile` span, feeding the retrace-storm window. The same
+    call lowers the program once more (the trace is shared with the
+    invocation, so it costs the text dump only) and records which
+    Mosaic kernels it contains: `pdt_jit_mosaic_kernels_total{family,
+    kernel}` and the span's `mosaic_kernels` attr — what says
+    afterwards whether a dispatcher ran its kernel or gave way to its
+    reference. Later invocations pay one boolean check. The engine's
+    `_jit_lru` / `_jit_singleton` seam routes every cached program
+    through here (pdt-lint PDT012 pins that), so compile observability
+    cannot be bypassed."""
     state = [True]
 
     def _first_call_timed(*args, **kwargs):
@@ -207,7 +218,14 @@ def compile_timed(fn, family: str, key=None):
             return fn(*args, **kwargs)
         t0 = time.perf_counter()
         with _trace.span("jit.compile", family=family,
-                         key="" if key is None else str(key)):
+                         key="" if key is None else str(key)) as sp:
+            if hasattr(fn, "lower"):
+                from ..ops import mosaic_kernels
+                kernels = mosaic_kernels(
+                    fn.lower(*args, **kwargs).as_text())
+                sp.attrs["mosaic_kernels"] = kernels
+                for name, n in kernels.items():
+                    _M_JIT_KERNELS.inc(n, family=family, kernel=name)
             out = fn(*args, **kwargs)
         dt = time.perf_counter() - t0
         _M_JIT_COMPILES.inc(family=family)

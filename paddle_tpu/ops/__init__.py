@@ -1,22 +1,56 @@
 """paddle_tpu.ops — TPU kernel library (Pallas/Mosaic), the counterpart of the
 reference's CUDA fused kernels («paddle/phi/kernels/fusion/» [U]).
 Each op ships a Pallas fast path + XLA fallback with identical semantics."""
+import contextlib as _contextlib
 import os as _os
+import re as _re
 
 import jax as _jax
+
+_xla_reference_depth = 0
 
 
 def on_tpu() -> bool:
     """Shared TPU-detection gate for every Pallas fast path.
 
     PDT_FORCE_MOSAIC=1 reports True on any platform: the offline Mosaic
-    lowering tier (tests/test_mosaic_lowering.py) uses it to route every
-    kernel down its non-interpret Pallas path while tracing on CPU, then
-    cross-lowers for TPU via `jax.export(..., platforms=["tpu"])` — the
-    Mosaic pass (BlockSpec/layout validation) runs without a chip."""
+    tier (tests/test_mosaic_lowering.py) uses it to route every kernel
+    down its non-interpret Pallas path while the process runs on CPU,
+    then compiles for a TPU topology without a chip. Inside
+    `xla_reference()` it reports False on any platform."""
+    if _xla_reference_depth:
+        return False
     if _os.environ.get("PDT_FORCE_MOSAIC") == "1":
         return True
     return _jax.devices()[0].platform == "tpu"
+
+
+@_contextlib.contextmanager
+def xla_reference():
+    """Kernels off: whatever is TRACED inside this block takes every
+    dispatcher's XLA reference path, on the chip too — how an oracle
+    (chip_smoke.py's float32 logits check) gets the model's plain
+    forward on a TPU. Trace-time only: a program jitted outside and
+    called inside keeps the kernels it was compiled with."""
+    global _xla_reference_depth
+    _xla_reference_depth += 1
+    try:
+        yield
+    finally:
+        _xla_reference_depth -= 1
+
+
+def mosaic_kernels(program_text: str) -> dict:
+    """{kernel name: count} of the Mosaic custom calls in a lowered
+    (StableHLO) program text — `jit(f).lower(...).as_text()`. An
+    interpret-mode or XLA-reference lowering contains none, so this
+    says afterwards which path a dispatcher took."""
+    out: dict = {}
+    for name in _re.findall(
+            r'@tpu_custom_call\b[^\n]*?kernel_name = "([^"]+)"',
+            program_text):
+        out[name] = out.get(name, 0) + 1
+    return out
 
 
 def mxu_dot(a, b, dims, preferred_element_type=None):

@@ -26,25 +26,17 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 from . import mxu_dot, on_tpu
 from ..core.tensor import Tensor, apply
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
-# Measured on the v5e (block sweep, round 3): per-grid-step overhead — not
-# MXU flops — dominates below ~(512, 512); (1024, 1024) is 3.2x faster fwd
-# and 3.5x faster bwd than (128, 128) at the bench shape (B8 S2048 H16 D64)
-# and beats both the stock jax flash kernel and splash defaults. Blocks are
-# therefore chosen as the largest power-of-two divisor of the sequence
-# length up to MAX_BLOCK, with a VMEM guard for large head dims.
+# Hypothesis, not measured on this code (docs/kernels.md): per-grid-step
+# overhead — not MXU flops — dominates small blocks, so blocks are chosen
+# as the largest power-of-two divisor of the sequence length up to
+# MAX_BLOCK, with a VMEM guard for large head dims.
 MAX_BLOCK = 1024
 NEG_INF = -1e30
 # Per-row scalars (lse, delta) are stored broadcast across a full 128-lane
@@ -90,7 +82,7 @@ def _auto_block(n: int, d: int, other: int = MAX_BLOCK) -> int:
 def _compiler_params(*sem):
     """Mosaic grid semantics ('parallel' dims may be reordered/partitioned;
     the accumulation dim must stay 'arbitrary'). None in interpret mode."""
-    if _interpret() or not _HAS_PLTPU:
+    if _interpret():
         return None
     return pltpu.CompilerParams(dimension_semantics=tuple(sem))
 
@@ -213,6 +205,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, group,
         compiler_params=_compiler_params("parallel", "parallel",
                                          "arbitrary"),
         interpret=_interpret(),
+        name="flash_fwd",
     )(q, k, v)
     return o, lse
 
@@ -351,6 +344,7 @@ def _flash_bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k, group,
         compiler_params=_compiler_params("parallel", "parallel",
                                          "arbitrary"),
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
 
     # dk/dv: grid over kv heads; the innermost axis fuses (group, q-block)
@@ -384,6 +378,7 @@ def _flash_bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k, group,
         compiler_params=_compiler_params("parallel", "parallel",
                                          "arbitrary"),
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -461,14 +456,22 @@ def flash_attention_values(q, k, v, causal=False, scale=None,
         # blocked kernel can't tile this shape — XLA fallback, identical math
         return _attention_xla(q, k, v, float(scale), bool(causal),
                               window_size)
-    group = h // hk
-    # (B, S, H, D) -> (B*H, S, D)
-    qb = jnp.swapaxes(q, 1, 2).reshape(b * h, sq, d)
-    kb = jnp.swapaxes(k, 1, 2).reshape(b * hk, sk, d)
-    vb = jnp.swapaxes(v, 1, 2).reshape(b * hk, sk, d)
-    ob = _flash(qb, kb, vb, float(scale), bool(causal), bq, bk, group,
-                window_size)
-    return jnp.swapaxes(ob.reshape(b, h, sq, d), 1, 2)
+
+    def local(q, k, v):
+        # this device's (batch, heads) shard: (B, S, H, D) -> (B*H, S, D)
+        b, _, h, _ = q.shape
+        hk = k.shape[2]
+        qb = jnp.swapaxes(q, 1, 2).reshape(b * h, sq, d)
+        kb = jnp.swapaxes(k, 1, 2).reshape(b * hk, sk, d)
+        vb = jnp.swapaxes(v, 1, 2).reshape(b * hk, sk, d)
+        ob = _flash(qb, kb, vb, float(scale), bool(causal), bq, bk,
+                    h // hk, window_size)
+        return jnp.swapaxes(ob.reshape(b, h, sq, d), 1, 2)
+
+    # contiguous head shards keep the q-head -> kv-head grouping
+    from ..distributed.mesh import shard_kernel
+    bshd = ("batch", None, "heads", None)
+    return shard_kernel(local, (q, k, v), (bshd, bshd, bshd), bshd)
 
 
 def flash_attention(q: Tensor, k: Tensor, v: Tensor, causal: bool = False,

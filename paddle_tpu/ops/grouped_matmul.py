@@ -28,13 +28,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 from . import mxu_dot, on_tpu
 
@@ -121,7 +115,7 @@ def grouped_matmul_values(lhs, rhs, group_sizes, block_aligned=False):
 def _use_pallas(lhs, rhs, block_aligned):
     m, k = lhs.shape
     n = rhs.shape[2]
-    return (block_aligned and on_tpu() and _HAS_PLTPU
+    return (block_aligned and on_tpu()
             and m % DEFAULT_BLOCK == 0 and k % DEFAULT_BLOCK == 0
             and n % DEFAULT_BLOCK == 0)
 

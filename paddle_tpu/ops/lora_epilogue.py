@@ -44,11 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from . import mxu_dot, on_tpu
 
@@ -112,37 +108,42 @@ def _lora_epilogue_kernel(ids_ref, x_ref, a_ref, b_ref, s_ref, o_ref):
     # one token per program: (1, K) x (K, r) -> (1, r) x (r, N); the
     # scalar-prefetched ids drove the BlockSpec index maps, so a_ref /
     # b_ref already hold THIS token's adapter row
-    h = mxu_dot(x_ref[:].astype(jnp.float32),
+    h = mxu_dot(x_ref[0].astype(jnp.float32),
                 a_ref[0].astype(jnp.float32),
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
     d = mxu_dot(h, b_ref[0].astype(jnp.float32),
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-    o_ref[:] = (d * s_ref[0, 0]).astype(o_ref.dtype)
+    o_ref[0] = (d * s_ref[0]).astype(o_ref.dtype)      # (1, 1) bcast
 
 
 def _lora_epilogue_pallas(x2, a, b, scale, ids, interpret):
     t, k = x2.shape
     r_stack, _, r = a.shape
     n = b.shape[2]
+    # one-row blocks ride a unit middle axis — (T, K) -> (T, 1, K) and
+    # so on — so that every block's last two dims EQUAL the array's
+    # (the Mosaic block rule: a (1, K) block of a (T, K) array has an
+    # undividable sublane; the TPU lowering refuses it)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(t,),
         in_specs=[
-            pl.BlockSpec((1, k), lambda tt, ids_: (tt, 0)),
+            pl.BlockSpec((1, 1, k), lambda tt, ids_: (tt, 0, 0)),
             pl.BlockSpec((1, k, r), lambda tt, ids_: (ids_[tt], 0, 0)),
             pl.BlockSpec((1, r, n), lambda tt, ids_: (ids_[tt], 0, 0)),
-            pl.BlockSpec((1, 1), lambda tt, ids_: (ids_[tt], 0)),
+            pl.BlockSpec((1, 1, 1), lambda tt, ids_: (ids_[tt], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, n), lambda tt, ids_: (tt, 0)),
+        out_specs=pl.BlockSpec((1, 1, n), lambda tt, ids_: (tt, 0, 0)),
     )
     return pl.pallas_call(
         _lora_epilogue_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t, n), x2.dtype),
+        out_shape=jax.ShapeDtypeStruct((t, 1, n), x2.dtype),
         interpret=interpret,
-    )(ids.astype(jnp.int32), x2, a, b, scale[:, None])
+    )(ids.astype(jnp.int32), x2[:, None, :], a, b,
+      scale[:, None, None])[:, 0, :]
 
 
 def lora_epilogue_values(x, a, b, scale, ids, use_kernel=None):

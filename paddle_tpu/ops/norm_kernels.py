@@ -24,11 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
 from . import on_tpu
 from ..core.tensor import Tensor, apply
 
@@ -82,6 +77,7 @@ def _rms_fwd(x2, w, eps, block_rows):
         out_shape=[jax.ShapeDtypeStruct((n, h), x2.dtype),
                    jax.ShapeDtypeStruct((n, LANES), jnp.float32)],
         interpret=_interpret(),
+        name="rms_norm_fwd",
     )(x2, w)
     return o, rstd
 
@@ -115,6 +111,7 @@ def _rms_bwd_rule(eps, block_rows, res, g):
         out_shape=[jax.ShapeDtypeStruct((n, h), x2.dtype),
                    jax.ShapeDtypeStruct((1, h), jnp.float32)],
         interpret=_interpret(),
+        name="rms_norm_bwd",
     )(x2, w, rstd, g)
     return dx, dw_acc[0].astype(w.dtype)
 
@@ -122,18 +119,30 @@ def _rms_bwd_rule(eps, block_rows, res, g):
 _rms.defvjp(_rms_fwd_rule, _rms_bwd_rule)
 
 
+def _row_roles(ndim):
+    """Rows are independent, so they split over whatever the mesh
+    splits the leading (batch, seq) dimensions over; the normalized
+    dimension stays whole."""
+    return (("batch", "seq") + (None,) * ndim)[:ndim - 1] + (None,)
+
+
 def rms_norm_values(x, w, eps=1e-6, block_rows=BLOCK_ROWS):
-    shape = x.shape
-    h = shape[-1]
-    x2 = x.reshape(-1, h)
-    n = x2.shape[0]
-    br = min(block_rows, n)
-    if n % br:  # fall back to XLA for ragged row counts
-        xf = x.astype(jnp.float32)
-        ms = jnp.mean(jnp.square(xf), -1, keepdims=True)
-        return (xf * jax.lax.rsqrt(ms + eps) * w.astype(jnp.float32)) \
-            .astype(x.dtype).reshape(shape)
-    return _rms(x2, w, float(eps), br).reshape(shape)
+    def local(x, w):
+        shape = x.shape
+        h = shape[-1]
+        x2 = x.reshape(-1, h)
+        n = x2.shape[0]
+        br = min(block_rows, n)
+        if n % br:  # fall back to XLA for ragged row counts
+            xf = x.astype(jnp.float32)
+            ms = jnp.mean(jnp.square(xf), -1, keepdims=True)
+            return (xf * jax.lax.rsqrt(ms + eps)
+                    * w.astype(jnp.float32)).astype(x.dtype)
+        return _rms(x2, w, float(eps), br).reshape(shape)
+
+    from ..distributed.mesh import shard_kernel
+    roles = _row_roles(x.ndim)
+    return shard_kernel(local, (x, w), (roles, (None,)), roles)
 
 
 def rms_norm(x: Tensor, weight: Tensor, epsilon: float = 1e-6) -> Tensor:
@@ -236,19 +245,25 @@ _ln.defvjp(_ln_fwd_rule, _ln_bwd_rule)
 
 
 def layer_norm_values(x, w, b, eps=1e-5, block_rows=BLOCK_ROWS):
-    shape = x.shape
-    h = shape[-1]
-    x2 = x.reshape(-1, h)
-    n = x2.shape[0]
-    br = min(block_rows, n)
-    if n % br:
-        xf = x.astype(jnp.float32)
-        mu = jnp.mean(xf, -1, keepdims=True)
-        var = jnp.mean(jnp.square(xf - mu), -1, keepdims=True)
-        return ((xf - mu) * jax.lax.rsqrt(var + eps)
-                * w.astype(jnp.float32) + b.astype(jnp.float32)) \
-            .astype(x.dtype).reshape(shape)
-    return _ln(x2, w, b, float(eps), br).reshape(shape)
+    def local(x, w, b):
+        shape = x.shape
+        h = shape[-1]
+        x2 = x.reshape(-1, h)
+        n = x2.shape[0]
+        br = min(block_rows, n)
+        if n % br:
+            xf = x.astype(jnp.float32)
+            mu = jnp.mean(xf, -1, keepdims=True)
+            var = jnp.mean(jnp.square(xf - mu), -1, keepdims=True)
+            return ((xf - mu) * jax.lax.rsqrt(var + eps)
+                    * w.astype(jnp.float32)
+                    + b.astype(jnp.float32)).astype(x.dtype)
+        return _ln(x2, w, b, float(eps), br).reshape(shape)
+
+    from ..distributed.mesh import shard_kernel
+    roles = _row_roles(x.ndim)
+    return shard_kernel(local, (x, w, b), (roles, (None,), (None,)),
+                        roles)
 
 
 def layer_norm(x: Tensor, weight: Tensor, bias: Tensor,

@@ -47,11 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from . import mxu_dot, on_tpu
 from ..core.tensor import Tensor, apply
@@ -465,6 +461,7 @@ def _ragged_pallas(q, k_pages, v_pages, query_start, query_len,
         out_shape=jax.ShapeDtypeStruct((hk, nqb, block_q * g, d),
                                        out_dtype),
         interpret=interpret,
+        name="ragged_paged_attention",
     )(qb_seq, query_start.astype(jnp.int32),
       query_len.astype(jnp.int32), context_len.astype(jnp.int32),
       block_tables.astype(jnp.int32), *inputs)
@@ -485,10 +482,7 @@ def _ragged_tp_shard_map(q, k_pages, v_pages, query_start, query_len,
     logical page = tp local shards). The kernel body never learns
     about the mesh, which is what keeps its interpret-mode oracle
     parity meaningful under TP."""
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     mesh, axis = tp
     P = jax.sharding.PartitionSpec
     quantized = k_scale is not None
@@ -515,10 +509,10 @@ def _ragged_tp_shard_map(q, k_pages, v_pages, query_start, query_len,
         local, mesh=mesh,
         in_specs=in_specs,
         out_specs=P(None, axis, None),
-        # pallas_call has no replication rule; the specs above are
-        # exact (descriptors replicated in, heads sharded out), so
-        # skipping the rep check loses nothing
-        check_rep=False,
+        # pallas_call cannot annotate varying-mesh-axes on its outputs;
+        # the specs above are exact (descriptors replicated in, heads
+        # sharded out), so skipping the vma check loses nothing
+        check_vma=False,
     )(*args)
 
 

@@ -199,7 +199,7 @@ class QuantedLinear(Layer):
         mode='dequant' — weights stored int8, dequantized into the fp
         matmul (weight-only memory win). mode='w8a8' — activations
         dynamically quantized per call and the matmul runs on the MXU's
-        int8 path (datasheet 2x-peak; 1.22x measured on v5e, r5 chip gate;
+        int8 path (datasheet 2x-peak, not measured on this code;
         ≙ the cuBLASLt int8 fused linear)."""
         if mode not in ("dequant", "w8a8"):
             raise ValueError(f"unknown convert mode {mode!r}")

@@ -60,6 +60,7 @@ consume visits); ``router.health`` fires inside every health probe.
 from __future__ import annotations
 
 import random
+import traceback
 from typing import Callable, List, Optional
 
 from .. import observability as telemetry
@@ -215,6 +216,7 @@ class ReplicaHandle:
         self.state = ReplicaState.HEALTHY
         self.consecutive_failures = 0
         self.last_error: Optional[str] = None
+        self.last_traceback: Optional[str] = None
         self.death_reason: Optional[str] = None
         self.restarts = 0                  # completed restarts
         self.restart_attempt = 0           # backoff exponent (resets on
@@ -400,6 +402,10 @@ class ReplicaHandle:
         the failure killed the replica (caller must fail over)."""
         self.consecutive_failures += 1
         self.last_error = f"{type(error).__name__}: {error}"
+        # the formatted text, not the exception: its frames would pin
+        # the dead engine (and its page pools) past the discard
+        self.last_traceback = "".join(
+            traceback.format_exception(error))
         if self.state in ReplicaState.DOWN:
             return False
         if self.consecutive_failures >= self.dead_after:

@@ -45,8 +45,12 @@ def token_source(args, vocab_size: int):
 
 def run_train(step_fn, loader, steps: int, log_every: int) -> float:
     """Drive `steps` train steps from an (endlessly cycled) loader;
-    returns the final loss."""
+    returns the final loss. Places the persistent compile cache first
+    (`JAX_COMPILATION_CACHE_DIR` from outside wins), so a recipe run
+    again does not compile its step again."""
     import itertools
+    from paddle_tpu.device import enable_compile_cache
+    enable_compile_cache()
     it = itertools.cycle(loader)
     loss = float("nan")
     t0 = time.perf_counter()
