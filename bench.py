@@ -117,21 +117,22 @@ def _assert_steady_state(where, snap, warm_snap=None):
 
 
 def _profile_detail(snap, warm_snap, gaps=None):
-    """`detail.profile`: decode-round decomposition medians over the
-    timed window (warm-phase buckets diffed out) + the top-3 dispatch
-    gaps from a sampled round, straight off `pdt_profile_*`."""
+    """`detail.profile`: the step's span self-time medians over the
+    timed window (warm-phase buckets diffed out), straight off
+    `pdt_span_self_seconds`, + the top-3 dispatch gaps from a sampled
+    round."""
     comp = {}
     cur = snap.get("histograms", {}).get(
-        "pdt_profile_round_seconds", {})
+        "pdt_span_self_seconds", {})
     warm = (warm_snap or {}).get("histograms", {}).get(
-        "pdt_profile_round_seconds", {})
+        "pdt_span_self_seconds", {})
     for labels, series in cur.items():
         name = labels.split('"')[1] if '"' in labels else labels
         q = _hist_quantiles(_hist_diff(series, warm.get(labels)),
                             qs=(0.5,))
         if q:
             comp[name] = q["p50"]
-    out = {"component_median_s": comp}
+    out = {"span_self_median_s": comp}
     if gaps:
         out["top_gaps"] = [
             {"op_pair": g["op_pair"], "gap_s": round(g["gap_s"], 6)}

@@ -28,7 +28,8 @@ _NON_TRACE_SUFFIXES = {"py", "md", "json", "jsonl", "prom", "txt",
                        "cc", "log", "tmp", "hb"}
 
 _REGISTRATION_TAILS = ("counter", "gauge", "histogram")
-_SPAN_TAILS = ("span", "event", "telemetry_span", "telemetry_event")
+_SPAN_TAILS = ("span", "event", "interval", "telemetry_span",
+               "telemetry_event")
 
 
 def collect_instruments(project: Project, scope, exclude,

@@ -201,6 +201,15 @@ _M_RUNNING = telemetry.gauge(
 _M_ADMISSIONS = telemetry.counter(
     "pdt_serving_admissions_total",
     "Requests admitted into a slot (prefill dispatched successfully).")
+_M_QUEUE_WAIT = telemetry.histogram(
+    "pdt_serving_queue_wait_seconds",
+    "Wait for a slot: enqueue (or requeue after a preemption) to the "
+    "claim of a slot, engine clock. One observation a claim.")
+_M_PREFILL_ROWS = telemetry.counter(
+    "pdt_serving_prefill_rows_total",
+    "Rows the ragged admission programs were dispatched with, by kind: "
+    "token = a prompt's real tokens, pad = the rest of the padded "
+    "token axis (block_q alignment and the padding grid).", ("kind",))
 _M_REJECTIONS = telemetry.counter(
     "pdt_serving_rejections_total",
     "add_request refusals by reason.", ("reason",))
@@ -317,6 +326,43 @@ _M_LORA_EVICTIONS = telemetry.counter(
     "pdt_lora_evictions_total",
     "Adapter rows evicted from an engine's stacks (evict_adapter "
     "commits; refusals for in-flight use do not count).")
+
+
+# what each part of a keyed family's program key is, as one letter of
+# the program's name: `pdt_ragged_t512` is the admission program of 512
+# padded tokens. The (t_pad, bound) families leave the page bound out:
+# it trims the XLA fallback's gather and does not shape the program on
+# the kernel path, and equal programs under one name are ONE entry of
+# the persistent compile cache (with the bound in the name a cold
+# set-up compiled every bound apart: +40 s, PERF.md PR 25).
+_PROGRAM_KEY_LETTERS = {
+    "ragged": "t", "draft": "t", "verify": "t",  # (t_pad, bound)
+    "suffix": "sb",                              # (shared_len, bucket)
+    "prefill": "b", "scatter": "b",              # bucket
+    "install": "n"}                              # pages
+
+
+def _name_program(jitted, family: str, key=None):
+    """Give a freshly built, never-called program a stable name from
+    its family and key: `pdt_decode`, `pdt_ragged_t512`. JAX reads
+    the wrapped function's `__name__` when it first traces it, and the
+    HLO module is `jit_<name>` — what the profiler's `XLA Modules` line
+    shows, so device time can be split by program (every builder's
+    local function is called `run` otherwise). The seam is the one
+    place that knows family and key; no builder names itself. The
+    name is part of the persistent compile cache's key."""
+    fn = getattr(jitted, "__wrapped__", None)
+    if fn is None:
+        return jitted
+    name = f"pdt_{family}"
+    if key is not None:
+        parts = key if isinstance(key, tuple) else (key,)
+        letters = _PROGRAM_KEY_LETTERS.get(family, "k" * len(parts))
+        for letter, part in zip(letters, parts):
+            name += f"_{letter}" + "".join(
+                c if c.isalnum() else "_" for c in str(part))
+    fn.__name__ = fn.__qualname__ = name
+    return jitted
 
 
 class EngineOverloaded(RuntimeError):
@@ -466,7 +512,12 @@ class Request:
     enqueue_time: float = 0.0
     preemptions: int = 0
     error: Optional[str] = None
-    first_token_time: Optional[float] = None  # engine clock; TTFT/TPOT
+    # engine clock, stamped whether or not telemetry is on: the claim
+    # of a slot (the latest one, after a preemption) and the first
+    # token. TTFT = first_token_time - arrival_time; its queueing part
+    # is the `serving.queue_wait` record of each claim.
+    admit_time: Optional[float] = None
+    first_token_time: Optional[float] = None
     arrival_time: float = 0.0      # original add_request tick: TTFT base
     # (enqueue_time restarts on requeue — it feeds max_queue_time)
     # stable caller-scoped identity: `rid` is engine-local and restarts
@@ -1414,84 +1465,77 @@ class ContinuousBatchingEngine:
         dispatch — so every host-visible transition (deadline
         finalization, slot release, re-admission) acts on committed
         token state exactly like the synchronous loop would."""
-        finished = self._finished_backlog
-        self._finished_backlog = []
-        prof = telemetry.enabled()
-        try:
-            if self._pending and self._harvest_due():
-                self._harvest_pending(finished)
-            # pdt-lint: disable=PDT001 decode-round decomposition is
-            # REAL wall (profile.py reconciles the components against
-            # the measured round wall) — a fake clock would fabricate
-            # the dispatch-gap attribution
-            p0 = time.perf_counter() if prof else 0.0
-            finished += self._expire()
-            finished += self._admit()
-            active = [i for i, r in enumerate(self._slot_req)
-                      if r is not None]
-            if prof:
-                # pdt-lint: disable=PDT001 same real-wall measurement
-                _profile.note_round("host", time.perf_counter() - p0)
-            if active:
-                try:
-                    # _decode appends starvation-guard finalizations
-                    # into `finished` BEFORE its dispatch, so they
-                    # survive an injected dispatch fault below.
-                    # handled=True: a speculative round already
-                    # committed tokens and finalizations itself
-                    handled = self._decode(finished)
-                except FaultError as e:
-                    # transient dispatch fault: it fires BEFORE the
-                    # compiled step runs, so slot/page state is
-                    # consistent and the next step() simply retries —
-                    # bounded so an always-on fault cannot livelock
-                    # run()
-                    self.num_decode_retries += 1
-                    self.last_failure = "".join(
-                        traceback.format_exception(e))
-                    _M_DECODE_RETRIES.inc()
-                    self._consec_decode_faults += 1
-                    if self._consec_decode_faults \
-                            > self.max_decode_retries:
-                        raise
-                    if self._invariants_enabled():
-                        self.check_invariants()
-                    self._update_telemetry_gauges()
-                    return finished
-                self._consec_decode_faults = 0
-                # pdt-lint: disable=PDT001 same real-wall decomposition
-                c0 = time.perf_counter() if prof else 0.0
-                for i in (() if handled else active):
-                    r = self._slot_req[i]
-                    if r is None:
-                        continue    # preempted/finalized during decode
-                    tok = int(self._tok[i])
-                    r.output.append(tok)
-                    hit_eos = self.eos is not None and tok == self.eos
-                    if hit_eos or len(r.output) >= r.max_new_tokens \
-                            or int(self._pos[i]) >= self.S - 1:
-                        self._finalize(r, RequestStatus.FINISHED, None,
-                                       finished)
-                        self._release_slot(i)
-                if prof and not handled:
-                    # pdt-lint: disable=PDT001 same real-wall measure
-                    hv = time.perf_counter() - c0
-                    _profile.note_round("harvest", hv)
-        except BaseException:
-            # ANY escaping error: requests already finalized this step
-            # must not be lost in the raise — the next step() (if the
-            # caller keeps going) delivers them
-            self._finished_backlog = finished
-            raise
-        # pdt-lint: disable=PDT001 same real-wall decomposition
-        p1 = time.perf_counter() if prof else 0.0
-        if self._invariants_enabled():
-            self.check_invariants()
-        self._update_telemetry_gauges()
-        if prof:
-            # pdt-lint: disable=PDT001 same real-wall measurement
-            _profile.note_round("host", time.perf_counter() - p1)
-        return finished
+        # the root of an engine step's span tree (docs/observability.md):
+        # its self time is expiry, the gauges and the glue between
+        # `serving.admit`, `serving.decode` and `serving.commit`
+        with telemetry.span("serving.step"):
+            finished = self._finished_backlog
+            self._finished_backlog = []
+            try:
+                if self._pending and self._harvest_due():
+                    self._harvest_pending(finished)
+                finished += self._expire()
+                with telemetry.span("serving.admit"):
+                    finished += self._admit()
+                active = [i for i, r in enumerate(self._slot_req)
+                          if r is not None]
+                if active:
+                    try:
+                        # _decode appends starvation-guard finalizations
+                        # into `finished` BEFORE its dispatch, so they
+                        # survive an injected dispatch fault below.
+                        # handled=True: a speculative round already
+                        # committed tokens and finalizations itself
+                        with telemetry.span("serving.decode"):
+                            handled = self._decode(finished)
+                    except FaultError as e:
+                        # transient dispatch fault: it fires BEFORE the
+                        # compiled step runs, so slot/page state is
+                        # consistent and the next step() simply retries —
+                        # bounded so an always-on fault cannot livelock
+                        # run()
+                        self.num_decode_retries += 1
+                        self.last_failure = "".join(
+                            traceback.format_exception(e))
+                        _M_DECODE_RETRIES.inc()
+                        self._consec_decode_faults += 1
+                        if self._consec_decode_faults \
+                                > self.max_decode_retries:
+                            raise
+                        if self._invariants_enabled():
+                            self.check_invariants()
+                        self._update_telemetry_gauges()
+                        return finished
+                    self._consec_decode_faults = 0
+                    if not handled:
+                        with telemetry.span("serving.commit"):
+                            self._commit(active, finished)
+            except BaseException:
+                # ANY escaping error: requests already finalized this step
+                # must not be lost in the raise — the next step() (if the
+                # caller keeps going) delivers them
+                self._finished_backlog = finished
+                raise
+            if self._invariants_enabled():
+                self.check_invariants()
+            self._update_telemetry_gauges()
+            return finished
+
+    def _commit(self, active, finished: List[Request]):
+        """The synchronous step's commit loop: append each active
+        slot's decoded token, finalize and release what ended."""
+        for i in active:
+            r = self._slot_req[i]
+            if r is None:
+                continue    # preempted/finalized during decode
+            tok = int(self._tok[i])
+            r.output.append(tok)
+            hit_eos = self.eos is not None and tok == self.eos
+            if hit_eos or len(r.output) >= r.max_new_tokens \
+                    or int(self._pos[i]) >= self.S - 1:
+                self._finalize(r, RequestStatus.FINISHED, None,
+                               finished)
+                self._release_slot(i)
 
     def _update_telemetry_gauges(self):
         """Refresh the point-in-time gauges once per step tick (queue
@@ -1771,7 +1815,7 @@ class ContinuousBatchingEngine:
                       output=list(payload["output"]),
                       status=RequestStatus.RUNNING,
                       deadline=None if budget is None else now + budget,
-                      enqueue_time=now, arrival_time=now,
+                      enqueue_time=now, arrival_time=now, admit_time=now,
                       preemptions=int(payload.get("preemptions", 0)),
                       first_token_time=None
                       if payload.get("first_token_age") is None
@@ -2084,7 +2128,8 @@ class ContinuousBatchingEngine:
         EngineInvariantError listing every violation."""
         if self.layout != "paged":
             return
-        with _M_INVARIANT_SECONDS.time():
+        with telemetry.span("serving.invariants"), \
+                _M_INVARIANT_SECONDS.time():
             self._check_invariants_paged()
 
     def _check_invariants_paged(self):
@@ -2444,6 +2489,16 @@ class ContinuousBatchingEngine:
         self._slot_adapter[slot] = self._adapter_row(req)
         self._slot_seq[slot] = self._admit_seq
         self._admit_seq += 1
+        # where the request stops waiting: one record a claim (a
+        # preempted request that queued again gets another), so TTFT
+        # splits into queue wait and prefill
+        req.admit_time = self._clock()
+        if telemetry.enabled():
+            wait = req.admit_time - req.enqueue_time
+            _M_QUEUE_WAIT.observe(wait)
+            telemetry.interval("serving.queue_wait", wait, rid=req.rid,
+                               request_id=req.request_id,
+                               preemptions=req.preemptions)
         return slot, req, prompt, shared
 
     def _admission_pool_exhausted(self, slot, req, free, finished):
@@ -2549,16 +2604,7 @@ class ContinuousBatchingEngine:
             self._pos[slot] = p_len
             self._tok[slot] = int(tok)
             req.output.append(int(tok))
-            _M_ADMISSIONS.inc()
-            if telemetry.enabled() and req.first_token_time is None:
-                # once per request: a preempted request's re-admission
-                # must not re-observe TTFT
-                req.first_token_time = self._clock()
-                ttft = req.first_token_time - req.arrival_time
-                _M_TTFT.observe(ttft, exemplar=req.request_id)
-                telemetry.event("serving.first_token", rid=req.rid,
-                                request_id=req.request_id,
-                                ttft_s=ttft)
+            self._note_admitted(req)
             if (self.eos is not None and int(tok) == self.eos) \
                     or len(req.output) >= req.max_new_tokens:
                 self._finalize(req, RequestStatus.FINISHED, None,
@@ -2566,6 +2612,20 @@ class ContinuousBatchingEngine:
                 self._release_slot(slot)
                 free.insert(0, slot)
         return finished
+
+    def _note_admitted(self, req: Request):
+        """An admission's prefill gave the request a token. The first
+        one is stamped once per request (a preempted request's
+        re-admission must not re-observe TTFT), telemetry on or off."""
+        _M_ADMISSIONS.inc()
+        if req.first_token_time is not None:
+            return
+        req.first_token_time = self._clock()
+        if telemetry.enabled():
+            ttft = req.first_token_time - req.arrival_time
+            _M_TTFT.observe(ttft, exemplar=req.request_id)
+            telemetry.event("serving.first_token", rid=req.rid,
+                            request_id=req.request_id, ttft_s=ttft)
 
     def _admit_shared(self, slot: int, req: Request, prompt: List[int],
                       pages: List[int]):
@@ -2716,8 +2776,11 @@ class ContinuousBatchingEngine:
             int(pk["context_len"][p["slot"]]) for p in batch)
         rids = ([p["req"].request_id for p in batch]
                 if telemetry.enabled() else ())
-        with telemetry.span("serving.ragged_prefill",
-                            tokens=int(pk["tokens"]),
+        # useful work against rows dispatched (`prefill_pad_share`)
+        tokens = int(pk["tokens"])
+        _M_PREFILL_ROWS.inc(tokens, kind="token")
+        _M_PREFILL_ROWS.inc(int(t_pad) - tokens, kind="pad")
+        with telemetry.span("serving.ragged_prefill", tokens=tokens,
                             t_pad=int(t_pad), rids=rids), \
                 self._tp_scope():
             jit = self._get_ragged_prefill(t_pad, bound)
@@ -2754,13 +2817,7 @@ class ContinuousBatchingEngine:
             tok = int(nxt[s])
             self._tok[s] = tok
             req.output.append(tok)
-            _M_ADMISSIONS.inc()
-            if telemetry.enabled() and req.first_token_time is None:
-                req.first_token_time = self._clock()
-                ttft = req.first_token_time - req.arrival_time
-                _M_TTFT.observe(ttft, exemplar=req.request_id)
-                telemetry.event("serving.first_token", rid=req.rid,
-                                request_id=req.request_id, ttft_s=ttft)
+            self._note_admitted(req)
             if (self.eos is not None and tok == self.eos) \
                     or len(req.output) >= req.max_new_tokens:
                 self._finalize(req, RequestStatus.FINISHED, None,
@@ -2844,7 +2901,8 @@ class ContinuousBatchingEngine:
         bypassed."""
         jit = cache.get(key)
         if jit is None:
-            jit = _profile.compile_timed(build(), family, key)
+            jit = _profile.compile_timed(
+                _name_program(build(), family, key), family, key)
             cache[key] = jit
             evicted = 0
             while len(cache) > (cap or self._max_prefill):
@@ -2860,7 +2918,8 @@ class ContinuousBatchingEngine:
         built once per engine lifetime (decode, chunk, sample, insert,
         draft_scan), no key space, no cache — but the same
         `compile_timed` first-call metering as `_jit_lru` misses."""
-        return _profile.compile_timed(build(), family)
+        return _profile.compile_timed(
+            _name_program(build(), family), family)
 
     def _pages_bound(self, contexts) -> int:
         """Power-of-two-bucketed static gather trim for a dispatch
@@ -3377,10 +3436,6 @@ class ContinuousBatchingEngine:
         round still makes progress, the REQUEST never fails."""
         if self._spec is not None and self._spec_decode(finished):
             return True
-        # pdt-lint: disable=PDT001 decode-round decomposition is REAL
-        # wall — the pre-dispatch host prep (slot growth, window
-        # reclaim, block-table upload) is the "host" component
-        d0 = time.perf_counter() if telemetry.enabled() else 0.0
         if self._decode_jit is None:
             # ragged mode: decode is the SAME ragged program at
             # block_q=1 — B sequences of one query token each. The
@@ -3451,8 +3506,6 @@ class ContinuousBatchingEngine:
             # (tokens/sec derives from it) — a fake clock here would
             # fabricate hardware throughput, not make tests exact
             t0 = time.perf_counter()
-            if telemetry.enabled():
-                _profile.note_round("host", t0 - d0)
             lg_rows = None
             if self.layout == "paged" and self.attn_impl == "ragged":
                 bidx = self._decode_idx
@@ -3488,24 +3541,22 @@ class ContinuousBatchingEngine:
                 self._caches = new_kv
             # pdt-lint: disable=PDT001 same real-wall measurement as t0
             t1 = time.perf_counter()
-            if telemetry.enabled():
-                _M_DECODE_DISPATCH.observe(t1 - t0)
-                _profile.note_round("dispatch", t1 - t0)
+            _M_DECODE_DISPATCH.observe(t1 - t0)
             if self.harvest_every > 1:
                 # deferred-harvest path: the token vector stays on
                 # device; defer the sync, commits, and sentry checks to
                 # the window's one batched harvest. The stride tick
                 # happens NOW (per dispatch) so the scan schedule
                 # matches the synchronous loop step for step.
-                scan, sc = False, 0.0
+                scan = False
                 if self._sentry is not None:
-                    # pdt-lint: disable=PDT001 sentry cost is REAL wall
-                    s0 = time.perf_counter()
-                    scan = self._sentry.step_tick()
-                    # pdt-lint: disable=PDT001 same measurement
-                    sc = time.perf_counter() - s0
-                    self._sentry.note_cost(sc)
-                    _profile.note_round("sentry", sc)
+                    with telemetry.span("serving.sentry"):
+                        # pdt-lint: disable=PDT001 sentry cost is REAL
+                        # wall (the bench bar divides it by step time)
+                        s0 = time.perf_counter()
+                        scan = self._sentry.step_tick()
+                        # pdt-lint: disable=PDT001 same measurement
+                        self._sentry.note_cost(time.perf_counter() - s0)
                 self._corrupt_kv_site()
                 act = tuple(i for i, r in enumerate(self._slot_req)
                             if r is not None)
@@ -3521,11 +3572,6 @@ class ContinuousBatchingEngine:
                     "pos": self._pos.copy()})
                 self._tok_dev = nxt
                 self._window_wall += t1 - t0
-                if telemetry.enabled():
-                    # pdt-lint: disable=PDT001 same real-wall
-                    # decomposition (sentry tick already attributed)
-                    tail = time.perf_counter() - t1 - sc
-                    _profile.note_round("host", tail)
                 return True
             # synchronous path (harvest_every=1, today's loop): the
             # D2H copy is the step's sync point — dispatch alone
@@ -3534,47 +3580,36 @@ class ContinuousBatchingEngine:
             # pdt-lint: disable=PDT001 same real-wall measurement
             dt = time.perf_counter() - t0
         if telemetry.enabled():
+            # the D2H sync wait is the device-side remainder of the
+            # step (dispatch returned before the device finished)
             _M_HARVEST.observe(dt - (t1 - t0))
-            # the D2H sync wait IS the device-side remainder of the
-            # round (dispatch returned before the device finished)
-            _profile.note_round("device", dt - (t1 - t0))
             _M_DECODE_STEP.observe(dt)
             _M_DECODE_TOKENS.inc(n_active)
             if dt > 0:
                 _M_TOKENS_PER_SEC.set(n_active / dt)
-            # pdt-lint: disable=PDT001 same real-wall decomposition:
-            # t0 + dt is the clock reading taken above, so this window
-            # also covers the decode_step span exit
-            _profile.note_round("host", time.perf_counter() - t0 - dt)
         # gray-failure corrupt site + sentry checks, AFTER the timed
         # window so decode_step_seconds stays comparable across
         # sentry-on/off engines (the sentry's own cost rides
         # sentry.spent — the bench's in-situ overhead numerator)
         self._corrupt_kv_site()
         if self._sentry is not None:
-            # pdt-lint: disable=PDT001 sentry cost is a REAL-wall
-            # hardware-honesty number (the <=3% bench bar divides it
-            # by real step time) — a fake clock would fabricate it
-            s0 = time.perf_counter()
-            scan = self._sentry.step_tick()
-            act = [i for i, r in enumerate(self._slot_req)
-                   if r is not None]
-            # pdt-lint: disable=PDT001 same real-wall measurement
-            sc = time.perf_counter() - s0
-            self._sentry.note_cost(sc)
-            _profile.note_round("sentry", sc)
-            self._harvest_sentry(nxt, lg_rows if scan else None, act,
-                                 lag=0)
-        # pdt-lint: disable=PDT001 same real-wall decomposition (the
-        # sentry block above attributes itself to "sentry")
-        e0 = time.perf_counter() if telemetry.enabled() else 0.0
+            with telemetry.span("serving.sentry"):
+                # pdt-lint: disable=PDT001 sentry cost is a REAL-wall
+                # hardware-honesty number (the <=3% bench bar divides
+                # it by real step time) — a fake clock would fabricate
+                # it
+                s0 = time.perf_counter()
+                scan = self._sentry.step_tick()
+                act = [i for i, r in enumerate(self._slot_req)
+                       if r is not None]
+                # pdt-lint: disable=PDT001 same real-wall measurement
+                self._sentry.note_cost(time.perf_counter() - s0)
+                self._harvest_sentry(nxt, lg_rows if scan else None,
+                                     act, lag=0)
         for i, r in enumerate(self._slot_req):
             if r is not None:
                 self._tok[i] = nxt[i]
                 self._pos[i] += 1
-        if telemetry.enabled():
-            # pdt-lint: disable=PDT001 same real-wall measurement
-            _profile.note_round("host", time.perf_counter() - e0)
         return False
 
     # -- pipelined harvest seam (harvest_every=k, ISSUE 18) -------------
@@ -3587,15 +3622,14 @@ class ContinuousBatchingEngine:
         """The k=1 synchronous harvest: ONE dispatch's D2H token sync."""
         return np.asarray(nxt)
 
-    def _harvest_sentry(self, nxt, lg_rows, act, lag: int) -> float:
+    def _harvest_sentry(self, nxt, lg_rows, act, lag: int):
         """Sentry checks over one harvested dispatch: the in-vocab
         token check, the every-Nth logit scan (pulled HERE — at k>1
         the pull rides the harvest, bounding detection latency at k
         steps, which `note_lag` meters), and the `serving.logits`
         VALUE fault site over the ACTIVE rows the scan inspects (the
         NaN-poisoned-logits drill; an inactive slot's garbage row is
-        not a harvest). Returns its total wall so the caller's
-        profiler window can attribute it to "sentry", not itself."""
+        not a harvest). Callers wrap it in a `serving.sentry` span."""
         # pdt-lint: disable=PDT001 sentry cost is REAL wall (bench bar)
         s0 = time.perf_counter()
         lg_np = None
@@ -3614,10 +3648,6 @@ class ContinuousBatchingEngine:
             note_lag(lag)
         if lg_np is not None:
             self._sentry.observe_logits(lg_np)
-        # pdt-lint: disable=PDT001 same real-wall measurement
-        elapsed = time.perf_counter() - s0
-        _profile.note_round("sentry", elapsed)
-        return elapsed
 
     def _harvest_due(self) -> bool:
         """Must the deferred window be harvested BEFORE this step's
@@ -3668,16 +3698,22 @@ class ContinuousBatchingEngine:
             stacked = np.asarray(jnp.stack([e["nxt"] for e in entries]))
             # pdt-lint: disable=PDT001 same real-wall measurement
             harvest_dt = time.perf_counter() - t0
+        # the window's one batched D2H sync is where the deferred
+        # rounds' device time surfaces on the host clock
+        _M_HARVEST.observe(harvest_dt)
+        with telemetry.span("serving.commit", window=len(entries)):
+            n_committed = self._commit_window(entries, stacked,
+                                              finished)
         if telemetry.enabled():
-            _M_HARVEST.observe(harvest_dt)
-            # the window's one batched D2H sync is where the deferred
-            # rounds' device time surfaces on the host clock
-            _profile.note_round("device", harvest_dt)
-        # pdt-lint: disable=PDT001 decode-round decomposition is REAL
-        # wall (profile.py reconciles components against the measured
-        # round wall) — a fake clock would fabricate attribution
-        c0 = time.perf_counter() if telemetry.enabled() else 0.0
-        sentry_s = 0.0
+            _M_DECODE_TOKENS.inc(n_committed)
+            wall = self._window_wall + harvest_dt
+            if wall > 0:
+                _M_TOKENS_PER_SEC.set(n_committed / wall)
+        self._window_wall = 0.0
+
+    def _commit_window(self, entries, stacked, finished) -> int:
+        """The deferred window's commits, per dispatch in dispatch
+        order; returns the tokens committed."""
         n = len(entries)
         n_committed = 0
         done_slots: set = set()
@@ -3686,9 +3722,10 @@ class ContinuousBatchingEngine:
             nxt = stacked[j]
             if self._sentry is not None:
                 act = [i for i in e["act"] if i not in done_slots]
-                sentry_s += self._harvest_sentry(
-                    nxt, e["lg"] if e["scan"] else None,
-                    act, lag=n - 1 - j)
+                with telemetry.span("serving.sentry"):
+                    self._harvest_sentry(
+                        nxt, e["lg"] if e["scan"] else None,
+                        act, lag=n - 1 - j)
             for i in e["act"]:
                 if i in done_slots:
                     continue        # finalized earlier in this window
@@ -3713,17 +3750,7 @@ class ContinuousBatchingEngine:
         for r in self._slot_req:
             if r is not None:
                 r.device_len = len(r.output)    # staleness resync
-        if telemetry.enabled():
-            # pdt-lint: disable=PDT001 same real-wall decomposition
-            # (in-window sentry pulls are attributed to "sentry" by
-            # _harvest_sentry, so they are excluded here)
-            hv = time.perf_counter() - c0 - sentry_s
-            _profile.note_round("harvest", hv)
-            _M_DECODE_TOKENS.inc(n_committed)
-            wall = self._window_wall + harvest_dt
-            if wall > 0:
-                _M_TOKENS_PER_SEC.set(n_committed / wall)
-        self._window_wall = 0.0
+        return n_committed
 
     def quiesce(self) -> int:
         """Drain the pipelined-decode window NOW: harvest every

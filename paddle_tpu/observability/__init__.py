@@ -15,7 +15,8 @@ Three modules:
 * `registry` — typed Counter/Gauge/Histogram instruments (labels,
   fixed bucket boundaries, monotonic-clock timers) behind the global
   `REGISTRY`.
-* `trace` — nestable `span()` / point `event()` -> structured JSONL
+* `trace` — nestable `span()` (each with its self time) / point
+  `event()` / unscoped `interval()` -> structured JSONL
   into a bounded ring buffer + optional file sink
   (`PDT_TELEMETRY_TRACE_FILE=`), interoperating with
   `profiler.RecordEvent` so spans land in the XLA timeline too. PLUS
@@ -31,14 +32,15 @@ Three modules:
   windowed reservoir) and the `SloMonitor` grading declarative
   objectives (TTFT/TPOT percentiles, error rate, availability) into
   pass/warn/breach with burn rates, exported as `pdt_slo_*` gauges.
-* `profile` — the performance attribution plane: decode-round
-  decomposition (`note_round`), the dispatch-gap sampler
-  (`gap_sampler`/`fence`, driven by `engine.profile_round()`),
-  compile-cache observability (`compile_timed` behind the engine's
-  `_jit_lru`/`_jit_singleton` seam + the retrace-storm detector), the
-  `pdt_mem_bytes{pool}` memory ledger, and
-  `render_profile_report(snapshot)` for the waterfall / top-gap /
-  compile-table / ledger text report.
+* `profile` — the performance attribution plane: the fleet step's
+  self-time table (`span_summary`, read from the
+  `pdt_span_self_seconds{name}` series every span observes), the
+  dispatch-gap sampler (`gap_sampler`/`fence`, driven by
+  `engine.profile_round()`), compile-cache observability
+  (`compile_timed` behind the engine's `_jit_lru`/`_jit_singleton`
+  seam + the retrace-storm detector), the `pdt_mem_bytes{pool}` memory
+  ledger, and `render_profile_report(snapshot)` for the waterfall /
+  top-gap / compile-table / ledger text report.
 * `status` — `render_fleet_status()`: the human-readable fleet report.
 * `__main__` — the operator CLI (`python -m paddle_tpu.observability
   snapshot|slo|trace ...`, installed as `paddle-tpu-obs`).
@@ -58,8 +60,9 @@ from .registry import (DEFAULT_BUCKETS, REGISTRY, Counter, Gauge,  # noqa: F401
                        Histogram, Registry, counter, disable, enable,
                        enabled, gauge, histogram, reset, snapshot, value)
 from .trace import (clear as clear_events, event, events,  # noqa: F401
-                    set_trace_file, span, trace_file, start_trace,
-                    end_trace, trace_of, attach as trace_attach,
+                    interval, set_trace_file, span, trace_file,
+                    start_trace, end_trace, trace_of,
+                    attach as trace_attach,
                     request_tree, export_chrome_trace,
                     load_trace_jsonl)
 from .export import (parse_prometheus, render_prometheus,  # noqa: F401
@@ -70,15 +73,15 @@ from .slo import (Reservoir, SloMonitor, SloObjective,  # noqa: F401
                   objectives_from_spec, quantile_from_buckets)
 from .status import render_fleet_status  # noqa: F401
 from . import profile  # noqa: F401
-from .profile import (memory_ledger, note_round,  # noqa: F401
+from .profile import (memory_ledger,  # noqa: F401
                       render_profile_report, snapshot_report)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "Registry", "REGISTRY",
     "DEFAULT_BUCKETS", "counter", "gauge", "histogram",
     "enable", "disable", "enabled", "reset", "snapshot", "value",
-    "span", "event", "events", "clear_events", "set_trace_file",
-    "trace_file", "start_trace", "end_trace", "trace_of",
+    "span", "event", "interval", "events", "clear_events",
+    "set_trace_file", "trace_file", "start_trace", "end_trace", "trace_of",
     "trace_attach", "request_tree", "export_chrome_trace",
     "load_trace_jsonl", "to_prometheus", "render_prometheus",
     "to_json", "write_json", "parse_prometheus",
@@ -86,6 +89,6 @@ __all__ = [
     "default_serving_objectives", "evaluate_snapshot",
     "format_slo_report", "objectives_from_spec",
     "quantile_from_buckets", "render_fleet_status",
-    "profile", "memory_ledger", "note_round",
+    "profile", "memory_ledger",
     "render_profile_report", "snapshot_report",
 ]

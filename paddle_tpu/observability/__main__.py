@@ -13,9 +13,10 @@
         [--top-gaps 10]
 
 `profile` renders the performance-attribution report from a saved
-metrics snapshot (JSON or Prometheus text): the decode-round
-decomposition waterfall, the ranked `pdt_profile_gap_seconds` table
-from the last `engine.profile_round()`, the per-family compile-cache
+metrics snapshot (JSON or Prometheus text): the fleet step's span
+self-time waterfall (`pdt_span_self_seconds{name}`), the ranked
+`pdt_profile_gap_seconds` table from the last
+`engine.profile_round()`, the per-family compile-cache
 table, and the `pdt_mem_bytes{pool}` memory ledger — exits non-zero
 when the snapshot carries no profile series at all.
 `status` renders a saved `ServingRouter.fleet_info()` snapshot as the
@@ -115,9 +116,9 @@ def _cmd_profile(args) -> int:
                                             top_gaps=args.top_gaps)
     print(report)
     # mirror `slo`'s exit-code contract: non-zero when there is
-    # nothing to attribute (no pdt_profile_*/pdt_jit_*/pdt_mem_*
-    # series in the snapshot at all)
-    empty = not (_profile.round_summary(snap)
+    # nothing to attribute (no pdt_span_self_seconds/pdt_profile_*/
+    # pdt_jit_*/pdt_mem_* series in the snapshot at all)
+    empty = not (_profile.span_summary(snap)
                  or _profile.gap_table(snap)
                  or _profile.compile_summary(snap)
                  or _profile.mem_summary(snap))

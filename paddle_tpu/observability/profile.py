@@ -1,5 +1,6 @@
-"""Performance attribution: decode-round decomposition, compile-cache
-observability, the dispatch-gap sampler, and the memory ledger.
+"""Performance attribution: the fleet step's self-time table,
+compile-cache observability, the dispatch-gap sampler, and the memory
+ledger.
 
 ROADMAP item 1 (the megakernel decode fusion ladder) deletes host
 dispatch gaps between the RMSNorm -> QKV -> RoPE -> ragged-attention ->
@@ -8,13 +9,16 @@ so each rung is chosen by ranked evidence and graded by the same
 instrument. Four surfaces, all in the PR-2 tradition (stdlib+jax only,
 guaranteed no-op unless telemetry is enabled):
 
-* **Decode-round decomposition** — the engine threads `note_round()`
-  through `step()`/`_decode`/`_harvest_*` (and the router through its
-  journal mirror), splitting each round's wall into
-  dispatch / device / harvest / journal / sentry / host components
-  (`pdt_profile_round_seconds{component}`). The components are measured
-  wall intervals, so their sums reconcile against an independently
-  timed round (test-pinned to 10%).
+* **Self-time table** — the fleet step is ONE span tree
+  (`router.step` -> `router.replica_step` -> `serving.step` ->
+  `serving.admit` / `serving.decode` / `serving.commit` / ..., drawn in
+  docs/observability.md) and `trace.span` observes each span's self
+  time into `pdt_span_self_seconds{name}`. Self times are disjoint and
+  add up to the root's duration, so `span_summary()` of a snapshot IS
+  the step's decomposition: no second set of clock reads, and the time
+  a dispatch waits for the device sits in the span round the dispatch
+  (`serving.ragged_prefill`, `serving.decode_step`,
+  `serving.harvest`), never in its host parent.
 * **Dispatch-gap sampler** — `gap_sampler()` + the `fence()` hooks in
   `models/llama.py`: `ContinuousBatchingEngine.profile_round()` runs
   ONE un-jitted decode round with `jax.block_until_ready` fences at
@@ -54,27 +58,12 @@ from . import registry as _registry
 from . import trace as _trace
 from .registry import counter, gauge, histogram
 
-__all__ = ["COMPONENTS", "note_round", "compile_timed", "note_cache",
+__all__ = ["compile_timed", "note_cache",
            "configure_retrace", "retrace_window", "gap_sampler",
            "fence", "gap_table", "memory_ledger", "perf_section",
-           "round_summary", "compile_summary", "mem_summary",
+           "span_summary", "compile_summary", "mem_summary",
            "render_profile_report", "snapshot_report"]
 
-# the decode-round attribution axes (see module docstring); "host" is
-# the expiry/admission/bookkeeping remainder the engine meters itself
-COMPONENTS = ("dispatch", "device", "harvest", "journal", "sentry",
-              "host")
-
-# round walls are sub-ms host slices up to multi-second cold dispatches
-_ROUND_BUCKETS = (1e-5, 2.5e-5, 5e-5, 1e-4, 2.5e-4, 5e-4, 0.001,
-                  0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
-                  1.0, 2.5, 5.0)
-
-_M_ROUND = histogram(
-    "pdt_profile_round_seconds",
-    "Wall seconds of one decode-round component (the engine/router "
-    "attribution hooks), by component.", ("component",),
-    buckets=_ROUND_BUCKETS)
 _M_GAP = gauge(
     "pdt_profile_gap_seconds",
     "Host dispatch gap between two op families summed over the most "
@@ -111,12 +100,6 @@ _M_MEM = gauge(
     "pdt_mem_bytes",
     "Memory ledger: bytes held per accounting pool (KV pools, draft "
     "pools, prefix-store spill, model-store residency).", ("pool",))
-
-
-def note_round(component: str, seconds: float) -> None:
-    """Record one decode-round component wall interval. No-op unless
-    telemetry is enabled (the Histogram gate)."""
-    _M_ROUND.observe(seconds, component=component)
 
 
 # -- compile-cache observability --------------------------------------
@@ -200,8 +183,9 @@ def compile_timed(fn, family: str, key=None):
     `pdt_jit_compiles_total{family}` / `pdt_jit_compile_seconds` under
     a `jit.compile` span, feeding the retrace-storm window. The same
     call lowers the program once more (the trace is shared with the
-    invocation, so it costs the text dump only) and records which
-    Mosaic kernels it contains: `pdt_jit_mosaic_kernels_total{family,
+    invocation, so it costs the text dump only) and records the HLO
+    module's name (the span's `module` attr) and which Mosaic kernels
+    it contains: `pdt_jit_mosaic_kernels_total{family,
     kernel}` and the span's `mosaic_kernels` attr — what says
     afterwards whether a dispatcher ran its kernel or gave way to its
     reference. Later invocations pay one boolean check. The engine's
@@ -221,8 +205,12 @@ def compile_timed(fn, family: str, key=None):
                          key="" if key is None else str(key)) as sp:
             if hasattr(fn, "lower"):
                 from ..ops import mosaic_kernels
-                kernels = mosaic_kernels(
-                    fn.lower(*args, **kwargs).as_text())
+                text = fn.lower(*args, **kwargs).as_text()
+                # `jit_pdt_decode`: the name the seam gave the program,
+                # as the profiler's `XLA Modules` line will show it
+                sp.attrs["module"] = text[8:text.find(" ", 8)] \
+                    if text.startswith("module @") else ""
+                kernels = mosaic_kernels(text)
                 sp.attrs["mosaic_kernels"] = kernels
                 for name, n in kernels.items():
                     _M_JIT_KERNELS.inc(n, family=family, kernel=name)
@@ -394,21 +382,14 @@ def _label_value(labels: str) -> str:
     return labels.split('"')[1] if '"' in labels else labels
 
 
-def round_summary(snapshot: Dict[str, object]) -> Dict[str, dict]:
-    """component -> {count, total_s, median_s} from a snapshot's
-    `pdt_profile_round_seconds` series."""
-    from .slo import quantile_from_buckets
-    out: Dict[str, dict] = {}
+def span_summary(snapshot: Dict[str, object]) -> Dict[str, dict]:
+    """span name -> {count, total_s} of self time, from a snapshot's
+    `pdt_span_self_seconds` series."""
     series = snapshot.get("histograms", {}).get(
-        "pdt_profile_round_seconds", {})
-    for labels, s in series.items():
-        if not s.get("count"):
-            continue
-        med = quantile_from_buckets(s["buckets"], 0.5)
-        out[_label_value(labels)] = {
-            "count": int(s["count"]), "total_s": float(s["sum"]),
-            "median_s": float(med) if med is not None else None}
-    return out
+        "pdt_span_self_seconds", {})
+    return {_label_value(labels): {"count": int(s["count"]),
+                                   "total_s": float(s["sum"])}
+            for labels, s in series.items() if s.get("count")}
 
 
 def compile_summary(snapshot: Dict[str, object]) -> Dict[str, dict]:
@@ -454,26 +435,34 @@ def _fmt_bytes(v: float) -> str:
 
 def render_profile_report(snapshot: Dict[str, object],
                           top_gaps: int = 10) -> str:
-    """The one profile report (waterfall + top gaps + compile table +
-    memory ledger) from any saved snapshot — shared by the
-    `paddle-tpu-obs profile` CLI, the recipes, and failing-test
-    attachments. Sections with no data are omitted; an entirely empty
+    """The one profile report (self-time waterfall + top gaps +
+    compile table + memory ledger) from any saved snapshot — shared by
+    the `paddle-tpu-obs profile` CLI, the recipes, and failing-test
+    attachments. The waterfall is one row a span name: self seconds,
+    count, and the share of the fleet step — the self times of the
+    step's spans (`router.*`, `serving.*`, `jit.compile`) add up to
+    the duration of their root, `router.step` (`serving.step` where no
+    router ran). Sections with no data are omitted; an entirely empty
     report renders a one-line notice."""
     lines: List[str] = []
-    rounds = round_summary(snapshot)
-    if rounds:
-        lines.append("decode-round decomposition")
-        total = sum(r["total_s"] for r in rounds.values())
-        order = [c for c in COMPONENTS if c in rounds] \
-            + sorted(set(rounds) - set(COMPONENTS))
-        for comp in order:
-            r = rounds[comp]
-            share = 100.0 * r["total_s"] / total if total > 0 else 0.0
-            bar = "#" * max(int(round(share / 4)), 1)
-            lines.append(
-                f"  {comp:<9} median {_fmt_s(r['median_s']):>9}  "
-                f"total {_fmt_s(r['total_s']):>9} ({share:5.1f}%) "
-                f"{bar}")
+    spans = span_summary(snapshot)
+    if spans:
+        root = next((r for r in ("router.step", "serving.step")
+                     if r in spans), None)
+        in_step = [n for n in spans if root and n.startswith(
+            ("router.", "serving.", "jit.compile"))]
+        total = sum(spans[n]["total_s"] for n in in_step)
+        lines.append("span self time" + (f" (share of {root})"
+                                         if root else ""))
+        for name in sorted(spans, key=lambda n: -spans[n]["total_s"]):
+            r = spans[name]
+            tail = ""
+            if name in in_step and total > 0:
+                share = 100.0 * r["total_s"] / total
+                tail = f" ({share:5.1f}%) " \
+                    + "#" * max(int(round(share / 4)), 1)
+            lines.append(f"  {name:<24} {_fmt_s(r['total_s']):>10} "
+                         f"{r['count']:>8}x{tail}")
     gaps = gap_table(snapshot)
     if gaps:
         lines.append("top dispatch gaps (last sampled round)")
@@ -501,8 +490,8 @@ def render_profile_report(snapshot: Dict[str, object],
         for pool in sorted(mem):
             lines.append(f"  {pool:<14} {_fmt_bytes(mem[pool]):>12}")
     if not lines:
-        return ("no profile data in snapshot (pdt_profile_*/pdt_jit_*/"
-                "pdt_mem_* series absent)")
+        return ("no profile data in snapshot (pdt_span_self_seconds/"
+                "pdt_profile_*/pdt_jit_*/pdt_mem_* series absent)")
     return "\n".join(lines)
 
 

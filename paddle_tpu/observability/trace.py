@@ -3,15 +3,27 @@ with REQUEST-SCOPED distributed traces across the serving fleet.
 
 Each completed span (and each point `event()`) becomes one dict —
 `{"name", "attrs", "ts", "ts_mono", "dur_s", "seq", "depth", "parent",
-"trace"}` — appended to a bounded in-memory ring buffer (oldest dropped
-first, so a serving process can trace forever in O(1) memory) and, when
-a file sink is configured (`set_trace_file()` or
-`PDT_TELEMETRY_TRACE_FILE=`), written as one JSON line for offline
-tooling (`jq`, pandas, the Chrome/Perfetto exporter below).
+"trace"}`, a span's also `"self_s"` — appended to a bounded in-memory
+ring buffer (oldest dropped first, so a serving process can trace
+forever in O(1) memory) and, when a file sink is configured
+(`set_trace_file()` or `PDT_TELEMETRY_TRACE_FILE=`), written as one
+JSON line for offline tooling (`jq`, pandas, the Chrome/Perfetto
+exporter below).
 
 Spans NEST via a per-thread stack: `parent` (the enclosing span's seq
 no) and `depth` reconstruct the local tree, and `seq` is a
 process-global monotone sequence so interleaved threads stay ordered.
+
+SELF TIME: a span's frame on that stack accumulates the durations of
+its direct children; on exit the record carries `self_s` (= `dur_s`
+minus the children) and the span observes `pdt_span_self_seconds
+{name}`. The self times of a tree are disjoint and add up to the
+root's `dur_s` by construction — the waterfall of
+`profile.render_profile_report` and the benchmark's
+`host_self_time_share` are sums of them. `interval()` records a
+duration that is not lexically scoped (a request's wait in the queue,
+which began in another call) as one record of the same shape, outside
+the self-time tree.
 
 ONE CLOCK: every event is stamped from a single monotonic clock
 (`time.perf_counter`) captured at span START (`ts_mono`); `dur_s` is
@@ -58,9 +70,9 @@ import time
 from collections import OrderedDict, deque
 from typing import Dict, List, Optional
 
-from .registry import enabled
+from .registry import enabled, histogram
 
-__all__ = ["span", "event", "events", "clear", "set_trace_file",
+__all__ = ["span", "event", "interval", "events", "clear", "set_trace_file",
            "trace_file", "start_trace", "end_trace", "trace_of",
            "attach", "request_tree", "format_tree",
            "export_chrome_trace", "load_trace_jsonl"]
@@ -70,6 +82,15 @@ _LOCK = threading.Lock()
 _RING: "deque[dict]" = deque(maxlen=_RING_CAP)
 _SEQ = itertools.count()
 _TLS = threading.local()
+
+# host slices from microseconds up to multi-second cold dispatches
+_M_SELF = histogram(
+    "pdt_span_self_seconds",
+    "Self time of a span: its duration minus its direct children's, "
+    "by span name. Self times of one tree are disjoint and add up to "
+    "the root's duration.", ("name",),
+    buckets=(1e-5, 2.5e-5, 5e-5, 1e-4, 2.5e-4, 5e-4, 0.001, 0.0025,
+             0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0))
 
 # -- the one clock ----------------------------------------------------
 # Every stamp is perf_counter; wall time is DERIVED from this base pair
@@ -277,7 +298,9 @@ class _Span:
         self._seq = next(_SEQ)
         self._parent, self._trace, self._depth = _resolve_links(
             stack, self.attrs)
-        stack.append((self._seq, self._trace))
+        # a span's frame is a LIST so that its children can add their
+        # durations to slot 2 (`attach()` frames stay tuples)
+        stack.append([self._seq, self._trace, 0.0])
         rec_cls = _record_event_cls()
         self._rec = None
         if rec_cls:
@@ -297,12 +320,20 @@ class _Span:
             except Exception:
                 pass
         stack = _TLS.stack
+        children = 0.0
         if stack and stack[-1][0] == self._seq:
-            stack.pop()
+            children = stack.pop()[2]
+        for frame in reversed(stack):
+            if type(frame) is list:        # the enclosing SPAN
+                frame[2] += dur
+                break
+        self_s = dur - children
+        _M_SELF.observe(self_s, name=self.name)
         ev = {"name": self.name, "attrs": self.attrs,
               "ts": _wall(self._t0), "ts_mono": self._t0,
-              "dur_s": dur, "seq": self._seq, "depth": self._depth,
-              "parent": self._parent, "trace": self._trace}
+              "dur_s": dur, "self_s": self_s, "seq": self._seq,
+              "depth": self._depth, "parent": self._parent,
+              "trace": self._trace}
         if exc_type is not None:
             ev["attrs"] = dict(self.attrs,
                                error=f"{exc_type.__name__}: {exc}")
@@ -321,18 +352,31 @@ def span(name: str, **attrs):
     return _Span(name, attrs)
 
 
-def event(name: str, **attrs):
-    """Point event (zero-duration span): fault fires, restarts,
-    membership changes. A `request_id=` attr joins the request's
-    distributed trace. No-op while disabled."""
+def interval(name: str, seconds: float, /, **attrs):
+    """One record for a duration that ENDS now and is not lexically
+    scoped — `seconds` long, measured by the caller on whatever clock
+    it keeps (`serving.queue_wait`: the engine's clock from a request's
+    enqueue to the claim of its slot). `ts_mono` is now minus
+    `seconds`, so the record sits where the wait sat on the timeline;
+    parent and trace resolve as for a span (a `request_id=` attr joins
+    the request's trace). It is no child of the enclosing span: the
+    wait was not time spent inside it. No-op while disabled."""
     if not enabled():
         return
     stack = getattr(_TLS, "stack", None) or []
     parent, trace, depth = _resolve_links(stack, attrs)
-    t = _CLOCK()
+    dur = float(seconds)
+    t = _CLOCK() - dur
     _emit({"name": name, "attrs": attrs, "ts": _wall(t), "ts_mono": t,
-           "dur_s": 0.0, "seq": next(_SEQ), "depth": depth,
+           "dur_s": dur, "seq": next(_SEQ), "depth": depth,
            "parent": parent, "trace": trace})
+
+
+def event(name: str, **attrs):
+    """Point event (zero-duration span): fault fires, restarts,
+    membership changes. A `request_id=` attr joins the request's
+    distributed trace. No-op while disabled."""
+    interval(name, 0.0, **attrs)
 
 
 # -- offline tooling ---------------------------------------------------

@@ -74,7 +74,9 @@ class RecordEvent:
     """Host span; shows up in the XLA timeline via TraceAnnotation.
     ≙ paddle.profiler.RecordEvent."""
 
-    _host_stats: dict[str, list] = defaultdict(list)
+    # name -> [calls, total seconds]: one pair a name, so a serving
+    # process that spans for ever holds O(names) memory here
+    _host_stats: dict[str, list] = defaultdict(lambda: [0, 0.0])
 
     def __init__(self, name: str, event_type=None):
         self.name = name
@@ -88,8 +90,9 @@ class RecordEvent:
 
     def end(self):
         if self._ann is not None:
-            dt = time.perf_counter() - self._t0
-            RecordEvent._host_stats[self.name].append(dt)
+            stat = RecordEvent._host_stats[self.name]
+            stat[0] += 1
+            stat[1] += time.perf_counter() - self._t0
             self._ann.__exit__(None, None, None)
             self._ann = None
 
@@ -209,11 +212,12 @@ class Profiler:
                  f"{'Host span':40s}{'calls':>8s}{'total(ms)':>12s}"
                  f"{'avg(ms)':>10s}",
                  "-" * 72]
-        for name, times in sorted(RecordEvent._host_stats.items(),
-                                  key=lambda kv: -sum(kv[1])):
-            tot = sum(times) * 1e3
-            lines.append(f"{name[:40]:40s}{len(times):8d}{tot:12.3f}"
-                         f"{tot / len(times):10.3f}")
+        for name, (calls, total_s) in sorted(
+                RecordEvent._host_stats.items(),
+                key=lambda kv: -kv[1][1]):
+            tot = total_s * 1e3
+            lines.append(f"{name[:40]:40s}{calls:8d}{tot:12.3f}"
+                         f"{tot / calls:10.3f}")
         if self._step_times:
             st = self._step_times
             lines.append("-" * 72)

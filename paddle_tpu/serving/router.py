@@ -935,127 +935,123 @@ class ServingRouter:
         live replica (harvesting token streams and terminal requests)
         -> fail over work stranded on replicas that died this tick.
         Returns the fleet requests that reached a terminal state."""
-        _M_STEPS.inc()
-        now = self._clock()
-        finished = self._terminal_backlog
-        self._terminal_backlog = []
-        for h in self.replicas:
-            if h.maybe_restart(now):
-                self.num_restarts += 1
-        unhealthy = set()
-        for h in self.replicas:
-            try:
-                h.check_health(now)     # may kill a wedged replica
-            except Exception as e:      # router.health fault fired
-                h.note_failure(now, e)
-                # a replica that just failed its probe sits this tick
-                # out — otherwise an immediately-successful step would
-                # erase the probe failure and the probe would mean
-                # nothing
-                unhealthy.add(h.index)
-        # canary probes launch where due (suspect/probation replicas
-        # immediately, healthy ones on the schedule) so this same
-        # tick's replica steps start serving them
-        self._launch_canaries(now)
-        for h in self.replicas:
-            if not h.alive() or h.index in unhealthy:
-                continue
-            # canary probes are infra, not traffic: they neither make
-            # a step "busy" for the restart-budget ledger nor count as
-            # served work — only a canary PASS proves anything
-            busy = h.real_outstanding() > 0
-            try:
-                done = h.step()
-            except Exception as e:
-                h.note_failure(self._clock(), e)
-                continue
-            canary_id = (h.canary["request_id"]
-                         if h.canary is not None else None)
-            # an idle tick is not evidence of stability: only steps that
-            # served real work reset the restart-backoff budget
-            h.note_success(self._clock(),
-                           did_work=busy or any(
-                               r.request_id != canary_id for r in done))
-            # poll sentry trips BEFORE delivering this step's
-            # terminals: a trip raised inside h.step() must park the
-            # very terminals it casts doubt on
-            if h.sentry is not None and h.sentry.trips > h.sentry_seen:
-                h.sentry_seen = h.sentry.trips
-                h.mark_suspect("sentry_trip")
-            canary_done = None
-            for req in done:
-                if canary_id is not None \
-                        and req.request_id == canary_id:
-                    canary_done = req
+        # the root of a fleet step's span tree (docs/observability.md);
+        # its self time is the router's own work between replica steps
+        with telemetry.span("router.step"):
+            _M_STEPS.inc()
+            now = self._clock()
+            finished = self._terminal_backlog
+            self._terminal_backlog = []
+            for h in self.replicas:
+                if h.maybe_restart(now):
+                    self.num_restarts += 1
+            unhealthy = set()
+            for h in self.replicas:
+                try:
+                    h.check_health(now)     # may kill a wedged replica
+                except Exception as e:      # router.health fault fired
+                    h.note_failure(now, e)
+                    # a replica that just failed its probe sits this tick
+                    # out — otherwise an immediately-successful step would
+                    # erase the probe failure and the probe would mean
+                    # nothing
+                    unhealthy.add(h.index)
+            # canary probes launch where due (suspect/probation replicas
+            # immediately, healthy ones on the schedule) so this same
+            # tick's replica steps start serving them
+            self._launch_canaries(now)
+            for h in self.replicas:
+                if not h.alive() or h.index in unhealthy:
                     continue
-                rec = self.requests.get(req.request_id)
-                if rec is None:
+                # canary probes are infra, not traffic: they neither make
+                # a step "busy" for the restart-budget ledger nor count as
+                # served work — only a canary PASS proves anything
+                busy = h.real_outstanding() > 0
+                try:
+                    done = h.step()
+                except Exception as e:
+                    h.note_failure(self._clock(), e)
                     continue
-                if h.state == ReplicaState.SUSPECT:
-                    # a terminal from a replica under suspicion must
-                    # not finalize until the canary rules — its stream
-                    # may be tainted (docs/serving.md "Gray failures")
-                    h.parked.append((rec, req))
-                else:
-                    self._finalize(rec, req, finished)
-            self._harvest(h)
-            if canary_done is not None:
-                self._canary_verdict(h, canary_done, finished,
-                                     self._clock())
-            h.finish_drain_if_empty(self._clock())
-        # disaggregation hand-off: finished prefills on prefill-role
-        # replicas migrate to decode replicas through the transfer
-        # plane, BEFORE the failover scan (a migrated request must not
-        # read as stranded on its source)
-        if self.roles_enabled:
-            self._migrate_ready()
-        # suspicion that resolved WITHOUT a canary verdict (the
-        # replica died, was killed, or drained mid-suspicion): deliver
-        # the parked terminals as the engine reported them — the taint
-        # window closes unproven, a documented detection-latency hole
-        # (docs/serving.md failure matrix), not silent data loss
-        for h in self.replicas:
-            if h.parked and h.state != ReplicaState.SUSPECT:
-                for rec, req in h.parked:
-                    if not rec.done:
+                canary_id = (h.canary["request_id"]
+                             if h.canary is not None else None)
+                # an idle tick is not evidence of stability: only steps that
+                # served real work reset the restart-backoff budget
+                h.note_success(self._clock(),
+                               did_work=busy or any(
+                                   r.request_id != canary_id for r in done))
+                # poll sentry trips BEFORE delivering this step's
+                # terminals: a trip raised inside h.step() must park the
+                # very terminals it casts doubt on
+                if h.sentry is not None and h.sentry.trips > h.sentry_seen:
+                    h.sentry_seen = h.sentry.trips
+                    h.mark_suspect("sentry_trip")
+                canary_done = None
+                for req in done:
+                    if canary_id is not None \
+                            and req.request_id == canary_id:
+                        canary_done = req
+                        continue
+                    rec = self.requests.get(req.request_id)
+                    if rec is None:
+                        continue
+                    if h.state == ReplicaState.SUSPECT:
+                        # a terminal from a replica under suspicion must
+                        # not finalize until the canary rules — its stream
+                        # may be tainted (docs/serving.md "Gray failures")
+                        h.parked.append((rec, req))
+                    else:
                         self._finalize(rec, req, finished)
-                h.parked = []
-        # failover pass: anything mirrored onto a replica that is no
-        # longer alive (died in the health or step pass, or was killed
-        # between ticks), plus orphans parked by an earlier all-dead tick
-        for h in self.replicas:
-            if not h.alive():
-                self._forget_caches(h.index)   # its warm cache is gone
-        for rec in list(self._live.values()):
-            if rec.done:
-                continue
-            h = (self.replicas[rec.replica]
-                 if rec.replica is not None else None)
-            if h is None or not h.alive() \
-                    or rec.generation != h.generation:
-                # a generation mismatch means the replica died AND
-                # restarted since this request was dispatched — the
-                # fresh engine never heard of it, however alive the
-                # handle looks now
-                self._failover_one(rec)
-        finished += self._terminal_backlog
-        self._terminal_backlog = []
-        # durability: mirror this tick's new tokens into the journal
-        # AFTER harvests and failovers, so one batched progress record
-        # reflects exactly what the router would have streamed
-        if self.journal is not None and telemetry.enabled():
-            # pdt-lint: disable=PDT001 the journal component of the
-            # decode-round decomposition is REAL wall (fsync cost) —
-            # a fake clock would fabricate the durability overhead
-            j0 = time.perf_counter()
-            self._journal_mirror()
-            # pdt-lint: disable=PDT001 same real-wall measurement
-            _profile.note_round("journal", time.perf_counter() - j0)
-        else:
-            self._journal_mirror()
-        for h in self.replicas:
-            h.update_gauges()
-        return finished
+                self._harvest(h)
+                if canary_done is not None:
+                    self._canary_verdict(h, canary_done, finished,
+                                         self._clock())
+                h.finish_drain_if_empty(self._clock())
+            # disaggregation hand-off: finished prefills on prefill-role
+            # replicas migrate to decode replicas through the transfer
+            # plane, BEFORE the failover scan (a migrated request must not
+            # read as stranded on its source)
+            if self.roles_enabled:
+                self._migrate_ready()
+            # suspicion that resolved WITHOUT a canary verdict (the
+            # replica died, was killed, or drained mid-suspicion): deliver
+            # the parked terminals as the engine reported them — the taint
+            # window closes unproven, a documented detection-latency hole
+            # (docs/serving.md failure matrix), not silent data loss
+            for h in self.replicas:
+                if h.parked and h.state != ReplicaState.SUSPECT:
+                    for rec, req in h.parked:
+                        if not rec.done:
+                            self._finalize(rec, req, finished)
+                    h.parked = []
+            # failover pass: anything mirrored onto a replica that is no
+            # longer alive (died in the health or step pass, or was killed
+            # between ticks), plus orphans parked by an earlier all-dead tick
+            for h in self.replicas:
+                if not h.alive():
+                    self._forget_caches(h.index)   # its warm cache is gone
+            for rec in list(self._live.values()):
+                if rec.done:
+                    continue
+                h = (self.replicas[rec.replica]
+                     if rec.replica is not None else None)
+                if h is None or not h.alive() \
+                        or rec.generation != h.generation:
+                    # a generation mismatch means the replica died AND
+                    # restarted since this request was dispatched — the
+                    # fresh engine never heard of it, however alive the
+                    # handle looks now
+                    self._failover_one(rec)
+            finished += self._terminal_backlog
+            self._terminal_backlog = []
+            # durability: mirror this tick's new tokens into the journal
+            # AFTER harvests and failovers, so one batched progress record
+            # reflects exactly what the router would have streamed
+            if self.journal is not None:
+                with telemetry.span("router.journal_mirror"):
+                    self._journal_mirror()
+            for h in self.replicas:
+                h.update_gauges()
+            return finished
 
     def _forget_caches(self, index: int):
         """A replica's warm state died with it: the dispatch policy

@@ -63,7 +63,8 @@ the chips), and EXACT per-model terminal-counter reconciliation
     python recipes/fleet_soak.py --duration 120 --replicas 4  # heavier
 `--profile` prints the performance-attribution report after the soak
 (ISSUE 20, docs/observability.md "Performance attribution"):
-decode-round waterfall, compile-cache table, memory ledger.
+span self-time waterfall (the router.step tree), compile-cache table,
+memory ledger.
 
     python recipes/fleet_soak.py                   # search + 2x soak
     python recipes/fleet_soak.py --qps 6 --overload 3
@@ -128,7 +129,7 @@ def main(argv=None):
                         "SLOs must hold at any window size")
     p.add_argument("--profile", action="store_true",
                    help="print the performance-attribution report "
-                        "(decode-round waterfall, compile-cache table, "
+                        "(span self-time waterfall, compile-cache table, "
                         "memory ledger — docs/observability.md "
                         "'Performance attribution') after the soak")
     args = p.parse_args(argv)

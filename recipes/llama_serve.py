@@ -352,8 +352,9 @@ def main(argv=None):
           "pid=replica, tid=request)")
 
     # 3f) performance attribution (docs/observability.md "Performance
-    # attribution"): where did the drill's decode rounds go, and what
-    # compiled — the waterfall + compile-cache table from the live
+    # attribution"): where did the drill's fleet steps go, and what
+    # compiled — the span self-time waterfall (one row a span of the
+    # router.step tree) + compile-cache table from the live
     # registry, same report `paddle-tpu-obs profile` renders offline
     # (fleet_info above already refreshed the pdt_mem_bytes ledger)
     from paddle_tpu.observability import profile as _profile

@@ -75,7 +75,7 @@ _TELEMETRY_FILES = ("test_serving.py", "test_chaos.py",
 _CHROME_TRACE_FILES = ("test_chaos.py", "test_router.py")
 
 # failing perf-sensitive tests additionally attach the performance-
-# attribution report (decode-round decomposition + compile table +
+# attribution report (span self-time waterfall + compile table +
 # memory ledger): a hang or throughput collapse then arrives with its
 # own waterfall instead of needing a rerun under a profiler
 _PROFILE_REPORT_FILES = ("test_async_pipeline.py", "test_tp_serving.py",

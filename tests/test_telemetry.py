@@ -183,6 +183,89 @@ class TestTrace:
         assert [ln["name"] for ln in lines] == ["sunk", "pt"]
         assert lines[0]["attrs"] == {"k": "v"}
 
+    def test_self_times_add_up_to_the_root(self):
+        """A span's `self_s` is its duration less its direct
+        children's: the self times of a tree are disjoint and add up
+        to the root's `dur_s`, and `pdt_span_self_seconds{name}`
+        carries the same sums."""
+        with telemetry.span("t.root"):
+            for _ in range(3):
+                with telemetry.span("t.mid"):
+                    with telemetry.span("t.leaf"):
+                        sum(range(2000))
+                    with telemetry.span("t.leaf"):
+                        pass
+            with _trace.attach("no-such-carrier"):
+                with telemetry.span("t.side"):
+                    sum(range(2000))
+        evs = [e for e in telemetry.events() if "self_s" in e]
+        root = evs[-1]
+        assert root["name"] == "t.root" and len(evs) == 11
+        assert all(0.0 <= e["self_s"] <= e["dur_s"] for e in evs)
+        assert sum(e["self_s"] for e in evs) \
+            == pytest.approx(root["dur_s"], rel=1e-9, abs=1e-12)
+        by_name = {}
+        for e in evs:
+            by_name[e["name"]] = by_name.get(e["name"], 0.0) + e["self_s"]
+        hist = telemetry.snapshot()["histograms"]["pdt_span_self_seconds"]
+        assert {k: v["count"] for k, v in hist.items()} == {
+            'name="t.root"': 1, 'name="t.mid"': 3, 'name="t.leaf"': 6,
+            'name="t.side"': 1}
+        for name, total in by_name.items():
+            assert hist[f'name="{name}"']["sum"] == pytest.approx(total)
+
+    def test_attached_span_still_counts_in_its_lexical_parent(self):
+        telemetry.start_trace("req-7")
+        with telemetry.span("t.outer"):
+            with _trace.attach("req-7"):
+                with telemetry.span("t.inner"):
+                    sum(range(2000))
+        inner, outer = [e for e in telemetry.events() if "self_s" in e]
+        assert inner["trace"] is not None and inner["self_s"] > 0.0
+        assert outer["self_s"] == pytest.approx(
+            outer["dur_s"] - inner["dur_s"], abs=1e-12)
+
+    def test_interval_is_one_record_outside_the_self_time_tree(self):
+        """An interval that is not lexically scoped: `dur_s` is what
+        the caller measured, `ts_mono` is its start, the request's
+        trace is joined through `request_id`, and the enclosing span's
+        self time does not lose it."""
+        tid = telemetry.start_trace("req-9")
+        with telemetry.span("t.claim"):
+            telemetry.interval("t.wait", 2.5, request_id="req-9", rid=3)
+        wait, claim = telemetry.events()[-2:]
+        assert wait["name"] == "t.wait" and wait["dur_s"] == 2.5
+        assert "self_s" not in wait
+        assert wait["trace"] == tid and wait["parent"] == claim["seq"]
+        assert wait["ts_mono"] + 2.5 == pytest.approx(
+            claim["ts_mono"] + claim["dur_s"], abs=0.05)
+        assert claim["self_s"] == claim["dur_s"]
+        tree = telemetry.request_tree("req-9")
+        assert [c["event"]["name"] for c in tree["children"]] \
+            == ["t.wait"]
+        assert list(telemetry.snapshot()["histograms"][
+            "pdt_span_self_seconds"]) == ['name="t.claim"']
+
+    def test_record_event_host_stats_do_not_grow(self):
+        """Each span enters `profiler.RecordEvent`; with no `Profiler`
+        collecting, a thousand spans must leave its class-level table
+        the size one span made it (one pair a name)."""
+        from paddle_tpu.profiler import Profiler, RecordEvent
+
+        def size():
+            return sum(len(v) for v in RecordEvent._host_stats.values())
+
+        with telemetry.span("t.grow"):
+            pass
+        before = size()
+        calls = RecordEvent._host_stats["t.grow"][0]
+        for _ in range(1000):
+            with telemetry.span("t.grow"):
+                pass
+        assert size() == before
+        assert RecordEvent._host_stats["t.grow"][0] == calls + 1000
+        assert "t.grow" in Profiler(timer_only=True).summary()
+
     def test_set_trace_file_none_sticks_over_env(self, tmp_path,
                                                  monkeypatch):
         """set_trace_file(None) must close the sink FOR GOOD — the env
