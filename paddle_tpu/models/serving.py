@@ -210,6 +210,13 @@ _M_PREFILL_ROWS = telemetry.counter(
     "Rows the ragged admission programs were dispatched with, by kind: "
     "token = a prompt's real tokens, pad = the rest of the padded "
     "token axis (block_q alignment and the padding grid).", ("kind",))
+_M_ATTN_PAGES = telemetry.counter(
+    "pdt_serving_attn_pages_total",
+    "Block-table columns of one ragged attention call of a dispatch "
+    "(admission or decode), by kind: walked = pages the kernel's loops "
+    "visit (per q block the KV blocks of its live page range), skipped "
+    "= q blocks x table columns less that, which a grid over the whole "
+    "table would have stepped over.", ("kind",))
 _M_REJECTIONS = telemetry.counter(
     "pdt_serving_rejections_total",
     "add_request refusals by reason.", ("reason",))
@@ -2780,6 +2787,9 @@ class ContinuousBatchingEngine:
         tokens = int(pk["tokens"])
         _M_PREFILL_ROWS.inc(tokens, kind="token")
         _M_PREFILL_ROWS.inc(int(t_pad) - tokens, kind="pad")
+        if telemetry.enabled():
+            self._count_attn_pages(pk["query_start"], pk["query_len"],
+                                   pk["context_len"], t_pad, bq)
         with telemetry.span("serving.ragged_prefill", tokens=tokens,
                             t_pad=int(t_pad), rids=rids), \
                 self._tp_scope():
@@ -2920,6 +2930,28 @@ class ContinuousBatchingEngine:
         `compile_timed` first-call metering as `_jit_lru` misses."""
         return _profile.compile_timed(
             _name_program(build(), family), family)
+
+    def _count_attn_pages(self, query_start, query_len, context_len,
+                          n_rows, block_q):
+        """`pdt_serving_attn_pages_total` for one ragged dispatch, from
+        the descriptors it is dispatched with: the kernel's own loop
+        bounds (`ragged_pages_walked`), evaluated on the host. Called
+        with telemetry on only."""
+        from ..ops.ragged_paged_attention import (kv_block_pages,
+                                                  ragged_pages_walked)
+        _, hk, hd, dt = self._kv_shape
+        if self._view_tp() is not None:
+            hk //= self._tp.tp                  # a shard's local heads
+        walked = ragged_pages_walked(
+            query_start, query_len, context_len, n_rows,
+            block_q=block_q, page_size=self.page_size,
+            window=self._window, table_pages=self.pps,
+            block_pages=kv_block_pages(
+                self.page_size, hd, hk,
+                1 if self._qkv else jnp.dtype(dt).itemsize, self.pps))
+        _M_ATTN_PAGES.inc(walked, kind="walked")
+        _M_ATTN_PAGES.inc(int(n_rows) // block_q * self.pps - walked,
+                          kind="skipped")
 
     def _pages_bound(self, contexts) -> int:
         """Power-of-two-bucketed static gather trim for a dispatch
@@ -3499,6 +3531,13 @@ class ContinuousBatchingEngine:
         # timeline row, and request_tree() fans it into each tree
         rids = ([r.request_id for r in self._slot_req if r is not None]
                 if telemetry.enabled() else ())
+        if telemetry.enabled() and self.layout == "paged" \
+                and self.attn_impl == "ragged":
+            # one query a slot at its position: the decode dispatch's
+            # descriptors, as built below
+            self._count_attn_pages(
+                np.arange(self.B), np.ones(self.B, np.int32), pos + 1,
+                self.B, 1)
         with telemetry.span("serving.decode_step", slots=n_active,
                             rids=rids):
             # pdt-lint: disable=PDT001 decode_step_seconds measures the

@@ -18,18 +18,29 @@ prefix-cache suffix prefill whose queries attend causally at
 Rows owned by no sequence are padding: their output is zero and their
 KV (see `ragged_scatter_values`) routes to the trash page.
 
-Kernel. The grid is (q-blocks, kv-heads, pages-per-seq); the block
-tables and the per-sequence descriptors are SCALAR-PREFETCHED so the
-page index feeds the BlockSpec index_map and Mosaic double-buffers page
-fetches (the `paged_attention.py` pattern, generalized from q = 1 to
-ragged q).  Each q block belongs to exactly one sequence (the packer
-aligns ``query_start`` to ``block_q``; decode batches use block_q = 1).
-Dead pages — beyond a sequence's causal frontier, wholly below its
-sliding window, or under a padding q block — skip both the FLOPs *and*
-the DMA: their index_map routes to the RESIDENT trash page 0, and since
-consecutive grid steps then fetch the same block, the Pallas pipeline
-elides the copy entirely.  This fixes the "DMA still runs" cost
-documented in `paged_attention.py`.
+Kernel. The grid is (q-blocks,): one step a q block, with every KV
+head of the step in it. Each q block belongs to exactly one sequence
+(the packer aligns ``query_start`` to ``block_q``; decode batches use
+block_q = 1). The page pools stay in HBM; the block tables and the
+per-sequence descriptors are SCALAR-PREFETCHED, and inside a step a
+loop walks the q block's LIVE pages only, a KV BLOCK of several pages x
+all heads a trip: from the block that holds the sliding window's lower
+edge (block 0 without a window) to the block of the q block's causal
+frontier. A trip starts the DMAs of the next block's pages (one
+descriptor a page, covering every head, into the other half of a
+two-slot VMEM buffer) and waits for its own — the double buffering the
+BlockSpec pipeline used to do a 4 KB page at a time — then per head
+multiplies ``(block_q*G, D) x (block keys, D)^T``, masks by position
+and folds the block into the online softmax. The trip count is dynamic:
+a padding q block, a sequence with no query and an idle slot do none
+and write zeros; no column past the frontier is visited, so the width
+of the block table costs nothing and no bound on it shapes the program.
+Pages of a live block that lie outside the live range (below the
+window's edge, past the frontier) copy the trash page 0 and are masked
+by position; their table entries are never read. `kv_block_pages`
+sizes the block from the static shapes against a fixed VMEM budget;
+`live_kv_blocks` is the loop's bounds, shared with the engine's
+`pdt_serving_attn_pages_total` counter (`ragged_pages_walked`).
 
 The XLA path (`_ragged_xla`) is the CI oracle: a page gather BOUNDED to
 the block-table prefix actually referenced (static trim when the
@@ -286,34 +297,104 @@ def _ragged_xla(q, k_pages, v_pages, query_start, query_len, context_len,
 # ---------------------------------------------------------------------------
 # Pallas kernel
 # ---------------------------------------------------------------------------
-def _ragged_kernel(qb_seq_ref, qstart_ref, qlen_ref, ctx_ref, bt_ref,
-                   q_ref, k_ref, v_ref, *rest, scale, page_size,
-                   block_q, group, window, quantized=False):
-    # quantized page pools (int8 storage) add two (1, page_size) f32
-    # per-page-row scale blocks; the dequant folds into the existing
-    # multiplies — logits scale per KEY row (columns of sim), the p@v
-    # weights scale per VALUE row (columns of p) — so the int8 tiles
-    # feed the MXU unwidened in HBM and no transposed broadcast is
-    # ever materialized
-    if quantized:
-        ks3_ref, vs3_ref, o_ref, acc_ref, m_ref, l_ref = rest
-        # (1, 1, page_size) blocks of the (P, 1, page_size) pools —
-        # the middle unit axis exists purely so the block's last two
-        # dims equal the array's (the Mosaic block-shape rule); drop
-        # it to the (1, page_size) row the broadcasts below want
-        ks_ref = ks3_ref[0]
-        vs_ref = vs3_ref[0]
-    else:
-        o_ref, acc_ref, m_ref, l_ref = rest
-    qb = pl.program_id(0)
-    i = pl.program_id(2)
-    n_pages = pl.num_programs(2)
+# VMEM the kernel gives its two-slot K + V block buffers; `kv_block_pages`
+# sizes a trip's KV block against it
+KV_BLOCK_VMEM_BYTES = 2 * 1024 * 1024
+# keys a trip at most: one 128-lane row of logits a query row. A trip's
+# time grows with its pages (about 0.2 us a page on a v5e, trash pages
+# of the last block included) and 4, 8 and 16 pages were within 10 % of
+# each other at the serving shapes, 8 ahead (PERF.md section 6, PR 26)
+KV_BLOCK_MAX_KEYS = 128
 
-    @pl.when(i == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+
+def kv_block_pages(page_size, head_dim, kv_heads, itemsize,
+                   table_pages) -> int:
+    """Pages of one KV block: what a trip of the kernel's loop copies
+    (one DMA a page, every KV head of the step in it) and multiplies at
+    once. A function of the static shapes only — the largest power of
+    two whose double-buffered K and V blocks fit `KV_BLOCK_VMEM_BYTES`,
+    within `KV_BLOCK_MAX_KEYS` keys and no wider than the block table
+    — so every caller (the kernel, the engine's page counter) computes
+    the same number."""
+    head_dim = -(-head_dim // LANES) * LANES        # as the kernel pads
+    page_bytes = kv_heads * page_size * head_dim * itemsize
+    fit = min(KV_BLOCK_VMEM_BYTES // (4 * page_bytes),  # K, V x 2 slots
+              KV_BLOCK_MAX_KEYS // page_size)
+    fit = 1 << (max(int(fit), 1).bit_length() - 1)
+    return min(fit, 1 << (int(table_pages) - 1).bit_length())
+
+
+def qblock_seq(query_start, query_len, n_qblocks, block_q, xp=jnp):
+    """q block -> owning sequence (padding blocks: -1). Every block
+    belongs to at most one sequence because starts are block-aligned."""
+    rows = xp.arange(n_qblocks, dtype=xp.int32) * block_q
+    in_seq = (rows[:, None] >= query_start[None, :]) \
+        & (rows[:, None] < (query_start + query_len)[None, :])
+    return xp.where(in_seq.any(1), in_seq.argmax(1), -1).astype(xp.int32)
+
+
+def live_kv_blocks(seq, qb_off, qlen, ctx, *, page_size, block_q,
+                   window, block_pages, xp=jnp):
+    """(first live page, last live page, first KV block, KV block count)
+    of a q block whose rows start ``qb_off`` rows into sequence
+    ``seq``'s query segment. Live pages run from the page that holds
+    the sliding window's lower edge of the block's first row (0 without
+    a window) to the page of its last row's causal frontier; KV blocks
+    are ``block_pages`` table columns wide and aligned to the table, so
+    the walk covers every block that holds a live page. A padding block
+    (``seq < 0``), a block past its sequence's queries and a sequence
+    with no queries have no block. THE bounds of the kernel's loop, in
+    scalars there and over arrays on the host (`ragged_pages_walked`)."""
+    first_q = ctx - qlen + qb_off                  # global pos of row 0
+    last_q = ctx - qlen + xp.minimum(qb_off + block_q, qlen) - 1
+    lo = 0 if window is None \
+        else xp.maximum(first_q - window + 1, 0) // page_size
+    hi = last_q // page_size
+    live = (seq >= 0) & (qb_off < qlen)
+    b0 = lo // block_pages
+    return lo, hi, b0, xp.where(live, hi // block_pages - b0 + 1, 0)
+
+
+def ragged_pages_walked(query_start, query_len, context_len, n_rows, *,
+                        block_q, page_size, window, block_pages,
+                        table_pages):
+    """Block-table columns the kernel's loops visit for one dispatch
+    (host side, from the dispatch's own descriptors): per q block the
+    columns of the KV blocks of its live page range (a last block that
+    overhangs a table of ``table_pages`` columns counts its columns in
+    the table)."""
+    qs = np.asarray(query_start, np.int32)
+    ql = np.asarray(query_len, np.int32)
+    cl = np.asarray(context_len, np.int32)
+    nqb = int(n_rows) // block_q
+    seq = qblock_seq(qs, ql, nqb, block_q, xp=np)
+    sc = np.maximum(seq, 0)
+    qb_off = np.arange(nqb, dtype=np.int32) * block_q - qs[sc]
+    _, _, b0, n_blocks = live_kv_blocks(
+        seq, qb_off, ql[sc], cl[sc], page_size=page_size,
+        block_q=block_q, window=window, block_pages=block_pages, xp=np)
+    end = np.minimum((b0 + n_blocks) * block_pages, table_pages)
+    return int(np.where(n_blocks > 0, end - b0 * block_pages, 0).sum())
+
+
+def _ragged_kernel(qb_seq_ref, qstart_ref, qlen_ref, ctx_ref, bt_ref,
+                   q_ref, k_hbm, v_hbm, *rest, scale, page_size,
+                   block_q, group, window, block_pages, quantized=False):
+    # quantized page pools (int8 storage) add the two per-page-row
+    # scale pools, gathered to one (1, keys) f32 row a KV block of each
+    # sequence; the dequant folds into the existing multiplies — logits
+    # scale per KEY row (columns of sim), the p@v weights scale per
+    # VALUE row (columns of p) — so the int8 pages feed the MXU
+    # unwidened in HBM and no transposed broadcast is ever materialized
+    if quantized:
+        (ks_hbm, vs_hbm, o_ref, kbuf, vbuf, sem, acc_ref, m_ref, l_ref,
+         ksbuf, vsbuf) = rest
+    else:
+        o_ref, kbuf, vbuf, sem, acc_ref, m_ref, l_ref = rest
+    hk = kbuf.shape[1]
+    keys = block_pages * page_size
+    pps = bt_ref.shape[1]
+    qb = pl.program_id(0)
 
     s = qb_seq_ref[qb]
     sc = jnp.maximum(s, 0)
@@ -321,145 +402,195 @@ def _ragged_kernel(qb_seq_ref, qstart_ref, qlen_ref, ctx_ref, bt_ref,
     qlen = qlen_ref[sc]
     qb_off = qb * block_q - qstart_ref[sc]
     first_q = ctx - qlen + qb_off                  # global pos of row 0
-    last_q = ctx - qlen + jnp.minimum(qb_off + block_q, qlen) - 1
-    live = (s >= 0) & (qb_off < qlen) & (i * page_size <= last_q)
-    if window is not None:
-        live = live & ((i + 1) * page_size > first_q - window + 1)
+    lo_page, hi_page, block0, n_trips = live_kv_blocks(
+        s, qb_off, qlen, ctx, page_size=page_size, block_q=block_q,
+        window=window, block_pages=block_pages)
 
-    @pl.when(live)
-    def _page():
-        q = q_ref[0, 0].astype(jnp.float32)          # (block_q*G, D)
-        k = k_ref[0, 0].astype(jnp.float32)          # (page_size, D)
-        v = v_ref[0, 0].astype(jnp.float32)
-        sim = mxu_dot(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+    m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
+
+    def block_copies(trip, slot):
+        """The DMAs of trip `trip`'s KV block into buffer `slot`: one
+        descriptor a page and pool, every KV head in it. The block's
+        columns outside the live range (below the window's edge, past
+        the frontier) copy the trash page: their keys are masked by
+        position, and the table is never read there."""
+        block = block0 + trip
+        copies = []
+        for j in range(block_pages):
+            col = block * block_pages + j
+            page = jnp.where(
+                (col >= lo_page) & (col <= hi_page),
+                bt_ref[sc, jnp.minimum(col, pps - 1)], TRASH_PAGE)
+            copies.append(pltpu.make_async_copy(
+                k_hbm.at[:, page], kbuf.at[slot, :, j], sem.at[0, slot]))
+            copies.append(pltpu.make_async_copy(
+                v_hbm.at[:, page], vbuf.at[slot, :, j], sem.at[1, slot]))
         if quantized:
-            # per-key-row dequant: sim[r, j] owes one factor ks[j]
-            sim = sim * ks_ref[:]                    # (1, ps) bcast
-        kpos = i * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, sim.shape, 1)
-        row = jax.lax.broadcasted_iota(jnp.int32, sim.shape, 0) // group
+            # the block's row of `_block_scale_rows`
+            row = sc * -(-pps // block_pages) + block
+            copies.append(pltpu.make_async_copy(
+                ks_hbm.at[row], ksbuf.at[slot], sem.at[2, slot]))
+            copies.append(pltpu.make_async_copy(
+                vs_hbm.at[row], vsbuf.at[slot], sem.at[3, slot]))
+        return copies
+
+    @pl.when(n_trips > 0)
+    def _prime():
+        for c in block_copies(0, 0):
+            c.start()
+
+    def trip_body(trip, carry):
+        slot = trip % 2
+
+        @pl.when(trip + 1 < n_trips)
+        def _prefetch():
+            for c in block_copies(trip + 1, 1 - slot):
+                c.start()
+
+        for c in block_copies(trip, slot):
+            c.wait()
+
+        shape = (block_q * group, keys)
+        kpos = (block0 + trip) * keys \
+            + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, shape, 0) // group
         qpos = first_q + row
         valid = (kpos <= qpos) & (qb_off + row < qlen)
         if window is not None:
             valid = valid & (kpos > qpos - window)
-        sim = jnp.where(valid, sim, NEG_INF)
-        m_prev = m_ref[:, :1]
-        m_cur = jnp.max(sim, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(sim > NEG_INF * 0.5, jnp.exp(sim - m_new), 0.0)
-        l_new = alpha * l_ref[:, :1] + jnp.sum(p, -1, keepdims=True)
-        pv = p * vs_ref[:] if quantized else p       # value-row dequant
-        acc_ref[:] = acc_ref[:] * alpha + mxu_dot(
-            pv, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+        if quantized:
+            ks = ksbuf[slot][:, :keys]               # (1, keys)
+            vs = vsbuf[slot][:, :keys]
+        for h in range(hk):
+            q = q_ref[h, 0].astype(jnp.float32)      # (block_q*G, D)
+            k = kbuf[slot, h].astype(jnp.float32).reshape(keys, -1)
+            v = vbuf[slot, h].astype(jnp.float32).reshape(keys, -1)
+            sim = mxu_dot(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            if quantized:
+                # per-key-row dequant: sim[r, j] owes one factor ks[j]
+                sim = sim * ks                       # (1, keys) bcast
+            sim = jnp.where(valid, sim, NEG_INF)
+            m_prev = m_ref[h, :, :1]
+            m_cur = jnp.max(sim, axis=-1, keepdims=True)
+            m_new = jnp.maximum(m_prev, m_cur)
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.where(sim > NEG_INF * 0.5, jnp.exp(sim - m_new), 0.0)
+            l_new = alpha * l_ref[h, :, :1] \
+                + jnp.sum(p, -1, keepdims=True)
+            pv = p * vs if quantized else p          # value-row dequant
+            acc_ref[h] = acc_ref[h] * alpha + mxu_dot(
+                pv, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+        return carry
 
-    @pl.when(i == n_pages - 1)
-    def _finish():
-        l = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0, 0] = jnp.where(m_ref[:, :1] > NEG_INF * 0.5,
-                                acc_ref[:] / l, 0.0).astype(o_ref.dtype)
+    jax.lax.fori_loop(0, n_trips, trip_body, None)
 
-
-def _page_index_map(qb, hh, ii, qb_seq, qstart, qlen, ctx, bt, *,
-                    page_size, block_q, window):
-    """BlockSpec index_map for k/v: live pages read their block-table
-    entry; DEAD pages (causally past the frontier, below the window, or
-    under a padding q block) route to the resident trash page 0 — the
-    pipeline then skips the DMA because the block index is unchanged."""
-    s = qb_seq[qb]
-    sc = jnp.maximum(s, 0)
-    c = ctx[sc]
-    ql = qlen[sc]
-    qb_off = qb * block_q - qstart[sc]
-    first_q = c - ql + qb_off
-    last_q = c - ql + jnp.minimum(qb_off + block_q, ql) - 1
-    live = (s >= 0) & (qb_off < ql) & (ii * page_size <= last_q)
-    if window is not None:
-        live = live & ((ii + 1) * page_size > first_q - window + 1)
-    return (hh, jnp.where(live, bt[sc, ii], TRASH_PAGE), 0, 0)
+    for h in range(hk):
+        l = jnp.maximum(l_ref[h, :, :1], 1e-30)
+        o_ref[h, 0] = jnp.where(m_ref[h, :, :1] > NEG_INF * 0.5,
+                                acc_ref[h] / l, 0.0).astype(o_ref.dtype)
 
 
-def _scale_index_map(qb, hh, ii, qb_seq, qstart, qlen, ctx, bt, *,
-                     page_size, block_q, window):
-    """Index map for the (P, 1, page_size) per-page scale pools of a
-    QUANTIZED page pool: EXACTLY the page index map's live/dead
-    routing (delegated, so the two can never drift — a scale routed
-    to a different page than its values would be silent
-    mis-dequantization), minus the head dim the scale pools do not
-    have. Dead pages ride the trash page's scales; their logits are
-    fully masked anyway."""
-    return _page_index_map(qb, hh, ii, qb_seq, qstart, qlen, ctx, bt,
-                           page_size=page_size, block_q=block_q,
-                           window=window)[1:]
+def _block_scale_rows(scale_pool, block_tables, block_pages):
+    """A quantized pool's per-page-row scales (P, page_size), gathered
+    along the block tables to one lane-dense row a KV block:
+    (N * blocks-per-sequence, 1, keys rounded up to whole 128-lane
+    tiles). Mosaic copies nothing narrower than a 128-lane tile out of
+    HBM, so a page's 16 scales cannot ride the page's own DMA; the
+    gather is XLA's (N x pps rows of 64 bytes, next to nothing) and a
+    trip copies its block's row. Dead columns gather whatever page id
+    they hold, clamped into the pool: masked keys, never used."""
+    n, pps = block_tables.shape
+    page_size = scale_pool.shape[1]
+    blocks = -(-pps // block_pages)
+    keys = block_pages * page_size
+    rows = scale_pool[block_tables]                # (N, pps, ps)
+    rows = jnp.pad(rows, ((0, 0), (0, blocks * block_pages - pps),
+                          (0, 0)))
+    rows = rows.reshape(n * blocks, 1, keys)
+    return jnp.pad(rows, ((0, 0), (0, 0), (0, -keys % LANES)))
 
 
+# jitted so that a program's layers share ONE trace and ONE lowering of
+# the kernel (same shapes, same statics -> the cached jaxpr, lowered to
+# one function the layers call): traced and lowered a layer at a time
+# the kernel's body cost a 24-layer program 24 times its 0.4 s
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "window", "block_q", "interpret"))
 def _ragged_pallas(q, k_pages, v_pages, query_start, query_len,
                    context_len, block_tables, scale, window, block_q,
                    interpret, k_scale=None, v_scale=None):
     t, h, d = q.shape
+    if d % LANES:
+        # Mosaic copies whole 128-lane tiles out of HBM, and a pool
+        # with a narrower head is lane-padded there anyway: pad q and
+        # the pools with zero lanes (q.k and p@v are unchanged on the
+        # real ones) and drop the output's. The pad is a copy of both
+        # pools a call: a head_dim below 128 pays it until the pools
+        # are stored lane-dense.
+        lanes = ((0, 0),) * 2 + ((0, -d % LANES),)
+        out = _ragged_pallas(
+            jnp.pad(q, lanes), jnp.pad(k_pages, ((0, 0),) + lanes),
+            jnp.pad(v_pages, ((0, 0),) + lanes), query_start,
+            query_len, context_len, block_tables, scale, window,
+            block_q, interpret, k_scale=k_scale, v_scale=v_scale)
+        return out[..., :d]
     hk, _, page_size, _ = k_pages.shape
     g = h // hk
-    n = block_tables.shape[0]
-    pps = block_tables.shape[1]
     quantized = k_scale is not None
     nqb = t // block_q
-    # q block qb -> owning sequence (padding blocks: -1); every block
-    # belongs to at most one sequence because starts are block-aligned
-    qb_rows = jnp.arange(nqb, dtype=jnp.int32) * block_q
-    in_seq = (qb_rows[:, None] >= query_start[None, :]) \
-        & (qb_rows[:, None] < (query_start + query_len)[None, :])
-    qb_seq = jnp.where(jnp.any(in_seq, 1),
-                       jnp.argmax(in_seq, 1), -1).astype(jnp.int32)
+    block_pages = kv_block_pages(page_size, d, hk,
+                                 k_pages.dtype.itemsize,
+                                 block_tables.shape[1])
+    qb_seq = qblock_seq(query_start, query_len, nqb, block_q)
     # (T, H, D) -> (HK, nqb, block_q*G, D): one MXU-ready q tile per
     # (kv head, q block); all reshapes live outside the kernel
     qk = jnp.transpose(q.reshape(t, hk, g, d), (1, 0, 2, 3))
     qk = qk.reshape(hk, nqb, block_q * g, d)
 
-    page_map = functools.partial(
-        _page_index_map, page_size=page_size, block_q=block_q,
-        window=window)
-    in_specs = [
-        pl.BlockSpec((1, 1, block_q * g, d),
-                     lambda qb, hh, ii, *refs: (hh, qb, 0, 0)),
-        pl.BlockSpec((1, 1, page_size, d), page_map),
-        pl.BlockSpec((1, 1, page_size, d), page_map),
-    ]
+    # every KV head of a q block in one grid step
+    q_spec = pl.BlockSpec((hk, 1, block_q * g, d),
+                          lambda qb, *refs: (0, qb, 0, 0))
+    hbm_spec = pl.BlockSpec(memory_space=pltpu.HBM)
+    in_specs = [q_spec, hbm_spec, hbm_spec]
     inputs = [qk, k_pages, v_pages]
+    kv_block = (2, hk, block_pages, page_size, d)     # two slots
+    scratch_shapes = [
+        pltpu.VMEM(kv_block, k_pages.dtype),
+        pltpu.VMEM(kv_block, v_pages.dtype),
+        pltpu.SemaphoreType.DMA((4 if quantized else 2, 2)),
+        pltpu.VMEM((hk, block_q * g, d), jnp.float32),
+        pltpu.VMEM((hk, block_q * g, LANES), jnp.float32),
+        pltpu.VMEM((hk, block_q * g, LANES), jnp.float32),
+    ]
     if quantized:
-        scale_map = functools.partial(
-            _scale_index_map, page_size=page_size, block_q=block_q,
-            window=window)
-        # (P, ps) -> (P, 1, ps): the unit middle axis makes the block's
-        # last two dims equal the array's (the Mosaic block rule — a
-        # (1, ps) block of a (P, ps) array has an undividable sublane)
-        in_specs += [pl.BlockSpec((1, 1, page_size), scale_map),
-                     pl.BlockSpec((1, 1, page_size), scale_map)]
-        inputs += [k_scale[:, None, :], v_scale[:, None, :]]
+        ks_rows = _block_scale_rows(k_scale, block_tables, block_pages)
+        in_specs += [hbm_spec, hbm_spec]
+        inputs += [ks_rows,
+                   _block_scale_rows(v_scale, block_tables, block_pages)]
+        scratch_shapes += [
+            pltpu.VMEM((2,) + ks_rows.shape[1:], jnp.float32)] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
-        grid=(nqb, hk, pps),
+        grid=(nqb,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, block_q * g, d),
-                               lambda qb, hh, ii, *refs: (hh, qb, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((block_q * g, d), jnp.float32),
-            pltpu.VMEM((block_q * g, LANES), jnp.float32),
-            pltpu.VMEM((block_q * g, LANES), jnp.float32),
-        ],
+        out_specs=q_spec,
+        scratch_shapes=scratch_shapes,
     )
-    out_dtype = q.dtype
     out = pl.pallas_call(
         functools.partial(_ragged_kernel, scale=scale,
                           page_size=page_size, block_q=block_q, group=g,
-                          window=window, quantized=quantized),
+                          window=window, block_pages=block_pages,
+                          quantized=quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((hk, nqb, block_q * g, d),
-                                       out_dtype),
+                                       q.dtype),
         interpret=interpret,
         name="ragged_paged_attention",
     )(qb_seq, query_start.astype(jnp.int32),
@@ -551,11 +682,11 @@ def ragged_paged_attention_values(q, k_pages, v_pages, query_start,
     multipliers of QUANTIZED int8 page pools (quantized serving,
     docs/serving.md "Quantized serving"; written by
     `ragged_scatter_quantized`). The XLA oracle dequantizes right
-    after the gather; the kernel dequantizes per page in flight —
+    after the gather; the kernel dequantizes per KV block in flight —
     key-row scales fold into the logits, value-row scales into the
-    softmax weights — so page DMA moves int8 bytes only. Trash-page
-    routing and dead-page skipping are unchanged (a dead page's
-    scales ride the resident trash page like its values)."""
+    softmax weights — so page DMA moves int8 bytes only. The scales
+    reach the kernel gathered along the block tables, one lane-dense
+    row a KV block (`_block_scale_rows`), and ride the same trips."""
     t, h, d = q.shape
     sc = scale if scale is not None else 1.0 / math.sqrt(d)
 

@@ -290,6 +290,61 @@ class TestRaggedPagedAttentionLowering:
             k_scale=ks, v_scale=vs), q, kp, kp, ks, ks)
 
 
+    # the benchmark's cells (ISSUE 26): (Q heads, rows, table columns,
+    # block_q) — InternLM2-1.8B has G = 2 and 128 columns of 16 tokens
+    # (max_seq_len 2048), Mistral-7B G = 4 and 64 columns; both 8 KV
+    # heads of 128 and 32 slots
+    CELL_SHAPES = {
+        "batch.decode": (16, 32, 128, 1),
+        "batch.admit512": (16, 512, 128, 8),
+        "chat.decode": (32, 32, 64, 1),
+        "chat.admit256": (32, 256, 64, 8),
+    }
+
+    @pytest.mark.parametrize("variant", ["bf16", "int8", "window", "tp2"])
+    @pytest.mark.parametrize("shape", sorted(CELL_SHAPES))
+    def test_cell_shapes(self, shape, variant):
+        """ISSUE 26: the in-kernel loop over KV blocks — HBM pools,
+        per-page DMAs indexed by the prefetched table, a dynamic trip
+        count — at the real shapes of both cells' decode and admission
+        programs, on int8 pools, with a window, and per shard of a
+        tp = 2 replica."""
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        from paddle_tpu.ops.ragged_paged_attention import \
+            ragged_paged_attention_values
+
+        h, rows, pps, block_q = self.CELL_SHAPES[shape]
+        slots, hk, d, page_size = 32, 8, 128, 16
+        pages = slots * pps + 1
+        q = jnp.zeros((rows, h, d), jnp.bfloat16)
+        kp = jnp.zeros((hk, pages, page_size, d),
+                       jnp.int8 if variant == "int8" else jnp.bfloat16)
+        i32 = jnp.zeros((slots,), jnp.int32)
+        bt = jnp.zeros((slots, pps), jnp.int32)
+        kw, extra, sharding, mesh = {}, (), None, None
+        if variant == "window":
+            kw["window"] = 512
+        if variant == "int8":
+            extra = (jnp.zeros((pages, page_size), jnp.float32),) * 2
+        if variant == "tp2":
+            mesh = Mesh(np.asarray(_v5e()[:2]), ("tp",))
+            kw["tp"] = (mesh, "tp")
+            rep = NamedSharding(mesh, P())
+            pool = NamedSharding(mesh, P("tp", None, None, None))
+            sharding = [NamedSharding(mesh, P(None, "tp", None)), pool,
+                        pool, rep, rep, rep, rep]
+
+        def fn(q, kp, vp, qs, ql, cl, bt, *scales):
+            if scales:
+                kw.update(k_scale=scales[0], v_scale=scales[1])
+            return ragged_paged_attention_values(
+                q, kp, vp, qs, ql, cl, bt, block_q=block_q, **kw)
+
+        kernels = _lower(fn, q, kp, kp, i32, i32, i32, bt, *extra,
+                         sharding=sharding)
+        assert kernels.get("ragged_paged_attention") == 1, kernels
+
+
 class TestQuantMatmulLowering:
     """ISSUE 15: the fused dequant-matmul epilogue — int8 weight tiles
     widened in VMEM, per-out-channel scale applied to the f32

@@ -21,8 +21,10 @@ from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.models.serving import (ContinuousBatchingEngine,
                                        PoolExhausted, RequestStatus)
 from paddle_tpu.ops.paged_attention import paged_attention_values
+from paddle_tpu.ops import ragged_paged_attention as rpa_mod
 from paddle_tpu.ops.ragged_paged_attention import (
-    gather_pages, pack_ragged_starts, ragged_paged_attention_values,
+    gather_pages, kv_block_pages, pack_ragged_starts,
+    ragged_pages_walked, ragged_paged_attention_values,
     ragged_scatter_values, token_arrays)
 from paddle_tpu.utils.faults import FaultInjector
 
@@ -186,6 +188,214 @@ class TestRaggedKernelParity:
             ragged_paged_attention_values(
                 jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
                 qs, ql, cl, bt, block_q=4, use_kernel=True)
+
+
+def _small_blocks(monkeypatch, pages, ps=4):
+    """KV blocks of `pages` pages: the tests' contexts are tens of
+    tokens, so the default block (256 keys) would hold any of them in
+    one trip and the loop's edges would go unwalked."""
+    monkeypatch.setattr(rpa_mod, "KV_BLOCK_MAX_KEYS", pages * ps)
+
+
+# each: (_case overrides, window, block_q, explicit query_start).
+# Blocks are 2 pages of 4 tokens, so a trip holds 8 keys.
+LOOP_EDGES = {
+    # 9 tokens = pages 0-2: the second block holds one live page and
+    # the context ends in the middle of it
+    "ends_mid_block": (dict(ql=(1, 9), cl=(9, 9), pps=4), None, 4, None),
+    # 8 and 16 tokens: the frontier's page is the last of its block
+    "ends_on_block_edge": (dict(ql=(1, 16, 1), cl=(8, 16, 16), pps=4,
+                                n_pages=16), None, 4, None),
+    "one_token": (dict(ql=(1, 1), cl=(1, 5), pps=4, block_q=1,
+                       tail_pad=0), None, 1, None),
+    # a decode batch with an idle slot: row 1 is owned by a sequence
+    # with no query -> no trip, zero output
+    "inactive_slot": (dict(ql=(1, 0, 1), cl=(11, 0, 6), pps=4, block_q=1,
+                           tail_pad=1), None, 1, (0, 1, 2)),
+    # rows 8-11 (q block 2) lie between the two segments, owned by none
+    "padding_qblock_between": (dict(ql=(5, 3), cl=(14, 3), pps=4,
+                                    tail_pad=4), None, 4, (0, 12)),
+    # window 6 at context 21: the lower edge (key 15) is in page 3, the
+    # second page of block 1; pages 0-1 are never visited
+    "window_edge_mid_block": (dict(ql=(1, 6), cl=(21, 23), pps=8,
+                                   n_pages=16), 6, 4, None),
+    # the cells' shape in small: 128 columns, a third of them live
+    "wide_table": (dict(ql=(1, 9, 1), cl=(170, 90, 140), pps=128,
+                        n_pages=110), None, 4, None),
+}
+
+
+class TestKernelLoopEdges:
+    """The in-kernel loop over KV blocks (ISSUE 26): the edges a
+    dynamic trip count brings, kernel (interpret) against the XLA
+    oracle and NumPy."""
+
+    @pytest.mark.parametrize("edge", sorted(LOOP_EDGES))
+    def test_loop_edge(self, edge, monkeypatch):
+        _small_blocks(monkeypatch, 2)
+        kw, window, block_q, qs_override = LOOP_EDGES[edge]
+        rng = np.random.default_rng(len(edge))
+        q, kp, vp, qs, ql, cl, bt = _case(rng, **dict(kw, block_q=block_q))
+        if qs_override is not None:
+            qs = np.asarray(qs_override, np.int32)
+        ref = np_ragged_oracle(q, kp, vp, qs, ql, cl, bt, window=window)
+        kern, xla = _both_paths(q, kp, vp, qs, ql, cl, bt, window=window,
+                                block_q=block_q)
+        np.testing.assert_allclose(kern, ref, atol=2e-5)
+        np.testing.assert_allclose(xla, ref, atol=2e-5)
+        seq_t, _ = token_arrays(qs, ql, cl, q.shape[0])
+        assert np.all(kern[seq_t < 0] == 0)
+
+    def test_dead_columns_are_never_read(self, monkeypatch):
+        """Columns past a context's frontier hold garbage: an id far
+        outside the pool, or the id of a page of NaNs (0 x NaN would
+        reach the output if the kernel read it and only masked it).
+        The kernel must not notice either."""
+        _small_blocks(monkeypatch, 2)
+        rng = np.random.default_rng(11)
+        q, kp, vp, qs, ql, cl, bt = _case(
+            rng, ql=(1, 7, 5), cl=(9, 7, 13), pps=8, n_pages=13)
+        poison = 12
+        kp[:, poison] = np.nan
+        vp[:, poison] = np.nan
+        for s in range(len(cl)):
+            live = -(-int(cl[s]) // 4)
+            bt[s, live:] = [poison if j % 2 else 2 ** 30
+                            for j in range(8 - live)]
+        ref = np_ragged_oracle(q, kp, vp, qs, ql, cl, bt)
+        kern = np.asarray(ragged_paged_attention_values(
+            jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), qs, ql, cl,
+            bt, block_q=4, use_kernel=True))
+        np.testing.assert_allclose(kern, ref, atol=2e-5)
+
+    @pytest.mark.parametrize("pool", ["bf16", "int8"])
+    @pytest.mark.parametrize("block_q", [1, 8])
+    @pytest.mark.parametrize("g", [1, 2, 4])
+    def test_group_blockq_pool_dtype(self, g, block_q, pool, monkeypatch):
+        """GQA group x q block x pool dtype: the decode form (block_q
+        1) and the admission form (block_q 8) of the cells' groups, on
+        bf16 pools and on int8 pools with their per-row scales."""
+        _small_blocks(monkeypatch, 2)
+        rng = np.random.default_rng(100 + 10 * g + block_q)
+        if block_q == 1:
+            kw = dict(ql=(1, 1, 1), cl=(9, 16, 3), tail_pad=0)
+        else:
+            kw = dict(ql=(1, 11, 5), cl=(9, 11, 13), tail_pad=8)
+        q, kp, vp, qs, ql, cl, bt = _case(rng, g=g, pps=4, n_pages=12,
+                                          block_q=block_q, **kw)
+        scales = {}
+        if pool == "bf16":
+            kp_d = jnp.asarray(kp, jnp.bfloat16)
+            vp_d = jnp.asarray(vp, jnp.bfloat16)
+            kp_f, vp_f = (np.asarray(x, np.float32) for x in (kp_d, vp_d))
+        else:
+            kp_d = jnp.asarray(rng.integers(-127, 128, kp.shape), jnp.int8)
+            vp_d = jnp.asarray(rng.integers(-127, 128, vp.shape), jnp.int8)
+            ks = rng.uniform(0.002, 0.02, kp.shape[1:3]).astype(np.float32)
+            vs = rng.uniform(0.002, 0.02, kp.shape[1:3]).astype(np.float32)
+            scales = dict(k_scale=jnp.asarray(ks), v_scale=jnp.asarray(vs))
+            kp_f = np.asarray(kp_d, np.float32) * ks[None, :, :, None]
+            vp_f = np.asarray(vp_d, np.float32) * vs[None, :, :, None]
+        ref = np_ragged_oracle(q, kp_f, vp_f, qs, ql, cl, bt)
+        args = (jnp.asarray(q), kp_d, vp_d, qs, ql, cl, bt)
+        kern = np.asarray(ragged_paged_attention_values(
+            *args, block_q=block_q, use_kernel=True, **scales))
+        xla = np.asarray(ragged_paged_attention_values(
+            *args, block_q=block_q, use_kernel=False, **scales))
+        np.testing.assert_allclose(kern, ref, atol=5e-5)
+        # the oracle rounds the softmax weights to the pool's dtype
+        # before p @ v (the kernel keeps them in float32): bf16 pools
+        # differ by that rounding, int8 pools (dequantized to float32)
+        # do not
+        np.testing.assert_allclose(kern, xla,
+                                   atol=2e-2 if pool == "bf16" else 5e-5)
+
+
+def _brute_walked(qs, ql, cl, n_rows, block_q, ps, window, block_pages,
+                  pps):
+    """Table columns the kernel has to visit, from the attention's
+    definition: per q block, the table-aligned KV blocks from the one
+    holding its lowest attended key to the one holding its highest."""
+    walked = 0
+    for qb in range(n_rows // block_q):
+        pages = set()
+        for s in range(len(ql)):
+            for j in range(int(ql[s])):
+                if (int(qs[s]) + j) // block_q != qb:
+                    continue
+                pos = int(cl[s]) - int(ql[s]) + j
+                lo = 0 if window is None else max(0, pos - window + 1)
+                pages.update((lo // ps, pos // ps))
+        if pages:
+            first = min(pages) // block_pages * block_pages
+            end = (max(pages) // block_pages + 1) * block_pages
+            walked += min(end, pps) - first
+    return walked
+
+
+class TestLivePageRange:
+    """`ragged_pages_walked`: the kernel's loop bounds on the host,
+    which `pdt_serving_attn_pages_total` counts with."""
+
+    @pytest.mark.parametrize("seed", range(6))
+    def test_walked_is_the_kernels_range(self, seed):
+        rng = np.random.default_rng(seed)
+        block_q = int(rng.choice([1, 4, 8]))
+        ps = int(rng.choice([4, 16]))
+        block_pages = int(rng.choice([1, 2, 8]))
+        window = [None, 5, 40][seed % 3]
+        n = int(rng.integers(2, 7))
+        ql = rng.integers(0, 30, n) if block_q > 1 else rng.integers(0, 2, n)
+        cl = np.where(ql > 0, ql + rng.integers(0, 200, n), 0)
+        if block_q > 1:
+            qs, total = pack_ragged_starts(ql, block_q=block_q)
+            total += 2 * block_q                       # padding q blocks
+        else:
+            qs, total = np.arange(n, dtype=np.int32), n
+        pps = -(-int(cl.max() + 1) // ps) + int(rng.integers(0, 3))
+        got = ragged_pages_walked(qs, ql, cl, total, block_q=block_q,
+                                  page_size=ps, window=window,
+                                  block_pages=block_pages,
+                                  table_pages=pps)
+        assert got == _brute_walked(qs, ql, cl, total, block_q, ps,
+                                    window, block_pages, pps)
+        assert 0 <= got <= total // block_q * pps
+
+    def test_block_pages_follow_the_shapes(self):
+        # the cells: 16-token pages, 8 KV heads of 128, bf16 -> 8 pages,
+        # 128 keys a trip
+        assert kv_block_pages(16, 128, 8, 2, 128) == 8
+        assert kv_block_pages(16, 128, 8, 2, 64) == 8
+        # a head under 128 lanes is padded to them; a table narrower
+        # than a block bounds it; many heads shrink it to the budget
+        assert kv_block_pages(16, 64, 8, 2, 128) == 8
+        assert kv_block_pages(4, 16, 2, 4, 4) == 4
+        assert kv_block_pages(16, 128, 64, 2, 128) == 2
+
+    def test_engine_counts_walked_and_skipped(self, model):
+        """A short engine run with telemetry on: every ragged dispatch
+        adds q blocks x table columns, split into walked and skipped."""
+        eng = _engine(model)
+        for p, n in JOBS:
+            eng.add_request(p, n)
+        steps = 0
+        while eng._queue or any(r is not None for r in eng._slot_req):
+            eng.step()
+            steps += 1
+        pages = telemetry.snapshot()["counters"][
+            "pdt_serving_attn_pages_total"]
+        packs = [e["attrs"] for e in telemetry.events()
+                 if e["name"] == "serving.ragged_prefill"]
+        decodes = [e for e in telemetry.events()
+                   if e["name"] == "serving.decode_step"]
+        qblocks = sum(a["t_pad"] // eng._ragged_block_q for a in packs) \
+            + len(decodes) * eng.B
+        assert pages['kind="walked"'] > 0 and pages['kind="skipped"'] > 0
+        assert pages['kind="walked"'] + pages['kind="skipped"'] \
+            == qblocks * eng.pps
+        # whole KV blocks only
+        assert pages['kind="walked"'] % kv_block_pages(
+            4, 16, 1, 4, eng.pps) == 0
 
 
 class TestScatterAndPacking:

@@ -248,6 +248,60 @@ class TestRaggedPagedAttentionCompile:
                                           v_scale=vs), q, kp, kp, ks, ks)
         assert np.isfinite(np.asarray(out, np.float32)).all()
 
+    @pytest.mark.parametrize("shape", [(16, 32, 128, 1), (16, 512, 128, 8),
+                                       (32, 32, 64, 1), (32, 256, 64, 8)])
+    @pytest.mark.parametrize("variant", ["bf16", "int8", "window"])
+    def test_cell_shapes_match_the_oracle(self, variant, shape):
+        """ISSUE 26: the in-kernel loop over KV blocks, executed at the
+        benchmark cells' shapes (Q heads, rows, table columns, block_q)
+        on contexts of a third of the table, against the XLA oracle.
+        The table's dead columns hold an id far outside the pool: a
+        DMA that read one would fault."""
+        from paddle_tpu.ops.ragged_paged_attention import (
+            pack_ragged_starts, ragged_paged_attention_values as rpa)
+        h, rows, pps, block_q = shape
+        n, hk, d = 32, 8, 128
+        rng = np.random.default_rng(rows + pps)
+        cl = rng.integers(self.PAGE, pps * self.PAGE // 2, n)
+        if block_q == 1:
+            ql, qs = np.ones(n, np.int32), np.arange(n, dtype=np.int32)
+        else:
+            ql = np.zeros(n, np.int32)
+            ql[:2] = rows // 2 - 40, rows // 2 - 9
+            cl[:2] = ql[:2] + (0, 77)          # a prefill, a continuation
+            qs, _ = pack_ragged_starts(ql, block_q=block_q)
+        pages = n * pps + 1
+        bt = np.full((n, pps), 2 ** 30, np.int32)
+        safe = np.zeros((n, pps), np.int32)
+        perm = rng.permutation(pages - 1) + 1
+        for s in range(n):
+            need = -(-int(cl[s]) // self.PAGE)
+            bt[s, :need] = safe[s, :need] = perm[s * pps:s * pps + need]
+        q = jnp.asarray(rng.standard_normal((rows, h, d)), jnp.bfloat16)
+        shp = (hk, pages, self.PAGE, d)
+        kw = {"window": 300} if variant == "window" else {}
+        if variant == "int8":
+            kp, vp = (jnp.asarray(rng.integers(-127, 128, shp), jnp.int8)
+                      for _ in range(2))
+            kw.update(
+                k_scale=jnp.asarray(rng.uniform(0.002, 0.02, shp[1:3]),
+                                    jnp.float32),
+                v_scale=jnp.asarray(rng.uniform(0.002, 0.02, shp[1:3]),
+                                    jnp.float32))
+        else:
+            kp, vp = (jnp.asarray(rng.standard_normal(shp), jnp.bfloat16)
+                      for _ in range(2))
+        got = _compile(lambda q, kp, vp: rpa(
+            q, kp, vp, qs, ql, cl, bt, block_q=block_q, **kw), q, kp, vp)
+        want = _compile(lambda q, kp, vp: rpa(
+            q, kp, vp, qs, ql, cl, safe, block_q=block_q,
+            use_kernel=False, **kw), q, kp, vp)
+        # bf16 outputs of O(1) values; the oracle rounds its softmax
+        # weights to the pool's dtype, the kernel keeps them in float32
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   atol=3e-2)
+
     @pytest.mark.parametrize("block_q", [8, 1])
     def test_tp_shard_map_kernel(self, block_q):
         """ISSUE 12: under tensor parallelism the kernel runs per head
