@@ -149,10 +149,10 @@ def _expert_ffn_rows(xs_in, eid, w_gate, w_up, w_down, e: int):
     es = eid[order]                               # (N,) sorted expert ids
     counts = jnp.bincount(eid, length=e)          # (E,)
 
-    block_m = DEFAULT_BLOCK
-    block_aligned = (on_tpu() and h % block_m == 0
-                     and i_size % block_m == 0)
-    if block_aligned:
+    # the kernel's row tile, or 0: groups as they come, through XLA
+    block_m = DEFAULT_BLOCK if (on_tpu() and h % DEFAULT_BLOCK == 0
+                                and i_size % DEFAULT_BLOCK == 0) else 0
+    if block_m:
         # pad each expert's group to a block_m multiple so no m-tile of
         # the Pallas kernel straddles a group boundary
         co = jnp.concatenate([jnp.zeros(1, counts.dtype),
@@ -171,12 +171,12 @@ def _expert_ffn_rows(xs_in, eid, w_gate, w_up, w_down, e: int):
         gs = counts
 
     hg = grouped_matmul_values(xs, w_gate.astype(xs.dtype), gs,
-                               block_aligned)
+                               block_m)
     hu = grouped_matmul_values(xs, w_up.astype(xs.dtype), gs,
-                               block_aligned)
+                               block_m)
     act = jax.nn.silu(hg.astype(jnp.float32)).astype(xs.dtype) * hu
     rows = grouped_matmul_values(act, w_down.astype(xs.dtype), gs,
-                                 block_aligned)                # (M, H)
+                                 block_m)                # (M, H)
     if pos is not None:
         rows = rows[pos]                                       # (N, H)
     # unsort back to the caller's order

@@ -8,7 +8,7 @@ from . import bert  # noqa: F401
 def __getattr__(name):
     import importlib
     if name in ("llama", "llama_pipe", "moe", "dit", "gpt", "serving",
-                "speculative", "generation", "ernie"):
+                "speculative", "generation", "ernie", "nemotron_h"):
         mod = importlib.import_module("." + name, __name__)
         globals()[name] = mod
         return mod

@@ -663,6 +663,14 @@ class LlamaForCausalLM(nn.Layer, GenerationMixin):
             self.lm_head = nn.Linear(cfg.hidden_size, cfg.vocab_size,
                                      bias_attr=False)
 
+    def cache_spec(self) -> list:
+        """What each layer keeps (models/cache_spec.py): keys and
+        values, in every layer."""
+        from paddle_tpu.models.cache_spec import KVSpec
+        cfg = self.config
+        return [KVSpec(cfg.num_key_value_heads, cfg.head_dim)] \
+            * cfg.num_hidden_layers
+
     def _logits(self, hidden):
         if self.lm_head is not None:
             # TP serving: lm_head is vocab-sharded; gather the logits

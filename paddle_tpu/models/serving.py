@@ -89,6 +89,7 @@ from ..utils.faults import (FaultError, fault_point, fault_value,
                             value_armed)
 from .. import observability as telemetry
 from ..observability import profile as _profile
+from .cache_spec import KVSpec, RaggedStateView, ReportSpec, StateSpec
 from .generation import RequestStatus
 
 _NULL_SCOPE = contextlib.nullcontext()
@@ -266,6 +267,14 @@ _M_PAGE_OCCUPANCY = telemetry.gauge(
 _M_INVARIANT_SECONDS = telemetry.histogram(
     "pdt_serving_invariant_check_seconds",
     "Duration of check_invariants() page-accounting sweeps.")
+# -- layers that keep a state (models/cache_spec.py) -------------------
+_M_STATE_BYTES = telemetry.gauge(
+    "pdt_serving_state_bytes",
+    "Bytes of the per-slot state arrays the engine allocated for the "
+    "model's state layers (all slots, all layers).")
+_M_STATE_SLOTS = telemetry.gauge(
+    "pdt_serving_state_slots_live",
+    "Slots whose state arrays hold a running sequence's state.")
 # -- speculative decoding (spec_decode=SpecConfig(...), ISSUE 10) ------
 _M_SPEC_ROUNDS = telemetry.counter(
     "pdt_spec_rounds_total",
@@ -370,6 +379,16 @@ def _name_program(jitted, family: str, key=None):
                 c if c.isalnum() else "_" for c in str(part))
     fn.__name__ = fn.__qualname__ = name
     return jitted
+
+
+def _cache_spec(model) -> list:
+    """What each layer of `model` keeps (models/cache_spec.py). A model
+    that does not say keeps keys and values in every layer."""
+    if hasattr(model, "cache_spec"):
+        return list(model.cache_spec())
+    cfg = model.config
+    return [KVSpec(cfg.num_key_value_heads, cfg.head_dim)] \
+        * cfg.num_hidden_layers
 
 
 class EngineOverloaded(RuntimeError):
@@ -589,6 +608,30 @@ class ContinuousBatchingEngine:
                  harvest_every: int = 1):
         cfg = model.config
         self.model = model
+        # -- what each layer keeps (models/cache_spec.py): pools for
+        # the KV layers only, a (slots, ...) array per state array of a
+        # state layer, nothing for the rest (a reporting layer's counts
+        # and records come back beside the tokens). Every feature that
+        # takes "a sequence's cache is its pages" for granted refuses a
+        # model with state layers, by name.
+        self._layer_spec = _cache_spec(model)
+        self._state_spec = [s for s in self._layer_spec
+                            if isinstance(s, StateSpec)]
+        self._report_spec = [s for s in self._layer_spec
+                             if isinstance(s, ReportSpec)]
+        if self._state_spec:
+            for feature, asked in (
+                    ("kv_layout='dense'", kv_layout != "paged"),
+                    ("attention_impl='legacy'", attention_impl != "ragged"),
+                    ("enable_prefix_caching", enable_prefix_caching),
+                    ("spec_decode", spec_decode is not None),
+                    ("quant.kv", quant is not None and quant.kv),
+                    ("quant.weights", quant is not None and quant.weights),
+                    ("submesh tp > 1", submesh is not None
+                     and int(submesh.tp) > 1),
+                    ("harvest_every > 1", int(harvest_every) > 1)):
+                if asked:
+                    self._refuse_state(feature)
         # -- pipelined decode (ISSUE 18, docs/serving.md "Pipelined
         # decode"): harvest_every=k defers the D2H token sync — the
         # greedy-sampled token stays ON DEVICE and feeds step N+1's
@@ -692,10 +735,24 @@ class ContinuousBatchingEngine:
             # different submeshes share one model object
             self._tp_pv, self._tp_bv = \
                 self._tp.shard_model_values(model)
-        hk, hd = cfg.num_key_value_heads, cfg.head_dim
-        L = cfg.num_hidden_layers
+        kv_spec = [s for s in self._layer_spec if isinstance(s, KVSpec)]
+        if len(set(kv_spec)) > 1:
+            raise ValueError(
+                f"KV layers of different shapes {sorted(set(kv_spec))}: "
+                "the engine keeps one pool geometry")
+        # a model without a KV layer keeps the page bookkeeping (block
+        # tables, reservations) over zero pools
+        hk, hd = kv_spec[0] if kv_spec else (1, 1)
+        L = len(kv_spec)
         dt = self._params[0]._value.dtype
         self._kv_shape = (L, hk, hd, dt)
+        self._state = [
+            tuple(jnp.zeros((int(max_batch_size),) + tuple(shape), d)
+                  for shape, d in zip(s.shapes, s.dtypes))
+            for s in self._state_spec]
+        # a slot's state is live iff the slot holds a dispatched sequence
+        self._state_live = np.zeros(int(max_batch_size), bool)
+
         if kv_layout == "dense":
             if enable_prefix_caching:
                 import warnings
@@ -989,6 +1046,52 @@ class ContinuousBatchingEngine:
                 self.page_size * hk_ * hd_ * 2 * L_      # int8 storage
                 + self.page_size * 4 * 2 * L_)           # f32 scales
 
+    def _refuse_state(self, feature: str):
+        raise ValueError(
+            f"{feature} is not supported for a model with state layers "
+            f"({type(self.model).__name__}): it takes a sequence's cache "
+            "to be its pages, and this model also keeps a recurrent "
+            "state per slot")
+
+    def _state_nbytes(self) -> int:
+        return self.B * sum(s.nbytes() for s in self._state_spec)
+
+    def _cache(self):
+        """What a step program takes, donated, as its cache: the page
+        pools, and beside them the state arrays where the model has
+        state layers."""
+        return (self._kv, self._state) if self._state_spec else self._kv
+
+    def _take_step(self, out, logits: bool = False):
+        """Unpack a ragged step program's outputs, keep the new cache,
+        and return (tokens, logit rows or None, the reporting layers'
+        (counts, records) or None)."""
+        nxt, rest = out[0], list(out[1:])
+        rows = rest.pop(0) if logits else None
+        cache = rest.pop(0)
+        if self._state_spec:
+            self._kv, self._state = cache
+        else:
+            self._kv = cache
+        return nxt, rows, (rest[0] if rest else None)
+
+    def _harvest_reports(self, reports, rows, slots, positions):
+        """What the reporting layers (cache_spec.ReportSpec) handed back
+        from one dispatch. Their counts go into the counters the
+        specification names, with telemetry on only; the records of the
+        dispatch's live packed rows `rows` (of `slots`, at `positions`)
+        go to a sentry that takes them. Each is one more D2H pull, so
+        neither happens unasked."""
+        counts, records = reports
+        if telemetry.enabled():
+            names = [c for s in self._report_spec for c in s.counters]
+            for (counter, kind), n in zip(names, np.asarray(counts)):
+                counter.inc(int(n), kind=kind)
+        take = getattr(self._sentry, "observe_layer_rows", None)
+        if take is not None:
+            take(np.asarray(slots), np.asarray(positions),
+                 [np.asarray(r)[rows] for r in records])
+
     def _build_quant_weights(self):
         """Quantize the Megatron-placed matmul weights once at engine
         build: the dispatch param list swaps each converted weight's
@@ -1052,6 +1155,8 @@ class ContinuousBatchingEngine:
         family; refuses to compose with prefix caching (cached KV is a
         function of the weights — a shared trie would silently alias
         KV across adapters), spec decode, and chunked prefill."""
+        if self._state_spec:
+            self._refuse_state("install_adapter")
         if self.layout != "paged" or self.attn_impl != "ragged":
             raise ValueError(
                 "install_adapter requires kv_layout='paged' with "
@@ -1556,6 +1661,9 @@ class ContinuousBatchingEngine:
             in_use = usable - len(self._free)
             _M_PAGES_IN_USE.set(in_use)
             _M_PAGE_OCCUPANCY.set(in_use / max(usable, 1))
+        if self._state_spec:
+            _M_STATE_BYTES.set(self._state_nbytes())
+            _M_STATE_SLOTS.set(int(self._state_live.sum()))
 
     def lifecycle_info(self) -> Dict[str, int]:
         """Robustness counters + queue depth (≙ serving-stack SLO
@@ -1591,7 +1699,11 @@ class ContinuousBatchingEngine:
         One sentry per engine incarnation; a fleet's ReplicaHandle
         attaches a fresh one on every (re)build. A sentry trip never
         raises — the step completes and the router reads
-        ``sentry.trips`` to drive SUSPECT -> canary -> quarantine."""
+        ``sentry.trips`` to drive SUSPECT -> canary -> quarantine.
+        A sentry with an ``observe_layer_rows(slots, positions,
+        records)`` method is also handed, after every synchronous
+        dispatch, the per-row records of the model's reporting layers
+        (models/cache_spec.py ``ReportSpec``) for the live rows."""
         self.quiesce()    # pending logit rows belong to the OLD sentry
         self._sentry = sentry
         self._decode_jit = None       # rebuild with/without logits out
@@ -1650,6 +1762,8 @@ class ContinuousBatchingEngine:
         per layer, shaped (hk, n_pages, page_size, hd) over the slot's
         live block-table window — the D2H gather is the transfer
         plane's serialize cost."""
+        if self._state_spec:
+            self._refuse_state("export_pages")
         if self.layout != "paged":
             raise ValueError("export_pages requires the paged layout")
         # pipelined decode: the payload serializes host slot state
@@ -1751,6 +1865,8 @@ class ContinuousBatchingEngine:
         every outcome. Raises EngineOverloaded (no free slot) /
         PoolExhausted (no pages) when the engine cannot take it NOW —
         capacity deferrals, distinct from transfer failures."""
+        if self._state_spec:
+            self._refuse_state("import_pages")
         if self.layout != "paged":
             raise ValueError("import_pages requires the paged layout")
         # pipelined decode: the active set must be CONSTANT within a
@@ -1951,6 +2067,8 @@ class ContinuousBatchingEngine:
         v_scale) rows of the quantized chain, shaped (n, page_size));
         a cross-mode chain is refused with :class:`QuantMismatch` —
         the spilled bytes are only interpretable in their own mode."""
+        if self._state_spec:
+            self._refuse_state("import_prefix")
         if self.layout != "paged" or not self._prefix_enabled:
             return 0
         if (kv_scales is None) == bool(self._qkv):
@@ -2110,6 +2228,7 @@ class ContinuousBatchingEngine:
         usable = self.num_pages - 1
         in_use = usable - len(self._free)
         info = {"layout": "paged", "page_bytes": page_bytes,
+                "state_bytes": self._state_nbytes(),
                 "kv_quant": self._qkv,
                 "total_pages": usable, "pages_in_use": in_use,
                 "bytes_pool": self.num_pages * page_bytes,
@@ -2224,6 +2343,24 @@ class ContinuousBatchingEngine:
         elif self._adapter_rows:
             errs.append(f"adapter rows {self._adapter_rows} registered "
                         "but no stacks resident")
+        # state layers: a slot's state is live iff the slot holds a
+        # sequence (a step ends with every claimed slot dispatched), and
+        # the arrays are still what the specification says
+        for i, r in enumerate(self._slot_req if self._state_spec else ()):
+            if bool(self._state_live[i]) != (r is not None):
+                errs.append(
+                    f"slot {i}: state live={bool(self._state_live[i])} "
+                    f"but the slot is "
+                    f"{'running' if r is not None else 'free'}")
+        for layer, (s, arrays) in enumerate(zip(self._state_spec,
+                                                self._state)):
+            got = tuple((tuple(a.shape[1:]), jnp.dtype(a.dtype).name)
+                        for a in arrays)
+            want = tuple((tuple(sh), jnp.dtype(d).name)
+                         for sh, d in zip(s.shapes, s.dtypes))
+            if got != want or any(a.shape[0] != self.B for a in arrays):
+                errs.append(f"state layer {layer}: arrays {got} are not "
+                            f"({self.B} slots of) {want}")
         if self._spec is not None:
             self._check_invariants_draft(errs)
         if self._tp is not None:
@@ -2373,6 +2510,9 @@ class ContinuousBatchingEngine:
         req = self._slot_req[slot]
         self._slot_req[slot] = None
         self._slot_adapter[slot] = 0
+        # the arrays keep the old state; the slot's next sequence
+        # starts from zero by its descriptors (cache_spec.py)
+        self._state_live[slot] = False
         if self.layout == "paged":
             if self._prefix_enabled and req is not None and register:
                 # register BEFORE the decrefs so the prompt pages never
@@ -2802,17 +2942,25 @@ class ContinuousBatchingEngine:
                 self._pv(),
                 self._slot_adapter[np.asarray(pk["token_seq"],
                                               np.int32)])
-            nxt, self._kv = jit(
+            nxt, _, reports = self._take_step(jit(
                 pv, self._bv(),
-                self._kv, jnp.asarray(pk["ids"]),
+                self._cache(), jnp.asarray(pk["ids"]),
                 jnp.asarray(pk["token_seq"]),
                 jnp.asarray(pk["positions"]),
                 jnp.asarray(pk["query_start"]),
                 jnp.asarray(pk["query_len"]),
                 jnp.asarray(pk["context_len"]),
                 jnp.asarray(self._bt), jnp.asarray(pk["sample_rows"]),
-                self._next_keys())
+                self._next_keys()))
             nxt = np.asarray(nxt)
+        if reports is not None:
+            seq = np.asarray(pk["token_seq"])
+            live = np.flatnonzero(seq >= 0)
+            self._harvest_reports(reports, live, seq[live],
+                                  np.asarray(pk["positions"])[live])
+        if self._state_spec:
+            for p in batch:
+                self._state_live[p["slot"]] = True
         self._corrupt_kv_site()
         if self._sentry is not None:
             rows = [p["slot"] for p in batch if p["sample"]]
@@ -2939,7 +3087,9 @@ class ContinuousBatchingEngine:
         with telemetry on only."""
         from ..ops.ragged_paged_attention import (kv_block_pages,
                                                   ragged_pages_walked)
-        _, hk, hd, dt = self._kv_shape
+        layers, hk, hd, dt = self._kv_shape
+        if not layers:
+            return                      # no KV layer: no attention call
         if self._view_tp() is not None:
             hk //= self._tp.tp                  # a shard's local heads
         walked = ragged_pages_walked(
@@ -2999,17 +3149,33 @@ class ContinuousBatchingEngine:
         tk, tp = self.top_k, self.top_p
         view_tp = self._view_tp(draft=draft)
         qkv = bool(self._qkv)
+        spec = _cache_spec(model) if draft else self._layer_spec
+        stateful = bool(self._state_spec) and not draft
 
         def run(pv, bv, kv, ids, tok_seq, qpos, qstart, qlen, ctx, bt,
                 sample_rows, key):
             from .generation import bind_state, _sample_token
             from .llama import RaggedKVCacheView
+            state = ()
+            if stateful:
+                kv, state = kv
+            pools, states = iter(kv), iter(state)
             with bind_state(params, buffers, pv, bv), no_grad():
-                views = [RaggedKVCacheView(
-                    e[0], e[1], bt, tok_seq, qpos, qstart, qlen, ctx,
-                    block_q, pages_bound, tp=view_tp,
-                    k_scale=e[2] if qkv else None,
-                    v_scale=e[3] if qkv else None) for e in kv]
+                views = []
+                for s in spec:
+                    if isinstance(s, KVSpec):
+                        e = next(pools)
+                        views.append(RaggedKVCacheView(
+                            e[0], e[1], bt, tok_seq, qpos, qstart, qlen,
+                            ctx, block_q, pages_bound, tp=view_tp,
+                            k_scale=e[2] if qkv else None,
+                            v_scale=e[3] if qkv else None))
+                    elif isinstance(s, StateSpec):
+                        views.append(RaggedStateView(
+                            next(states), tok_seq, qstart, qlen, ctx,
+                            one_token=block_q == 1))
+                    else:
+                        views.append(None)      # ReportSpec or nothing
                 logits, new = model.forward(
                     Tensor(ids[None]), past_key_values=views,
                     use_cache=True)
@@ -3018,14 +3184,22 @@ class ContinuousBatchingEngine:
                     rows = rows[jnp.clip(sample_rows, 0,
                                          rows.shape[0] - 1)]
                 nxt, _ = _sample_token(rows, key, strat, temp, tk, tp)
-                kv_out = [
+                cache = [
                     (v.k_pages._value, v.v_pages._value,
                      v.k_scale._value, v.v_scale._value) if qkv
                     else (v.k_pages._value, v.v_pages._value)
-                    for v in new]
-                if return_logits:
-                    return nxt, rows, kv_out
-                return nxt, kv_out
+                    for v in new if isinstance(v, RaggedKVCacheView)]
+                if stateful:
+                    cache = (cache, [v.arrays for v in new
+                                     if isinstance(v, RaggedStateView)])
+                out = (nxt,) + ((rows,) if return_logits else ()) \
+                    + (cache,)
+                reports = [v for s, v in zip(spec, new)
+                           if isinstance(s, ReportSpec)]
+                if reports:
+                    out += ((jnp.concatenate([c for c, _ in reports]),
+                             tuple(r for _, r in reports)),)
+                return out
 
         if not jit:
             # raw op-by-op program for the dispatch-gap sampler
@@ -3369,6 +3543,16 @@ class ContinuousBatchingEngine:
 
         return jax.jit(run, donate_argnums=(2,))
 
+    def _decode_query_lens(self):
+        """One query a slot. A state layer must leave an idle slot's
+        state alone, so for a model with state layers an idle slot's
+        row is dispatched with NO query (the KV layers' idle rows
+        trash-route either way)."""
+        if not self._state_spec:
+            return self._decode_ones
+        return jnp.asarray(np.fromiter(
+            (r is not None for r in self._slot_req), np.int32, self.B))
+
     def _requeue_or_starve(self, req: Request,
                            finished: List[Request]):
         """Shared tail of both preemption paths (decode-time eviction,
@@ -3517,7 +3701,7 @@ class ContinuousBatchingEngine:
                         self._slot_freed[i] += 1
             if not any(r is not None for r in self._slot_req):
                 return False          # every slot preempted away
-            kv = self._kv
+            kv = self._cache()
             bt = jnp.asarray(self._bt)
         else:
             kv = self._caches
@@ -3545,9 +3729,10 @@ class ContinuousBatchingEngine:
             # (tokens/sec derives from it) — a fake clock here would
             # fabricate hardware throughput, not make tests exact
             t0 = time.perf_counter()
-            lg_rows = None
+            lg_rows = reports = None
             if self.layout == "paged" and self.attn_impl == "ragged":
                 bidx = self._decode_idx
+                qlen = self._decode_query_lens()
                 # pipelined mode: mid-window the token input is the
                 # PREVIOUS dispatch's on-device output — the greedy
                 # feedback needs no host round-trip (the whole point)
@@ -3562,22 +3747,20 @@ class ContinuousBatchingEngine:
                         self._bv(),
                         kv, tok_in, bidx,
                         jnp.asarray(pos.astype(np.int32)), bidx,
-                        self._decode_ones,
+                        qlen,
                         jnp.asarray((pos + 1).astype(np.int32)), bt,
                         bidx, self._next_keys())
-                if self._decode_logits:
-                    nxt, lg_rows, new_kv = out
-                else:
-                    nxt, new_kv = out
+                nxt, lg_rows, reports = self._take_step(
+                    out, self._decode_logits)
             else:
                 nxt, new_kv = self._decode_jit(
                     self._pv(), self._bv(),
                     kv, jnp.asarray(self._tok), jnp.asarray(pos), bt,
                     self._next_keys())
-            if self.layout == "paged":
-                self._kv = new_kv
-            else:
-                self._caches = new_kv
+                if self.layout == "paged":
+                    self._kv = new_kv
+                else:
+                    self._caches = new_kv
             # pdt-lint: disable=PDT001 same real-wall measurement as t0
             t1 = time.perf_counter()
             _M_DECODE_DISPATCH.observe(t1 - t0)
@@ -3626,6 +3809,10 @@ class ContinuousBatchingEngine:
             _M_DECODE_TOKENS.inc(n_active)
             if dt > 0:
                 _M_TOKENS_PER_SEC.set(n_active / dt)
+        if reports is not None:
+            act = [i for i, r in enumerate(self._slot_req)
+                   if r is not None]
+            self._harvest_reports(reports, act, act, pos[act])
         # gray-failure corrupt site + sentry checks, AFTER the timed
         # window so decode_step_seconds stays comparable across
         # sentry-on/off engines (the sentry's own cost rides
@@ -3839,7 +4026,7 @@ class ContinuousBatchingEngine:
         bidx = jnp.arange(self.B, dtype=jnp.int32)
         ones = jnp.ones(self.B, jnp.int32)
         args = (self._lora_pv(self._pv(), self._slot_adapter),
-                self._bv(), self._kv, jnp.asarray(self._tok), bidx,
+                self._bv(), self._cache(), jnp.asarray(self._tok), bidx,
                 jnp.asarray(pos.astype(np.int32)), bidx, ones,
                 jnp.asarray((pos + 1).astype(np.int32)),
                 jnp.asarray(self._bt), bidx, jax.random.PRNGKey(0))

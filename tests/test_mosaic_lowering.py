@@ -370,12 +370,32 @@ class TestGroupedMatmulLowering:
         x = jnp.zeros((n, BENCH_HIDDEN), jnp.bfloat16)
         w = jnp.zeros((e, BENCH_HIDDEN, BENCH_HIDDEN), jnp.bfloat16)
         sizes = jnp.full((e,), n // e, jnp.int32)
-        # block_aligned: the Pallas kernel, not XLA's ragged_dot (whose
-        # own TPU kernel refuses bf16 under this suite's global
-        # "highest" matmul precision — "Bad lhs type")
+        # groups in multiples of 128 rows: the Pallas kernel, not XLA's
+        # ragged_dot (whose own TPU kernel refuses bf16 under this
+        # suite's global "highest" matmul precision — "Bad lhs type")
         assert _lower(
-            lambda x, w, sizes: grouped_matmul_values(x, w, sizes, True),
-            x, w, sizes) == {"_gmm_kernel": 1}
+            lambda x, w, sizes: grouped_matmul_values(x, w, sizes, 128),
+            x, w, sizes) == {"grouped_matmul": 1}
+
+    @pytest.mark.parametrize("rows,k,n", [
+        (64, 1024, 2688), (64, 2688, 1024),        # a decode step
+        (1024, 1024, 2688), (1024, 2688, 1024)])   # an admission
+    def test_expert_layer_shapes(self, rows, k, n):
+        """ISSUE 27: the latent expert layer of the reasoning cell: 128
+        held experts of 512, 22 choices a row, groups padded to
+        `row_block` (16 rows at decode, 64 at an admission), tiles of
+        1024 and 896 that divide 2688."""
+        from paddle_tpu.ops.grouped_matmul import (grouped_matmul_values,
+                                                   row_block)
+        held, top_k = 128, 22
+        bm = row_block(rows * top_k / 512)
+        m = -(-(rows * top_k + held * (bm - 1)) // bm) * bm
+        x = jnp.zeros((m, k), jnp.bfloat16)
+        w = jnp.zeros((held, k, n), jnp.bfloat16)
+        sizes = jnp.full((held,), bm, jnp.int32)
+        assert _lower(
+            lambda x, w, sizes: grouped_matmul_values(x, w, sizes, bm),
+            x, w, sizes) == {"grouped_matmul": 1}
 
 
 class TestLoraEpilogueLowering:

@@ -579,6 +579,7 @@ class TestDocsAndSiteConsistency:
         import paddle_tpu.distributed.fleet.elastic   # noqa: F401
         import paddle_tpu.distributed.launch          # noqa: F401
         import paddle_tpu.loadgen                     # noqa: F401
+        import paddle_tpu.models.nemotron_h           # noqa: F401
         import paddle_tpu.models.serving              # noqa: F401
         import paddle_tpu.observability.slo           # noqa: F401
         import paddle_tpu.serving                     # noqa: F401
