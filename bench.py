@@ -1075,9 +1075,9 @@ def bench_paged_attention(on_tpu: bool) -> dict:
     def _pool(n_seqs):
         num_pages = n_seqs * pps + 1
         kp = jnp.asarray(rng.standard_normal(
-            (hk, num_pages, ps, d)).astype(np.float32), dt)
+            (num_pages, ps, hk * d)).astype(np.float32), dt)
         vp = jnp.asarray(rng.standard_normal(
-            (hk, num_pages, ps, d)).astype(np.float32), dt)
+            (num_pages, ps, hk * d)).astype(np.float32), dt)
         bt = (np.arange(n_seqs * pps, dtype=np.int32)
               .reshape(n_seqs, pps) + 1)
         return kp, vp, bt
@@ -1095,7 +1095,7 @@ def bench_paged_attention(on_tpu: bool) -> dict:
         """The pre-trim baseline: gather the FULL block table, then the
         shared masked core — what `_paged_xla` cost before ISSUE 6."""
         t = q.shape[0]
-        kc, vc = rpa.gather_pages(kp, vp, jnp.asarray(bt),
+        kc, vc = rpa.gather_pages(kp, vp, jnp.asarray(bt), hk,
                                   pages_bound=bt.shape[1])
         seq_t, pos_t = rpa.token_arrays(qs, ql, cl, t)
         tok_seq = np.maximum(seq_t, 0)

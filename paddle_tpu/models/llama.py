@@ -213,8 +213,9 @@ def _update_kv_cache(cache: Tensor, new: Tensor, offset) -> Tensor:
 class PagedKVCacheView:
     """`past_key_value` for the paged decode path (≙ the reference serving
     engine's blocked KV cache under «fused_multi_transformer», SURVEY.md
-    §2.1 fused row): per-layer page pools (HK, P, page_size, D) plus the
-    SHARED per-sequence block table (B, pps). The token's write position
+    §2.1 fused row): per-layer page pools (P, page_size, HK*D), stored
+    token-major as the row scatter writes them, plus the SHARED
+    per-sequence block table (B, pps). The token's write position
     and the context length both come from `position_offset`, which must be
     a (B,) vector on this path. Decode-only (seq_len == 1)."""
 
@@ -231,7 +232,7 @@ class PagedKVCacheView:
 class RaggedKVCacheView:
     """`past_key_value` for the RAGGED serving path (≙ the ragged
     paged-attention design, PAPERS.md arxiv 2604.15464): per-layer page
-    pools (HK, P, page_size, D), the shared per-sequence block table
+    pools (P, page_size, HK*D), the shared per-sequence block table
     (N, pps), and the descriptors of ONE packed mixed batch — decode
     steps, full prefills, chunk continuations, and prefix-cache suffix
     prefills all ride the same (1, T) token axis. `token_seq`/

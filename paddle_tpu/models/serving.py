@@ -84,6 +84,7 @@ import jax.numpy as jnp
 
 from ..core.tensor import Tensor
 from ..distributed import mesh as mesh_mod
+from ..ops.ragged_paged_attention import pages_to_payload, payload_to_pages
 from ..autograd import no_grad
 from ..utils.faults import (FaultError, fault_point, fault_value,
                             value_armed)
@@ -781,13 +782,17 @@ class ContinuousBatchingEngine:
                 raise ValueError("num_pages must be >= 2 (page 0 is "
                                  "reserved)")
             def _pool():
+                # stored token-major, the layout the row scatter
+                # writes (ops/ragged_paged_attention.py): a token's
+                # row over all KV heads is contiguous, so the write
+                # updates the donated pool in place
                 pool_dt = jnp.int8 if self._qkv else dt
-                z = jnp.zeros((hk, self.num_pages, self.page_size, hd),
+                z = jnp.zeros((self.num_pages, self.page_size, hk * hd),
                               pool_dt)
                 if self._tp is None:
                     return z
-                # sharded allocator contract: the pool splits on the
-                # KV-head axis, so every page id names tp local shards
+                # sharded allocator contract: the pool splits its rows
+                # by KV head, so every page id names tp local shards
                 return jax.device_put(z, self._tp.kv_sharding(hk))
 
             def _spool():
@@ -986,8 +991,8 @@ class ContinuousBatchingEngine:
             self._d_num_pages = int(spec_decode.num_pages
                                     or self.B * self.pps + 1)
             def _d_pool():
-                z = jnp.zeros((d_hk, self._d_num_pages, self.page_size,
-                               d_hd),
+                z = jnp.zeros((self._d_num_pages, self.page_size,
+                               d_hk * d_hd),
                               jnp.int8 if self._qkv else d_dt)
                 if self._tp is None:
                     return z
@@ -1728,17 +1733,17 @@ class ContinuousBatchingEngine:
         entry = self._kv[0]
         kp = entry[0]
         idx = np.asarray(live, np.int32)
-        sub = np.asarray(kp[:, idx])
+        sub = np.asarray(kp[idx])
         mut = fault_value("serving.kv_page", sub, tag=self.fault_tag)
         if mut is sub:
             return
-        new_kp = kp.at[:, jnp.asarray(idx)].set(
+        new_kp = kp.at[jnp.asarray(idx)].set(
             jnp.asarray(np.asarray(mut), kp.dtype))
         if self._tp is not None:
             # keep the pool on its declared submesh sharding — the
             # eager scatter above may have resolved to replicated
-            new_kp = jax.device_put(new_kp,
-                                    self._tp.kv_sharding(kp.shape[0]))
+            new_kp = jax.device_put(
+                new_kp, self._tp.kv_sharding(self._kv_shape[1]))
         # quantized engines keep their scale pools untouched: the
         # damage lands in the int8 lattice bytes (a flipped high bit
         # is a sign/magnitude flip after dequant — same loudness)
@@ -1788,14 +1793,14 @@ class ContinuousBatchingEngine:
                           np.asarray(e[3][pages])) for e in self._kv]
         if self._tp is not None and self._tp.tp > 1:
             # tensor-parallel source: serialize one payload FRAGMENT
-            # per shard — each `shard.data[:, pages]` gather runs on
+            # per shard — each `shard.data[pages]` gather runs on
             # its own device and only its result crosses to the host,
             # so migration bytes stay local per shard (the wire format
             # is the fragments; `assemble_payload_kv` is the
             # consumer-side logical view)
             from ..serving import submesh as tp_mod
-            per_layer = [(tp_mod.kv_fragments(e[0], pages),
-                          tp_mod.kv_fragments(e[1], pages))
+            per_layer = [(tp_mod.kv_fragments(e[0], pages, hd),
+                          tp_mod.kv_fragments(e[1], pages, hd))
                          for e in self._kv]
             n_tp = len(per_layer[0][0])
             kv_shards = [[(kf[s], vf[s]) for kf, vf in per_layer]
@@ -1804,8 +1809,11 @@ class ContinuousBatchingEngine:
                 [sum(k.nbytes + v.nbytes for k, v in shard)
                  for shard in kv_shards])
         else:
-            kv = [(np.asarray(e[0][:, pages]), np.asarray(e[1][:, pages]))
-                  for e in self._kv]
+            # the payload keeps its head-major shape: the few exported
+            # pages are transposed here, off the step
+            kv = [tuple(np.ascontiguousarray(pages_to_payload(
+                np.asarray(pool[pages]), hk)) for pool in e[:2])
+                for e in self._kv]
         payload_kv = {"kv": kv, "kv_shards": kv_shards,
                       "kv_scales": kv_scales}
         return {
@@ -2130,8 +2138,12 @@ class ContinuousBatchingEngine:
         dequant scales (`scale_rows`: one (k_scale, v_scale) pair of
         (n_pages, page_size) arrays per layer) — the quantized BYTES
         move verbatim, never re-quantized, which is what keeps
-        migrated streams bit-identical."""
+        migrated streams bit-identical. `rows` arrive in the payload's
+        head-major shape (hk, n_pages, page_size, hd) and are
+        transposed here, on the host, to the pools' token-major rows."""
         n = len(page_ids)
+        rows = [(payload_to_pages(np.asarray(rk)),
+                 payload_to_pages(np.asarray(rv))) for rk, rv in rows]
         jit = self._jit_lru(self._install_jits, n,
                             self._build_install, family="install")
         if self._tp is not None:
@@ -2139,8 +2151,7 @@ class ContinuousBatchingEngine:
             # each device receives only ITS fragment of the transfer
             hk = self.model.config.num_key_value_heads
             sh = self._tp.kv_sharding(hk)
-            rows_dev = [(jax.device_put(np.asarray(rk), sh),
-                         jax.device_put(np.asarray(rv), sh))
+            rows_dev = [(jax.device_put(rk, sh), jax.device_put(rv, sh))
                         for rk, rv in rows]
             srows_dev = None if scale_rows is None else [
                 (jax.device_put(np.asarray(sk), self._tp.replicated()),
@@ -2163,14 +2174,14 @@ class ContinuousBatchingEngine:
         def _ins(kv, ids_, rows_, srows_):
             if quant:
                 return [
-                    (kp.at[:, ids_].set(rk.astype(kp.dtype)),
-                     vp.at[:, ids_].set(rv.astype(vp.dtype)),
+                    (kp.at[ids_].set(rk.astype(kp.dtype)),
+                     vp.at[ids_].set(rv.astype(vp.dtype)),
                      ks.at[ids_].set(sk.astype(ks.dtype)),
                      vs.at[ids_].set(sv.astype(vs.dtype)))
                     for (kp, vp, ks, vs), (rk, rv), (sk, sv)
                     in zip(kv, rows_, srows_)]
-            return [(kp.at[:, ids_].set(rk.astype(kp.dtype)),
-                     vp.at[:, ids_].set(rv.astype(vp.dtype)))
+            return [(kp.at[ids_].set(rk.astype(kp.dtype)),
+                     vp.at[ids_].set(rv.astype(vp.dtype)))
                     for (kp, vp), (rk, rv) in zip(kv, rows_)]
         return jax.jit(_ins, donate_argnums=(0,))
 
@@ -3488,13 +3499,9 @@ class ContinuousBatchingEngine:
             with bind_state(params, buffers, pv, bv), no_grad():
                 caches = []
                 for (kp, vp) in kv:
-                    # (hk, n_pp, ps, hd) -> (1, shared_len, hk, hd)
-                    kd = jnp.transpose(kp[:, bt_prefix],
-                                       (1, 2, 0, 3)).reshape(
-                        1, shared_len, hk, hd)
-                    vd = jnp.transpose(vp[:, bt_prefix],
-                                       (1, 2, 0, 3)).reshape(
-                        1, shared_len, hk, hd)
+                    # (n_pp, ps, hk*hd) -> (1, shared_len, hk, hd)
+                    kd = kp[bt_prefix].reshape(1, shared_len, hk, hd)
+                    vd = vp[bt_prefix].reshape(1, shared_len, hk, hd)
                     pad = jnp.zeros((1, bucket, hk, hd), kd.dtype)
                     caches.append(
                         (Tensor(jnp.concatenate([kd, pad], 1)),

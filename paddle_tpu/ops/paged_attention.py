@@ -4,11 +4,14 @@
 «fused_multi_transformer» decode kernels and the paged-KV design the
 L10 inference engine needs (SURVEY.md §1 L10, §7 step 6 "paged attention
 (serving)"). TPU-native design: the KV cache lives in fixed-size pages
-(HK, num_pages, page_size, D); each sequence owns a row of page indices
-(block table). The Pallas kernel walks a sequence's pages with the block
-table SCALAR-PREFETCHED, so the page index feeds the BlockSpec index_map
-and Mosaic double-buffers page fetches from HBM — the TPU equivalent of
-vLLM's gather-free paged attention. Online softmax accumulates across
+stored token-major, (num_pages, page_size, HK*D) — the layout of the
+row scatter that writes them (ragged_paged_attention.py's docstring);
+each sequence owns a row of page indices (block table). The Pallas
+kernel walks a sequence's pages with the block table SCALAR-PREFETCHED,
+so the page index feeds the BlockSpec index_map and Mosaic
+double-buffers page fetches (one contiguous block a page, every KV head
+in it; a head is a lane slice) from HBM — the TPU equivalent of vLLM's
+gather-free paged attention. Online softmax accumulates across
 pages in VMEM scratch; pages past the sequence's context length are
 masked (their DMA still runs — grid shapes are static — but a cheaper
 `pl.when` skips the FLOPs).
@@ -35,6 +38,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import mxu_dot, on_tpu
+from .ragged_paged_attention import (gather_pages, masked_page_attention,
+                                     set_rows)
 from ..core.tensor import Tensor, apply
 
 NEG_INF = -1e30
@@ -50,9 +55,9 @@ def _paged_kernel(ctx_ref, bt_ref,          # scalar-prefetched
                   q_ref, k_ref, v_ref, o_ref,
                   acc_ref, m_ref, l_ref, *, scale, page_size, window):
     b = pl.program_id(0)
-    h = pl.program_id(1)
-    i = pl.program_id(2)
-    n_pages = pl.num_programs(2)
+    i = pl.program_id(1)
+    n_pages = pl.num_programs(1)
+    _, hk, _, d = q_ref.shape
 
     @pl.when(i == 0)
     def _init():
@@ -70,46 +75,49 @@ def _paged_kernel(ctx_ref, bt_ref,          # scalar-prefetched
 
     @pl.when(live)
     def _page():
-        q = q_ref[0, 0].astype(jnp.float32)          # (G, D)
-        k = k_ref[0, 0].astype(jnp.float32)          # (page_size, D)
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = mxu_dot(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # (G, page_size)
-        pos = i * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        valid = pos < ctx
-        if window is not None:
-            valid = valid & (pos >= ctx - window)
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[:, :1]                         # (G, 1)
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                        # (G, page_size)
-        l_new = alpha * l_ref[:, :1] + jnp.sum(p, -1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + mxu_dot(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # (G, D)
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+        for h in range(hk):
+            q = q_ref[0, h].astype(jnp.float32)      # (G, D)
+            # head h of the page: its lanes of every stored row
+            k = k_ref[0, :, h * d:(h + 1) * d].astype(jnp.float32)
+            v = v_ref[0, :, h * d:(h + 1) * d].astype(jnp.float32)
+            s = mxu_dot(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale   # (G, ps)
+            pos = i * page_size + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            valid = pos < ctx
+            if window is not None:
+                valid = valid & (pos >= ctx - window)
+            s = jnp.where(valid, s, NEG_INF)
+            m_prev = m_ref[h, :, :1]                  # (G, 1)
+            m_cur = jnp.max(s, axis=-1, keepdims=True)
+            m_new = jnp.maximum(m_prev, m_cur)
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)                    # (G, page_size)
+            l_new = alpha * l_ref[h, :, :1] + jnp.sum(p, -1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + mxu_dot(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)   # (G, D)
+            m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
 
     @pl.when(i == n_pages - 1)
     def _finish():
-        l = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0, 0] = (acc_ref[:] / l).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[:, :, :1], 1e-30)
+        o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
 
 
 def paged_attention_values(q, k_pages, v_pages, context_lens, block_tables,
                            scale=None, window=None, use_kernel=None):
-    """q: (B, H, D); k_pages/v_pages: (HK, P, page_size, D);
+    """q: (B, H, D); k_pages/v_pages: (P, page_size, HK*D);
     context_lens: (B,) int32; block_tables: (B, pages_per_seq) int32.
     `window`: static sliding-window size — the decode query sees only
     keys in [ctx - window, ctx). `use_kernel`: None routes by platform;
     True forces the Pallas kernel (interpret mode off-TPU — the CI
     kernel/oracle parity path). Returns (B, H, D)."""
     b, h, d = q.shape
-    hk, _, page_size, _ = k_pages.shape
+    _, page_size, row = k_pages.shape
+    hk = row // d
     g = h // hk
     pps = block_tables.shape[1]
     sc = scale if scale is not None else 1.0 / math.sqrt(d)
@@ -122,21 +130,22 @@ def paged_attention_values(q, k_pages, v_pages, context_lens, block_tables,
     qh = q.reshape(b, hk, g, d)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hk, pps),
+        # a step is one page of one sequence, every KV head in it
+        grid=(b, pps),
         in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda bb, hh, ii, ctx, bt:
-                         (bb, hh, 0, 0)),
-            pl.BlockSpec((1, 1, page_size, d), lambda bb, hh, ii, ctx, bt:
-                         (hh, bt[bb, ii], 0, 0)),
-            pl.BlockSpec((1, 1, page_size, d), lambda bb, hh, ii, ctx, bt:
-                         (hh, bt[bb, ii], 0, 0)),
+            pl.BlockSpec((1, hk, g, d), lambda bb, ii, ctx, bt:
+                         (bb, 0, 0, 0)),
+            pl.BlockSpec((1, page_size, row), lambda bb, ii, ctx, bt:
+                         (bt[bb, ii], 0, 0)),
+            pl.BlockSpec((1, page_size, row), lambda bb, ii, ctx, bt:
+                         (bt[bb, ii], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, d), lambda bb, hh, ii, ctx, bt:
-                               (bb, hh, 0, 0)),
+        out_specs=pl.BlockSpec((1, hk, g, d), lambda bb, ii, ctx, bt:
+                               (bb, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((g, d), jnp.float32),
-            pltpu.VMEM((g, LANES), jnp.float32),
-            pltpu.VMEM((g, LANES), jnp.float32),
+            pltpu.VMEM((hk, g, d), jnp.float32),
+            pltpu.VMEM((hk, g, LANES), jnp.float32),
+            pltpu.VMEM((hk, g, LANES), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -156,11 +165,10 @@ def _paged_xla(q, k_pages, v_pages, context_lens, block_tables, scale,
     prefix actually referenced (static trim on pps when the context
     lengths are concrete), and the masking math is the ONE shared copy
     in `ragged_paged_attention.masked_page_attention`."""
-    from .ragged_paged_attention import gather_pages, masked_page_attention
     b, h, d = q.shape
-    hk = k_pages.shape[0]
+    hk = k_pages.shape[2] // d
     g = h // hk
-    kc, vc = gather_pages(k_pages, v_pages, block_tables,
+    kc, vc = gather_pages(k_pages, v_pages, block_tables, hk,
                           context_lens=context_lens)
     ctx = jnp.asarray(context_lens, jnp.int32)
     out = masked_page_attention(q.reshape(b, hk, g, d), kc, vc,
@@ -186,14 +194,13 @@ def paged_append_values(k_pages, v_pages, k, v, block_tables, positions):
     """Write one token per sequence into the page pools.
 
     k/v: (B, HK, D); positions: (B,) global position of the new token;
-    block_tables: (B, pps). Returns the updated (k_pages, v_pages)."""
-    page_size = k_pages.shape[2]
+    block_tables: (B, pps). Returns the updated (k_pages, v_pages):
+    the ragged path's row scatter (`set_rows`)."""
+    page_size = k_pages.shape[1]
     page_idx = jnp.take_along_axis(
         block_tables, (positions // page_size)[:, None], axis=1)[:, 0]
-    slot = positions % page_size
-    kp = k_pages.at[:, page_idx, slot].set(jnp.swapaxes(k, 0, 1))
-    vp = v_pages.at[:, page_idx, slot].set(jnp.swapaxes(v, 0, 1))
-    return kp, vp
+    return set_rows(k_pages, v_pages, page_idx, positions % page_size,
+                    k, v)
 
 
 def paged_prefill_scatter(k_pages, v_pages, k_rows, v_rows, block_table,
@@ -204,31 +211,28 @@ def paged_prefill_scatter(k_pages, v_pages, k_rows, v_rows, block_table,
     block_table: (pps,) page ids for that sequence; rows at positions
     >= true_len are routed to `trash_page` (a permanently reserved page
     that is never read) so the scatter stays static-shape."""
-    t = k_rows.shape[0]
-    page_size = k_pages.shape[2]
-    pos = jnp.arange(t)
+    page_size = k_pages.shape[1]
+    pos = jnp.arange(k_rows.shape[0])
     page_idx = jnp.where(pos < true_len,
                          block_table[pos // page_size], trash_page)
-    slot = pos % page_size
-    kp = k_pages.at[:, page_idx, slot].set(jnp.swapaxes(k_rows, 0, 1))
-    vp = v_pages.at[:, page_idx, slot].set(jnp.swapaxes(v_rows, 0, 1))
-    return kp, vp
+    return set_rows(k_pages, v_pages, page_idx, pos % page_size,
+                    k_rows, v_rows)
 
 
 class PagedKVCache:
     """Page-pool KV cache for serving (one per layer).
 
     ≙ the inference engine's cache manager role (SURVEY.md §1 L10): a
-    fixed pool of (page_size x D) pages per KV head plus per-sequence
-    block tables. `append` writes one token per sequence and returns the
+    fixed pool of (page_size x HK*D) pages plus per-sequence block
+    tables. `append` writes one token per sequence and returns the
     updated cache (functional — jit/donation friendly).
     """
 
     def __init__(self, num_kv_heads, head_dim, num_pages, page_size=16,
                  dtype=jnp.bfloat16):
         self.page_size = page_size
-        self.k_pages = jnp.zeros((num_kv_heads, num_pages, page_size,
-                                  head_dim), dtype)
+        self.k_pages = jnp.zeros((num_pages, page_size,
+                                  num_kv_heads * head_dim), dtype)
         self.v_pages = jnp.zeros_like(self.k_pages)
 
     def append(self, k, v, block_tables, positions):

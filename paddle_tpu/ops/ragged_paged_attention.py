@@ -18,6 +18,22 @@ prefix-cache suffix prefill whose queries attend causally at
 Rows owned by no sequence are padding: their output is zero and their
 KV (see `ragged_scatter_values`) routes to the trash page.
 
+Stored layout. A page pool is TOKEN-MAJOR: ``(P, page_size, HK*D)``, a
+token's K (or V) row over all KV heads contiguous, head ``h`` its lanes
+``[h*D, (h+1)*D)``. The layout is the WRITE's: a step's rows land with
+one row scatter ``pool.at[page, slot].set(rows)`` whose update window
+is a whole stored row, so XLA updates the donated pool in place. (Stored
+head-major, ``(HK, P, page_size, D)``, the same scatter's window was
+``(HK, D)`` across the slowest axis; XLA gave it a layout of its own
+and every layer of every program copied each pool whole in front of
+the scatter and back behind it: PERF.md section 6, PR 28.) A page is
+one contiguous block, so the kernel fetches it with one DMA descriptor,
+and a head is a lane slice of the VMEM block: the same ``(keys, D)``
+tile the MXU multiplies. ``HK`` is read off the shapes
+(``pool.shape[2] // q.shape[2]``); `pages_to_payload` /
+`payload_to_pages` are the transposes at the engine's export / import
+boundary, whose payload stays ``(HK, n_pages, page_size, D)``.
+
 Kernel. The grid is (q-blocks,): one step a q block, with every KV
 head of the step in it. Each q block belongs to exactly one sequence
 (the packer aligns ``query_start`` to ``block_q``; decode batches use
@@ -27,14 +43,16 @@ loop walks the q block's LIVE pages only, a KV BLOCK of several pages x
 all heads a trip: from the block that holds the sliding window's lower
 edge (block 0 without a window) to the block of the q block's causal
 frontier. A trip starts the DMAs of the next block's pages (one
-descriptor a page, covering every head, into the other half of a
-two-slot VMEM buffer) and waits for its own — the double buffering the
-BlockSpec pipeline used to do a 4 KB page at a time — then per head
-multiplies ``(block_q*G, D) x (block keys, D)^T``, masks by position
-and folds the block into the online softmax. The trip count is dynamic:
-a padding q block, a sequence with no query and an idle slot do none
-and write zeros; no column past the frontier is visited, so the width
-of the block table costs nothing and no bound on it shapes the program.
+descriptor a page, a contiguous ``(page_size, HK*D)`` block with every
+head in it, into the other half of a two-slot VMEM buffer) and waits
+for its own — the double buffering the BlockSpec pipeline used to do a
+4 KB page at a time — then per head multiplies ``(block_q*G, D) x
+(block keys, D)^T`` (the head's lane slice of the block), masks by
+position and folds the block into the online softmax. The trip count is
+dynamic: a padding q block, a sequence with no query and an idle slot
+do none and write zeros; no column past the frontier is visited, so the
+width of the block table costs nothing and no bound on it shapes the
+program.
 Pages of a live block that lie outside the live range (below the
 window's edge, past the frontier) copy the trash page 0 and are masked
 by position; their table entries are never read. `kv_block_pages`
@@ -197,26 +215,39 @@ def gather_page_scales(scale_pool, block_tables, bound):
     return sg.reshape(bt.shape[0], bound * scale_pool.shape[1])
 
 
-def gather_pages(k_pages, v_pages, block_tables, context_lens=None,
-                 pages_bound=None):
-    """Gather block-table pages to per-sequence contiguous caches
-    (N, S, HK, D), bounding the gather to the block-table prefix
-    actually referenced: when ``context_lens`` is CONCRETE (host-side
-    numpy / eager call) the trim is static — ``S = ceil(max(ctx) /
-    page_size) * page_size`` — instead of materializing the full
-    ``pps * page_size`` worst case.  ``pages_bound`` overrides the trim
-    explicitly (traced callers that know a static bound)."""
-    page_size = k_pages.shape[2]
-    pps = block_tables.shape[1]
+def pages_to_payload(pages, kv_heads):
+    """Stored pages (n, page_size, HK*D) -> the export payload's
+    head-major (HK, n, page_size, D). The engine's `export_pages` /
+    `import_pages` boundary keeps that documented shape whatever the
+    pools store; numpy or jax arrays."""
+    n, page_size, row = pages.shape
+    return pages.reshape(n, page_size, kv_heads,
+                         row // kv_heads).transpose(2, 0, 1, 3)
+
+
+def payload_to_pages(rows):
+    """The inverse of `pages_to_payload`: (HK, n, page_size, D) ->
+    (n, page_size, HK*D), the rows an install writes into the pool."""
+    hk, n, page_size, d = rows.shape
+    return rows.transpose(1, 2, 0, 3).reshape(n, page_size, hk * d)
+
+
+def gather_pages(k_pages, v_pages, block_tables, kv_heads,
+                 context_lens=None, pages_bound=None):
+    """Gather block-table pages of the (P, page_size, HK*D) pools to
+    per-sequence contiguous caches (N, S, HK, D), bounding the gather
+    to the block-table prefix actually referenced: when
+    ``context_lens`` is CONCRETE (host-side numpy / eager call) the
+    trim is static — ``S = ceil(max(ctx) / page_size) * page_size`` —
+    instead of materializing the full ``pps * page_size`` worst case.
+    ``pages_bound`` overrides the trim explicitly (traced callers that
+    know a static bound)."""
+    page_size = k_pages.shape[1]
     bound = page_gather_bound(block_tables, context_lens, pages_bound,
                               page_size)
     bt = block_tables[:, :bound]
-    n = bt.shape[0]
-    kg = jnp.transpose(k_pages[:, bt], (1, 2, 3, 0, 4))
-    vg = jnp.transpose(v_pages[:, bt], (1, 2, 3, 0, 4))
-    s_max = bound * page_size
-    hk, d = k_pages.shape[0], k_pages.shape[3]
-    return (kg.reshape(n, s_max, hk, d), vg.reshape(n, s_max, hk, d))
+    shape = (bt.shape[0], bound * page_size, kv_heads, -1)
+    return k_pages[bt].reshape(shape), v_pages[bt].reshape(shape)
 
 
 def masked_page_attention(q, kc, vc, q_positions, context_lens, scale,
@@ -257,15 +288,14 @@ def _ragged_xla(q, k_pages, v_pages, query_start, query_len, context_len,
     per row right after the gather, so the masked core itself stays
     dtype-oblivious."""
     t, h, d = q.shape
-    hk = k_pages.shape[0]
+    hk = k_pages.shape[2] // d
     g = h // hk
-    n = block_tables.shape[0]
-    kc, vc = gather_pages(k_pages, v_pages, block_tables,
+    kc, vc = gather_pages(k_pages, v_pages, block_tables, hk,
                           context_lens=context_len,
                           pages_bound=pages_bound)
     if k_scale is not None:
         bound = page_gather_bound(block_tables, context_len,
-                                  pages_bound, k_pages.shape[2])
+                                  pages_bound, k_pages.shape[1])
         ks = gather_page_scales(k_scale, block_tables, bound)  # (N, S)
         vs = gather_page_scales(v_scale, block_tables, bound)
         kc = kc.astype(jnp.float32) * ks[:, :, None, None]
@@ -316,8 +346,8 @@ def kv_block_pages(page_size, head_dim, kv_heads, itemsize,
     within `KV_BLOCK_MAX_KEYS` keys and no wider than the block table
     — so every caller (the kernel, the engine's page counter) computes
     the same number."""
-    head_dim = -(-head_dim // LANES) * LANES        # as the kernel pads
-    page_bytes = kv_heads * page_size * head_dim * itemsize
+    row = -(-kv_heads * head_dim // LANES) * LANES  # as VMEM tiles it
+    page_bytes = page_size * row * itemsize
     fit = min(KV_BLOCK_VMEM_BYTES // (4 * page_bytes),  # K, V x 2 slots
               KV_BLOCK_MAX_KEYS // page_size)
     fit = 1 << (max(int(fit), 1).bit_length() - 1)
@@ -391,7 +421,7 @@ def _ragged_kernel(qb_seq_ref, qstart_ref, qlen_ref, ctx_ref, bt_ref,
          ksbuf, vsbuf) = rest
     else:
         o_ref, kbuf, vbuf, sem, acc_ref, m_ref, l_ref = rest
-    hk = kbuf.shape[1]
+    hk, d = q_ref.shape[0], q_ref.shape[3]
     keys = block_pages * page_size
     pps = bt_ref.shape[1]
     qb = pl.program_id(0)
@@ -412,7 +442,8 @@ def _ragged_kernel(qb_seq_ref, qstart_ref, qlen_ref, ctx_ref, bt_ref,
 
     def block_copies(trip, slot):
         """The DMAs of trip `trip`'s KV block into buffer `slot`: one
-        descriptor a page and pool, every KV head in it. The block's
+        descriptor a page and pool, a contiguous (page_size, HK*D)
+        block with every KV head in it. The block's
         columns outside the live range (below the window's edge, past
         the frontier) copy the trash page: their keys are masked by
         position, and the table is never read there."""
@@ -424,9 +455,9 @@ def _ragged_kernel(qb_seq_ref, qstart_ref, qlen_ref, ctx_ref, bt_ref,
                 (col >= lo_page) & (col <= hi_page),
                 bt_ref[sc, jnp.minimum(col, pps - 1)], TRASH_PAGE)
             copies.append(pltpu.make_async_copy(
-                k_hbm.at[:, page], kbuf.at[slot, :, j], sem.at[0, slot]))
+                k_hbm.at[page], kbuf.at[slot, j], sem.at[0, slot]))
             copies.append(pltpu.make_async_copy(
-                v_hbm.at[:, page], vbuf.at[slot, :, j], sem.at[1, slot]))
+                v_hbm.at[page], vbuf.at[slot, j], sem.at[1, slot]))
         if quantized:
             # the block's row of `_block_scale_rows`
             row = sc * -(-pps // block_pages) + block
@@ -465,8 +496,10 @@ def _ragged_kernel(qb_seq_ref, qstart_ref, qlen_ref, ctx_ref, bt_ref,
             vs = vsbuf[slot][:, :keys]
         for h in range(hk):
             q = q_ref[h, 0].astype(jnp.float32)      # (block_q*G, D)
-            k = kbuf[slot, h].astype(jnp.float32).reshape(keys, -1)
-            v = vbuf[slot, h].astype(jnp.float32).reshape(keys, -1)
+            # head h of the block: its lanes of every stored row
+            head = pl.ds(h * d, d)
+            k = kbuf[slot, :, :, head].astype(jnp.float32).reshape(keys, d)
+            v = vbuf[slot, :, :, head].astype(jnp.float32).reshape(keys, d)
             sim = mxu_dot(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
@@ -527,21 +560,18 @@ def _ragged_pallas(q, k_pages, v_pages, query_start, query_len,
                    context_len, block_tables, scale, window, block_q,
                    interpret, k_scale=None, v_scale=None):
     t, h, d = q.shape
-    if d % LANES:
-        # Mosaic copies whole 128-lane tiles out of HBM, and a pool
-        # with a narrower head is lane-padded there anyway: pad q and
-        # the pools with zero lanes (q.k and p@v are unchanged on the
-        # real ones) and drop the output's. The pad is a copy of both
-        # pools a call: a head_dim below 128 pays it until the pools
-        # are stored lane-dense.
-        lanes = ((0, 0),) * 2 + ((0, -d % LANES),)
-        out = _ragged_pallas(
-            jnp.pad(q, lanes), jnp.pad(k_pages, ((0, 0),) + lanes),
-            jnp.pad(v_pages, ((0, 0),) + lanes), query_start,
-            query_len, context_len, block_tables, scale, window,
-            block_q, interpret, k_scale=k_scale, v_scale=v_scale)
-        return out[..., :d]
-    hk, _, page_size, _ = k_pages.shape
+    hk = k_pages.shape[2] // d
+    if k_pages.shape[2] % LANES:
+        # Mosaic copies whole 128-lane tiles out of HBM ("Slice shape
+        # ... must be aligned to tiling (128)"), and a pool whose
+        # stored row is no whole number of tiles is lane-padded there
+        # anyway: pad the rows with zero lanes, which no head's slice
+        # reads. A copy of both pools a call, paid only by rows
+        # narrower than a tile (HK*D under 128: toy widths, or a head
+        # sharded thinner than a tile) or ragged against it.
+        lanes = ((0, 0), (0, 0), (0, -k_pages.shape[2] % LANES))
+        k_pages, v_pages = jnp.pad(k_pages, lanes), jnp.pad(v_pages, lanes)
+    _, page_size, row = k_pages.shape
     g = h // hk
     quantized = k_scale is not None
     nqb = t // block_q
@@ -560,7 +590,7 @@ def _ragged_pallas(q, k_pages, v_pages, query_start, query_len,
     hbm_spec = pl.BlockSpec(memory_space=pltpu.HBM)
     in_specs = [q_spec, hbm_spec, hbm_spec]
     inputs = [qk, k_pages, v_pages]
-    kv_block = (2, hk, block_pages, page_size, d)     # two slots
+    kv_block = (2, block_pages, page_size, row)       # two slots
     scratch_shapes = [
         pltpu.VMEM(kv_block, k_pages.dtype),
         pltpu.VMEM(kv_block, v_pages.dtype),
@@ -607,7 +637,8 @@ def _ragged_tp_shard_map(q, k_pages, v_pages, query_start, query_len,
     """The Pallas kernel under tensor parallelism (serving/submesh.py):
     heads are data-parallel in attention, so each TP shard runs the
     UNCHANGED kernel over its local (H/tp, HK/tp) heads via shard_map —
-    q sharded on its head axis, the page pools on theirs, and the
+    q sharded on its head axis, the page pools on their rows' (the last
+    axis: head-major within a row, so a shard holds whole heads), and the
     descriptors/block tables REPLICATED in-spec (they are host-side
     scalars describing every shard's identical page geometry: one
     logical page = tp local shards). The kernel body never learns
@@ -624,8 +655,9 @@ def _ragged_tp_shard_map(q, k_pages, v_pages, query_start, query_len,
                               window, block_q, interpret,
                               k_scale=ks, v_scale=vs)
 
-    in_specs = (P(None, axis, None), P(axis, None, None, None),
-                P(axis, None, None, None), P(), P(), P(), P())
+    # a shard's heads are a contiguous run of every stored row's lanes
+    in_specs = (P(None, axis, None), P(None, None, axis),
+                P(None, None, axis), P(), P(), P(), P())
     args = (q, k_pages, v_pages, query_start.astype(jnp.int32),
             query_len.astype(jnp.int32), context_len.astype(jnp.int32),
             block_tables.astype(jnp.int32))
@@ -654,9 +686,9 @@ def ragged_paged_attention_values(q, k_pages, v_pages, query_start,
                                   use_kernel=None, pages_bound=None,
                                   tp=None, k_scale=None, v_scale=None):
     """q: (T, H, D) packed ragged queries; k_pages/v_pages:
-    (HK, P, page_size, D); query_start/query_len/context_len: (N,)
-    int32 per-sequence descriptors; block_tables: (N, pages_per_seq)
-    int32.  Row j of sequence s sits at global position
+    (P, page_size, HK*D), token-major (the module docstring says why);
+    query_start/query_len/context_len: (N,) int32 per-sequence
+    descriptors; block_tables: (N, pages_per_seq) int32.  Row j of sequence s sits at global position
     ``context_len[s] - query_len[s] + j`` and attends its sequence's
     pages causally (band-limited by ``window`` when set).  Returns
     (T, H, D); padding rows (owned by no sequence) return zero.
@@ -727,6 +759,28 @@ def ragged_paged_attention_values(q, k_pages, v_pages, query_start,
                           v_scale=v_scale)
 
 
+def _row_targets(k_pages, block_tables, token_seq, positions):
+    """(page, slot in the page) each packed row is written to; padding
+    rows (``token_seq`` -1) go to the trash page's slot 0."""
+    page_size = k_pages.shape[1]
+    live = token_seq >= 0
+    sc = jnp.maximum(token_seq, 0)
+    page_idx = jnp.where(
+        live, block_tables[sc, positions // page_size], TRASH_PAGE)
+    return page_idx, jnp.where(live, positions % page_size, 0)
+
+
+def set_rows(k_pages, v_pages, page_idx, slot, k_rows, v_rows):
+    """``pool[page_idx[t], slot[t]] = rows[t]`` for K and for V: THE
+    write of every paged path. rows: (T, HK, D); an update is a whole
+    stored row, so a donated pool is updated in place."""
+    t = k_rows.shape[0]
+    return (k_pages.at[page_idx, slot].set(
+                k_rows.reshape(t, -1).astype(k_pages.dtype)),
+            v_pages.at[page_idx, slot].set(
+                v_rows.reshape(t, -1).astype(v_pages.dtype)))
+
+
 def ragged_scatter_values(k_pages, v_pages, k_rows, v_rows, block_tables,
                           token_seq, positions):
     """Scatter packed ragged KV rows into the page pools.
@@ -735,18 +789,11 @@ def ragged_scatter_values(k_pages, v_pages, k_rows, v_rows, block_tables,
     block_tables: (N, pps); token_seq: (T,) owning sequence per row
     (-1 = padding); positions: (T,) global position per row. Padding
     rows route to the trash page (never read). Returns the updated
-    (k_pages, v_pages) — one scatter for the whole mixed batch."""
-    page_size = k_pages.shape[2]
-    live = token_seq >= 0
-    sc = jnp.maximum(token_seq, 0)
-    page_idx = jnp.where(
-        live, block_tables[sc, positions // page_size], TRASH_PAGE)
-    slot = jnp.where(live, positions % page_size, 0)
-    kp = k_pages.at[:, page_idx, slot].set(
-        jnp.swapaxes(k_rows, 0, 1).astype(k_pages.dtype))
-    vp = v_pages.at[:, page_idx, slot].set(
-        jnp.swapaxes(v_rows, 0, 1).astype(v_pages.dtype))
-    return kp, vp
+    (k_pages, v_pages) — one row scatter (`set_rows`) for the whole
+    mixed batch."""
+    page_idx, slot = _row_targets(k_pages, block_tables, token_seq,
+                                  positions)
+    return set_rows(k_pages, v_pages, page_idx, slot, k_rows, v_rows)
 
 
 def ragged_scatter_quantized(k_pages, v_pages, k_scale, v_scale,
@@ -768,12 +815,8 @@ def ragged_scatter_quantized(k_pages, v_pages, k_scale, v_scale,
     exact zeros, never a division). Padding rows trash-route values
     AND scales to page 0."""
     from ..nn.quant import absmax_round_clip_values
-    page_size = k_pages.shape[2]
-    live = token_seq >= 0
-    sc = jnp.maximum(token_seq, 0)
-    page_idx = jnp.where(
-        live, block_tables[sc, positions // page_size], TRASH_PAGE)
-    slot = jnp.where(live, positions % page_size, 0)
+    page_idx, slot = _row_targets(k_pages, block_tables, token_seq,
+                                  positions)
 
     def _q(rows):
         rf = rows.astype(jnp.float32)
@@ -784,8 +827,7 @@ def ragged_scatter_quantized(k_pages, v_pages, k_scale, v_scale,
 
     kq, ks_row = _q(k_rows)
     vq, vs_row = _q(v_rows)
-    kp = k_pages.at[:, page_idx, slot].set(jnp.swapaxes(kq, 0, 1))
-    vp = v_pages.at[:, page_idx, slot].set(jnp.swapaxes(vq, 0, 1))
+    kp, vp = set_rows(k_pages, v_pages, page_idx, slot, kq, vq)
     ks = k_scale.at[page_idx, slot].set(ks_row)
     vs = v_scale.at[page_idx, slot].set(vs_row)
     return kp, vp, ks, vs

@@ -20,9 +20,10 @@ its paged KV cache across that slice:
   partial-sum all-reduce), trading the determinism guarantee for the
   full Megatron compute split — bench-only until a tolerance-graded
   quality gate exists.
-* **KV pages** — the page pools (HK, P, page_size, D) shard the
-  KV-HEAD axis over `tp`: one LOGICAL page = `tp` local shards, each
-  holding HK/tp heads of every resident token. The page allocator,
+* **KV pages** — the page pools (P, page_size, HK*D) shard their
+  stored ROW over `tp`; a row's lanes are head-major, so this is the
+  KV-HEAD split: one LOGICAL page = `tp` local shards, each holding
+  HK/tp contiguous heads of every resident token. The page allocator,
   block tables, and ragged descriptors stay host-side REPLICATED
   scalars — sharding never touches the accounting, so
   `check_invariants()` is unchanged and migration/export walk the
@@ -137,11 +138,13 @@ class SubMesh:
         return NamedSharding(self.jax_mesh, PartitionSpec(*axes))
 
     def kv_sharding(self, num_kv_heads: int) -> NamedSharding:
-        """Page pools (HK, P, page_size, D): shard the KV-head axis
-        when `tp` divides it (one logical page = tp local shards),
-        replicate otherwise (draft pools with hk < tp)."""
+        """Page pools (P, page_size, HK*D): shard the stored row, whose
+        lanes are head-major, so a shard holds HK/tp whole contiguous
+        heads of every token (one logical page = tp local shards),
+        when `tp` divides HK; replicate otherwise (draft pools with
+        hk < tp)."""
         if num_kv_heads % self.tp == 0 and self.tp > 1:
-            return self.sharding(TP_AXIS, None, None, None)
+            return self.sharding(None, None, TP_AXIS)
         return self._repl
 
     def validate_model(self, cfg) -> None:
@@ -256,21 +259,26 @@ def carve_submeshes(num_replicas: int, config: TpConfig,
     return meshes
 
 
-def kv_fragments(arr, pages: np.ndarray) -> List[np.ndarray]:
-    """Per-shard host gathers of one page pool's selected page columns:
-    one (hk_local, n_pages, page_size, hd) numpy fragment per TP shard,
-    ordered by head offset. The gather `shard.data[:, pages]` executes
-    ON that shard's device and only its result crosses to the host —
-    migration bytes stay local to each device's host link (the
-    serialize half of per-shard transfer; `export_pages`). Replicated
-    arrays yield one fragment (every shard holds the whole pool)."""
+def kv_fragments(arr, pages: np.ndarray,
+                 head_dim: int) -> List[np.ndarray]:
+    """Per-shard host gathers of one page pool's selected pages: one
+    (hk_local, n_pages, page_size, hd) numpy fragment per TP shard (the
+    payload's head-major shape, transposed on the host from the stored
+    token-major rows), ordered by head offset. The gather
+    `shard.data[pages]` executes ON that shard's device and only its
+    result crosses to the host — migration bytes stay local to each
+    device's host link (the serialize half of per-shard transfer;
+    `export_pages`). Replicated arrays yield one fragment (every shard
+    holds the whole pool)."""
+    from ..ops.ragged_paged_attention import pages_to_payload
     by_off: Dict[int, object] = {}
     for s in arr.addressable_shards:
-        off = s.index[0].start or 0
+        off = s.index[2].start or 0
         if off not in by_off:               # replicated: keep one copy
             by_off[off] = s.data
-    return [np.asarray(by_off[off][:, pages])
-            for off in sorted(by_off)]
+    return [np.ascontiguousarray(pages_to_payload(
+        np.asarray(by_off[off][pages]), by_off[off].shape[2] // head_dim))
+        for off in sorted(by_off)]
 
 
 def record_shard_bytes(nbytes_per_shard: Sequence[int]) -> None:

@@ -176,7 +176,7 @@ class TestPagedAttentionLowering:
 
         b, pages, page_size = 8, 64, 16
         q = jnp.zeros((b, BENCH_H, BENCH_D), jnp.bfloat16)
-        kp = jnp.zeros((BENCH_HK, pages, page_size, BENCH_D), jnp.bfloat16)
+        kp = jnp.zeros((pages, page_size, BENCH_HK * BENCH_D), jnp.bfloat16)
         ctx = jnp.full((b,), 100, jnp.int32)
         bt = jnp.zeros((b, 8), jnp.int32)
         _lower(lambda q, kp, vp: paged_attention_values(
@@ -199,7 +199,7 @@ class TestRaggedPagedAttentionLowering:
         cl = np.array([512, 512, 900, 800, 700, 600], np.int32)
         qs, total = pack_ragged_starts(ql, block_q=8)
         q = jnp.zeros((total, BENCH_H, BENCH_D), jnp.bfloat16)
-        kp = jnp.zeros((BENCH_HK, pages, page_size, BENCH_D),
+        kp = jnp.zeros((pages, page_size, BENCH_HK * BENCH_D),
                        jnp.bfloat16)
         bt = jnp.zeros((len(ql), 64), jnp.int32)
         _lower(lambda q, kp, vp: ragged_paged_attention_values(
@@ -215,7 +215,7 @@ class TestRaggedPagedAttentionLowering:
         ql = np.ones(b, np.int32)
         cl = np.full(b, 100, np.int32)
         q = jnp.zeros((b, BENCH_H, BENCH_D), jnp.bfloat16)
-        kp = jnp.zeros((BENCH_HK, pages, page_size, BENCH_D),
+        kp = jnp.zeros((pages, page_size, BENCH_HK * BENCH_D),
                        jnp.bfloat16)
         bt = jnp.zeros((b, 8), jnp.int32)
         _lower(lambda q, kp, vp: ragged_paged_attention_values(
@@ -230,7 +230,28 @@ class TestRaggedPagedAttentionLowering:
         h, hk, d = heads
         b, pps, page_size = 8, 128, 16
         q = jnp.zeros((b * block_q, h, d), jnp.bfloat16)
-        kp = jnp.zeros((hk, b * pps + 1, page_size, d), jnp.bfloat16)
+        kp = jnp.zeros((b * pps + 1, page_size, hk * d), jnp.bfloat16)
+        i32 = jnp.zeros((b,), jnp.int32)
+        bt = jnp.zeros((b, pps), jnp.int32)
+        _lower(lambda q, kp, vp, qs, ql, cl, bt:
+               ragged_paged_attention_values(q, kp, vp, qs, ql, cl, bt,
+                                             block_q=block_q),
+               q, kp, kp, i32, i32, i32, bt)
+
+    @pytest.mark.parametrize("heads", [(1, 64), (2, 16), (3, 64)])
+    @pytest.mark.parametrize("block_q", [8, 1])
+    def test_row_no_whole_number_of_tiles(self, block_q, heads):
+        """A stored row (HK*D lanes) narrower than a 128-lane tile, or
+        ragged against it, cannot be sliced out of HBM by a DMA: the
+        kernel's entry pads such pools (toy widths, a head sharded
+        thinner than a tile)."""
+        from paddle_tpu.ops.ragged_paged_attention import \
+            ragged_paged_attention_values
+
+        hk, d = heads
+        b, pps, page_size = 8, 16, 16
+        q = jnp.zeros((b * block_q, 2 * hk, d), jnp.bfloat16)
+        kp = jnp.zeros((b * pps + 1, page_size, hk * d), jnp.bfloat16)
         i32 = jnp.zeros((b,), jnp.int32)
         bt = jnp.zeros((b, pps), jnp.int32)
         _lower(lambda q, kp, vp, qs, ql, cl, bt:
@@ -251,12 +272,12 @@ class TestRaggedPagedAttentionLowering:
         mesh = Mesh(np.asarray(_v5e()[:2]), ("tp",))
         b, pps, page_size = 8, 128, 16
         q = jnp.zeros((b * block_q, 32, BENCH_D), jnp.bfloat16)
-        kp = jnp.zeros((8, b * pps + 1, page_size, BENCH_D),
+        kp = jnp.zeros((b * pps + 1, page_size, 8 * BENCH_D),
                        jnp.bfloat16)
         i32 = jnp.zeros((b,), jnp.int32)
         bt = jnp.zeros((b, pps), jnp.int32)
         rep = NamedSharding(mesh, P())
-        pool = NamedSharding(mesh, P("tp", None, None, None))
+        pool = NamedSharding(mesh, P(None, None, "tp"))
         _lower(lambda q, kp, vp, qs, ql, cl, bt:
                ragged_paged_attention_values(
                    q, kp, vp, qs, ql, cl, bt, block_q=block_q,
@@ -282,7 +303,7 @@ class TestRaggedPagedAttentionLowering:
             cl = np.array([100, 90, 80, 70], np.int32)
         qs, total = pack_ragged_starts(ql, block_q=block_q)
         q = jnp.zeros((total, BENCH_H, BENCH_D), jnp.bfloat16)
-        kp = jnp.zeros((BENCH_HK, pages, page_size, BENCH_D), jnp.int8)
+        kp = jnp.zeros((pages, page_size, BENCH_HK * BENCH_D), jnp.int8)
         ks = jnp.zeros((pages, page_size), jnp.float32)
         bt = jnp.zeros((len(ql), 64), jnp.int32)
         _lower(lambda q, kp, vp, ks, vs: ragged_paged_attention_values(
@@ -317,7 +338,7 @@ class TestRaggedPagedAttentionLowering:
         slots, hk, d, page_size = 32, 8, 128, 16
         pages = slots * pps + 1
         q = jnp.zeros((rows, h, d), jnp.bfloat16)
-        kp = jnp.zeros((hk, pages, page_size, d),
+        kp = jnp.zeros((pages, page_size, hk * d),
                        jnp.int8 if variant == "int8" else jnp.bfloat16)
         i32 = jnp.zeros((slots,), jnp.int32)
         bt = jnp.zeros((slots, pps), jnp.int32)
@@ -330,7 +351,7 @@ class TestRaggedPagedAttentionLowering:
             mesh = Mesh(np.asarray(_v5e()[:2]), ("tp",))
             kw["tp"] = (mesh, "tp")
             rep = NamedSharding(mesh, P())
-            pool = NamedSharding(mesh, P("tp", None, None, None))
+            pool = NamedSharding(mesh, P(None, None, "tp"))
             sharding = [NamedSharding(mesh, P(None, "tp", None)), pool,
                         pool, rep, rep, rep, rep]
 
@@ -343,6 +364,75 @@ class TestRaggedPagedAttentionLowering:
         kernels = _lower(fn, q, kp, kp, i32, i32, i32, bt, *extra,
                          sharding=sharding)
         assert kernels.get("ragged_paged_attention") == 1, kernels
+
+
+class TestPoolWriteInPlace:
+    """ISSUE 28: a page pool is stored token-major, (P, page_size,
+    HK*D), so that the layout XLA gives the K/V row scatter IS the
+    stored one. Stored head-major every layer of every program held,
+    for K and for V, one whole-pool `copy` in front of the scatter and
+    one behind it (40 of a decode step's 51 ms in the batch cell). The
+    static proof that the write engages in place: one layer's write +
+    ragged attention call, pools donated, compiled for the v5e at the
+    three cells' pool shapes — no `copy` of a pool-shaped array, and
+    less than one pool of temporaries."""
+
+    # (KV heads, head_dim, pool dtype, slots, table columns): the batch
+    # cell (InternLM2, chat differs in columns only), the hybrid's
+    # attention block, the int8 engine's pools with their scale pools
+    POOLS = {
+        "hk8-d128-bf16": (8, 128, jnp.bfloat16, 32, 128),
+        "hk2-d128-bf16": (2, 128, jnp.bfloat16, 64, 512),
+        "hk8-d128-int8": (8, 128, jnp.int8, 32, 128),
+    }
+
+    @pytest.mark.parametrize("block_q", [1, 8])
+    @pytest.mark.parametrize("pool", sorted(POOLS))
+    def test_write_then_attention_copies_no_pool(self, pool, block_q):
+        import re
+        from paddle_tpu.ops.ragged_paged_attention import (
+            ragged_paged_attention_values, ragged_scatter_quantized,
+            ragged_scatter_values)
+
+        hk, d, dt, slots, pps = self.POOLS[pool]
+        page_size, g = 16, 2
+        pages = slots * pps + 1
+        rows = slots if block_q == 1 else 512
+        quantized = dt == jnp.int8
+
+        def layer(kp, vp, ks, vs, q, k, v, bt, seq, pos, qs, ql, cl):
+            kw = {}
+            if quantized:
+                kp, vp, ks, vs = ragged_scatter_quantized(
+                    kp, vp, ks, vs, k, v, bt, seq, pos)
+                kw = dict(k_scale=ks, v_scale=vs)
+            else:
+                kp, vp = ragged_scatter_values(kp, vp, k, v, bt, seq, pos)
+            out = ragged_paged_attention_values(
+                q, kp, vp, qs, ql, cl, bt, block_q=block_q, **kw)
+            return out, kp, vp, ks, vs
+
+        one = jax.sharding.SingleDeviceSharding(_v5e()[0])
+
+        def aval(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+        kp = aval((pages, page_size, hk * d), dt)
+        ks = aval((pages, page_size), jnp.float32)
+        kv = aval((rows, hk, d), jnp.bfloat16)
+        row, slot = aval((rows,), jnp.int32), aval((slots,), jnp.int32)
+        compiled = jax.jit(layer, donate_argnums=(0, 1, 2, 3)).lower(
+            kp, kp, ks, ks, aval((rows, hk * g, d), jnp.bfloat16), kv, kv,
+            aval((slots, pps), jnp.int32), row, row, slot, slot,
+            slot).compile()
+        pool_shape = re.escape(f"[{pages},{page_size},{hk * d}]")
+        copies = [line.strip()[:160]
+                  for line in compiled.as_text().splitlines()
+                  if re.search(r"\bcopy(-start)?\(", line)
+                  and re.search(pool_shape, line)]
+        assert not copies, copies
+        pool_bytes = pages * page_size * hk * d * jnp.dtype(dt).itemsize
+        assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
 
 
 class TestQuantMatmulLowering:

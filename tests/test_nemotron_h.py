@@ -413,8 +413,9 @@ def test_llama_states_kv_for_every_layer_and_keeps_its_pools():
     eng = ContinuousBatchingEngine(model, max_batch_size=2, max_seq_len=64,
                                    page_size=8)
     assert eng._kv_shape[:3] == (2, 2, 32)
+    # token-major pools: (pages, page_size, KV heads x head_dim)
     assert [tuple(a.shape for a in e) for e in eng._kv] == \
-        [((2, 17, 8, 32),) * 2] * 2
+        [((17, 8, 2 * 32),) * 2] * 2
     assert eng._state == [] and eng._cache() is eng._kv
     rid = eng.add_request(list(range(1, 12)), max_new_tokens=3)
     assert len(eng.run()[rid]) == 3

@@ -221,8 +221,9 @@ class TestDequantMatmulOracle:
 
 # -- quantized KV pages through the ragged kernel ----------------------
 def _quant_pools(hk, pages, ps, d):
-    return (jnp.zeros((hk, pages, ps, d), jnp.int8),
-            jnp.zeros((hk, pages, ps, d), jnp.int8),
+    # token-major, as the engine stores them: a row holds every head
+    return (jnp.zeros((pages, ps, hk * d), jnp.int8),
+            jnp.zeros((pages, ps, hk * d), jnp.int8),
             jnp.zeros((pages, ps), jnp.float32),
             jnp.zeros((pages, ps), jnp.float32))
 
@@ -275,8 +276,8 @@ class TestQuantizedPagesOracle:
     def _numpy_oracle(self, case):
         q, kp, vp, ks, vs, qs, ql, cl, bt, seq, pos, geo = case
         hk, g, d, ps = geo
-        kp_n = np.asarray(kp, np.float32)
-        vp_n = np.asarray(vp, np.float32)
+        kp_n = np.asarray(kp, np.float32).reshape(kp.shape[:2] + (hk, d))
+        vp_n = np.asarray(vp, np.float32).reshape(vp.shape[:2] + (hk, d))
         ks_n, vs_n = np.asarray(ks), np.asarray(vs)
         total = q.shape[0]
         ref = np.zeros((total, hk * g, d), np.float32)
@@ -290,8 +291,8 @@ class TestQuantizedPagesOracle:
             vd = np.zeros((S, hk, d), np.float32)
             for p_ in range(S):
                 pg, sl = bt[i, p_ // ps], p_ % ps
-                kd[p_] = kp_n[:, pg, sl] * ks_n[pg, sl]
-                vd[p_] = vp_n[:, pg, sl] * vs_n[pg, sl]
+                kd[p_] = kp_n[pg, sl] * ks_n[pg, sl]
+                vd[p_] = vp_n[pg, sl] * vs_n[pg, sl]
             qt = q[t].reshape(hk, g, d)
             for hh in range(hk):
                 for gg in range(g):

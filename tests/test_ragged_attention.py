@@ -23,15 +23,23 @@ from paddle_tpu.models.serving import (ContinuousBatchingEngine,
 from paddle_tpu.ops.paged_attention import paged_attention_values
 from paddle_tpu.ops import ragged_paged_attention as rpa_mod
 from paddle_tpu.ops.ragged_paged_attention import (
-    gather_pages, kv_block_pages, pack_ragged_starts,
-    ragged_pages_walked, ragged_paged_attention_values,
+    gather_pages, kv_block_pages, pack_ragged_starts, pages_to_payload,
+    payload_to_pages, ragged_pages_walked, ragged_paged_attention_values,
     ragged_scatter_values, token_arrays)
 from paddle_tpu.utils.faults import FaultInjector
 
 
+def _pool(pages, dtype=None):
+    """A head-major (HK, P, page_size, D) array, the shape the NumPy
+    oracle indexes and the export payload keeps, as the pool the ops
+    store: token-major (P, page_size, HK*D)."""
+    return jnp.asarray(payload_to_pages(np.asarray(pages)), dtype)
+
+
 def np_ragged_oracle(q, kp, vp, qs, ql, cl, bt, window=None):
-    """Independent NumPy reference: per-token loop over the page table,
-    full-precision softmax. Padding rows output zero."""
+    """Independent NumPy reference: per-token loop over the page table
+    (pools head-major, (HK, P, page_size, D)), full-precision softmax.
+    Padding rows output zero."""
     hk, _, ps, d = kp.shape
     h = q.shape[1]
     g = h // hk
@@ -63,7 +71,8 @@ def np_ragged_oracle(q, kp, vp, qs, ql, cl, bt, window=None):
 def _case(rng, hk=2, g=2, d=16, ps=4, n_pages=12, pps=4,
           ql=(1, 7, 5), cl=(9, 7, 13), block_q=4, tail_pad=4,
           bt=None):
-    """Build one ragged batch: packed q, page pools, block tables,
+    """Build one ragged batch: packed q, page pools (head-major, as
+    the oracle reads them: `_pool` stores them), block tables,
     descriptors. Defaults mix a decode step, a full prefill, and a
     suffix continuation (context > query: nonzero position offset)."""
     h = hk * g
@@ -88,7 +97,7 @@ def _case(rng, hk=2, g=2, d=16, ps=4, n_pages=12, pps=4,
 
 def _both_paths(q, kp, vp, qs, ql, cl, bt, window=None, block_q=4):
     """(interpret-mode Pallas kernel, XLA gather oracle) outputs."""
-    args = (jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+    args = (jnp.asarray(q), _pool(kp), _pool(vp),
             qs, ql, cl, bt)
     kern = np.asarray(ragged_paged_attention_values(
         *args, window=window, block_q=block_q, use_kernel=True))
@@ -169,15 +178,15 @@ class TestRaggedKernelParity:
         q, kp, vp, qs, ql, cl, bt = _case(
             rng, ql=(1,) * b, cl=(9, 6, 2), block_q=1, tail_pad=0)
         ragged = np.asarray(ragged_paged_attention_values(
-            jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+            jnp.asarray(q), _pool(kp), _pool(vp),
             qs, ql, cl, bt, block_q=1, use_kernel=True))
         legacy = np.asarray(paged_attention_values(
-            jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+            jnp.asarray(q), _pool(kp), _pool(vp),
             jnp.asarray(cl), jnp.asarray(bt), use_kernel=True))
         np.testing.assert_allclose(ragged, legacy, atol=2e-5)
         # and the legacy interpret kernel agrees with ITS oracle
         legacy_xla = np.asarray(paged_attention_values(
-            jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+            jnp.asarray(q), _pool(kp), _pool(vp),
             jnp.asarray(cl), jnp.asarray(bt)))
         np.testing.assert_allclose(legacy, legacy_xla, atol=2e-5)
 
@@ -186,7 +195,7 @@ class TestRaggedKernelParity:
         q, kp, vp, qs, ql, cl, bt = _case(rng, tail_pad=3)  # t % 4 != 0
         with pytest.raises(ValueError, match="block_q"):
             ragged_paged_attention_values(
-                jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+                jnp.asarray(q), _pool(kp), _pool(vp),
                 qs, ql, cl, bt, block_q=4, use_kernel=True)
 
 
@@ -264,7 +273,7 @@ class TestKernelLoopEdges:
                             for j in range(8 - live)]
         ref = np_ragged_oracle(q, kp, vp, qs, ql, cl, bt)
         kern = np.asarray(ragged_paged_attention_values(
-            jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), qs, ql, cl,
+            jnp.asarray(q), _pool(kp), _pool(vp), qs, ql, cl,
             bt, block_q=4, use_kernel=True))
         np.testing.assert_allclose(kern, ref, atol=2e-5)
 
@@ -285,17 +294,18 @@ class TestKernelLoopEdges:
                                           block_q=block_q, **kw)
         scales = {}
         if pool == "bf16":
-            kp_d = jnp.asarray(kp, jnp.bfloat16)
-            vp_d = jnp.asarray(vp, jnp.bfloat16)
-            kp_f, vp_f = (np.asarray(x, np.float32) for x in (kp_d, vp_d))
+            kp_d, vp_d = _pool(kp, jnp.bfloat16), _pool(vp, jnp.bfloat16)
+            kp_f, vp_f = (np.asarray(jnp.asarray(x, jnp.bfloat16),
+                                     np.float32) for x in (kp, vp))
         else:
-            kp_d = jnp.asarray(rng.integers(-127, 128, kp.shape), jnp.int8)
-            vp_d = jnp.asarray(rng.integers(-127, 128, vp.shape), jnp.int8)
+            kp_i = rng.integers(-127, 128, kp.shape).astype(np.int8)
+            vp_i = rng.integers(-127, 128, vp.shape).astype(np.int8)
+            kp_d, vp_d = _pool(kp_i), _pool(vp_i)
             ks = rng.uniform(0.002, 0.02, kp.shape[1:3]).astype(np.float32)
             vs = rng.uniform(0.002, 0.02, kp.shape[1:3]).astype(np.float32)
             scales = dict(k_scale=jnp.asarray(ks), v_scale=jnp.asarray(vs))
-            kp_f = np.asarray(kp_d, np.float32) * ks[None, :, :, None]
-            vp_f = np.asarray(vp_d, np.float32) * vs[None, :, :, None]
+            kp_f = kp_i.astype(np.float32) * ks[None, :, :, None]
+            vp_f = vp_i.astype(np.float32) * vs[None, :, :, None]
         ref = np_ragged_oracle(q, kp_f, vp_f, qs, ql, cl, bt)
         args = (jnp.asarray(q), kp_d, vp_d, qs, ql, cl, bt)
         kern = np.asarray(ragged_paged_attention_values(
@@ -410,12 +420,12 @@ class TestScatterAndPacking:
         k_rows = rng.standard_normal((t, hk, d)).astype(np.float32)
         v_rows = rng.standard_normal((t, hk, d)).astype(np.float32)
         bt = np.array([[1, 2, 0], [3, 0, 0]], np.int32)
-        kp0 = np.zeros((hk, n_pages, ps, d), np.float32)
+        kp0 = np.zeros((n_pages, ps, hk * d), np.float32)
         kp, vp = ragged_scatter_values(
             jnp.asarray(kp0), jnp.asarray(kp0.copy()),
             jnp.asarray(k_rows), jnp.asarray(v_rows),
             jnp.asarray(bt), jnp.asarray(seq_t), jnp.asarray(pos_t))
-        kp = np.asarray(kp)
+        kp = pages_to_payload(np.asarray(kp), hk)   # (hk, P, ps, d)
         for row in range(t):
             s, pos = int(seq_t[row]), int(pos_t[row])
             if s < 0:
@@ -452,16 +462,16 @@ class TestGatherTrim:
     concrete, and the trim never changes results."""
 
     def test_gather_bounded_to_referenced_prefix(self):
-        kp = jnp.zeros((2, 33, 4, 8))
+        kp = jnp.zeros((33, 4, 2 * 8))
         bt = jnp.asarray(np.zeros((3, 8), np.int32))
         ctx = np.array([5, 9, 2], np.int32)               # 3 pages max
-        kc, _ = gather_pages(kp, kp, bt, context_lens=ctx)
-        assert kc.shape[1] == 3 * 4                       # trimmed
-        kc_full, _ = gather_pages(kp, kp, bt, pages_bound=8)
+        kc, _ = gather_pages(kp, kp, bt, 2, context_lens=ctx)
+        assert kc.shape == (3, 3 * 4, 2, 8)               # trimmed
+        kc_full, _ = gather_pages(kp, kp, bt, 2, pages_bound=8)
         assert kc_full.shape[1] == 8 * 4                  # full on demand
         # traced context lengths cannot trim (shape must be static)
         shape = jax.eval_shape(
-            lambda c: gather_pages(kp, kp, bt, context_lens=c)[0],
+            lambda c: gather_pages(kp, kp, bt, 2, context_lens=c)[0],
             jax.ShapeDtypeStruct((3,), jnp.int32)).shape
         assert shape[1] == 8 * 4
 
@@ -469,7 +479,7 @@ class TestGatherTrim:
         rng = np.random.default_rng(8)
         q, kp, vp, qs, ql, cl, bt = _case(rng, pps=8, n_pages=40)
         trimmed = np.asarray(ragged_paged_attention_values(
-            jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+            jnp.asarray(q), _pool(kp), _pool(vp),
             qs, ql, cl, bt, use_kernel=False))
         ref = np_ragged_oracle(q, kp, vp, qs, ql, cl, bt)
         np.testing.assert_allclose(trimmed, ref, atol=2e-5)

@@ -10,6 +10,13 @@ from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.nn import functional as F
 from paddle_tpu.ops.paged_attention import (PagedKVCache,
                                             paged_attention_values)
+from paddle_tpu.ops.ragged_paged_attention import payload_to_pages
+
+
+def _pool(pages):
+    """A head-major (HK, P, page, D) array, as the oracles here index
+    it, as the token-major pool (P, page, HK*D) the ops store."""
+    return jnp.asarray(payload_to_pages(np.asarray(pages)))
 
 
 def _mha_oracle(q, k, v, seq_len):
@@ -95,7 +102,9 @@ class TestPagedAttention:
 
     def test_matches_oracle(self):
         args = self._setup()
-        out = paged_attention_values(*[jnp.asarray(a) for a in args])
+        q, kp, vp, cl, bt = args
+        out = paged_attention_values(jnp.asarray(q), _pool(kp), _pool(vp),
+                                     jnp.asarray(cl), jnp.asarray(bt))
         ref = self._oracle(*args)
         np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-4,
                                    atol=1e-5)
@@ -104,8 +113,8 @@ class TestPagedAttention:
         args = self._setup(b=2, h=8, hk=2, d=32, page=16, pps=2, seed=3)
         q, kp, vp, cl, bt = args
         cl = np.array([1, 32], np.int32)  # one-token and full contexts
-        out = paged_attention_values(jnp.asarray(q), jnp.asarray(kp),
-                                     jnp.asarray(vp), jnp.asarray(cl),
+        out = paged_attention_values(jnp.asarray(q), _pool(kp),
+                                     _pool(vp), jnp.asarray(cl),
                                      jnp.asarray(bt))
         ref = self._oracle(q, kp, vp, cl, bt)
         np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-4,
@@ -118,8 +127,8 @@ class TestPagedAttention:
         q, kp, vp, cl, bt = args
         cl = np.array([5, 20, 32], np.int32)
         w = 12
-        out = paged_attention_values(jnp.asarray(q), jnp.asarray(kp),
-                                     jnp.asarray(vp), jnp.asarray(cl),
+        out = paged_attention_values(jnp.asarray(q), _pool(kp),
+                                     _pool(vp), jnp.asarray(cl),
                                      jnp.asarray(bt), window=w)
         # oracle: re-gather each sequence keeping only [ctx-w, ctx)
         b_, h, d = q.shape
@@ -145,10 +154,12 @@ class TestPagedAttention:
         k = jnp.ones((b, hk, d))
         v = jnp.full((b, hk, d), 2.0)
         cache = cache.append(k, v, bt, jnp.asarray([0, 5], jnp.int32))
-        # seq 0 pos 0 -> page 0 slot 0; seq 1 pos 5 -> page 3 slot 1
-        assert float(cache.k_pages[0, 0, 0, 0]) == 1.0
-        assert float(cache.v_pages[0, 3, 1, 0]) == 2.0
-        assert float(cache.k_pages[0, 0, 1, 0]) == 0.0
+        # seq 0 pos 0 -> page 0 slot 0; seq 1 pos 5 -> page 3 slot 1;
+        # a stored row holds every head: (num_pages, page, hk * d)
+        assert cache.k_pages.shape == (8, page, hk * d)
+        assert np.all(np.asarray(cache.k_pages[0, 0]) == 1.0)
+        assert np.all(np.asarray(cache.v_pages[3, 1]) == 2.0)
+        assert np.all(np.asarray(cache.k_pages[0, 1]) == 0.0)
 
 
 class TestGenerate:

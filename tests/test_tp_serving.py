@@ -122,9 +122,12 @@ class TestTpEngine:
         kp = eng._kv[0][0]
         assert set(kp.sharding.device_set) == set(sm.devices)
         # one logical page = tp local shards: each shard holds hk/tp
-        # heads of the WHOLE pool
+        # heads (a contiguous run of every stored row) of the WHOLE
+        # pool
+        hd = model.config.head_dim
+        assert kp.shape[2] == hk * hd
         shard_shapes = {s.data.shape for s in kp.addressable_shards}
-        assert shard_shapes == {(hk // 2,) + kp.shape[1:]}
+        assert shard_shapes == {kp.shape[:2] + (hk // 2 * hd,)}
         # a resharded pool must be caught by the invariant checker
         from paddle_tpu.models.serving import EngineInvariantError
         good = eng._kv[0]
@@ -298,14 +301,15 @@ class TestShardMapKernel:
         ctx = np.asarray([7, 9, 5], np.int32)
         qstart, t = pack_ragged_starts(qlens, block_q=4)
         q = rng.standard_normal((t, h, d)).astype(np.float32)
-        kp = rng.standard_normal((hk, 16, ps, d)).astype(np.float32)
-        vp = rng.standard_normal((hk, 16, ps, d)).astype(np.float32)
+        kp = rng.standard_normal((16, ps, hk * d)).astype(np.float32)
+        vp = rng.standard_normal((16, ps, hk * d)).astype(np.float32)
         bt = rng.integers(1, 16, (n, pps)).astype(np.int32)
         qlen = np.asarray(qlens, np.int32)
         want = np.asarray(ragged_paged_attention_values(
             q, kp, vp, qstart, qlen, ctx, bt, use_kernel=False))
-        shard = NamedSharding(sm.jax_mesh,
-                              PartitionSpec(TP_AXIS, None, None, None))
+        assert sm.kv_sharding(hk).spec == PartitionSpec(None, None,
+                                                         TP_AXIS)
+        shard = sm.kv_sharding(hk)
         got = np.asarray(ragged_paged_attention_values(
             jax.device_put(q, NamedSharding(
                 sm.jax_mesh, PartitionSpec(None, TP_AXIS, None))),

@@ -171,7 +171,7 @@ class TestPagedAttentionCompile:
         h, hk, d = heads
         b, pages_per_seq, page = 8, 128, 16   # 2048-token contexts
         q = jnp.zeros((b, h, d), jnp.bfloat16)
-        kp = jnp.zeros((hk, b * pages_per_seq, page, d), jnp.bfloat16)
+        kp = jnp.zeros((b * pages_per_seq, page, hk * d), jnp.bfloat16)
         bt = jnp.arange(b * pages_per_seq, dtype=jnp.int32).reshape(
             b, pages_per_seq)
         cl = jnp.full((b,), 2000, jnp.int32)
@@ -200,7 +200,7 @@ class TestRaggedPagedAttentionCompile:
         cl = np.array([512, 512, 1800, 1500, 900, 600], np.int32)
         qs, total = pack_ragged_starts(ql, block_q=8)
         q = jnp.zeros((total, h, d), jnp.bfloat16)
-        kp = jnp.zeros((hk, len(ql) * self.PPS + 1, self.PAGE, d), dtype)
+        kp = jnp.zeros((len(ql) * self.PPS + 1, self.PAGE, hk * d), dtype)
         bt = 1 + jnp.arange(len(ql) * self.PPS, dtype=jnp.int32).reshape(
             len(ql), self.PPS)
         return q, kp, qs, ql, cl, bt
@@ -211,7 +211,7 @@ class TestRaggedPagedAttentionCompile:
         ql = np.ones(b, np.int32)
         cl = np.full(b, 2000, np.int32)
         q = jnp.zeros((b, h, d), jnp.bfloat16)
-        kp = jnp.zeros((hk, b * self.PPS + 1, self.PAGE, d), dtype)
+        kp = jnp.zeros((b * self.PPS + 1, self.PAGE, hk * d), dtype)
         bt = 1 + jnp.arange(b * self.PPS, dtype=jnp.int32).reshape(
             b, self.PPS)
         return q, kp, qs, ql, cl, bt
@@ -241,7 +241,7 @@ class TestRaggedPagedAttentionCompile:
 
         q, kp, qs, ql, cl, bt = (self._mixed if block_q == 8
                                  else self._decode)(heads, jnp.int8)
-        ks = jnp.ones((kp.shape[1], self.PAGE), jnp.float32)
+        ks = jnp.ones(kp.shape[:2], jnp.float32)
         out = _compile(
             lambda q, kp, vp, ks, vs: rpa(q, kp, vp, qs, ql, cl, bt,
                                           block_q=block_q, k_scale=ks,
@@ -278,15 +278,15 @@ class TestRaggedPagedAttentionCompile:
             need = -(-int(cl[s]) // self.PAGE)
             bt[s, :need] = safe[s, :need] = perm[s * pps:s * pps + need]
         q = jnp.asarray(rng.standard_normal((rows, h, d)), jnp.bfloat16)
-        shp = (hk, pages, self.PAGE, d)
+        shp = (pages, self.PAGE, hk * d)
         kw = {"window": 300} if variant == "window" else {}
         if variant == "int8":
             kp, vp = (jnp.asarray(rng.integers(-127, 128, shp), jnp.int8)
                       for _ in range(2))
             kw.update(
-                k_scale=jnp.asarray(rng.uniform(0.002, 0.02, shp[1:3]),
+                k_scale=jnp.asarray(rng.uniform(0.002, 0.02, shp[:2]),
                                     jnp.float32),
-                v_scale=jnp.asarray(rng.uniform(0.002, 0.02, shp[1:3]),
+                v_scale=jnp.asarray(rng.uniform(0.002, 0.02, shp[:2]),
                                     jnp.float32))
         else:
             kp, vp = (jnp.asarray(rng.standard_normal(shp), jnp.bfloat16)
@@ -323,7 +323,7 @@ class TestRaggedPagedAttentionCompile:
             q, kp, vp, qs, ql, cl, bt, block_q=block_q), q, kp, vp)
         mesh = Mesh(np.asarray(jax.devices()[:2]), ("tp",))
         qd = jax.device_put(q, NamedSharding(mesh, P(None, "tp", None)))
-        pool = NamedSharding(mesh, P("tp", None, None, None))
+        pool = NamedSharding(mesh, P(None, None, "tp"))
         got = _compile(lambda q, kp, vp: rpa(
             q, kp, vp, qs, ql, cl, bt, block_q=block_q,
             tp=(mesh, "tp")), qd, jax.device_put(kp, pool),
@@ -616,7 +616,7 @@ class TestPagedEngineDecodeCompile:
                 kd = kd.at[:, :k.shape[1]].set(k._value)
                 vd = vd.at[:, :v.shape[1]].set(v._value)
                 dense.append((Tensor(kd), Tensor(vd)))
-                kp = jnp.zeros((hk, n_pages, page_size, hd),
+                kp = jnp.zeros((n_pages, page_size, hk * hd),
                                k._value.dtype)
                 vp = jnp.zeros_like(kp)
                 for i in range(b):
