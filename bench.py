@@ -48,10 +48,6 @@ TARGET_MFU = 0.40
 PEAK_BF16_FLOPS = {"TPU v5 lite": 197e12, "TPU v5e": 197e12,
                    "TPU v5p": 459e12, "TPU v4": 275e12}
 
-# which serving attention path the engine benches run (ISSUE 6):
-# default ragged; set PDT_BENCH_ATTENTION_IMPL=legacy to A/B
-ATTENTION_IMPL = os.environ.get("PDT_BENCH_ATTENTION_IMPL", "ragged")
-
 
 def emit(payload: dict) -> None:
     print(json.dumps(payload), flush=True)
@@ -116,11 +112,10 @@ def _assert_steady_state(where, snap, warm_snap=None):
         f"timed window ({delta}) — warm-up did not reach steady state")
 
 
-def _profile_detail(snap, warm_snap, gaps=None):
+def _profile_detail(snap, warm_snap):
     """`detail.profile`: the step's span self-time medians over the
     timed window (warm-phase buckets diffed out), straight off
-    `pdt_span_self_seconds`, + the top-3 dispatch gaps from a sampled
-    round."""
+    `pdt_span_self_seconds`."""
     comp = {}
     cur = snap.get("histograms", {}).get(
         "pdt_span_self_seconds", {})
@@ -132,12 +127,7 @@ def _profile_detail(snap, warm_snap, gaps=None):
                             qs=(0.5,))
         if q:
             comp[name] = q["p50"]
-    out = {"span_self_median_s": comp}
-    if gaps:
-        out["top_gaps"] = [
-            {"op_pair": g["op_pair"], "gap_s": round(g["gap_s"], 6)}
-            for g in gaps[:3]]
-    return out
+    return {"span_self_median_s": comp}
 
 
 # dotted paths into the bench JSON that gate regressions (tokens/sec
@@ -255,8 +245,7 @@ def bench_decode(model, cfg, on_tpu: bool) -> dict:
     else:
         slots, p_len, warm, steps, max_seq = 2, 8, 2, 4, 64
     eng = ContinuousBatchingEngine(model, max_batch_size=slots,
-                                   max_seq_len=max_seq,
-                                   attention_impl=ATTENTION_IMPL)
+                                   max_seq_len=max_seq)
     rng = np.random.default_rng(0)
     # engine telemetry rides the same JSON (ISSUE 2): BENCH_r*.json
     # trajectories carry serving signals, not just matmul timings
@@ -276,9 +265,6 @@ def bench_decode(model, cfg, on_tpu: bool) -> dict:
         snap = telemetry.snapshot()
         # ISSUE 20: the steady-state claim is now checked, not assumed
         _assert_steady_state("bench_decode", snap, warm_snap)
-        # dispatch-gap sample of one round (observation only: streams
-        # and PRNG state are untouched — see profile_round docstring)
-        gaps = eng.profile_round()
     finally:
         telemetry.disable(clear_override=True)
         model.train()
@@ -299,10 +285,9 @@ def bench_decode(model, cfg, on_tpu: bool) -> dict:
         "decode_tokens_per_sec": round(slots * steps / dt, 1),
         "decode_batch_slots": slots,
         "decode_step_ms": round(dt / steps * 1e3, 3),
-        "attention_impl": eng.attn_impl,
         # ISSUE 20: where the decode round's wall actually goes (the
         # fusion ladder's shopping list rides the bench JSON)
-        "profile": _profile_detail(snap, warm_snap, gaps),
+        "profile": _profile_detail(snap, warm_snap),
         "engine_telemetry": {
             "ttft_cold_avg_s": round(ttft["sum"] / ttft["count"], 4)
             if ttft.get("count") else None,
@@ -368,8 +353,7 @@ def bench_router(model, cfg, on_tpu: bool) -> dict:
                 lambda i: ContinuousBatchingEngine(
                     model, max_batch_size=slots, page_size=page,
                     max_seq_len=sys_pages * page + 64,
-                    enable_prefix_caching=True,
-                    attention_impl=ATTENTION_IMPL),
+                    enable_prefix_caching=True),
                 num_replicas=n, policy=policy, page_size=page)
             for p in prompts:
                 router.submit(p, max_new_tokens=new_toks)
@@ -453,8 +437,7 @@ def bench_disagg(model, cfg, on_tpu: bool) -> dict:
                 lambda i: ContinuousBatchingEngine(
                     model, max_batch_size=slots, page_size=page,
                     max_seq_len=sys_pages * page + 64,
-                    enable_prefix_caching=True,
-                    attention_impl=ATTENTION_IMPL),
+                    enable_prefix_caching=True),
                 num_replicas=n_replicas, policy="prefix_affinity",
                 page_size=page, roles=mode_roles)
             ids = [router.submit(p, max_new_tokens=new_toks)
@@ -637,8 +620,7 @@ def bench_tp(on_tpu: bool) -> dict:
         # only (queued jobs would otherwise prefill inside the timed
         # decode window and pollute the gated decode_tokens_per_sec)
         return ContinuousBatchingEngine(
-            model, max_batch_size=n_jobs, max_seq_len=128, submesh=sm,
-            attention_impl="ragged")
+            model, max_batch_size=n_jobs, max_seq_len=128, submesh=sm)
 
     def timed_run(sm):
         # ONE engine across both phases: the warm pass compiles every
@@ -756,7 +738,7 @@ def bench_soak(model, cfg, on_tpu: bool) -> dict:
             lambda i: ContinuousBatchingEngine(
                 model, max_batch_size=slots, page_size=page,
                 max_seq_len=prompt_max + out_max + 2 * page,
-                attention_impl=ATTENTION_IMPL, clock=clock),
+                clock=clock),
             num_replicas=2, policy="least_outstanding", page_size=page,
             max_replica_outstanding=4 * slots, clock=clock,
             sleep=clock.advance, slo_monitor=mon, admission=qos)
@@ -872,7 +854,7 @@ def bench_autoscale(model, cfg, on_tpu: bool) -> dict:
             lambda i: ContinuousBatchingEngine(
                 model, max_batch_size=slots, page_size=page,
                 max_seq_len=prompt_max + out_max + 2 * page,
-                attention_impl=ATTENTION_IMPL, clock=clock),
+                clock=clock),
             num_replicas=peak_replicas, policy="least_outstanding",
             page_size=page, max_replica_outstanding=4 * slots,
             clock=clock, sleep=clock.advance, journal=journal)
@@ -1403,8 +1385,7 @@ def bench_journal(model, cfg, on_tpu: bool) -> dict:
     def fleet(journal):
         return ServingRouter(
             lambda i: ContinuousBatchingEngine(
-                model, max_batch_size=slots, max_seq_len=max_seq,
-                attention_impl=ATTENTION_IMPL),
+                model, max_batch_size=slots, max_seq_len=max_seq),
             num_replicas=1, journal=journal)
 
     detail = {}
@@ -1547,8 +1528,7 @@ def bench_journal(model, cfg, on_tpu: bool) -> dict:
         recovered = ServingRouter.recover(
             RouterJournal(wal, fsync="off"),
             lambda i: ContinuousBatchingEngine(
-                model, max_batch_size=slots, max_seq_len=max_seq,
-                attention_impl=ATTENTION_IMPL),
+                model, max_batch_size=slots, max_seq_len=max_seq),
             num_replicas=1)
         recover_wall = time.perf_counter() - t0
         detail["recovery"] = {
@@ -1619,8 +1599,7 @@ def bench_sentry(model, cfg, on_tpu: bool) -> dict:
             return ServingRouter(
                 lambda i: ContinuousBatchingEngine(
                     model, max_batch_size=slots + 1,
-                    max_seq_len=max_seq,
-                    attention_impl=ATTENTION_IMPL),
+                    max_seq_len=max_seq),
                 num_replicas=1, sentry=sentry,
                 canary=None if sentry is None
                 else CanaryConfig(interval=3600.0))
@@ -1764,7 +1743,7 @@ def bench_async_pipeline(model, cfg, on_tpu: bool) -> dict:
                 lambda i: ContinuousBatchingEngine(
                     model, max_batch_size=slots + 1,
                     max_seq_len=max_seq,
-                    attention_impl=ATTENTION_IMPL, harvest_every=k),
+                    harvest_every=k),
                 num_replicas=1, journal=jr,
                 sentry=SentryConfig(scan_every=nth),
                 canary=CanaryConfig(interval=3600.0))

@@ -12,6 +12,12 @@ launches («fused_multi_transformer» [U]); under XLA the loop body is a
 traced region, so there is no per-token dispatch at all. The KV cache is
 donated through the scan carry and updated in place in HBM.
 
+This is the REFERENCE decode stack: `generate()` through the dense
+(B, S, HK, D) tuple cache shares no cache code with the serving engine
+(`models/serving.py`, paged pools + the ragged kernel), and the
+engine's greedy streams are held to it request by request
+(tests/test_serving.py, tests/test_ragged_attention.py).
+
 The model must implement `forward(input_ids, past_key_values=...,
 position_offset=..., use_cache=True)` returning (logits, caches) — see
 LlamaForCausalLM.

@@ -22,7 +22,6 @@ from paddle_tpu import nn
 from paddle_tpu.nn import functional as F
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.models.generation import GenerationMixin
-from paddle_tpu.observability import profile as _pf
 
 
 @dataclass
@@ -210,25 +209,6 @@ def _update_kv_cache(cache: Tensor, new: Tensor, offset) -> Tensor:
     return _apply("kv_cache_update", fn, (cache, new))
 
 
-class PagedKVCacheView:
-    """`past_key_value` for the paged decode path (≙ the reference serving
-    engine's blocked KV cache under «fused_multi_transformer», SURVEY.md
-    §2.1 fused row): per-layer page pools (P, page_size, HK*D), stored
-    token-major as the row scatter writes them, plus the SHARED
-    per-sequence block table (B, pps). The token's write position
-    and the context length both come from `position_offset`, which must be
-    a (B,) vector on this path. Decode-only (seq_len == 1)."""
-
-    def __init__(self, k_pages, v_pages, block_tables):
-        self.k_pages = k_pages if isinstance(k_pages, Tensor) \
-            else Tensor(k_pages)
-        self.v_pages = v_pages if isinstance(v_pages, Tensor) \
-            else Tensor(v_pages)
-        bt = block_tables._value if isinstance(block_tables, Tensor) \
-            else block_tables
-        self.block_tables = jnp.asarray(bt, jnp.int32)
-
-
 class RaggedKVCacheView:
     """`past_key_value` for the RAGGED serving path (≙ the ragged
     paged-attention design, PAPERS.md arxiv 2604.15464): per-layer page
@@ -323,40 +303,6 @@ class LlamaAttention(nn.Layer):
                                         past_key_value, use_cache, b, s)
         q = apply_rope(q, cos, sin, position_offset)
         k = apply_rope(k, cos, sin, position_offset)
-        if isinstance(past_key_value, PagedKVCacheView):
-            if s != 1:
-                raise ValueError(
-                    "paged KV cache is decode-only (seq_len == 1); "
-                    "prefill scatters rows via paged_prefill_scatter")
-            from paddle_tpu.ops.paged_attention import (
-                paged_append_values, paged_attention_values)
-            from paddle_tpu.core.tensor import apply as _apply
-            pos = (position_offset._value
-                   if isinstance(position_offset, Tensor)
-                   else jnp.asarray(position_offset, jnp.int32))
-            if jnp.ndim(pos) != 1:
-                raise ValueError(
-                    "paged KV cache needs a (B,) position_offset vector")
-            bt = past_key_value.block_tables
-
-            def fn_append(kp, vp, kk, vv):
-                return paged_append_values(kp, vp, kk[:, 0], vv[:, 0],
-                                           bt, pos)
-            kp_new, vp_new = _apply(
-                "paged_kv_append", fn_append,
-                (past_key_value.k_pages, past_key_value.v_pages, k, v),
-                multi_output=True)
-
-            def fn_attn(qq, kp, vp):
-                return paged_attention_values(qq[:, 0], kp, vp, pos + 1,
-                                              bt,
-                                              window=self.sliding_window)
-            out = _apply("paged_attention", fn_attn,
-                         (q, kp_new, vp_new))
-            out = self.o_proj(out.reshape([b, s, -1]))
-            if use_cache:
-                return out, PagedKVCacheView(kp_new, vp_new, bt)
-            return out
         if past_key_value is not None:
             k_cache, v_cache = past_key_value
             k_cache = _update_kv_cache(k_cache, k, position_offset)
@@ -492,18 +438,12 @@ class LlamaAttention(nn.Layer):
         seq = view.token_seq
         bt = view.block_tables
 
-        # profile.fence: op-family boundaries for the dispatch-gap
-        # sampler (engine.profile_round) — inert single None-check and
-        # identity unless a sampler is armed around an EAGER pass
-        q, k, v = _pf.fence("qkv", (q, k, v))
-
         def fn_rope(x, c, s_):
             cv = c[pos].astype(jnp.float32)[None, :, None, :]
             sv = s_[pos].astype(jnp.float32)[None, :, None, :]
             return rope_rotate_values(x, cv, sv)
         q = _apply("rope_ragged", fn_rope, (q, cos, sin))
         k = _apply("rope_ragged", fn_rope, (k, cos, sin))
-        q, k = _pf.fence("rope", (q, k))
 
         win = self.sliding_window
         quantized = view.k_scale is not None
@@ -520,7 +460,6 @@ class LlamaAttention(nn.Layer):
                 "ragged_kv_scatter_q", fn_scatter_q,
                 (view.k_pages, view.v_pages, view.k_scale,
                  view.v_scale, k, v), multi_output=True)
-            kp_new, vp_new = _pf.fence("kv_scatter", (kp_new, vp_new))
 
             def fn_attn_q(qq, kp, vp, ks, vs):
                 return ragged_paged_attention_values(
@@ -531,7 +470,6 @@ class LlamaAttention(nn.Layer):
                     k_scale=ks, v_scale=vs)[None]
             out = _apply("ragged_paged_attention", fn_attn_q,
                          (q, kp_new, vp_new, ks_new, vs_new))
-            out = _pf.fence("attention", out)
         else:
             def fn_scatter(kp, vp, kk, vv):
                 return ragged_scatter_values(kp, vp, kk[0], vv[0], bt,
@@ -539,7 +477,6 @@ class LlamaAttention(nn.Layer):
             kp_new, vp_new = _apply(
                 "ragged_kv_scatter", fn_scatter,
                 (view.k_pages, view.v_pages, k, v), multi_output=True)
-            kp_new, vp_new = _pf.fence("kv_scatter", (kp_new, vp_new))
             ks_new = vs_new = None
 
             def fn_attn(qq, kp, vp):
@@ -550,11 +487,9 @@ class LlamaAttention(nn.Layer):
                     pages_bound=view.pages_bound, tp=view.tp)[None]
             out = _apply("ragged_paged_attention", fn_attn,
                          (q, kp_new, vp_new))
-            out = _pf.fence("attention", out)
         # TP serving: each device computed ITS heads; gather them
         # before the o_proj row matmul (exact-mode fence)
         out = self.o_proj(_tp_repl(out.reshape([1, s, -1])))
-        out = _pf.fence("oproj", out)
         if use_cache:
             return out, RaggedKVCacheView(
                 kp_new, vp_new, bt, seq, pos, view.query_start,
@@ -593,8 +528,7 @@ class LlamaDecoderLayer(nn.Layer):
     def forward(self, x, cos, sin, attention_mask=None,
                 past_key_value=None, position_offset=0, use_cache=False):
         attn = self.self_attn(
-            _pf.fence("rmsnorm", self.input_layernorm(x)), cos, sin,
-            attention_mask,
+            self.input_layernorm(x), cos, sin, attention_mask,
             past_key_value=past_key_value,
             position_offset=position_offset,
             use_cache=use_cache)
@@ -602,8 +536,7 @@ class LlamaDecoderLayer(nn.Layer):
         if use_cache and past_key_value is not None:
             attn, new_kv = attn
         x = x + attn
-        x = _pf.fence("mlp",
-                      x + self.mlp(self.post_attention_layernorm(x)))
+        x = x + self.mlp(self.post_attention_layernorm(x))
         if use_cache and past_key_value is not None:
             return x, new_kv
         return x
@@ -625,7 +558,7 @@ class LlamaModel(nn.Layer):
 
     def forward(self, input_ids, attention_mask=None,
                 past_key_values=None, position_offset=0, use_cache=False):
-        x = _pf.fence("embed", self.embed_tokens(input_ids))
+        x = self.embed_tokens(input_ids)
         if past_key_values is not None:
             new_caches = []
             for layer, kv in zip(self.layers, past_key_values):

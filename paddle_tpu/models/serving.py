@@ -17,40 +17,37 @@ rows) — TPU-native:
   HBM-in-use is proportional to the tokens actually resident, not to
   B x S_max. Page 0 is a permanently reserved trash page: writes from
   inactive slots and padded prefill rows land there and are never read.
-* Admission happens BETWEEN steps on the host: prompt lengths are
-  bucketed to a padding grid so prefill programs are reused (LRU-capped),
-  and a request is admitted only when its WORST-CASE page demand fits the
-  pool net of other slots' outstanding reservations — growth can then
-  never strand a mid-flight request.
-* Greedy decoding by default; temperature / top-k / top-p sampling rides
-  the same compiled step via `_sample_token` (seeded, reproducible).
-* `enable_prefix_caching=True` (paged only) turns on vLLM-style
-  AUTOMATIC PREFIX CACHING: a finished request's full-page prompt KV is
-  retained (per-page refcounts, LRU eviction under pool pressure) and a
-  later request with the same token prefix attaches those pages
-  read-only — safe because full pages are immutable, decode only appends
-  past them — and prefills just the suffix with chunked attention over
-  the gathered prefix rows (`position_offset = shared_len`, so rope
-  angles are exact).
-* Sliding-window models serve on the paged layout too: the paged kernel
-  applies the window band, and pages that slide wholly below the window
-  are RECLAIMED between steps (their block-table entries trash-route),
-  so resident KV is bounded by the window, not the sequence.
-* `kv_layout="dense"` keeps the previous per-slot contiguous caches
-  (also the parity oracle for the paged path).
-* `attention_impl="ragged"` (the default on the paged layout) batches
-  EVERY admission through one ragged paged-attention dispatch
+* Admission happens BETWEEN steps on the host: a request is admitted
+  only when its WORST-CASE page demand fits the pool net of other
+  slots' outstanding reservations — growth can then never strand a
+  mid-flight request.
+* EVERY admission goes through one ragged paged-attention dispatch
   (`ops/ragged_paged_attention.py`): the admitted prompts — full
   prefills, prefix-cache suffix prefills, and chunk continuations —
   are PACKED along one token axis with per-sequence (query_start,
   query_len, context_len) descriptors, so admitting N ragged prompts
-  costs ONE dispatch instead of N, and the only program key is the
-  padded token count (no per-bucket prefill LRU, no per-(shared_len,
-  bucket) suffix programs, no separate chunk program). Decode rides
-  the same builder at block_q=1. `attention_impl="legacy"` keeps the
-  per-bucket jnp-attention prefill paths and the q=1 decode kernel —
-  greedy outputs are bit-identical between the two, which makes the
-  chaos drills the regression harness for the kernel.
+  costs ONE dispatch instead of N, and the program key is the padded
+  token count (packed lengths are rounded up to a padding grid so
+  programs are reused, LRU-capped) and a power-of-two page bound.
+  Decode is the same builder (`_build_ragged_step`) at block_q=1;
+  speculation's draft backfill and verify pass are two more variants
+  of it.
+* Greedy decoding by default; temperature / top-k / top-p sampling rides
+  the same compiled step via `_sample_token` (seeded, reproducible).
+* `enable_prefix_caching=True` turns on vLLM-style AUTOMATIC PREFIX
+  CACHING: a finished request's full-page prompt KV is retained
+  (per-page refcounts, LRU eviction under pool pressure) and a later
+  request with the same token prefix attaches those pages read-only —
+  safe because full pages are immutable, decode only appends past them
+  — and prefills just the suffix, whose rows attend the attached pages
+  through the page table at their own positions.
+* Sliding-window models: the ragged kernel applies the window band, and
+  pages that slide wholly below the window are RECLAIMED between steps
+  (their block-table entries trash-route), so resident KV is bounded by
+  the window, not the sequence.
+* The parity oracle is outside the engine: `model.generate()` through
+  the dense-tuple cache (`models/generation.py`) — greedy streams are
+  bit-identical to it per request, through preemption and failover.
 * REQUEST LIFECYCLE HARDENING (≙ production TPU serving stacks, which
   treat KV-pool exhaustion and preemption as first-class events): a
   monotonic-clock tick per step expires requests past their deadline /
@@ -261,10 +258,10 @@ _M_DECODE_RETRIES = telemetry.counter(
     "pdt_serving_decode_retries_total",
     "Transient decode-dispatch faults retried.")
 _M_PAGES_IN_USE = telemetry.gauge(
-    "pdt_serving_pages_in_use", "Allocated KV pages (paged layout).")
+    "pdt_serving_pages_in_use", "Allocated KV pages.")
 _M_PAGE_OCCUPANCY = telemetry.gauge(
     "pdt_serving_page_occupancy",
-    "Fraction of usable KV pages allocated (paged layout).")
+    "Fraction of usable KV pages allocated.")
 _M_INVARIANT_SECONDS = telemetry.histogram(
     "pdt_serving_invariant_check_seconds",
     "Duration of check_invariants() page-accounting sweeps.")
@@ -354,8 +351,6 @@ _M_LORA_EVICTIONS = telemetry.counter(
 # set-up compiled every bound apart: +40 s, PERF.md PR 25).
 _PROGRAM_KEY_LETTERS = {
     "ragged": "t", "draft": "t", "verify": "t",  # (t_pad, bound)
-    "suffix": "sb",                              # (shared_len, bucket)
-    "prefill": "b", "scatter": "b",              # bucket
     "install": "n"}                              # pages
 
 
@@ -506,8 +501,6 @@ class QuantServingConfig:
     quarantine re-serve (values differ from bf16 within a test-pinned
     logit-error budget). Spec-decode draft pools quantize alongside.
 
-    Requires ``kv_layout="paged"`` + ``attention_impl="ragged"`` (the
-    one dispatch family the quantized page layout threads through).
     Fleets must be quant-homogeneous: cross-mode migration or spill
     restore is refused with :class:`QuantMismatch`."""
 
@@ -607,6 +600,16 @@ class ContinuousBatchingEngine:
                  submesh=None,
                  quant: Optional[QuantServingConfig] = None,
                  harvest_every: int = 1):
+        # the engine keeps its cache one way and runs one attention path;
+        # the two keywords stay only as checked input while
+        # benchmark/configs/*.json pass them (ROADMAP D1a)
+        if kv_layout != "paged" or attention_impl != "ragged":
+            raise ValueError(
+                f"kv_layout={kv_layout!r}, attention_impl="
+                f"{attention_impl!r}: the dense layout and the legacy "
+                "attention path were removed in PR 29 — the engine "
+                "serves kv_layout='paged' with attention_impl='ragged' "
+                "only")
         cfg = model.config
         self.model = model
         # -- what each layer keeps (models/cache_spec.py): pools for
@@ -622,8 +625,6 @@ class ContinuousBatchingEngine:
                              if isinstance(s, ReportSpec)]
         if self._state_spec:
             for feature, asked in (
-                    ("kv_layout='dense'", kv_layout != "paged"),
-                    ("attention_impl='legacy'", attention_impl != "ragged"),
                     ("enable_prefix_caching", enable_prefix_caching),
                     ("spec_decode", spec_decode is not None),
                     ("quant.kv", quant is not None and quant.kv),
@@ -643,12 +644,6 @@ class ContinuousBatchingEngine:
             raise ValueError(
                 f"harvest_every must be >= 1, got {harvest_every}")
         if self.harvest_every > 1:
-            if kv_layout != "paged" or attention_impl != "ragged":
-                raise ValueError(
-                    "harvest_every > 1 requires kv_layout='paged' with "
-                    "attention_impl='ragged' — the deferred-harvest "
-                    "window feeds the device token ring back through "
-                    "the ragged dispatch only")
             if do_sample:
                 raise ValueError(
                     "harvest_every > 1 is greedy-only: a window "
@@ -664,13 +659,6 @@ class ContinuousBatchingEngine:
         self._quant = quant
         self._qw_mode = quant.weights if quant is not None else None
         self._qkv = quant.kv if quant is not None else None
-        if quant is not None and (kv_layout != "paged"
-                                  or attention_impl != "ragged"):
-            raise ValueError(
-                "quant= requires kv_layout='paged' with "
-                "attention_impl='ragged' — the quantized page layout "
-                "and the fused dequant epilogue thread through the "
-                "ragged dispatch family only")
         # -- tensor parallelism (serving/submesh.py, docs/serving.md
         # "Tensor parallelism"): one engine = one GSPMD submesh -------
         # Param/buffer values are device_put onto the submesh per the
@@ -679,18 +667,8 @@ class ContinuousBatchingEngine:
         # side accounting (allocator, block tables, descriptors) stays
         # replicated scalars, untouched by sharding.
         self._tp = submesh
-        if submesh is not None and int(submesh.tp) > 1:
-            if kv_layout != "paged":
-                raise ValueError(
-                    "tensor parallelism requires kv_layout='paged' — "
-                    "the dense per-slot caches have no page shards")
-            if attention_impl != "ragged":
-                raise ValueError(
-                    "tensor parallelism requires attention_impl="
-                    "'ragged' (the one dispatch the submesh shards)")
+        if submesh is not None:
             submesh.validate_model(cfg)
-        elif submesh is not None:
-            submesh.validate_model(cfg)   # tp=1: placement only
         self.B = int(max_batch_size)
         self.S = int(max_seq_len or cfg.max_position_embeddings)
         if self.S > cfg.max_position_embeddings:
@@ -700,18 +678,8 @@ class ContinuousBatchingEngine:
                 f"max_seq_len {self.S} exceeds the model's rope table "
                 f"(max_position_embeddings="
                 f"{cfg.max_position_embeddings})")
-        if kv_layout not in ("paged", "dense"):
-            raise ValueError(f"kv_layout {kv_layout!r}: paged|dense")
-        if attention_impl not in ("ragged", "legacy"):
-            raise ValueError(
-                f"attention_impl {attention_impl!r}: ragged|legacy")
-        # ragged attention walks the page table; the dense layout has
-        # no pages, so it always serves through the legacy paths
-        self.attn_impl = attention_impl if kv_layout == "paged" \
-            else "legacy"
         self._window = getattr(cfg, "sliding_window", None)
-        if kv_layout == "paged" and self._window is not None \
-                and enable_prefix_caching:
+        if self._window is not None and enable_prefix_caching:
             # slid-out pages are reclaimed and their block-table entries
             # trash-routed, so a window model's prompt pages are not
             # stable shareable KV
@@ -722,7 +690,6 @@ class ContinuousBatchingEngine:
             enable_prefix_caching = False
         self.eos = eos_token_id
         self.pad = int(prompt_pad)
-        self.layout = kv_layout
         self.strategy = "sampling" if do_sample else "greedy_search"
         self.temperature = float(temperature)
         self.top_k = int(top_k)
@@ -753,118 +720,90 @@ class ContinuousBatchingEngine:
             for s in self._state_spec]
         # a slot's state is live iff the slot holds a dispatched sequence
         self._state_live = np.zeros(int(max_batch_size), bool)
+        self.page_size = int(page_size)
+        self.pps = -(-self.S // self.page_size)
+        # +1: page 0 is the reserved trash page
+        self.num_pages = int(num_pages or self.B * self.pps + 1)
+        if self.num_pages < 2:
+            raise ValueError("num_pages must be >= 2 (page 0 is "
+                             "reserved)")
 
-        if kv_layout == "dense":
-            if enable_prefix_caching:
-                import warnings
-                warnings.warn(
-                    "enable_prefix_caching requires kv_layout='paged' — "
-                    "prefix caching is DISABLED on the dense layout")
-            self._prefix_enabled = False
-            self.prefix_hits = 0
-            self.prefix_tokens_reused = 0
-            if prefill_chunk:
-                import warnings
-                warnings.warn("prefill_chunk requires kv_layout='paged' "
-                              "— chunked prefill is DISABLED on the "
-                              "dense layout")
-            self._chunk = None      # chunked prefill is paged-only
-            self._caches = [
-                (jnp.zeros((self.B, self.S, hk, hd), dt),
-                 jnp.zeros((self.B, self.S, hk, hd), dt))
-                for _ in range(L)]
+        def _pool():
+            # stored token-major, the layout the row scatter writes
+            # (ops/ragged_paged_attention.py): a token's row over all
+            # KV heads is contiguous, so the write updates the donated
+            # pool in place
+            pool_dt = jnp.int8 if self._qkv else dt
+            z = jnp.zeros((self.num_pages, self.page_size, hk * hd),
+                          pool_dt)
+            if self._tp is None:
+                return z
+            # sharded allocator contract: the pool splits its rows
+            # by KV head, so every page id names tp local shards
+            return jax.device_put(z, self._tp.kv_sharding(hk))
+
+        def _spool():
+            # per-page-row dequant scales of a QUANTIZED pool:
+            # head-free (one scale per row, shared by every head),
+            # so they REPLICATE over a TP submesh like the
+            # descriptors
+            z = jnp.zeros((self.num_pages, self.page_size),
+                          jnp.float32)
+            if self._tp is None:
+                return z
+            return jax.device_put(z, self._tp.replicated())
+
+        if self._qkv:
+            self._kv = [(_pool(), _pool(), _spool(), _spool())
+                        for _ in range(L)]
         else:
-            self.page_size = int(page_size)
-            self.pps = -(-self.S // self.page_size)
-            # +1: page 0 is the reserved trash page
-            self.num_pages = int(num_pages or self.B * self.pps + 1)
-            if self.num_pages < 2:
-                raise ValueError("num_pages must be >= 2 (page 0 is "
-                                 "reserved)")
-            def _pool():
-                # stored token-major, the layout the row scatter
-                # writes (ops/ragged_paged_attention.py): a token's
-                # row over all KV heads is contiguous, so the write
-                # updates the donated pool in place
-                pool_dt = jnp.int8 if self._qkv else dt
-                z = jnp.zeros((self.num_pages, self.page_size, hk * hd),
-                              pool_dt)
-                if self._tp is None:
-                    return z
-                # sharded allocator contract: the pool splits its rows
-                # by KV head, so every page id names tp local shards
-                return jax.device_put(z, self._tp.kv_sharding(hk))
-
-            def _spool():
-                # per-page-row dequant scales of a QUANTIZED pool:
-                # head-free (one scale per row, shared by every head),
-                # so they REPLICATE over a TP submesh like the
-                # descriptors
-                z = jnp.zeros((self.num_pages, self.page_size),
-                              jnp.float32)
-                if self._tp is None:
-                    return z
-                return jax.device_put(z, self._tp.replicated())
-
-            if self._qkv:
-                self._kv = [(_pool(), _pool(), _spool(), _spool())
-                            for _ in range(L)]
-            else:
-                self._kv = [(_pool(), _pool()) for _ in range(L)]
-            self._bt = np.zeros((self.B, self.pps), np.int32)
-            self._free: List[int] = list(range(1, self.num_pages))
-            self._slot_pages: List[List[int]] = [[] for _ in range(self.B)]
-            self._slot_reserved = np.zeros(self.B, np.int64)
-            # pages ever attached (shared + allocated) — the next block-
-            # table index to fill; stays monotonic even after window
-            # reclamation frees leading pages
-            self._slot_next_idx = np.zeros(self.B, np.int64)
-            self._slot_freed = np.zeros(self.B, np.int64)
-            self._scatter_jits: "OrderedDict[int, object]" = OrderedDict()
-            # -- automatic prefix caching (vLLM-style, opt-in) ---------
-            # Full pages are immutable once written (decode only appends
-            # past them), so a finished request's full-page prompt KV can
-            # be SHARED read-only by later requests with the same token
-            # prefix: the new request attaches the cached pages to its
-            # block table and prefills only the suffix (chunked-prefill
-            # attention over the gathered prefix rows). The cache is a
-            # PAGE TRIE (≙ vLLM hash-chain / SGLang radix): one node per
-            # (parent, page-of-tokens), so match/registration are O(p_len)
-            # and key memory is linear, with exact-token keys (no hash-
-            # collision risk). Per-page refcounts arbitrate slots + trie
-            # nodes; childless LRU nodes are evicted under pool pressure.
-            self._prefix_enabled = bool(enable_prefix_caching)
-            self._max_prefix_entries = int(max_prefix_entries)
-            self._page_rc = np.zeros(self.num_pages, np.int32)
-            # node key -> {"page": id, "parent": key|None, "children": n}
-            self._prefix_nodes: "OrderedDict[tuple, dict]" = OrderedDict()
-            self._slot_shared_pages: List[List[int]] = \
-                [[] for _ in range(self.B)]
-            self._suffix_jits: "OrderedDict[tuple, object]" = OrderedDict()
-            # migration/prefix-store page-content installs, by count
-            self._install_jits: "OrderedDict[int, object]" = OrderedDict()
-            self.prefix_hits = 0
-            self.prefix_tokens_reused = 0
-            # chunked prefill (vLLM-style): prompts longer than the
-            # chunk run through ONE compiled fixed-size chunk program
-            # with traced offsets (llama.py's verify-attention branch),
-            # so long prompts never mint new per-bucket programs
-            self._chunk = int(prefill_chunk) if prefill_chunk else None
-            if self._chunk is not None:
-                if self._chunk % self.page_size:
-                    raise ValueError(
-                        f"prefill_chunk {self._chunk} must be a multiple "
-                        f"of page_size {self.page_size} (chunk starts "
-                        "must be page-aligned for the rebased scatter)")
-                if self.S % self._chunk:
-                    # a final chunk crossing S would hit JAX's
-                    # dynamic-slice start clamping and silently shift
-                    # rows to wrong positions
-                    raise ValueError(
-                        f"max_seq_len {self.S} must be a multiple of "
-                        f"prefill_chunk {self._chunk}")
-                self._chunk_jit = None
-                self._sample_jit = None
+            self._kv = [(_pool(), _pool()) for _ in range(L)]
+        self._bt = np.zeros((self.B, self.pps), np.int32)
+        self._free: List[int] = list(range(1, self.num_pages))
+        self._slot_pages: List[List[int]] = [[] for _ in range(self.B)]
+        self._slot_reserved = np.zeros(self.B, np.int64)
+        # pages ever attached (shared + allocated) — the next block-
+        # table index to fill; stays monotonic even after window
+        # reclamation frees leading pages
+        self._slot_next_idx = np.zeros(self.B, np.int64)
+        self._slot_freed = np.zeros(self.B, np.int64)
+        # -- automatic prefix caching (vLLM-style, opt-in) ---------
+        # Full pages are immutable once written (decode only appends
+        # past them), so a finished request's full-page prompt KV can
+        # be SHARED read-only by later requests with the same token
+        # prefix: the new request attaches the cached pages to its
+        # block table and prefills only the suffix (its rows attend
+        # the attached pages through the page table). The cache is a
+        # PAGE TRIE (≙ vLLM hash-chain / SGLang radix): one node per
+        # (parent, page-of-tokens), so match/registration are O(p_len)
+        # and key memory is linear, with exact-token keys (no hash-
+        # collision risk). Per-page refcounts arbitrate slots + trie
+        # nodes; childless LRU nodes are evicted under pool pressure.
+        self._prefix_enabled = bool(enable_prefix_caching)
+        self._max_prefix_entries = int(max_prefix_entries)
+        self._page_rc = np.zeros(self.num_pages, np.int32)
+        # node key -> {"page": id, "parent": key|None, "children": n}
+        self._prefix_nodes: "OrderedDict[tuple, dict]" = OrderedDict()
+        self._slot_shared_pages: List[List[int]] = \
+            [[] for _ in range(self.B)]
+        # migration/prefix-store page-content installs, by count
+        self._install_jits: "OrderedDict[int, object]" = OrderedDict()
+        self.prefix_hits = 0
+        self.prefix_tokens_reused = 0
+        # chunked prefill (vLLM-style): an admission dispatch packs at
+        # most `prefill_chunk` tokens; a longer prompt continues in the
+        # next dispatch, attending its earlier rows through its pages
+        # (_ragged_batches)
+        self._chunk = int(prefill_chunk) if prefill_chunk else None
+        if self._chunk is not None:
+            if self._chunk % self.page_size:
+                raise ValueError(
+                    f"prefill_chunk {self._chunk} must be a multiple "
+                    f"of page_size {self.page_size}")
+            if self.S % self._chunk:
+                raise ValueError(
+                    f"max_seq_len {self.S} must be a multiple of "
+                    f"prefill_chunk {self._chunk}")
         # host-side slot state
         self._pos = np.zeros(self.B, np.int32)        # next write position
         self._tok = np.zeros(self.B, np.int32)        # last emitted token
@@ -893,7 +832,6 @@ class ContinuousBatchingEngine:
         self._admit_seq = 0                 # global admission order
         self._slot_seq = np.zeros(self.B, np.int64)
         self._decode_jit = None
-        self._insert_jit = None
         # deferred-harvest window (harvest_every > 1): one entry per
         # un-harvested dispatch {nxt (device), lg (device|None), scan,
         # act (active slots — constant within a window), pos (host
@@ -903,7 +841,6 @@ class ContinuousBatchingEngine:
         self._pending: List[dict] = []
         self._tok_dev = None
         self._window_wall = 0.0             # dispatch walls this window
-        self._profile_raw = None            # profile_round's eager step
         # gray-failure defense (ISSUE 14, serving/sentry.py): an
         # attached numeric sentry observes every token harvest (and,
         # every Nth step, the ragged decode program's sampled-row
@@ -931,7 +868,6 @@ class ContinuousBatchingEngine:
         self._adapter_rows: Dict[str, int] = {}
         self._lora_free_rows: List[int] = []
         self._slot_adapter = np.zeros(self.B, np.int32)
-        self._prefill_jits: "OrderedDict[int, object]" = OrderedDict()
         # ragged path: ONE program family keyed only on the padded
         # token count of the admission batch (the decode program lives
         # in _decode_jit at block_q=1)
@@ -944,11 +880,6 @@ class ContinuousBatchingEngine:
         self.num_spec_accepted = 0
         self.num_spec_degraded = 0
         if spec_decode is not None:
-            if self.layout != "paged" or self.attn_impl != "ragged":
-                raise ValueError(
-                    "spec_decode requires kv_layout='paged' with "
-                    "attention_impl='ragged' — the verify pass IS a "
-                    "ragged multi-token dispatch over the page table")
             if do_sample:
                 raise ValueError(
                     "spec_decode is greedy-only (bit-identical to the "
@@ -1162,12 +1093,6 @@ class ContinuousBatchingEngine:
         KV across adapters), spec decode, and chunked prefill."""
         if self._state_spec:
             self._refuse_state("install_adapter")
-        if self.layout != "paged" or self.attn_impl != "ragged":
-            raise ValueError(
-                "install_adapter requires kv_layout='paged' with "
-                "attention_impl='ragged' — the per-token adapter-row "
-                "vector threads through the ragged dispatch family "
-                "only")
         if self._prefix_enabled:
             raise ValueError(
                 "install_adapter refuses to compose with prefix "
@@ -1533,16 +1458,15 @@ class ContinuousBatchingEngine:
                     request_id=request_id if request_id is not None
                     else str(self._next_rid),
                     priority=int(priority), adapter=adapter)
-        if self.layout == "paged":
-            usable = self.num_pages - 1
-            need = self._worst_pages(r)
-            if need > usable:
-                raise ValueError(
-                    f"request needs up to {need} KV pages (prompt "
-                    f"{len(toks)} + max_new_tokens {max_new_tokens} at "
-                    f"page_size {self.page_size}) but the pool has only "
-                    f"{usable} usable pages — it could never be "
-                    f"admitted; raise num_pages")
+        usable = self.num_pages - 1
+        need = self._worst_pages(r)
+        if need > usable:
+            raise ValueError(
+                f"request needs up to {need} KV pages (prompt "
+                f"{len(toks)} + max_new_tokens {max_new_tokens} at "
+                f"page_size {self.page_size}) but the pool has only "
+                f"{usable} usable pages — it could never be "
+                f"admitted; raise num_pages")
         if self.admission_policy is not None \
                 and not self.admission_policy(self, r):
             _M_REJECTIONS.inc(reason="policy")
@@ -1593,7 +1517,7 @@ class ContinuousBatchingEngine:
                     self._harvest_pending(finished)
                 finished += self._expire()
                 with telemetry.span("serving.admit"):
-                    finished += self._admit()
+                    finished += self._admit_ragged()
                 active = [i for i, r in enumerate(self._slot_req)
                           if r is not None]
                 if active:
@@ -1661,11 +1585,10 @@ class ContinuousBatchingEngine:
             return
         _M_QUEUE_DEPTH.set(len(self._queue))
         _M_RUNNING.set(sum(r is not None for r in self._slot_req))
-        if self.layout == "paged":
-            usable = self.num_pages - 1
-            in_use = usable - len(self._free)
-            _M_PAGES_IN_USE.set(in_use)
-            _M_PAGE_OCCUPANCY.set(in_use / max(usable, 1))
+        usable = self.num_pages - 1
+        in_use = usable - len(self._free)
+        _M_PAGES_IN_USE.set(in_use)
+        _M_PAGE_OCCUPANCY.set(in_use / max(usable, 1))
         if self._state_spec:
             _M_STATE_BYTES.set(self._state_nbytes())
             _M_STATE_SLOTS.set(int(self._state_live.sum()))
@@ -1697,10 +1620,9 @@ class ContinuousBatchingEngine:
     def attach_sentry(self, sentry) -> None:
         """Attach a `serving.sentry.NumericSentry`: token in-vocab
         checks ride every harvest (decode, ragged admission, spec
-        verify), and when the sentry scans logits the RAGGED decode
-        program is rebuilt to return its sampled-row logits for the
-        every-Nth-step scan (legacy/dense decode paths run token
-        checks only — the scan needs the ragged program's row output).
+        verify), and when the sentry scans logits the decode program
+        is rebuilt to return its sampled-row logits for the
+        every-Nth-step scan.
         One sentry per engine incarnation; a fleet's ReplicaHandle
         attaches a fresh one on every (re)build. A sentry trip never
         raises — the step completes and the router reads
@@ -1715,7 +1637,7 @@ class ContinuousBatchingEngine:
 
     def _corrupt_kv_site(self):
         """The ``serving.kv_page`` VALUE fault site (utils/faults.py
-        CORRUPT mode), visited once per KV commit of a BUSY paged
+        CORRUPT mode), visited once per KV commit of a BUSY
         engine — decode step, ragged admission, spec verify — so
         ``nth=`` visit counting targets one replica like
         ``router.step`` (or arm with ``tag=``). The mutation gathers
@@ -1724,8 +1646,7 @@ class ContinuousBatchingEngine:
         seeded-deterministic, and guaranteed to land in pages a live
         request (or an in-flight canary) will actually read — damage
         in free/trash pages would drill nothing."""
-        if self.layout != "paged" \
-                or not value_armed("serving.kv_page", self.fault_tag):
+        if not value_armed("serving.kv_page", self.fault_tag):
             return
         live = sorted({p for pages in self._slot_pages for p in pages})
         if not live:
@@ -1769,8 +1690,6 @@ class ContinuousBatchingEngine:
         plane's serialize cost."""
         if self._state_spec:
             self._refuse_state("export_pages")
-        if self.layout != "paged":
-            raise ValueError("export_pages requires the paged layout")
         # pipelined decode: the payload serializes host slot state
         # (ctx/last_token/output) — drain the deferred window first so
         # it reflects every token the device produced (quiesce seam,
@@ -1875,8 +1794,6 @@ class ContinuousBatchingEngine:
         capacity deferrals, distinct from transfer failures."""
         if self._state_spec:
             self._refuse_state("import_pages")
-        if self.layout != "paged":
-            raise ValueError("import_pages requires the paged layout")
         # pipelined decode: the active set must be CONSTANT within a
         # deferred window (the device token ring carries no entry for
         # a slot installed mid-window) — drain the window before the
@@ -2077,7 +1994,7 @@ class ContinuousBatchingEngine:
         the spilled bytes are only interpretable in their own mode."""
         if self._state_spec:
             self._refuse_state("import_prefix")
-        if self.layout != "paged" or not self._prefix_enabled:
+        if not self._prefix_enabled:
             return 0
         if (kv_scales is None) == bool(self._qkv):
             _M_QUANT_MISMATCH.inc(kind="prefix")
@@ -2132,7 +2049,7 @@ class ContinuousBatchingEngine:
 
     def _install_kv(self, page_ids: List[int], rows, scale_rows=None):
         """Write transferred page contents into the pool — one donated
-        program per page count, LRU-capped like the scatter programs
+        program per page count, LRU-capped
         (migration imports + prefix-store spill restores land here).
         Quantized engines additionally install each page's per-row
         dequant scales (`scale_rows`: one (k_scale, v_scale) pair of
@@ -2218,15 +2135,11 @@ class ContinuousBatchingEngine:
         return os.environ.get("PDT_CHECK_INVARIANTS") == "1"
 
     def cache_memory_info(self) -> Dict[str, float]:
-        """KV-cache HBM accounting. For the paged layout `bytes_in_use`
-        is proportional to pages actually allocated (≙ the inference
-        engine's memory-optim story, SURVEY.md §1 L10)."""
+        """KV-cache HBM accounting: `bytes_in_use` is proportional to
+        pages actually allocated (≙ the inference engine's memory-optim
+        story, SURVEY.md §1 L10)."""
         L, hk, hd, dt = self._kv_shape
         itemsize = jnp.dtype(dt).itemsize
-        if self.layout == "dense":
-            total = self.B * self.S * hk * hd * itemsize * 2 * L
-            return {"layout": "dense", "bytes_pool": total,
-                    "bytes_in_use": total, "utilization": 1.0}
         if self._qkv:
             # int8 storage + (page_size,) f32 scale rows per page per
             # pool — the HONEST per-page bill the residency A/B in
@@ -2263,8 +2176,6 @@ class ContinuousBatchingEngine:
         live block-table window points only at allocated pages while
         everything outside it trash-routes to page 0. Raises
         EngineInvariantError listing every violation."""
-        if self.layout != "paged":
-            return
         with telemetry.span("serving.invariants"), \
                 _M_INVARIANT_SECONDS.time():
             self._check_invariants_paged()
@@ -2524,85 +2435,36 @@ class ContinuousBatchingEngine:
         # the arrays keep the old state; the slot's next sequence
         # starts from zero by its descriptors (cache_spec.py)
         self._state_live[slot] = False
-        if self.layout == "paged":
-            if self._prefix_enabled and req is not None and register:
-                # register BEFORE the decrefs so the prompt pages never
-                # transit through the free list
-                self._register_prefix(slot, req)
-            for p in self._slot_pages[slot]:
-                self._decref(p)
-            for p in self._slot_shared_pages[slot]:
-                self._decref(p)
-            self._slot_pages[slot] = []
-            self._slot_shared_pages[slot] = []
-            self._slot_reserved[slot] = 0
-            self._slot_next_idx[slot] = 0
-            self._slot_freed[slot] = 0
-            # inactive slots keep decoding garbage; their block-table row
-            # must point at the trash page, not at reclaimed pages
-            self._bt[slot] = 0
-            if self._spec is not None:
-                # the draft cache dies with the slot: preemption
-                # re-prefills, failover re-dispatch, and migration all
-                # DROP draft state — the next spec round rebuilds it
-                # from the folded stream (never torn, by construction)
-                self._d_release(slot)
+        if self._prefix_enabled and req is not None and register:
+            # register BEFORE the decrefs so the prompt pages never
+            # transit through the free list
+            self._register_prefix(slot, req)
+        for p in self._slot_pages[slot]:
+            self._decref(p)
+        for p in self._slot_shared_pages[slot]:
+            self._decref(p)
+        self._slot_pages[slot] = []
+        self._slot_shared_pages[slot] = []
+        self._slot_reserved[slot] = 0
+        self._slot_next_idx[slot] = 0
+        self._slot_freed[slot] = 0
+        # inactive slots keep decoding garbage; their block-table row
+        # must point at the trash page, not at reclaimed pages
+        self._bt[slot] = 0
+        if self._spec is not None:
+            # the draft cache dies with the slot: preemption
+            # re-prefills, failover re-dispatch, and migration all
+            # DROP draft state — the next spec round rebuilds it
+            # from the folded stream (never torn, by construction)
+            self._d_release(slot)
 
     def _next_keys(self, n: int = 1):
         keys = jax.random.split(self._key, n + 1)
         self._key = keys[0]
         return keys[1:] if n > 1 else keys[1]
 
-    def _bucket(self, n: int) -> int:
-        # clamped to the cache: a prompt near max_seq_len must not
-        # round its prefill window past the cache end
-        return min(int(-(-n // self.pad) * self.pad), self.S)
-
-    def _get_prefill(self, bucket: int):
-        # scatter programs carry their own LRU cap (_get_scatter)
-        return self._jit_lru(self._prefill_jits, bucket,
-                             lambda: self._build_prefill(bucket),
-                             family="prefill")
-
-    def _build_prefill(self, p_len: int):
-        """One compiled program per prompt bucket: causal pass over the
-        padded prompt -> (first token, per-layer KV rows for the
-        prompt window). Layout-agnostic — rows are inserted into the
-        dense cache or scattered into pages by a separate donated
-        program."""
-        model = self.model
-        params, buffers = self._params, self._buffers
-        cfg = model.config
-        hk, hd = cfg.num_key_value_heads, cfg.head_dim
-        L = cfg.num_hidden_layers
-        strat, temp = self.strategy, self.temperature
-        tk, tp = self.top_k, self.top_p
-
-        def run(pv, bv, ids, true_len, key):
-            from .generation import bind_state, _sample_token
-            with bind_state(params, buffers, pv, bv), no_grad():
-                dt = pv[0].dtype
-                caches = [(Tensor(jnp.zeros((1, p_len, hk, hd), dt)),
-                           Tensor(jnp.zeros((1, p_len, hk, hd), dt)))
-                          for _ in range(L)]
-                # key-validity mask: padded tail positions excluded
-                am = (jnp.arange(p_len) < true_len)[None, :]
-                logits, new_caches = model.forward(
-                    Tensor(ids), attention_mask=Tensor(am),
-                    past_key_values=caches, position_offset=0,
-                    use_cache=True)
-                # first generated token comes from the LAST REAL row
-                last = logits._value[0, true_len - 1]
-                tok, _ = _sample_token(last[None], key, strat, temp,
-                                       tk, tp)
-                return tok[0], [(k._value[0], v._value[0])
-                                for k, v in new_caches]
-
-        return jax.jit(run)
-
     def _claim_candidate(self, free):
-        """The admission preamble shared by the legacy and ragged
-        loops: peek the FIFO head, match + PIN any cached prefix pages
+        """The admission preamble: peek the FIFO head, match + PIN any cached prefix pages
         (pin BEFORE reservation — under pool pressure _reserve_ok may
         evict the matched entry itself, and unpinned pages would land
         on the free list while still referenced), check the worst-case
@@ -2613,31 +2475,29 @@ class ContinuousBatchingEngine:
         req = self._queue[0]
         prompt = self._effective_prompt(req)
         shared = None
-        if self.layout == "paged" and self._prefix_enabled:
+        if self._prefix_enabled:
             shared = self._match_prefix(prompt)
             if shared is not None:
                 shared = list(shared)
                 for p in shared:
                     self._incref(p)
-        if self.layout == "paged":
-            # the pin is held ACROSS the reservation (it may evict the
-            # matched chain), so the reservation's own error path must
-            # unpin — an unguarded raise here would leak the refcounts
-            # and fail a later check_invariants() far from the cause
-            # (PDT005 found this unguarded)
-            try:
-                ok = self._reserve_ok(req,
-                                      len(shared) if shared else 0)
-            except BaseException:
-                if shared:
-                    for p in shared:
-                        self._decref(p)
-                raise
-            if not ok:
-                if shared:
-                    for p in shared:
-                        self._decref(p)    # unpin before waiting
-                return None
+        # the pin is held ACROSS the reservation (it may evict the
+        # matched chain), so the reservation's own error path must
+        # unpin — an unguarded raise here would leak the refcounts
+        # and fail a later check_invariants() far from the cause
+        # (PDT005 found this unguarded)
+        try:
+            ok = self._reserve_ok(req, len(shared) if shared else 0)
+        except BaseException:
+            if shared:
+                for p in shared:
+                    self._decref(p)
+            raise
+        if not ok:
+            if shared:
+                for p in shared:
+                    self._decref(p)    # unpin before waiting
+            return None
         slot = free.pop(0)
         self._queue.pop(0)
         # slot ownership is recorded BEFORE any dispatch so a failed
@@ -2689,88 +2549,6 @@ class ContinuousBatchingEngine:
         self._slot_next_idx[slot] = len(shared)
         return len(shared) * self.page_size
 
-    def _admit(self):
-        if self.layout == "paged" and self.attn_impl == "ragged":
-            return self._admit_ragged()
-        finished = []
-        free = [i for i, r in enumerate(self._slot_req) if r is None]
-        while free and self._queue:
-            claim = self._claim_candidate(free)
-            if claim is None:
-                break                      # FIFO: wait for pages to free
-            slot, req, prompt, shared = claim
-            p_len = len(prompt)
-            try:
-                # request_id joins the request's distributed trace when
-                # a fleet router opened one (trace.start_trace) — the
-                # engine itself needs no router awareness
-                with telemetry.span("serving.prefill", rid=req.rid,
-                                    request_id=req.request_id,
-                                    prompt_len=p_len,
-                                    shared_pages=len(shared)
-                                    if shared else 0):
-                    try:
-                        fault_point("serving.prefill")
-                        if shared:
-                            tok = self._admit_shared(slot, req, prompt,
-                                                     shared)
-                        elif self.layout == "paged" and self._chunk \
-                                and p_len >= self._chunk:
-                            tok = self._admit_chunked(slot, req, p_len,
-                                                      prompt)
-                        else:
-                            bucket = self._bucket(max(p_len, 1))
-                            jit = self._get_prefill(bucket)
-                            ids = np.zeros((1, bucket), np.int32)
-                            ids[0, :p_len] = prompt
-                            tok, rows = jit(
-                                self._pv(), self._bv(),
-                                jnp.asarray(ids), jnp.int32(p_len),
-                                self._next_keys())
-                            if self.layout == "paged":
-                                self._paged_insert(slot, req, p_len,
-                                                   bucket, rows)
-                            else:
-                                self._dense_insert(slot, rows)
-                    finally:
-                        if shared:
-                            for p in shared:
-                                # unpin: the slot holds refs
-                                self._decref(p)
-            except PoolExhausted:
-                # admission-time allocation failed (injected, or an
-                # accounting bug): back out and REQUEUE — pages free as
-                # running requests complete — under the same starvation
-                # guard as decode-time preemption. register=False: the
-                # prefilled rows were never scattered into the pages.
-                if self._admission_pool_exhausted(slot, req, free,
-                                                  finished):
-                    continue       # starved out: try the next request
-                break              # pool exhausted: stop admitting
-            except Exception as e:
-                # isolable only while the shared KV is intact: a failure
-                # DURING a donating dispatch (scatter/insert consume the
-                # old buffers) leaves self._kv/_caches deleted, and
-                # "keep serving" would just crash one step later with
-                # the root cause buried — re-raise instead
-                arr = (self._kv if self.layout == "paged"
-                       else self._caches)[0][0]
-                if getattr(arr, "is_deleted", lambda: False)():
-                    raise
-                self._admission_failed(slot, req, e, free, finished)
-                continue
-            self._pos[slot] = p_len
-            self._tok[slot] = int(tok)
-            req.output.append(int(tok))
-            self._note_admitted(req)
-            if (self.eos is not None and int(tok) == self.eos) \
-                    or len(req.output) >= req.max_new_tokens:
-                self._finalize(req, RequestStatus.FINISHED, None,
-                               finished)
-                self._release_slot(slot)
-                free.insert(0, slot)
-        return finished
-
     def _note_admitted(self, req: Request):
         """An admission's prefill gave the request a token. The first
         one is stamped once per request (a preempted request's
@@ -2785,45 +2563,15 @@ class ContinuousBatchingEngine:
             telemetry.event("serving.first_token", rid=req.rid,
                             request_id=req.request_id, ttft_s=ttft)
 
-    def _admit_shared(self, slot: int, req: Request, prompt: List[int],
-                      pages: List[int]):
-        """Admission with a prefix-cache hit: attach the cached pages
-        read-only, then prefill only the suffix (chunked attention over
-        the gathered prefix KV). `prompt` is the effective prompt
-        (original + any tokens generated before a preemption)."""
-        p_len = len(prompt)
-        shared_len = self._attach_shared(slot, pages)
-        self._reserve_and_alloc(slot, req, p_len)
-        suffix = prompt[shared_len:]
-        bucket = self._bucket(len(suffix))
-        jit = self._get_suffix_prefill(shared_len, bucket)
-        ids = np.zeros((1, bucket), np.int32)
-        ids[0, :len(suffix)] = suffix
-        tok, rows = jit(
-            self._pv(), self._bv(),
-            self._kv, jnp.asarray(np.asarray(pages, np.int32)),
-            jnp.asarray(ids), jnp.int32(len(suffix)), self._next_keys())
-        # scatter the suffix rows into the pages AFTER the shared ones:
-        # shared_len is page-aligned, so a rebased sub-block-table keeps
-        # the per-bucket scatter program shape-stable
-        sub_bt = np.zeros(self.pps, np.int32)
-        sub_bt[:self.pps - len(pages)] = self._bt[slot, len(pages):]
-        sjit = self._get_scatter(bucket)
-        self._kv = sjit(self._kv, rows, jnp.asarray(sub_bt),
-                        jnp.int32(len(suffix)))
-        self.prefix_hits += 1
-        self.prefix_tokens_reused += shared_len
-        return int(tok)
-
-    # -- ragged admission (attention_impl="ragged") ---------------------
+    # -- admission -------------------------------------------------------
     def _admit_ragged(self):
         """Batched admission through the ragged paged-attention path:
-        collect every admittable request (same FIFO + worst-case page
-        reservation as the legacy path), then prefill them ALL in one
-        packed dispatch — full prefills, prefix-cache suffix prefills,
-        and (when `prefill_chunk` bounds the dispatch) chunk
-        continuations ride one token axis. Loops while instant-finish
-        admissions free slots, mirroring the legacy admit loop."""
+        collect every admittable request (FIFO + worst-case page
+        reservation), then prefill them ALL in one packed dispatch —
+        full prefills, prefix-cache suffix prefills, and (when
+        `prefill_chunk` bounds the dispatch) chunk continuations ride
+        one token axis. Loops while instant-finish admissions free
+        slots."""
         finished: List[Request] = []
         while True:
             entries = self._collect_ragged_entries(finished)
@@ -3059,8 +2807,7 @@ class ContinuousBatchingEngine:
                  family: str = "misc"):
         """The one keyed-LRU program-cache discipline (build on miss,
         evict oldest past the cap, MRU-bump on hit) behind every keyed
-        program family (prefill, scatter, install, ragged, suffix,
-        draft, verify). Every miss routes through
+        program family (ragged, install, draft, verify). Every miss routes through
         `profile.compile_timed`, so the program's first invocation is
         metered as `pdt_jit_compiles_total{family}` + compile-seconds
         + the retrace-storm window, and cache footprint/evictions ride
@@ -3084,8 +2831,7 @@ class ContinuousBatchingEngine:
 
     def _jit_singleton(self, family: str, build):
         """The singleton-program arm of the compile-metering seam:
-        built once per engine lifetime (decode, chunk, sample, insert,
-        draft_scan), no key space, no cache — but the same
+        built once per engine lifetime (decode, draft_scan), no key space, no cache — but the same
         `compile_timed` first-call metering as `_jit_lru` misses."""
         return _profile.compile_timed(
             _name_program(build(), family), family)
@@ -3124,9 +2870,7 @@ class ContinuousBatchingEngine:
 
     def _get_ragged_prefill(self, t_pad: int, pages_bound: int):
         """One jit object per (padded token count, pow2 gather bound) —
-        the whole program key space on the ragged admission path
-        (compare the legacy per-bucket prefill + per-(shared_len,
-        bucket) suffix + chunk families)."""
+        the whole program key space of admission."""
         return self._jit_lru(
             self._ragged_jits, (t_pad, pages_bound),
             lambda: self._build_ragged_step(self._ragged_block_q,
@@ -3136,8 +2880,7 @@ class ContinuousBatchingEngine:
     def _build_ragged_step(self, block_q: int, pages_bound=None,
                            draft: bool = False,
                            select_rows: bool = True,
-                           return_logits: bool = False,
-                           jit: bool = True):
+                           return_logits: bool = False):
         """The one ragged program: packed ids -> per-token rope ->
         ONE KV scatter into the pages -> ragged paged attention with
         per-sequence descriptors -> sample each slot's designated row.
@@ -3212,35 +2955,9 @@ class ContinuousBatchingEngine:
                              tuple(r for _, r in reports)),)
                 return out
 
-        if not jit:
-            # raw op-by-op program for the dispatch-gap sampler
-            # (profile_round): eager execution is what lets the
-            # per-op-family `profile.fence` hooks in llama.py observe
-            # real dispatch boundaries; no donation, so the sampled
-            # round leaves the pools untouched
-            return run
         return jax.jit(run, donate_argnums=(2,))
 
-    # -- dense layout --------------------------------------------------
-    def _dense_insert(self, slot: int, rows):
-        # one donated-in-place program writes every layer's slot rows
-        # (2L separate .at[].set dispatches would each copy the full
-        # batch cache); rows are (bucket, hk, hd) — bucket <= S, written
-        # from position 0
-        if self._insert_jit is None:
-            self._insert_jit = self._jit_singleton(
-                "insert", self._build_insert)
-        self._caches = self._insert_jit(self._caches, rows,
-                                        jnp.int32(slot))
-
-    def _build_insert(self):
-        def _insert(caches, rows_, s_):
-            return [(ck.at[s_, :rk.shape[0]].set(rk.astype(ck.dtype)),
-                     cv.at[s_, :rv.shape[0]].set(rv.astype(cv.dtype)))
-                    for (ck, cv), (rk, rv) in zip(caches, rows_)]
-        return jax.jit(_insert, donate_argnums=(0,))
-
-    # -- paged layout --------------------------------------------------
+    # -- page accounting ------------------------------------------------
     def _worst_pages(self, req: Request) -> int:
         worst_len = min(len(req.prompt) + req.max_new_tokens, self.S)
         return -(-worst_len // self.page_size)
@@ -3317,10 +3034,9 @@ class ContinuousBatchingEngine:
             parent = key
         if not pages:
             return None
-        # attach a POWER-OF-TWO page count: each distinct shared_len is
-        # a separate compiled suffix-prefill program, so an unquantized
-        # match family would thrash the program LRU with multi-second
-        # recompiles that cost more than the prefill they save
+        # attach a POWER-OF-TWO page count. The ragged program's key has
+        # no shared_len in it, so nothing needs this; it stays because
+        # lifting it changes what a hit reuses (ROADMAP D1c)
         return pages[:1 << (len(pages).bit_length() - 1)]
 
     def _register_prefix(self, slot: int, req: Request):
@@ -3365,31 +3081,6 @@ class ContinuousBatchingEngine:
         self._slot_next_idx[slot] += 1
         return page
 
-    def _paged_insert(self, slot: int, req: Request, p_len: int,
-                      bucket: int, rows):
-        self._reserve_and_alloc(slot, req, p_len)
-        jit = self._get_scatter(bucket)
-        self._kv = jit(self._kv, rows, jnp.asarray(self._bt[slot]),
-                       jnp.int32(p_len))
-
-    def _get_scatter(self, bucket: int):
-        # own LRU cap: suffix-prefill admissions reach buckets that
-        # never enter _prefill_jits, so a coupled eviction would leak
-        return self._jit_lru(self._scatter_jits, bucket,
-                             self._build_scatter, family="scatter")
-
-    def _build_scatter(self):
-        from paddle_tpu.ops.paged_attention import \
-            paged_prefill_scatter
-
-        def _scatter(kv, rows_, bt_row, true_len):
-            return [
-                paged_prefill_scatter(kp, vp, rk.astype(kp.dtype),
-                                      rv.astype(vp.dtype), bt_row,
-                                      true_len)
-                for (kp, vp), (rk, rv) in zip(kv, rows_)]
-        return jax.jit(_scatter, donate_argnums=(0,))
-
     def _reserve_and_alloc(self, slot: int, req: Request, p_len: int):
         """Record the slot's worst-case reservation and allocate pages
         covering the prompt — the common preamble of every paged
@@ -3398,158 +3089,7 @@ class ContinuousBatchingEngine:
         while self._slot_next_idx[slot] * self.page_size < p_len:
             self._alloc_page(slot)
 
-    def _admit_chunked(self, slot: int, req: Request, p_len: int,
-                       prompt: List[int]):
-        """Long-prompt admission: fixed-size chunks through ONE compiled
-        program with a traced position offset (the model's verify-
-        attention branch). Padded tail rows of the last chunk leave
-        garbage KV only at positions >= p_len, which decode overwrites
-        sequentially before ever attending them."""
-        C = self._chunk
-        self._reserve_and_alloc(slot, req, p_len)
-        if self._chunk_jit is None:
-            self._chunk_jit = self._jit_singleton(
-                "chunk", lambda: self._build_chunk_prefill(C))
-        cfg = self.model.config
-        hk, hd = cfg.num_key_value_heads, cfg.head_dim
-        dt = self._params[0]._value.dtype
-        work = [(jnp.zeros((1, self.S, hk, hd), dt),
-                 jnp.zeros((1, self.S, hk, hd), dt))
-                for _ in range(cfg.num_hidden_layers)]
-        n_chunks = -(-p_len // C)
-        ids_pad = np.zeros((1, n_chunks * C), np.int32)
-        ids_pad[0, :p_len] = prompt
-        pv, bv = self._pv(), self._bv()
-        sjit = self._get_scatter(C)
-        lg = None
-        for ci in range(n_chunks):
-            off = ci * C
-            lg, rows, work = self._chunk_jit(
-                pv, bv, work, jnp.asarray(ids_pad[:, off:off + C]),
-                jnp.int32(off))
-            # scatter this chunk's rows into the pages after page off/ps
-            k0 = off // self.page_size
-            sub_bt = np.zeros(self.pps, np.int32)
-            sub_bt[:self.pps - k0] = self._bt[slot, k0:]
-            self._kv = sjit(self._kv, rows, jnp.asarray(sub_bt),
-                            jnp.int32(min(C, p_len - off)))
-        if self._sample_jit is None:
-            self._sample_jit = self._jit_singleton(
-                "sample", self._build_sample)
-        last_local = p_len - (n_chunks - 1) * C
-        return int(self._sample_jit(lg[last_local - 1],
-                                    self._next_keys()))
-
-    def _build_sample(self):
-        from .generation import _sample_token
-        strat, temp = self.strategy, self.temperature
-        tk, tp = self.top_k, self.top_p
-        return jax.jit(
-            lambda row, key: _sample_token(row[None], key, strat,
-                                           temp, tk, tp)[0][0])
-
-    def _build_chunk_prefill(self, C: int):
-        """One program for EVERY chunk of EVERY long prompt: the offset
-        is traced, so no per-length or per-offset recompiles."""
-        model = self.model
-        params, buffers = self._params, self._buffers
-
-        def run(pv, bv, work, ids, off):
-            from .generation import bind_state
-            with bind_state(params, buffers, pv, bv), no_grad():
-                pkv = [(Tensor(k), Tensor(v)) for k, v in work]
-                logits, new = model.forward(
-                    Tensor(ids), past_key_values=pkv,
-                    position_offset=Tensor(off), use_cache=True)
-                rows = [
-                    (jax.lax.dynamic_slice_in_dim(k._value[0], off, C, 0),
-                     jax.lax.dynamic_slice_in_dim(v._value[0], off, C, 0))
-                    for k, v in new]
-                return (logits._value[0],
-                        rows,
-                        [(k._value, v._value) for k, v in new])
-
-        return jax.jit(run, donate_argnums=(2,))
-
-    def _get_suffix_prefill(self, shared_len: int, bucket: int):
-        # own budget (2x prefill's): keys span shared_len x bucket,
-        # but shared_len is power-of-two-quantized (_match_prefix)
-        # so the space stays log-bounded
-        return self._jit_lru(
-            self._suffix_jits, (shared_len, bucket),
-            lambda: self._build_suffix_prefill(shared_len, bucket),
-            cap=2 * self._max_prefill, family="suffix")
-
-    def _build_suffix_prefill(self, shared_len: int, bucket: int):
-        """Compiled program for prefix-hit admission: gather the shared
-        prefix pages to dense rows, run chunked prefill of the suffix
-        over them (end-aligned causal, position_offset = shared_len so
-        rope angles are exact), sample the first token, return the
-        suffix KV rows for scatter. One program per (shared_len,
-        suffix bucket), LRU-capped with the other prefill programs."""
-        model = self.model
-        params, buffers = self._params, self._buffers
-        cfg = model.config
-        hk, hd = cfg.num_key_value_heads, cfg.head_dim
-        strat, temp = self.strategy, self.temperature
-        tk, tp = self.top_k, self.top_p
-
-        def run(pv, bv, kv, bt_prefix, ids, true_len, key):
-            from .generation import bind_state, _sample_token
-            with bind_state(params, buffers, pv, bv), no_grad():
-                caches = []
-                for (kp, vp) in kv:
-                    # (n_pp, ps, hk*hd) -> (1, shared_len, hk, hd)
-                    kd = kp[bt_prefix].reshape(1, shared_len, hk, hd)
-                    vd = vp[bt_prefix].reshape(1, shared_len, hk, hd)
-                    pad = jnp.zeros((1, bucket, hk, hd), kd.dtype)
-                    caches.append(
-                        (Tensor(jnp.concatenate([kd, pad], 1)),
-                         Tensor(jnp.concatenate([vd, pad], 1))))
-                am = (jnp.arange(shared_len + bucket)
-                      < shared_len + true_len)[None, :]
-                logits, new_caches = model.forward(
-                    Tensor(ids), attention_mask=Tensor(am),
-                    past_key_values=caches, position_offset=shared_len,
-                    use_cache=True)
-                last = logits._value[0, true_len - 1]
-                tok, _ = _sample_token(last[None], key, strat, temp,
-                                       tk, tp)
-                rows = [(k._value[0, shared_len:],
-                         v._value[0, shared_len:])
-                        for k, v in new_caches]
-                return tok[0], rows
-
-        return jax.jit(run)
-
     # -- decode --------------------------------------------------------
-    def _build_decode(self):
-        model = self.model
-        params, buffers = self._params, self._buffers
-        strat, temp = self.strategy, self.temperature
-        tk, tp = self.top_k, self.top_p
-        paged = self.layout == "paged"
-
-        def run(pv, bv, kv, tok, pos, bt, key):
-            from .generation import bind_state, _sample_token
-            with bind_state(params, buffers, pv, bv), no_grad():
-                if paged:
-                    from .llama import PagedKVCacheView
-                    pkv = [PagedKVCacheView(k, v, bt) for k, v in kv]
-                else:
-                    pkv = [(Tensor(k), Tensor(v)) for k, v in kv]
-                logits, new_caches = model.forward(
-                    Tensor(tok[:, None]), past_key_values=pkv,
-                    position_offset=Tensor(pos), use_cache=True)
-                nxt, _ = _sample_token(logits._value[:, 0], key, strat,
-                                       temp, tk, tp)
-                if paged:
-                    return nxt, [(c.k_pages._value, c.v_pages._value)
-                                 for c in new_caches]
-                return nxt, [(k._value, v._value) for k, v in new_caches]
-
-        return jax.jit(run, donate_argnums=(2,))
-
     def _decode_query_lens(self):
         """One query a slot. A state layer must leave an idle slot's
         state alone, so for a model with state layers an idle slot's
@@ -3660,59 +3200,47 @@ class ContinuousBatchingEngine:
         if self._spec is not None and self._spec_decode(finished):
             return True
         if self._decode_jit is None:
-            # ragged mode: decode is the SAME ragged program at
-            # block_q=1 — B sequences of one query token each. The
-            # constant descriptor arrays (slot indices, unit query
-            # lens) are built once: B never changes for the engine's
-            # lifetime and re-uploading them every step would tax the
-            # exact hot loop this path exists to speed up.
-            if self.layout == "paged" and self.attn_impl == "ragged":
-                # sentry variant: the program also returns its
-                # sampled-row logits, so the every-Nth scan is a host
-                # pull, not a second dispatch (attach_sentry resets
-                # _decode_jit so this rebuild happens)
-                self._decode_logits = (self._sentry is not None
-                                       and self._sentry.wants_logits)
-                self._decode_jit = self._jit_singleton(
-                    "decode", lambda: self._build_ragged_step(
-                        1, return_logits=self._decode_logits))
-                self._decode_idx = jnp.arange(self.B, dtype=jnp.int32)
-                self._decode_ones = jnp.ones(self.B, jnp.int32)
-            else:
-                self._decode_logits = False
-                self._decode_jit = self._jit_singleton(
-                    "decode", self._build_decode)
+            # decode is the SAME ragged program at block_q=1 — B
+            # sequences of one query token each. The constant
+            # descriptor arrays (slot indices, unit query lens) are
+            # built once: B never changes for the engine's lifetime and
+            # re-uploading them every step would tax the hot loop.
+            # Sentry variant: the program also returns its sampled-row
+            # logits, so the every-Nth scan is a host pull, not a
+            # second dispatch (attach_sentry resets _decode_jit so this
+            # rebuild happens)
+            self._decode_logits = (self._sentry is not None
+                                   and self._sentry.wants_logits)
+            self._decode_jit = self._jit_singleton(
+                "decode", lambda: self._build_ragged_step(
+                    1, return_logits=self._decode_logits))
+            self._decode_idx = jnp.arange(self.B, dtype=jnp.int32)
+            self._decode_ones = jnp.ones(self.B, jnp.int32)
         # inactive slots decode garbage at a clamped position; their
-        # outputs are never read. Paged: their block-table rows are all
-        # trash-page, so their KV writes land in page 0 (never read);
-        # dense: their cache rows are overwritten at admission.
+        # outputs are never read, and their block-table rows are all
+        # trash-page, so their KV writes land in page 0 (never read)
         pos = np.clip(self._pos, 0, self.S - 1)
-        if self.layout == "paged":
-            for i, r in enumerate(self._slot_req):
-                if r is None:
-                    continue
-                if not self._grow_slot(i, finished):
-                    continue          # slot i itself was preempted
-                if self._window is not None:
-                    # reclaim pages that slid wholly below the attention
-                    # window [ctx - w, ctx): the kernel never reads them
-                    ws = int(self._pos[i]) + 1 - self._window
-                    while (self._slot_freed[i] + 1) * self.page_size \
-                            <= ws:
-                        j = int(self._slot_freed[i])
-                        page = int(self._bt[i, j])
-                        if page != 0:
-                            self._slot_pages[i].remove(page)
-                            self._decref(page)
-                            self._bt[i, j] = 0      # trash-route
-                        self._slot_freed[i] += 1
-            if not any(r is not None for r in self._slot_req):
-                return False          # every slot preempted away
-            kv = self._cache()
-            bt = jnp.asarray(self._bt)
-        else:
-            kv = self._caches
-            bt = jnp.zeros((), jnp.int32)     # unused placeholder
+        for i, r in enumerate(self._slot_req):
+            if r is None:
+                continue
+            if not self._grow_slot(i, finished):
+                continue          # slot i itself was preempted
+            if self._window is not None:
+                # reclaim pages that slid wholly below the attention
+                # window [ctx - w, ctx): the kernel never reads them
+                ws = int(self._pos[i]) + 1 - self._window
+                while (self._slot_freed[i] + 1) * self.page_size <= ws:
+                    j = int(self._slot_freed[i])
+                    page = int(self._bt[i, j])
+                    if page != 0:
+                        self._slot_pages[i].remove(page)
+                        self._decref(page)
+                        self._bt[i, j] = 0      # trash-route
+                    self._slot_freed[i] += 1
+        if not any(r is not None for r in self._slot_req):
+            return False          # every slot preempted away
+        kv = self._cache()
+        bt = jnp.asarray(self._bt)
         # fault BEFORE the dispatch (and before the PRNG key advances):
         # a retried step replays an identical sampling stream
         fault_point("serving.decode")
@@ -3722,8 +3250,7 @@ class ContinuousBatchingEngine:
         # timeline row, and request_tree() fans it into each tree
         rids = ([r.request_id for r in self._slot_req if r is not None]
                 if telemetry.enabled() else ())
-        if telemetry.enabled() and self.layout == "paged" \
-                and self.attn_impl == "ragged":
+        if telemetry.enabled():
             # one query a slot at its position: the decode dispatch's
             # descriptors, as built below
             self._count_attn_pages(
@@ -3736,38 +3263,26 @@ class ContinuousBatchingEngine:
             # (tokens/sec derives from it) — a fake clock here would
             # fabricate hardware throughput, not make tests exact
             t0 = time.perf_counter()
-            lg_rows = reports = None
-            if self.layout == "paged" and self.attn_impl == "ragged":
-                bidx = self._decode_idx
-                qlen = self._decode_query_lens()
-                # pipelined mode: mid-window the token input is the
-                # PREVIOUS dispatch's on-device output — the greedy
-                # feedback needs no host round-trip (the whole point)
-                tok_in = (self._tok_dev if self._tok_dev is not None
-                          else jnp.asarray(self._tok))
-                with self._tp_scope():
-                    # multi-LoRA: decode packs one row per slot in
-                    # slot order, so the gather vector IS the
-                    # slot-adapter map
-                    out = self._decode_jit(
-                        self._lora_pv(self._pv(), self._slot_adapter),
-                        self._bv(),
-                        kv, tok_in, bidx,
-                        jnp.asarray(pos.astype(np.int32)), bidx,
-                        qlen,
-                        jnp.asarray((pos + 1).astype(np.int32)), bt,
-                        bidx, self._next_keys())
-                nxt, lg_rows, reports = self._take_step(
-                    out, self._decode_logits)
-            else:
-                nxt, new_kv = self._decode_jit(
-                    self._pv(), self._bv(),
-                    kv, jnp.asarray(self._tok), jnp.asarray(pos), bt,
-                    self._next_keys())
-                if self.layout == "paged":
-                    self._kv = new_kv
-                else:
-                    self._caches = new_kv
+            bidx = self._decode_idx
+            qlen = self._decode_query_lens()
+            # pipelined mode: mid-window the token input is the
+            # PREVIOUS dispatch's on-device output — the greedy
+            # feedback needs no host round-trip (the whole point)
+            tok_in = (self._tok_dev if self._tok_dev is not None
+                      else jnp.asarray(self._tok))
+            with self._tp_scope():
+                # multi-LoRA: decode packs one row per slot in slot
+                # order, so the gather vector IS the slot-adapter map
+                out = self._decode_jit(
+                    self._lora_pv(self._pv(), self._slot_adapter),
+                    self._bv(),
+                    kv, tok_in, bidx,
+                    jnp.asarray(pos.astype(np.int32)), bidx,
+                    qlen,
+                    jnp.asarray((pos + 1).astype(np.int32)), bt,
+                    bidx, self._next_keys())
+            nxt, lg_rows, reports = self._take_step(
+                out, self._decode_logits)
             # pdt-lint: disable=PDT001 same real-wall measurement as t0
             t1 = time.perf_counter()
             _M_DECODE_DISPATCH.observe(t1 - t0)
@@ -3999,51 +3514,6 @@ class ContinuousBatchingEngine:
         if n:
             self._harvest_pending(self._finished_backlog)
         return n
-
-    def profile_round(self):
-        """Dispatch-gap sample of ONE decode round: run the decode
-        program op-by-op (un-jitted) with `profile.fence`
-        block_until_ready fences at every op-family boundary
-        (models/llama.py), attributing the host wall between fences as
-        the dispatch gap of that op pair. Returns the ranked gap table
-        (list of {op_pair, gap_s, device_s, count} rows, summed over
-        layers) and publishes `pdt_profile_gap_seconds{op_pair}` — the
-        megakernel fusion ladder's shopping list (ROADMAP item 1).
-
-        The sampled round is OBSERVATION ONLY: the window is quiesced
-        first, the eager pass donates nothing, its outputs are
-        discarded, and the sample key is a constant — engine state,
-        the PRNG stream, and the served tokens stay bit-identical
-        (test-pinned). The un-jitted pass is 10-100x slower than the
-        compiled step, so sample on demand, not per step."""
-        if self.layout != "paged" or self.attn_impl != "ragged":
-            raise RuntimeError(
-                "profile_round requires the paged+ragged decode path "
-                f"(layout={self.layout!r}, attn_impl={self.attn_impl!r})")
-        if self._tp is not None:
-            raise RuntimeError(
-                "profile_round is single-mesh only: the eager sampler "
-                "cannot drive the shard_map kernel path")
-        self.quiesce()
-        if not any(r is not None for r in self._slot_req):
-            raise RuntimeError("profile_round needs >= 1 active slot")
-        if self._profile_raw is None:
-            self._profile_raw = self._build_ragged_step(1, jit=False)
-        pos = np.clip(self._pos, 0, self.S - 1)
-        bidx = jnp.arange(self.B, dtype=jnp.int32)
-        ones = jnp.ones(self.B, jnp.int32)
-        args = (self._lora_pv(self._pv(), self._slot_adapter),
-                self._bv(), self._cache(), jnp.asarray(self._tok), bidx,
-                jnp.asarray(pos.astype(np.int32)), bidx, ones,
-                jnp.asarray((pos + 1).astype(np.int32)),
-                jnp.asarray(self._bt), bidx, jax.random.PRNGKey(0))
-        # untimed warmup pass: per-op executables and lazy imports
-        # must not pollute the sampled gaps
-        jax.block_until_ready(
-            jax.tree_util.tree_leaves(self._profile_raw(*args)))
-        with _profile.gap_sampler() as sampler:
-            self._profile_raw(*args)
-        return sampler.table()
 
     # -- speculative decoding (spec_decode=SpecConfig, ISSUE 10) -------
     def _spec_decode(self, finished: List[Request]) -> bool:

@@ -34,13 +34,12 @@ Three modules:
   pass/warn/breach with burn rates, exported as `pdt_slo_*` gauges.
 * `profile` — the performance attribution plane: the fleet step's
   self-time table (`span_summary`, read from the
-  `pdt_span_self_seconds{name}` series every span observes), the
-  dispatch-gap sampler (`gap_sampler`/`fence`, driven by
-  `engine.profile_round()`), compile-cache observability
-  (`compile_timed` behind the engine's `_jit_lru`/`_jit_singleton`
-  seam + the retrace-storm detector), the `pdt_mem_bytes{pool}` memory
-  ledger, and `render_profile_report(snapshot)` for the waterfall /
-  top-gap / compile-table / ledger text report.
+  `pdt_span_self_seconds{name}` series every span observes),
+  compile-cache observability (`compile_timed` behind the engine's
+  `_jit_lru`/`_jit_singleton` seam + the retrace-storm detector), the
+  `pdt_mem_bytes{pool}` memory ledger, and
+  `render_profile_report(snapshot)` for the waterfall / compile-table /
+  ledger text report.
 * `status` — `render_fleet_status()`: the human-readable fleet report.
 * `__main__` — the operator CLI (`python -m paddle_tpu.observability
   snapshot|slo|trace ...`, installed as `paddle-tpu-obs`).

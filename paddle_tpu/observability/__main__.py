@@ -10,14 +10,11 @@
         --request REQUEST_ID
     python -m paddle_tpu.observability status --from FLEET.json
     python -m paddle_tpu.observability profile --from SNAP.json
-        [--top-gaps 10]
 
 `profile` renders the performance-attribution report from a saved
 metrics snapshot (JSON or Prometheus text): the fleet step's span
-self-time waterfall (`pdt_span_self_seconds{name}`), the ranked
-`pdt_profile_gap_seconds` table from the last
-`engine.profile_round()`, the per-family compile-cache
-table, and the `pdt_mem_bytes{pool}` memory ledger — exits non-zero
+self-time waterfall (`pdt_span_self_seconds{name}`), the per-family
+compile-cache table, and the `pdt_mem_bytes{pool}` memory ledger — exits non-zero
 when the snapshot carries no profile series at all.
 `status` renders a saved `ServingRouter.fleet_info()` snapshot as the
 operator report (per-replica role + health, role aggregates,
@@ -112,14 +109,11 @@ def _cmd_status(args) -> int:
 def _cmd_profile(args) -> int:
     from . import profile as _profile
     snap = _load_snapshot(args.src)
-    report = _profile.render_profile_report(snap,
-                                            top_gaps=args.top_gaps)
-    print(report)
+    print(_profile.render_profile_report(snap))
     # mirror `slo`'s exit-code contract: non-zero when there is
-    # nothing to attribute (no pdt_span_self_seconds/pdt_profile_*/
-    # pdt_jit_*/pdt_mem_* series in the snapshot at all)
+    # nothing to attribute (no pdt_span_self_seconds/pdt_jit_*/
+    # pdt_mem_* series in the snapshot at all)
     empty = not (_profile.span_summary(snap)
-                 or _profile.gap_table(snap)
                  or _profile.compile_summary(snap)
                  or _profile.mem_summary(snap))
     return 1 if empty else 0
@@ -185,8 +179,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     s.add_argument("--from", dest="src", metavar="SNAP.json",
                    required=True,
                    help="saved JSON snapshot or Prometheus text")
-    s.add_argument("--top-gaps", type=int, default=10,
-                   help="rows in the dispatch-gap table (default 10)")
     s.set_defaults(fn=_cmd_profile)
 
     t = sub.add_parser("trace", help="trace tooling")

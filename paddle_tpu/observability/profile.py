@@ -1,13 +1,8 @@
 """Performance attribution: the fleet step's self-time table,
-compile-cache observability, the dispatch-gap sampler, and the memory
-ledger.
+compile-cache observability, and the memory ledger.
 
-ROADMAP item 1 (the megakernel decode fusion ladder) deletes host
-dispatch gaps between the RMSNorm -> QKV -> RoPE -> ragged-attention ->
-MLP ops of a decode round — this module is how those gaps are MEASURED,
-so each rung is chosen by ranked evidence and graded by the same
-instrument. Four surfaces, all in the PR-2 tradition (stdlib+jax only,
-guaranteed no-op unless telemetry is enabled):
+Three surfaces, all in the PR-2 tradition (stdlib+jax only, guaranteed
+no-op unless telemetry is enabled):
 
 * **Self-time table** — the fleet step is ONE span tree
   (`router.step` -> `router.replica_step` -> `serving.step` ->
@@ -19,15 +14,6 @@ guaranteed no-op unless telemetry is enabled):
   a dispatch waits for the device sits in the span round the dispatch
   (`serving.ragged_prefill`, `serving.decode_step`,
   `serving.harvest`), never in its host parent.
-* **Dispatch-gap sampler** — `gap_sampler()` + the `fence()` hooks in
-  `models/llama.py`: `ContinuousBatchingEngine.profile_round()` runs
-  ONE un-jitted decode round with `jax.block_until_ready` fences at
-  every op-family boundary, attributing host time between fences as
-  the dispatch gap of that op pair (`pdt_profile_gap_seconds{op_pair}`,
-  ranked by `gap_table()` — the fusion ladder's shopping list). The
-  sampled round is purely functional: outputs are discarded, engine
-  state and the PRNG stream are untouched, so the served token stream
-  stays bit-identical.
 * **Compile-cache observability** — `compile_timed()` wraps every
   program the engine's `_jit_lru`/`_jit_singleton` seam builds: the
   first invocation (the one that traces and compiles) is metered as
@@ -43,7 +29,7 @@ guaranteed no-op unless telemetry is enabled):
   into the one `pdt_mem_bytes{pool}` family, surfaced by
   `fleet_info()["perf"]` and `render_fleet_status`.
 
-`render_profile_report(snapshot)` renders all four surfaces from any
+`render_profile_report(snapshot)` renders all three surfaces from any
 saved snapshot — the `paddle-tpu-obs profile` CLI, the post-kill-drill
 report in `recipes/llama_serve.py`, and failing-test attachments in
 `tests/conftest.py` all print the same text.
@@ -59,16 +45,11 @@ from . import trace as _trace
 from .registry import counter, gauge, histogram
 
 __all__ = ["compile_timed", "note_cache",
-           "configure_retrace", "retrace_window", "gap_sampler",
-           "fence", "gap_table", "memory_ledger", "perf_section",
+           "configure_retrace", "retrace_window",
+           "memory_ledger", "perf_section",
            "span_summary", "compile_summary", "mem_summary",
            "render_profile_report", "snapshot_report"]
 
-_M_GAP = gauge(
-    "pdt_profile_gap_seconds",
-    "Host dispatch gap between two op families summed over the most "
-    "recently gap-sampled decode round (profile_round), by op pair — "
-    "the megakernel fusion ladder's ranked shopping list.", ("op_pair",))
 _M_JIT_COMPILES = counter(
     "pdt_jit_compiles_total",
     "Programs compiled through the _jit_lru/_jit_singleton seam "
@@ -233,95 +214,6 @@ def note_cache(family: str, entries: int, evicted: int = 0) -> None:
         _M_JIT_EVICTIONS.inc(evicted, family=family)
 
 
-# -- dispatch-gap sampler ---------------------------------------------
-
-class _GapSampler:
-    """Collects (op, dispatch_done_t, fence_done_t) triples from the
-    `fence()` hooks of ONE un-jitted decode round. The gap of pair
-    A->B is the host wall between A's fence completing (device idle)
-    and B's ops all being enqueued — the dispatch overhead a fused
-    kernel would delete. `device_s` is B's fence wait, i.e. its device
-    compute (plus copy) once enqueued."""
-
-    def __init__(self):
-        self._events: List = []    # (op, t_dispatched, t_done)
-
-    def note(self, op: str, t_dispatched: float, t_done: float):
-        self._events.append((op, t_dispatched, t_done))
-
-    def table(self) -> List[Dict[str, object]]:
-        pairs: Dict[str, Dict[str, float]] = {}
-        prev_op, prev_done = None, None
-        for op, t_disp, t_done in self._events:
-            if prev_op is not None:
-                row = pairs.setdefault(
-                    f"{prev_op}->{op}",
-                    {"gap_s": 0.0, "device_s": 0.0, "count": 0})
-                row["gap_s"] += max(t_disp - prev_done, 0.0)
-                row["device_s"] += t_done - t_disp
-                row["count"] += 1
-            prev_op, prev_done = op, t_done
-        out = [{"op_pair": k, **v} for k, v in pairs.items()]
-        out.sort(key=lambda r: -r["gap_s"])
-        for row in out:
-            _M_GAP.set(row["gap_s"], op_pair=row["op_pair"])
-        return out
-
-
-_SAMPLER: Optional[_GapSampler] = None
-
-
-class gap_sampler:
-    """Context manager arming the op-family fences for one sampled
-    round. Enter returns the sampler; call `.table()` after the round
-    for the ranked gap table (it also publishes the
-    `pdt_profile_gap_seconds{op_pair}` gauges)."""
-
-    def __enter__(self) -> _GapSampler:
-        global _SAMPLER
-        self._sampler = _GapSampler()
-        _SAMPLER = self._sampler
-        return self._sampler
-
-    def __exit__(self, *exc):
-        global _SAMPLER
-        _SAMPLER = None
-        return False
-
-
-def fence(op: str, value):
-    """Op-family boundary hook (models/llama.py threads these through
-    the ragged decode path): inert — one global check — unless a
-    `gap_sampler()` is armed, in which case the value is
-    block_until_ready-fenced and the (dispatch-done, fence-done) pair
-    recorded. Returns `value` unchanged either way, so the hook is
-    transparent under jit tracing."""
-    s = _SAMPLER
-    if s is None:
-        return value
-    import jax
-    t_disp = time.perf_counter()
-    leaves = value if isinstance(value, (tuple, list)) else (value,)
-    for leaf in leaves:
-        jax.block_until_ready(getattr(leaf, "_value", leaf))
-    s.note(op, t_disp, time.perf_counter())
-    return value
-
-
-def gap_table(snapshot: Dict[str, object]) -> List[Dict[str, object]]:
-    """Ranked dispatch-gap rows from a saved snapshot's
-    `pdt_profile_gap_seconds` gauges."""
-    series = snapshot.get("gauges", {}).get("pdt_profile_gap_seconds",
-                                            {})
-    rows = []
-    for labels, v in series.items():
-        # labels: op_pair="a->b"
-        pair = labels.split('"')[1] if '"' in labels else labels
-        rows.append({"op_pair": pair, "gap_s": float(v)})
-    rows.sort(key=lambda r: -r["gap_s"])
-    return rows
-
-
 # -- memory ledger -----------------------------------------------------
 
 def _engine_pools(engine) -> Dict[str, float]:
@@ -433,10 +325,9 @@ def _fmt_bytes(v: float) -> str:
     return f"{v:.1f}GiB"
 
 
-def render_profile_report(snapshot: Dict[str, object],
-                          top_gaps: int = 10) -> str:
-    """The one profile report (self-time waterfall + top gaps +
-    compile table + memory ledger) from any saved snapshot — shared by
+def render_profile_report(snapshot: Dict[str, object]) -> str:
+    """The one profile report (self-time waterfall + compile table +
+    memory ledger) from any saved snapshot — shared by
     the `paddle-tpu-obs profile` CLI, the recipes, and failing-test
     attachments. The waterfall is one row a span name: self seconds,
     count, and the share of the fleet step — the self times of the
@@ -463,12 +354,6 @@ def render_profile_report(snapshot: Dict[str, object],
                     + "#" * max(int(round(share / 4)), 1)
             lines.append(f"  {name:<24} {_fmt_s(r['total_s']):>10} "
                          f"{r['count']:>8}x{tail}")
-    gaps = gap_table(snapshot)
-    if gaps:
-        lines.append("top dispatch gaps (last sampled round)")
-        for row in gaps[:top_gaps]:
-            lines.append(f"  {row['op_pair']:<28} "
-                         f"{_fmt_s(row['gap_s']):>9}")
     compiles = compile_summary(snapshot)
     if compiles:
         lines.append("compile cache")
@@ -491,11 +376,10 @@ def render_profile_report(snapshot: Dict[str, object],
             lines.append(f"  {pool:<14} {_fmt_bytes(mem[pool]):>12}")
     if not lines:
         return ("no profile data in snapshot (pdt_span_self_seconds/"
-                "pdt_profile_*/pdt_jit_*/pdt_mem_* series absent)")
+                "pdt_jit_*/pdt_mem_* series absent)")
     return "\n".join(lines)
 
 
-def snapshot_report(top_gaps: int = 10) -> str:
+def snapshot_report() -> str:
     """`render_profile_report` of the LIVE registry."""
-    return render_profile_report(_registry.snapshot(),
-                                 top_gaps=top_gaps)
+    return render_profile_report(_registry.snapshot())

@@ -1,4 +1,4 @@
-"""Paged attention — serving decode kernel over a block-table KV cache.
+"""Paged attention — the q = 1 decode op over a block-table KV cache.
 
 ≙ reference serving-path attention: «masked_multihead_attention» +
 «fused_multi_transformer» decode kernels and the paged-KV design the
@@ -23,9 +23,11 @@ Forward-parity is tested against a NumPy oracle and the contiguous-cache
 The ragged sibling (`ragged_paged_attention.py`) generalizes this grid
 to mixed prefill+decode batches AND fixes the "DMA still runs" cost
 above: dead pages route their index_map to the resident trash page, so
-the pipeline skips the copy. This kernel remains the minimal q = 1 form
-(and the `attention_impl="legacy"` engine path); the XLA fallback below
-is the decode special case of the ragged masked-attention core.
+the pipeline skips the copy, and it is what the serving engine runs.
+This op remains as the public `incubate.nn.functional.paged_attention`
+and as the minimal q = 1 reference the ragged kernel's tests compare
+with; the XLA fallback below is the decode special case of the ragged
+masked-attention core.
 """
 from __future__ import annotations
 
@@ -201,22 +203,6 @@ def paged_append_values(k_pages, v_pages, k, v, block_tables, positions):
         block_tables, (positions // page_size)[:, None], axis=1)[:, 0]
     return set_rows(k_pages, v_pages, page_idx, positions % page_size,
                     k, v)
-
-
-def paged_prefill_scatter(k_pages, v_pages, k_rows, v_rows, block_table,
-                          true_len, trash_page=0):
-    """Scatter a prefilled prompt's KV rows into the page pools.
-
-    k_rows/v_rows: (T, HK, D) rows for positions 0..T-1 of ONE sequence;
-    block_table: (pps,) page ids for that sequence; rows at positions
-    >= true_len are routed to `trash_page` (a permanently reserved page
-    that is never read) so the scatter stays static-shape."""
-    page_size = k_pages.shape[1]
-    pos = jnp.arange(k_rows.shape[0])
-    page_idx = jnp.where(pos < true_len,
-                         block_table[pos // page_size], trash_page)
-    return set_rows(k_pages, v_pages, page_idx, pos % page_size,
-                    k_rows, v_rows)
 
 
 class PagedKVCache:

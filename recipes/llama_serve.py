@@ -65,12 +65,6 @@ def main(argv=None):
                         "outputs-identical assert proves losslessness "
                         "through SIGKILL failover; prints acceptance "
                         "rate + effective tokens/sec (0 = off)")
-    p.add_argument("--attention-impl", default="ragged",
-                   choices=("ragged", "legacy"),
-                   help="serving attention path: the fused ragged "
-                        "paged-attention kernel (default) or the "
-                        "legacy per-bucket prefill + q=1 decode paths "
-                        "(greedy outputs are bit-identical)")
     p.add_argument("--replicas", type=int, default=3,
                    help="fleet size for the router failover drill")
     p.add_argument("--roles", default="prefill:2,decode:2",
@@ -183,9 +177,7 @@ def main(argv=None):
     eng = ContinuousBatchingEngine(model, max_batch_size=4,
                                    max_seq_len=min(
                                        256, cfg.max_position_embeddings),
-                                   enable_prefix_caching=True,
-                                   attention_impl=args.attention_impl)
-    print(f"engine attention_impl: {eng.attn_impl}")
+                                   enable_prefix_caching=True)
     rids = [eng.add_request(
         system + rng.integers(1, cfg.vocab_size,
                               int(rng.integers(4, 10))).tolist(), n)
@@ -207,7 +199,7 @@ def main(argv=None):
     eng = ContinuousBatchingEngine(
         model, max_batch_size=2,
         max_seq_len=min(256, cfg.max_position_embeddings),
-        max_waiting=3, attention_impl=args.attention_impl)
+        max_waiting=3)
     for _ in range(3):
         eng.add_request(rng.integers(1, cfg.vocab_size, 6).tolist(), 8)
     try:
@@ -274,7 +266,6 @@ def main(argv=None):
                 model, max_batch_size=2,
                 max_seq_len=min(256, cfg.max_position_embeddings),
                 enable_prefix_caching=True,
-                attention_impl=args.attention_impl,
                 spec_decode=SpecConfig(draft, k=speculate)
                 if speculate else None),
             num_replicas=args.replicas, policy="prefix_affinity",
@@ -379,8 +370,7 @@ def main(argv=None):
             lambda i: ContinuousBatchingEngine(
                 model, max_batch_size=2,
                 max_seq_len=min(256, cfg.max_position_embeddings),
-                enable_prefix_caching=True,
-                attention_impl=args.attention_impl),
+                enable_prefix_caching=True),
             num_replicas=n_roles, policy="prefix_affinity",
             page_size=16, roles=roles)
 
@@ -509,8 +499,7 @@ def main(argv=None):
         return ContinuousBatchingEngine(
             model, max_batch_size=2,
             max_seq_len=min(256, cfg.max_position_embeddings),
-            enable_prefix_caching=True,
-            attention_impl=args.attention_impl)
+            enable_prefix_caching=True)
 
     dur_kwargs = dict(num_replicas=args.replicas,
                       policy="prefix_affinity", page_size=16)
@@ -580,8 +569,7 @@ def main(argv=None):
             return ServingRouter(
                 lambda i: ContinuousBatchingEngine(
                     model, max_batch_size=3,
-                    max_seq_len=min(256, cfg.max_position_embeddings),
-                    attention_impl=args.attention_impl),
+                    max_seq_len=min(256, cfg.max_position_embeddings)),
                 num_replicas=args.replicas, policy="round_robin",
                 page_size=16,
                 sentry=SentryConfig(scan_every=8) if sentried

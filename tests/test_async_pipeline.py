@@ -141,9 +141,6 @@ class TestEnginePipeline:
         with pytest.raises(ValueError, match="spec_decode"):
             _engine(model, 4,
                     spec_decode=SpecConfig(draft_model=model, k=2))
-        with pytest.raises(ValueError, match="ragged"):
-            _engine(model, 4, kv_layout="dense",
-                    attention_impl="legacy")
 
     def test_quiesce_drains_the_window(self, model):
         eng = _engine(model, 4)

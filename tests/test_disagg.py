@@ -162,13 +162,6 @@ class TestTransferPlane:
         src2.step()
         with pytest.raises(ValueError, match="no resident request"):
             src2.export_pages(waiting)
-        dense = ContinuousBatchingEngine(model, max_batch_size=1,
-                                         max_seq_len=64,
-                                         kv_layout="dense")
-        r = dense.add_request(*JOBS[0])
-        dense.step()
-        with pytest.raises(ValueError, match="paged"):
-            dense.export_pages(r)
 
     def test_import_validations_and_capacity(self, model):
         src = _engine(model)

@@ -17,8 +17,7 @@ import paddle_tpu as paddle
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.models.serving import (ContinuousBatchingEngine,
                                        QuantServingConfig)
-from paddle_tpu.ops.paged_attention import (paged_append_values,
-                                            paged_prefill_scatter)
+from paddle_tpu.ops.paged_attention import paged_append_values
 from paddle_tpu.ops.ragged_paged_attention import (
     KV_QMAX, pack_ragged_starts, pages_to_payload, payload_to_pages,
     ragged_paged_attention_values, ragged_scatter_quantized,
@@ -119,9 +118,9 @@ class TestRowWrite:
             np.testing.assert_array_equal(kp[tgt], want)
             np.testing.assert_allclose(ks[tgt], amax / KV_QMAX, rtol=1e-6)
 
-    def test_decode_append_and_prefill_scatter_write_whole_rows(self):
-        """The non-ragged path's two writes (`paged_append_values`,
-        `paged_prefill_scatter`) are the same row scatter."""
+    def test_decode_append_writes_whole_rows(self):
+        """The q = 1 op's write (`paged_append_values`) is the same row
+        scatter."""
         rng = np.random.default_rng(3)
         kp0 = jnp.zeros((PAGES, PS, HK * D), jnp.float32)
         k, v = _rows(rng, 2), _rows(rng, 2)
@@ -131,16 +130,6 @@ class TestRowWrite:
         np.testing.assert_array_equal(np.asarray(kp)[2, 1], k[0].reshape(-1))
         np.testing.assert_array_equal(np.asarray(vp)[4, 2], v[1].reshape(-1))
         assert np.count_nonzero(np.asarray(kp)) == 2 * HK * D
-        rows = _rows(rng, 8)
-        kp, _ = paged_prefill_scatter(
-            kp0, kp0, jnp.asarray(rows), jnp.asarray(rows),
-            jnp.asarray(BT[0]), 6)
-        got = pages_to_payload(np.asarray(kp), HK)       # (HK, P, PS, D)
-        for p in range(6):
-            np.testing.assert_array_equal(
-                got[:, BT[0, p // PS], p % PS], rows[p])
-        # rows past true_len went to the trash page, not to page 2
-        assert not np.asarray(kp)[2, 2:].any()
 
 
 class TestKernelAtServingWidths:

@@ -381,8 +381,6 @@ def _refused(**kw):
     ("quant.kv", dict(quant=QuantServingConfig(kv="int8"))),
     ("quant.weights", dict(quant=QuantServingConfig(weights="int8"))),
     ("harvest_every > 1", dict(harvest_every=2)),
-    ("kv_layout='dense'", dict(kv_layout="dense")),
-    ("attention_impl='legacy'", dict(attention_impl="legacy")),
     ("submesh tp > 1", dict(submesh=types.SimpleNamespace(tp=2))),
 ])
 def test_unsupported_features_refuse_by_name(name, kw):

@@ -1,6 +1,6 @@
 """Performance attribution plane (ISSUE 20,
 paddle_tpu/observability/profile.py): the fleet step's span tree and
-its self times (ISSUE 25), the dispatch-gap sampler, compile-cache
+its self times (ISSUE 25), compile-cache
 observability behind the `_jit_lru`/`_jit_singleton` seam, the memory
 ledger, histogram exemplars, and the `paddle-tpu-obs profile` CLI.
 
@@ -96,14 +96,9 @@ class TestDisabledNoOp:
             telemetry.disable(clear_override=True)  # back to env-driven
         for section in ("counters", "gauges", "histograms"):
             assert not any(
-                n.startswith(("pdt_span_", "pdt_profile_", "pdt_jit_",
-                              "pdt_mem_"))
+                n.startswith(("pdt_span_", "pdt_jit_", "pdt_mem_"))
                 for n in snap.get(section, {})), snap[section]
         assert telemetry.events() == []
-
-    def test_fence_is_identity_when_unarmed(self):
-        x = object()
-        assert profile.fence("qkv", x) is x
 
 
 # -- the fleet step's span tree ----------------------------------------
@@ -304,58 +299,6 @@ class TestStepTree:
                             modules["ragged"]), modules
 
 
-# -- dispatch-gap sampler ----------------------------------------------
-class TestGapSampler:
-    def test_profile_round_table_and_determinism(self, model):
-        """The sampled round is observation-only: interleaving
-        profile_round() between steps must leave the greedy streams
-        bit-identical to an undisturbed engine."""
-        plain = _engine(model)
-        sampled = _engine(model)
-        for eng in (plain, sampled):
-            for p, n in JOBS:
-                eng.add_request(list(p), n)
-            for _ in range(3):
-                eng.step()
-        tables = []
-        for i in range(6):
-            plain.step()
-            sampled.step()
-            if i % 2 == 0:
-                tables.append(sampled.profile_round())
-        out_p = {r.request_id: list(r.output)
-                 for r in plain._slot_req if r is not None}
-        out_s = {r.request_id: list(r.output)
-                 for r in sampled._slot_req if r is not None}
-        assert out_p == out_s
-        # ranked table over the fenced op families of llama.py
-        table = tables[-1]
-        assert table, "sampled round produced no gap rows"
-        pairs = [row["op_pair"] for row in table]
-        gaps = [row["gap_s"] for row in table]
-        assert gaps == sorted(gaps, reverse=True)
-        fenced = {p for pair in pairs for p in pair.split("->")}
-        assert fenced <= {"embed", "rmsnorm", "qkv", "rope",
-                          "kv_scatter", "attention", "oproj", "mlp"}
-        assert "qkv" in fenced and "attention" in fenced
-        # and the ranked gauges are published
-        gs = telemetry.snapshot()["gauges"].get(
-            "pdt_profile_gap_seconds", {})
-        assert len(gs) == len(table)
-
-    def test_profile_round_requires_ragged_paged(self, model):
-        eng = _engine(model, attention_impl="legacy")
-        eng.add_request([1, 2], 8)
-        eng.step()
-        with pytest.raises(RuntimeError, match="paged\\+ragged"):
-            eng.profile_round()
-
-    def test_profile_round_requires_active_slot(self, model):
-        eng = _engine(model)
-        with pytest.raises(RuntimeError, match="active slot"):
-            eng.profile_round()
-
-
 # -- compile-cache observability ---------------------------------------
 class TestCompileObservability:
     def test_fifty_warm_pipelined_rounds_zero_compiles(self, model):
@@ -498,7 +441,6 @@ class TestReportAndCli:
         eng = _warm_engine(model)
         for _ in range(4):
             eng.step()
-        eng.profile_round()
         profile.memory_ledger([eng])
         path = tmp_path / "snap.json"
         path.write_text(json.dumps(telemetry.snapshot()))
@@ -513,13 +455,8 @@ class TestReportAndCli:
                 if ln.startswith("  serving.")]
         assert {"serving.step", "serving.admit", "serving.decode",
                 "serving.decode_step", "serving.commit"} <= set(rows)
-        assert "top dispatch gaps" in out
         assert "compile cache" in out
         assert "memory ledger" in out
-        # ranked: first gap row is the largest
-        gap_lines = [ln for ln in out.splitlines()
-                     if "->" in ln]
-        assert gap_lines, out
 
     def test_cli_prom_text_input(self, model, tmp_path, capsys):
         json_path = self._fleet_snapshot(model, tmp_path)

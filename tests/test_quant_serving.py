@@ -368,12 +368,6 @@ class TestQuantConfig:
         with pytest.raises(ValueError, match="neither"):
             QuantServingConfig()
 
-    def test_requires_paged_ragged(self, model):
-        with pytest.raises(ValueError, match="paged"):
-            _engine(model, kv_layout="dense")
-        with pytest.raises(ValueError, match="ragged"):
-            _engine(model, attention_impl="legacy")
-
 
 class TestQuantEngine:
     def test_deterministic_and_all_modes_serve(self, model, jobs,

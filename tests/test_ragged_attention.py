@@ -4,12 +4,14 @@ against an independent NumPy oracle — mixed batches, prefix-shared
 pages at nonzero position offsets, sliding windows, GQA group sizes,
 and empty/degenerate sequences — plus the scatter/packing helpers, the
 bounded-gather static trim, and the ENGINE-level contract: greedy
-streams bit-identical between `attention_impl="ragged"` and `"legacy"`
+streams bit-identical to `model.generate()`'s, request by request,
 through a forced preemption and a SIGKILL replica failover.
 
 conftest runs this file with PDT_TELEMETRY=1 and
 PDT_CHECK_INVARIANTS=1, so every engine step here re-proves page
 accounting on the ragged path."""
+import contextlib
+
 import numpy as np
 import pytest
 import jax
@@ -515,15 +517,27 @@ def _drain(eng):
     return reqs
 
 
+def _generate(model, jobs=JOBS):
+    """The oracle outside the engine: each request alone through
+    `generate()`'s dense-tuple cache (models/generation.py)."""
+    outs = []
+    for prompt, n in jobs:
+        toks, _ = model.generate(
+            paddle.to_tensor(np.asarray(prompt, np.int32)[None]),
+            max_new_tokens=n, decode_strategy="greedy_search")
+        outs.append([int(x) for x in np.asarray(toks._value).ravel()[:n]])
+    return outs
+
+
 class TestRaggedEngineParity:
-    """The ISSUE 6 acceptance contract: `attention_impl="ragged"` and
-    `"legacy"` produce IDENTICAL greedy streams — in the clean run,
+    """The ISSUE 6 acceptance contract: the engine's greedy streams
+    are IDENTICAL to `generate()`'s per request — in the clean run,
     through a forced preemption, and through a SIGKILL replica
     failover (the PR-4/5 chaos drills as the kernel's regression
     harness)."""
 
-    def _run(self, model, impl, jobs=JOBS, fault=None, **kw):
-        eng = _engine(model, attention_impl=impl, **kw)
+    def _run(self, model, jobs=JOBS, fault=None, **kw):
+        eng = _engine(model, **kw)
         rids = [eng.add_request(p, n) for p, n in jobs]
         if fault is None:
             reqs = _drain(eng)
@@ -534,34 +548,27 @@ class TestRaggedEngineParity:
         return eng, rids, reqs
 
     def test_streams_identical_clean(self, model):
-        outs = {}
-        for impl in ("legacy", "ragged"):
-            _, rids, reqs = self._run(model, impl)
-            outs[impl] = [reqs[r].output for r in rids]
-            assert all(reqs[r].status == RequestStatus.FINISHED
-                       for r in rids)
-        assert outs["ragged"] == outs["legacy"]
+        _, rids, reqs = self._run(model)
+        assert all(reqs[r].status == RequestStatus.FINISHED
+                   for r in rids)
+        assert [reqs[r].output for r in rids] == _generate(model)
 
     def test_streams_identical_through_preemption(self, model):
         """Forced pool exhaustion mid-decode: the victim requeues and
-        re-prefills through the ragged path — final streams equal the
-        legacy run under the SAME fault."""
-        outs = {}
-        for impl in ("legacy", "ragged"):
-            eng, rids, reqs = self._run(
-                model, impl, jobs=JOBS[:2],
-                fault=("serving.alloc_page",
-                       dict(nth=4, exc=PoolExhausted)))
-            assert eng.num_preemptions == 1, impl
-            assert all(reqs[r].status == RequestStatus.FINISHED
-                       for r in rids), impl
-            outs[impl] = [reqs[r].output for r in rids]
-        assert outs["ragged"] == outs["legacy"]
+        re-prefills its prompt and what it had generated — final
+        streams equal the oracle's, which saw no fault."""
+        eng, rids, reqs = self._run(
+            model, jobs=JOBS[:2],
+            fault=("serving.alloc_page",
+                   dict(nth=4, exc=PoolExhausted)))
+        assert eng.num_preemptions == 1
+        assert all(reqs[r].status == RequestStatus.FINISHED
+                   for r in rids)
+        assert [reqs[r].output for r in rids] == _generate(model, JOBS[:2])
 
     def test_streams_identical_through_sigkill_failover(self, model):
         """A replica SIGKILL mid-decode with zero-loss failover: fleet
-        outputs are identical between the two impls (and equal the
-        single-engine reference)."""
+        outputs equal the oracle's."""
         from paddle_tpu.serving import ServingRouter
 
         class Clock:
@@ -574,30 +581,24 @@ class TestRaggedEngineParity:
             def __call__(self):
                 return self.t
 
-        outs = {}
-        for impl in ("legacy", "ragged"):
-            clock = Clock()
-            router = ServingRouter(
-                lambda i: _engine(model, attention_impl=impl,
-                                  clock=clock),
-                num_replicas=3, policy="round_robin", clock=clock,
-                sleep=clock.advance, page_size=4)
-            ids = [router.submit(p, n) for p, n in JOBS]
-            router.step()
-            router.step()                            # mid-decode
-            router.kill_replica(1)
-            out = router.run()
-            assert router.num_failovers == 1, impl
-            outs[impl] = [out[i] for i in ids]
-        assert outs["ragged"] == outs["legacy"]
-        _, rids, reqs = self._run(model, "ragged")
-        assert outs["ragged"] == [reqs[r].output for r in rids]
+        clock = Clock()
+        router = ServingRouter(
+            lambda i: _engine(model, clock=clock),
+            num_replicas=3, policy="round_robin", clock=clock,
+            sleep=clock.advance, page_size=4)
+        ids = [router.submit(p, n) for p, n in JOBS]
+        router.step()
+        router.step()                            # mid-decode
+        router.kill_replica(1)
+        out = router.run()
+        assert router.num_failovers == 1
+        assert [out[i] for i in ids] == _generate(model)
 
     def test_one_dispatch_per_admission_round(self, model):
         """Admitting N ragged prompts costs ONE dispatch: the first
         step's admission produces a single serving.ragged_prefill span
-        carrying every admitted request_id, and no legacy per-bucket
-        prefill/suffix/chunk programs are ever minted."""
+        carrying every admitted request_id, and the only programs
+        compiled are that admission's and the decode step."""
         eng = _engine(model, max_batch_size=3)
         rids = [eng.add_request(p, n) for p, n in JOBS]
         eng.step()
@@ -607,9 +608,10 @@ class TestRaggedEngineParity:
         batch_rids = set(spans[0]["attrs"]["rids"])
         assert batch_rids == {str(r) for r in rids}
         eng.run()
-        assert len(eng._prefill_jits) == 0
-        assert len(eng._suffix_jits) == 0
-        assert len(eng._ragged_jits) >= 1
+        assert len(eng._ragged_jits) == 1
+        compiled = telemetry.snapshot()["counters"][
+            "pdt_jit_compiles_total"]
+        assert set(compiled) == {'family="ragged"', 'family="decode"'}
 
     def test_prefix_cache_rides_ragged_admission(self, model):
         """A prefix-cache hit admits through the packed suffix path:
@@ -632,9 +634,8 @@ class TestRaggedEngineParity:
         spills into chunk-continuation pieces, and the stream equals
         the unchunked engine's."""
         prompt = list(np.arange(1, 30) % 60 + 1)
-        ref_eng, _, ref_reqs = self._run(model, "ragged",
-                                         jobs=[(prompt, 6)])
-        eng = _engine(model, attention_impl="ragged", prefill_chunk=8)
+        ref_eng, _, ref_reqs = self._run(model, jobs=[(prompt, 6)])
+        eng = _engine(model, prefill_chunk=8)
         rid = eng.add_request(prompt, 6)
         reqs = _drain(eng)
         assert reqs[rid].output == list(ref_reqs.values())[0].output
@@ -658,13 +659,19 @@ class TestRaggedEngineParity:
         assert bound == 1                            # ceil(3/4) -> pow2
         assert bound < eng.pps
 
-    def test_attention_impl_validation_and_dense_fallback(self, model):
-        with pytest.raises(ValueError, match="attention_impl"):
-            _engine(model, attention_impl="fused")
-        eng = _engine(model, kv_layout="dense", attention_impl="ragged")
-        assert eng.attn_impl == "legacy"   # dense has no page table
-        eng2 = _engine(model)
-        assert eng2.attn_impl == "ragged"  # the default
+    @pytest.mark.parametrize("kw,takes", [
+        (dict(kv_layout="dense"), False),
+        (dict(attention_impl="legacy"), False),
+        (dict(attention_impl="fused"), False),
+        (dict(kv_layout="paged", attention_impl="ragged"), True)],
+        ids=["dense", "legacy", "fused", "paged+ragged"])
+    def test_layout_and_attention_keywords_are_checked(self, model, kw,
+                                                       takes):
+        """The two keywords the benchmark's configurations still pass
+        take one value each; anything else is refused by name."""
+        with (contextlib.nullcontext() if takes else
+              pytest.raises(ValueError, match="removed in PR 29")):
+            _engine(model, **kw)
 
     def test_sampling_seeded_reproducible_on_ragged(self, model):
         def run(seed, **kw):

@@ -338,16 +338,75 @@ class TestGPTGenerate:
         assert np.isfinite(float(score[0]))
 
 
-@pytest.mark.slow
+_SYS = list(range(3, 19))                 # two full pages of 8
+
+# The engine's modes, each held to the same oracle. A mode changes how
+# the cache is filled and walked, never the stream. Per mode: engine
+# keywords, (prompt, max_new_tokens) jobs and, where the engine shows
+# it, that the mode was taken ("holds"); "window" builds a
+# sliding-window model, "eos_at" declares the first request's token at
+# that index as EOS.
+_ENGINE_MODES = {
+    "small_pages": dict(
+        engine=dict(max_batch_size=2, page_size=4),
+        jobs=[(list(range(5, 5 + n)), k)
+              for n, k in ((3, 6), (13, 8), (7, 5), (10, 7))]),
+    "sliding_window": dict(
+        window=16,
+        engine=dict(max_batch_size=2, page_size=8),
+        jobs=[(list(range(2, 11 + 4 * j)), 30) for j in range(3)]),
+    "sliding_window_chunked": dict(
+        window=16,
+        engine=dict(max_batch_size=1, page_size=8, prompt_pad=8,
+                    prefill_chunk=8),
+        jobs=[(list(range(2, 29)), 12)],
+        holds=lambda eng: all(t <= 8 for t, _ in eng._ragged_jits)),
+    "prefix_hit": dict(
+        engine=dict(max_batch_size=1, page_size=8,
+                    enable_prefix_caching=True),
+        jobs=[(_SYS + t, 6) for t in ([70, 80, 90], [100, 101])],
+        holds=lambda eng: (eng.prefix_hits, eng.prefix_tokens_reused)
+        == (1, 16)),
+    "prefix_hit_chunked": dict(
+        engine=dict(max_batch_size=1, page_size=8, prompt_pad=8,
+                    prefill_chunk=8, enable_prefix_caching=True),
+        jobs=[(_SYS + t, 5) for t in ([70], list(range(100, 112)))],
+        holds=lambda eng: eng.prefix_hits == 1
+        and all(t <= 8 for t, _ in eng._ragged_jits)),
+    "long_prompt_chunked": dict(
+        engine=dict(max_batch_size=2, page_size=8, prompt_pad=8,
+                    prefill_chunk=16),
+        jobs=[(list(range(1, 1 + n)), 6) for n in (5, 16, 23, 40)],
+        # + 8: a second piece of a batch starts on the next row block
+        holds=lambda eng: all(t <= 24 for t, _ in eng._ragged_jits)),
+    "harvest_every_4": dict(
+        engine=dict(max_batch_size=2, harvest_every=4),
+        jobs=[([5, 42, 7], 6), ([9, 1, 2, 3, 4], 9), ([11, 13], 5)],
+        holds=lambda eng: not eng._pending),
+    "harvest_every_4_eos": dict(
+        eos_at=2,
+        engine=dict(max_batch_size=2, harvest_every=4),
+        jobs=[([5, 42, 7], 9), ([9, 1, 2, 3, 4], 9)],
+        holds=lambda eng: not eng._pending),
+    "one_admission_program": dict(
+        engine=dict(max_batch_size=1, prompt_pad=4,
+                    max_prefill_programs=1),
+        jobs=[(list(range(1, 1 + n)), 3) for n in (3, 7, 3, 11)],
+        holds=lambda eng: len(eng._ragged_jits) == 1),
+}
+
+
 class TestContinuousBatching:
     """In-flight batching (VERDICT r3 next #3): slots at different
     positions decode in ONE compiled step; admission reuses freed slots.
-    Oracle: per-request generate() greedy outputs."""
+    Oracle: per-request generate() greedy outputs — the engine's parity
+    oracle, so tier-1 runs this class."""
 
-    def _model(self):
+    def _model(self, window=None):
         from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
         paddle.seed(7)
         cfg = LlamaConfig.tiny()
+        cfg.sliding_window = window
         m = LlamaForCausalLM(cfg)
         m.eval()
         return m, cfg
@@ -376,6 +435,25 @@ class TestContinuousBatching:
         for rid, p, n in zip(rids, prompts, lens):
             ref = self._ref_greedy(m, p, n)
             assert results[rid] == ref, (rid, results[rid], ref)
+
+    @pytest.mark.parametrize("mode", list(_ENGINE_MODES))
+    def test_mode_matches_per_request_greedy(self, mode):
+        from paddle_tpu.models.serving import ContinuousBatchingEngine
+        spec = _ENGINE_MODES[mode]
+        m, cfg = self._model(window=spec.get("window"))
+        refs = [self._ref_greedy(m, p, n) for p, n in spec["jobs"]]
+        eos = None
+        if "eos_at" in spec:
+            eos = refs[0][spec["eos_at"]]
+            refs = [r[:r.index(eos) + 1] if eos in r else r
+                    for r in refs]
+            assert len(refs[0]) < spec["jobs"][0][1]    # it does cut
+        eng = ContinuousBatchingEngine(m, max_seq_len=64,
+                                       eos_token_id=eos, **spec["engine"])
+        rids = [eng.add_request(p, n) for p, n in spec["jobs"]]
+        results = eng.run()
+        assert [results[r] for r in rids] == refs
+        assert spec.get("holds", bool)(eng)
 
     def test_mid_flight_admission(self):
         """A request added while others are mid-decode joins without
@@ -455,26 +533,6 @@ class TestPagedEngine:
         m.eval()
         return m, cfg
 
-    def test_paged_matches_dense_engine(self):
-        """The paged engine's outputs equal the dense engine's (same
-        model, same prompts) — the engine-level paged == dense oracle."""
-        from paddle_tpu.models.serving import ContinuousBatchingEngine
-        m, cfg = self._model()
-        rng_ = np.random.default_rng(5)
-        prompts = [list(rng_.integers(1, cfg.vocab_size,
-                                      rng_.integers(3, 14)))
-                   for _ in range(4)]
-        lens = [6, 8, 5, 7]
-        outs = {}
-        for layout in ("paged", "dense"):
-            eng = ContinuousBatchingEngine(m, max_batch_size=2,
-                                           max_seq_len=64,
-                                           kv_layout=layout)
-            rids = [eng.add_request(p, n) for p, n in zip(prompts, lens)]
-            res = eng.run()
-            outs[layout] = [res[r] for r in rids]
-        assert outs["paged"] == outs["dense"]
-
     def test_memory_occupancy_proportional(self):
         """bytes_in_use tracks pages actually allocated, not B*S_max;
         finished requests return their pages."""
@@ -544,32 +602,6 @@ class TestPagedEngine:
         assert tiny_p == greedy
         assert len(s3) == 8
 
-    def test_sliding_window_paged_matches_dense(self):
-        """r5: sliding-window models serve on the PAGED layout (window
-        band in the paged kernel) — outputs equal the dense-layout
-        oracle, and pages that slide out of the window are reclaimed so
-        resident KV is bounded by the window, not the sequence."""
-        from paddle_tpu.models.serving import ContinuousBatchingEngine
-        from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
-        cfg = LlamaConfig.tiny()
-        cfg.sliding_window = 16
-        paddle.seed(0)
-        m = LlamaForCausalLM(cfg)
-        m.eval()
-        rng_ = np.random.default_rng(4)
-        prompts = [list(rng_.integers(1, cfg.vocab_size, 9 + 4 * j))
-                   for j in range(3)]
-        outs = {}
-        for layout in ("dense", "paged"):
-            eng = ContinuousBatchingEngine(m, max_batch_size=2,
-                                           max_seq_len=64, page_size=8,
-                                           kv_layout=layout)
-            rids = [eng.add_request(p, 30) for p in prompts]
-            res = eng.run()
-            outs[layout] = [res[r] for r in rids]
-        assert outs["paged"] == outs["dense"]
-        assert eng.layout == "paged"
-
     def test_sliding_window_reclaims_pages(self):
         from paddle_tpu.models.serving import ContinuousBatchingEngine
         from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
@@ -600,7 +632,7 @@ class TestPagedEngine:
         for n_len in (3, 7, 11, 15):
             eng.add_request(list(range(1, n_len + 1)), 2)
         eng.run()
-        assert len(eng._prefill_jits) <= 2
+        assert len(eng._ragged_jits) <= 2
 
 
 class TestPrefixCaching:
@@ -726,17 +758,6 @@ class TestPrefixCaching:
         rc = eng._page_rc
         assert all(rc[p] == 0 for p in eng._free)
         assert len(set(eng._free)) == len(eng._free)   # no double-free
-
-    def test_dense_layout_warns_and_disables(self):
-        m, cfg = self._model()
-        from paddle_tpu.models.serving import ContinuousBatchingEngine
-        with pytest.warns(UserWarning, match="prefix caching is DISABLED"):
-            eng = ContinuousBatchingEngine(m, max_batch_size=1,
-                                           kv_layout="dense",
-                                           max_seq_len=96,
-                                           enable_prefix_caching=True)
-        rid = eng.add_request([5, 4, 3], 4)
-        assert len(eng.run()[rid]) == 4 and eng.prefix_hits == 0
 
 
 class TestBeamSearch:
@@ -1061,9 +1082,9 @@ class TestNoRepeatNgram:
 
 
 class TestChunkedPrefill:
-    """Chunked prefill (≙ vLLM chunked prefill): long prompts run
-    through ONE fixed-size chunk program with traced offsets instead of
-    minting per-bucket programs. Oracle: the default bucketed engine."""
+    """Chunked prefill (≙ vLLM chunked prefill): an admission dispatch
+    packs at most `prefill_chunk` tokens, a longer prompt continues in
+    the next. Oracle: the same engine without a chunk bound."""
 
     def _model(self):
         cfg = LlamaConfig(vocab_size=256, hidden_size=64,
@@ -1075,11 +1096,11 @@ class TestChunkedPrefill:
         m.eval()
         return cfg, m
 
-    def test_matches_bucketed_engine(self):
+    def test_matches_unchunked_engine(self):
         from paddle_tpu.models.serving import ContinuousBatchingEngine
         cfg, m = self._model()
         rng = np.random.default_rng(7)
-        # short (bucket path), exact multiple, ragged, long
+        # short, exact multiple, ragged, long
         prompts = [list(rng.integers(1, cfg.vocab_size, p))
                    for p in (5, 16, 23, 40)]
         outs = {}
@@ -1091,9 +1112,9 @@ class TestChunkedPrefill:
             res = eng.run()
             outs[chunk] = [res[r] for r in rids]
             if chunk:
-                # long prompts minted no per-bucket programs: only the
-                # short prompt (5 <= chunk) used the bucket path
-                assert len(eng._prefill_jits) <= 1
+                # no admission program is as wide as the long prompts
+                # (+ 8: a second piece starts on the next row block)
+                assert all(t <= chunk + 8 for t, _ in eng._ragged_jits)
         assert outs[16] == outs[None]
 
     def test_chunk_must_align_to_pages(self):

@@ -101,14 +101,6 @@ def plain(model):
 
 
 class TestSpecConfigValidation:
-    def test_requires_paged_ragged(self, model, draft):
-        with pytest.raises(ValueError, match="ragged"):
-            _engine(model, kv_layout="dense",
-                    spec_decode=SpecConfig(draft))
-        with pytest.raises(ValueError, match="ragged"):
-            _engine(model, attention_impl="legacy",
-                    spec_decode=SpecConfig(draft))
-
     def test_greedy_only(self, model, draft):
         with pytest.raises(ValueError, match="greedy"):
             _engine(model, do_sample=True, temperature=0.8,

@@ -93,13 +93,6 @@ class TestCarving:
         with pytest.raises(ValueError, match="num_key_value_heads"):
             _tp_engine(model, sm)
 
-    def test_engine_requires_paged_ragged(self, model):
-        sm = carve_submeshes(1, TpConfig(tp=2))[0]
-        with pytest.raises(ValueError, match="kv_layout='paged'"):
-            _tp_engine(model, sm, kv_layout="dense")
-        with pytest.raises(ValueError, match="ragged"):
-            _tp_engine(model, sm, attention_impl="legacy")
-
 
 # -- engine-level parity + sharded allocator ---------------------------
 class TestTpEngine:

@@ -555,17 +555,18 @@ class TestRaggedEPCompile:
 
 
 class TestPagedEngineDecodeCompile:
-    """Round-5: the serving engine's paged decode path on the chip.
+    """The serving engine's decode path on the chip.
 
     Chip-gate r5 finding: asserting exact greedy-token equality between
-    the paged and dense ENGINES is unsound on silicon — the Pallas paged
-    kernel and the XLA dense attention are both correct but accumulate in
-    different orders (measured max |Δ| = one bf16 ulp), and greedy argmax
-    amplifies a near-tie into a different trajectory after ~10 tokens
-    (interpret mode can't see this: both layouts run the same XLA math
+    the engine and the dense-tuple reference is unsound on silicon — the
+    Pallas kernel and the XLA dense attention are both correct but
+    accumulate in different orders (measured max |Δ| = one bf16 ulp), and
+    greedy argmax amplifies a near-tie into a different trajectory after
+    ~10 tokens (interpret mode can't see this: both run the same XLA math
     there). So the chip test asserts (a) single-step LOGIT parity between
-    a paged and a dense decode step on identical cache state, and (b)
-    both engines run end-to-end producing well-formed outputs."""
+    a ragged decode step through the page table and `generate()`'s
+    dense-tuple cache on identical cache state, and (b) the engine runs
+    end-to-end producing well-formed outputs."""
 
     def _tiny(self):
         import paddle_tpu as paddle
@@ -580,11 +581,11 @@ class TestPagedEngineDecodeCompile:
         m.eval()
         return cfg, m
 
-    def test_paged_decode_step_logits_match_dense_on_chip(self):
-        import paddle_tpu as paddle
+    def test_ragged_decode_step_logits_match_dense_on_chip(self):
         from paddle_tpu.core.tensor import Tensor, no_grad
-        from paddle_tpu.models.llama import PagedKVCacheView
-        from paddle_tpu.ops.paged_attention import paged_prefill_scatter
+        from paddle_tpu.models.llama import RaggedKVCacheView
+        from paddle_tpu.ops.ragged_paged_attention import \
+            ragged_scatter_values
 
         cfg, m = self._tiny()
         hk, hd = cfg.num_key_value_heads, cfg.head_dim
@@ -596,10 +597,15 @@ class TestPagedEngineDecodeCompile:
         ids = np.zeros((b, p_max), np.int64)
         for i, pl_ in enumerate(p_lens):
             ids[i, :pl_] = rng.integers(1, cfg.vocab_size, pl_)
+        # the prompts' rows as one packed axis: owner and position
+        seq = jnp.asarray(np.repeat(np.arange(b), p_lens), jnp.int32)
+        pos = jnp.asarray(np.concatenate([np.arange(n) for n in p_lens]),
+                          jnp.int32)
+        bt = jnp.arange(1, 1 + b * pps, dtype=jnp.int32).reshape(b, pps)
 
         with no_grad():
-            # dense prefill (zero caches + validity mask, the engine's
-            # own prefill contract) -> per-layer (B, S, HK, D) caches
+            # dense prefill (zero caches + validity mask) -> per-layer
+            # (B, S, HK, D) caches
             zero = [(Tensor(jnp.zeros((b, p_max, hk, hd), jnp.float32)),
                      Tensor(jnp.zeros((b, p_max, hk, hd), jnp.float32)))
                     for _ in range(cfg.num_hidden_layers)]
@@ -608,8 +614,10 @@ class TestPagedEngineDecodeCompile:
                                   attention_mask=Tensor(am),
                                   past_key_values=zero,
                                   position_offset=0, use_cache=True)
-            dense, paged = [], []
+            dense, views = [], []
             n_pages = 1 + b * pps              # page 0 = trash page
+            one = jnp.arange(b, dtype=jnp.int32)
+            ctx = jnp.asarray(p_lens, jnp.int32)
             for (k, v) in caches:
                 kd = jnp.zeros((b, s_max, hk, hd), k._value.dtype)
                 vd = jnp.zeros_like(kd)
@@ -618,47 +626,42 @@ class TestPagedEngineDecodeCompile:
                 dense.append((Tensor(kd), Tensor(vd)))
                 kp = jnp.zeros((n_pages, page_size, hk * hd),
                                k._value.dtype)
-                vp = jnp.zeros_like(kp)
-                for i in range(b):
-                    bt_row = jnp.arange(1 + i * pps, 1 + (i + 1) * pps)
-                    kp, vp = paged_prefill_scatter(
-                        kp, vp, k._value[i, :p_lens[i]].astype(kp.dtype),
-                        v._value[i, :p_lens[i]].astype(kp.dtype),
-                        bt_row, p_lens[i])
-                paged.append((kp, vp))
-            bt = jnp.arange(1, 1 + b * pps, dtype=jnp.int32).reshape(
-                b, pps)
+                kp, vp = ragged_scatter_values(
+                    kp, jnp.zeros_like(kp), k._value[seq, pos],
+                    v._value[seq, pos], bt, seq, pos)
+                # the decode descriptors: one query a sequence, at its
+                # next position
+                views.append(RaggedKVCacheView(
+                    kp, vp, bt, one, ctx, one, jnp.ones_like(one),
+                    ctx + 1, block_q=1))
             tok = jnp.asarray([[7], [11]], jnp.int64)
-            pos = jnp.asarray(p_lens, jnp.int32)
 
             lg_dense, _ = m.forward(Tensor(tok), past_key_values=dense,
-                                    position_offset=Tensor(pos),
+                                    position_offset=Tensor(ctx),
                                     use_cache=True)
-            pkv = [PagedKVCacheView(kp, vp, bt) for kp, vp in paged]
-            lg_paged, _ = m.forward(Tensor(tok), past_key_values=pkv,
-                                    position_offset=Tensor(pos),
-                                    use_cache=True)
+            lg_ragged, _ = m.forward(Tensor(tok.reshape(1, b)),
+                                     past_key_values=views,
+                                     use_cache=True)
         np.testing.assert_allclose(
-            np.asarray(lg_paged._value, np.float32),
-            np.asarray(lg_dense._value, np.float32), rtol=2e-2, atol=2e-2)
+            np.asarray(lg_ragged._value, np.float32)[0],
+            np.asarray(lg_dense._value, np.float32)[:, 0],
+            rtol=2e-2, atol=2e-2)
 
-    def test_both_engine_layouts_run_on_chip(self):
+    def test_engine_runs_on_chip(self):
         from paddle_tpu.models.serving import ContinuousBatchingEngine
 
         cfg, m = self._tiny()
         rng = np.random.default_rng(1)
         prompts = [list(rng.integers(1, cfg.vocab_size, 12 + 5 * j))
                    for j in range(3)]
-        for layout in ("paged", "dense"):
-            eng = ContinuousBatchingEngine(m, max_batch_size=2,
-                                           max_seq_len=256,
-                                           kv_layout=layout)
-            rids = [eng.add_request(p, 16) for p in prompts]
-            res = eng.run()
-            assert sorted(res) == sorted(rids)
-            for r in rids:
-                assert len(res[r]) == 16
-                assert all(0 <= t < cfg.vocab_size for t in res[r])
+        eng = ContinuousBatchingEngine(m, max_batch_size=2,
+                                       max_seq_len=256)
+        rids = [eng.add_request(p, 16) for p in prompts]
+        res = eng.run()
+        assert sorted(res) == sorted(rids)
+        for r in rids:
+            assert len(res[r]) == 16
+            assert all(0 <= t < cfg.vocab_size for t in res[r])
 
     def test_speculative_decode_on_chip(self):
         """Draft-propose + one-forward verify (vector-offset rope, s>1
@@ -686,8 +689,9 @@ class TestPagedEngineDecodeCompile:
                                       np.asarray(want._value))
 
     def test_prefix_caching_suffix_prefill_on_chip(self):
-        """The prefix-hit admission path (page gather + chunked suffix
-        prefill + rebased scatter) must compile and run on silicon."""
+        """The prefix-hit admission (the suffix's rows attend the
+        attached pages at their own positions) must compile and run on
+        silicon."""
         from paddle_tpu.models.serving import ContinuousBatchingEngine
 
         cfg, m = self._tiny()
