@@ -46,9 +46,11 @@ frontier. A trip starts the DMAs of the next block's pages (one
 descriptor a page, a contiguous ``(page_size, HK*D)`` block with every
 head in it, into the other half of a two-slot VMEM buffer) and waits
 for its own — the double buffering the BlockSpec pipeline used to do a
-4 KB page at a time — then per head multiplies ``(block_q*G, D) x
+4 KB page at a time — then multiplies every head's ``(block_q*G, D) x
 (block keys, D)^T`` (the head's lane slice of the block), masks by
-position and folds the block into the online softmax. The trip count is
+position, folds the block into every head's online softmax and
+multiplies every head's ``p @ V``: three passes over the heads, the
+products of one kind together (below). The trip count is
 dynamic: a padding q block, a sequence with no query and an idle slot
 do none and write zeros; no column past the frontier is visited, so the
 width of the block table costs nothing and no bound on it shapes the
@@ -59,6 +61,25 @@ by position; their table entries are never read. `kv_block_pages`
 sizes the block from the static shapes against a fixed VMEM budget;
 `live_kv_blocks` is the loop's bounds, shared with the engine's
 `pdt_serving_attn_pages_total` counter (`ragged_pages_walked`).
+
+Operand dtypes. q, K and V meet the MXU at the dtype they are stored
+in (`_operand_dtype`: the wider of q's and the pools', q's for int8
+pools, whose values are exact in it), the softmax weights rounded to
+the same dtype beside them; the products accumulate in float32, and the
+logits, the mask and the online softmax state are float32. A bf16 x
+bf16 product is exact in float32, so ``q . K^T`` loses nothing to the
+narrow operands; ``p`` loses what the chip always took from it: a
+float32 `tpu.matmul` at DEFAULT precision is ONE pass of the v5e's MXU
+with each operand rounded to bf16 on its way in, not the three or six
+passes of a float32 emulation (PERF.md section 6, PR 30: the float32
+kernel and this one give the same bits). What a trip's compute costs is
+the ORDER of its products: ``K^T`` is a transposed weight load of the
+MXU and ``V`` a plain one, and a loop that did both a head at a time
+alternated the two kinds on every MXU: at 8 KV heads a trip's compute
+alone took 1.41 us and the trip 1.56, where its DMAs alone take 0.81;
+in three passes over the heads the compute takes 0.78 us and the trip
+1.06. A float32 q (the CPU parity tests) keeps float32 operands
+throughout.
 
 The XLA path (`_ragged_xla`) is the CI oracle: a page gather BOUNDED to
 the block-table prefix actually referenced (static trim when the
@@ -331,9 +352,12 @@ def _ragged_xla(q, k_pages, v_pages, query_start, query_len, context_len,
 # sizes a trip's KV block against it
 KV_BLOCK_VMEM_BYTES = 2 * 1024 * 1024
 # keys a trip at most: one 128-lane row of logits a query row. A trip's
-# time grows with its pages (about 0.2 us a page on a v5e, trash pages
-# of the last block included) and 4, 8 and 16 pages were within 10 % of
-# each other at the serving shapes, 8 ahead (PERF.md section 6, PR 26)
+# time grows with its pages (about 0.13 us a page on a v5e at 8 KV
+# heads, trash pages of the last block included: 0.1 us is the page's
+# two DMAs). 256 keys a trip are 10 % ahead at the batch cell's decode
+# shape and 26 % at the hybrid's, and 15 % behind at the batch cell's
+# admission shape, where a q block's last KV block is mostly trash:
+# 128 stays (PERF.md section 6, PR 30)
 KV_BLOCK_MAX_KEYS = 128
 
 
@@ -407,6 +431,15 @@ def ragged_pages_walked(query_start, query_len, context_len, n_rows, *,
     return int(np.where(n_blocks > 0, end - b0 * block_pages, 0).sum())
 
 
+def _operand_dtype(q_dtype, pool_dtype):
+    """The dtype q, K, V and the softmax weights meet the MXU at: the
+    wider of what the call was given, so nothing is widened that both
+    sides store narrow. int8 pages (each an exact bf16 value) take q's."""
+    if jnp.issubdtype(pool_dtype, jnp.integer):
+        return jnp.dtype(q_dtype)
+    return jnp.promote_types(q_dtype, pool_dtype)
+
+
 def _ragged_kernel(qb_seq_ref, qstart_ref, qlen_ref, ctx_ref, bt_ref,
                    q_ref, k_hbm, v_hbm, *rest, scale, page_size,
                    block_q, group, window, block_pages, quantized=False):
@@ -422,6 +455,7 @@ def _ragged_kernel(qb_seq_ref, qstart_ref, qlen_ref, ctx_ref, bt_ref,
     else:
         o_ref, kbuf, vbuf, sem, acc_ref, m_ref, l_ref = rest
     hk, d = q_ref.shape[0], q_ref.shape[3]
+    operand = _operand_dtype(q_ref.dtype, kbuf.dtype)
     keys = block_pages * page_size
     pps = bt_ref.shape[1]
     qb = pl.program_id(0)
@@ -494,19 +528,27 @@ def _ragged_kernel(qb_seq_ref, qstart_ref, qlen_ref, ctx_ref, bt_ref,
         if quantized:
             ks = ksbuf[slot][:, :keys]               # (1, keys)
             vs = vsbuf[slot][:, :keys]
+        # Three passes over the heads, not one: every head's q . K^T,
+        # then every head's softmax, then every head's p @ V. K^T is a
+        # TRANSPOSED weight load of the MXU and V a plain one; in one
+        # pass a head the two kinds alternate on each MXU, and a trip
+        # took 1.41 us of compute at 8 heads; grouped, each MXU loads
+        # its K blocks, then its V blocks: 0.78 us (docs/kernels.md,
+        # "Operand dtypes and the order of the products")
+        sims = []
         for h in range(hk):
-            q = q_ref[h, 0].astype(jnp.float32)      # (block_q*G, D)
+            q = q_ref[h, 0].astype(operand)          # (block_q*G, D)
             # head h of the block: its lanes of every stored row
-            head = pl.ds(h * d, d)
-            k = kbuf[slot, :, :, head].astype(jnp.float32).reshape(keys, d)
-            v = vbuf[slot, :, :, head].astype(jnp.float32).reshape(keys, d)
+            k = kbuf[slot, :, :, pl.ds(h * d, d)].astype(operand)
             sim = mxu_dot(
-                q, k, (((1,), (1,)), ((), ())),
+                q, k.reshape(keys, d), (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
             if quantized:
                 # per-key-row dequant: sim[r, j] owes one factor ks[j]
                 sim = sim * ks                       # (1, keys) bcast
-            sim = jnp.where(valid, sim, NEG_INF)
+            sims.append(jnp.where(valid, sim, NEG_INF))
+        weights = []
+        for h, sim in enumerate(sims):
             m_prev = m_ref[h, :, :1]
             m_cur = jnp.max(sim, axis=-1, keepdims=True)
             m_new = jnp.maximum(m_prev, m_cur)
@@ -514,12 +556,15 @@ def _ragged_kernel(qb_seq_ref, qstart_ref, qlen_ref, ctx_ref, bt_ref,
             p = jnp.where(sim > NEG_INF * 0.5, jnp.exp(sim - m_new), 0.0)
             l_new = alpha * l_ref[h, :, :1] \
                 + jnp.sum(p, -1, keepdims=True)
-            pv = p * vs if quantized else p          # value-row dequant
-            acc_ref[h] = acc_ref[h] * alpha + mxu_dot(
-                pv, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
             m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
             l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+            pv = p * vs if quantized else p          # value-row dequant
+            weights.append((pv.astype(operand), alpha))
+        for h, (pv, alpha) in enumerate(weights):
+            v = vbuf[slot, :, :, pl.ds(h * d, d)].astype(operand)
+            acc_ref[h] = acc_ref[h] * alpha + mxu_dot(
+                pv, v.reshape(keys, d), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
         return carry
 
     jax.lax.fori_loop(0, n_trips, trip_body, None)

@@ -311,15 +311,18 @@ class TestRaggedPagedAttentionLowering:
             k_scale=ks, v_scale=vs), q, kp, kp, ks, ks)
 
 
-    # the benchmark's cells (ISSUE 26): (Q heads, rows, table columns,
-    # block_q) — InternLM2-1.8B has G = 2 and 128 columns of 16 tokens
-    # (max_seq_len 2048), Mistral-7B G = 4 and 64 columns; both 8 KV
-    # heads of 128 and 32 slots
+    # the benchmark's cells (ISSUE 26, 30): (Q heads, KV heads, slots,
+    # rows, table columns, block_q) — InternLM2-1.8B has G = 2 and 128
+    # columns of 16 tokens (max_seq_len 2048), Mistral-7B G = 4 and 64
+    # columns, both 8 KV heads of 128 and 32 slots; the hybrid's one
+    # attention block G = 16 on 2 KV heads, 64 slots of 512 columns
     CELL_SHAPES = {
-        "batch.decode": (16, 32, 128, 1),
-        "batch.admit512": (16, 512, 128, 8),
-        "chat.decode": (32, 32, 64, 1),
-        "chat.admit256": (32, 256, 64, 8),
+        "batch.decode": (16, 8, 32, 32, 128, 1),
+        "batch.admit512": (16, 8, 32, 512, 128, 8),
+        "chat.decode": (32, 8, 32, 32, 64, 1),
+        "chat.admit256": (32, 8, 32, 256, 64, 8),
+        "reasoning.decode": (32, 2, 64, 64, 512, 1),
+        "reasoning.admit1024": (32, 2, 64, 1024, 512, 8),
     }
 
     @pytest.mark.parametrize("variant", ["bf16", "int8", "window", "tp2"])
@@ -327,15 +330,16 @@ class TestRaggedPagedAttentionLowering:
     def test_cell_shapes(self, shape, variant):
         """ISSUE 26: the in-kernel loop over KV blocks — HBM pools,
         per-page DMAs indexed by the prefetched table, a dynamic trip
-        count — at the real shapes of both cells' decode and admission
-        programs, on int8 pools, with a window, and per shard of a
-        tp = 2 replica."""
+        count — at the real shapes of the three cells' decode and
+        admission programs with the cells' own dtype pair (bf16 q on
+        bf16 pools: bf16 MXU operands, ISSUE 30), on int8 pools, with a
+        window, and per shard of a tp = 2 replica."""
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
         from paddle_tpu.ops.ragged_paged_attention import \
             ragged_paged_attention_values
 
-        h, rows, pps, block_q = self.CELL_SHAPES[shape]
-        slots, hk, d, page_size = 32, 8, 128, 16
+        h, hk, slots, rows, pps, block_q = self.CELL_SHAPES[shape]
+        d, page_size = 128, 16
         pages = slots * pps + 1
         q = jnp.zeros((rows, h, d), jnp.bfloat16)
         kp = jnp.zeros((pages, page_size, hk * d),

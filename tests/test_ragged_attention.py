@@ -236,6 +236,15 @@ LOOP_EDGES = {
 }
 
 
+# `_case` overrides of the decode form (block_q 1: three decode rows)
+# and of the admission form (block_q 8: a decode row, a prefill, a
+# continuation)
+BLOCK_Q_CASES = {
+    1: dict(ql=(1, 1, 1), cl=(9, 16, 3), tail_pad=0),
+    8: dict(ql=(1, 11, 5), cl=(9, 11, 13), tail_pad=8),
+}
+
+
 class TestKernelLoopEdges:
     """The in-kernel loop over KV blocks (ISSUE 26): the edges a
     dynamic trip count brings, kernel (interpret) against the XLA
@@ -288,10 +297,7 @@ class TestKernelLoopEdges:
         bf16 pools and on int8 pools with their per-row scales."""
         _small_blocks(monkeypatch, 2)
         rng = np.random.default_rng(100 + 10 * g + block_q)
-        if block_q == 1:
-            kw = dict(ql=(1, 1, 1), cl=(9, 16, 3), tail_pad=0)
-        else:
-            kw = dict(ql=(1, 11, 5), cl=(9, 11, 13), tail_pad=8)
+        kw = BLOCK_Q_CASES[block_q]
         q, kp, vp, qs, ql, cl, bt = _case(rng, g=g, pps=4, n_pages=12,
                                           block_q=block_q, **kw)
         scales = {}
@@ -321,6 +327,116 @@ class TestKernelLoopEdges:
         # do not
         np.testing.assert_allclose(kern, xla,
                                    atol=2e-2 if pool == "bf16" else 5e-5)
+
+
+def _bf16(x):
+    """x rounded to bfloat16, as the float32 array the oracle reads."""
+    return np.asarray(jnp.asarray(x, jnp.bfloat16), np.float32)
+
+
+def _all_eqns(jaxpr):
+    """A jaxpr's equations, those of its sub-jaxprs (a pallas_call's
+    body, its loop, its `pl.when` branches) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _all_eqns(sub)
+
+
+class TestOperandDtypes:
+    """ISSUE 30: q, K and V go to the MXU at the dtype they are stored
+    in, the softmax weights beside them (what the chip's MXU made of a
+    float32 operand anyway: one pass, the operand rounded to bf16); the
+    accumulator, the logits and the softmax state are float32. The
+    parity cases above pass a float32 q; these pass the cells' own
+    pair, bf16 q on bf16 (or int8) pools."""
+
+    @staticmethod
+    def _close(kern, q, kp, vp, *desc, window=None):
+        """The float32 cases' atol, widened only by bf16's roundings:
+        of the output (half a unit of its 8 significant bits, 2^-8 of a
+        value at most) and of each softmax weight in front of p @ V
+        (2^-8 of sum_j p_j |v_j| at most)."""
+        ref = np_ragged_oracle(q, kp, vp, *desc, window=window)
+        mass = np_ragged_oracle(q, kp, np.abs(vp), *desc, window=window)
+        err = np.abs(np.asarray(kern, np.float32) - ref)
+        bound = 5e-5 + 2.0 ** -8 * (np.abs(ref) + mass)
+        assert (err <= bound).all(), (err.max(), (err - bound).max())
+
+    @pytest.mark.parametrize("window", [None, 6])
+    @pytest.mark.parametrize("block_q", [1, 8])
+    @pytest.mark.parametrize("g", [1, 2, 4, 16])
+    def test_all_bf16_call(self, g, block_q, window, monkeypatch):
+        _small_blocks(monkeypatch, 2)
+        rng = np.random.default_rng(300 + 10 * g + block_q)
+        kw = BLOCK_Q_CASES[block_q]
+        q, kp, vp, qs, ql, cl, bt = _case(rng, g=g, pps=4, n_pages=12,
+                                          block_q=block_q, **kw)
+        kern = ragged_paged_attention_values(
+            jnp.asarray(q, jnp.bfloat16), _pool(kp, jnp.bfloat16),
+            _pool(vp, jnp.bfloat16), qs, ql, cl, bt, window=window,
+            block_q=block_q, use_kernel=True)
+        assert kern.dtype == jnp.bfloat16
+        self._close(kern, _bf16(q), _bf16(kp), _bf16(vp), qs, ql, cl, bt,
+                    window=window)
+
+    @pytest.mark.parametrize("block_q", [1, 8])
+    def test_bf16_q_on_int8_pools(self, block_q, monkeypatch):
+        """int8 pages widen to q's dtype (exact) and no further."""
+        _small_blocks(monkeypatch, 2)
+        rng = np.random.default_rng(400 + block_q)
+        kw = BLOCK_Q_CASES[block_q]
+        q, kp, vp, qs, ql, cl, bt = _case(rng, g=2, pps=4, n_pages=12,
+                                          block_q=block_q, **kw)
+        kp_i = rng.integers(-127, 128, kp.shape).astype(np.int8)
+        vp_i = rng.integers(-127, 128, vp.shape).astype(np.int8)
+        ks = rng.uniform(0.002, 0.02, kp.shape[1:3]).astype(np.float32)
+        vs = rng.uniform(0.002, 0.02, kp.shape[1:3]).astype(np.float32)
+        kern = ragged_paged_attention_values(
+            jnp.asarray(q, jnp.bfloat16), _pool(kp_i), _pool(vp_i), qs, ql,
+            cl, bt, block_q=block_q, use_kernel=True,
+            k_scale=jnp.asarray(ks), v_scale=jnp.asarray(vs))
+        self._close(kern, _bf16(q),
+                    kp_i.astype(np.float32) * ks[None, :, :, None],
+                    vp_i.astype(np.float32) * vs[None, :, :, None],
+                    qs, ql, cl, bt)
+
+    @pytest.mark.parametrize("q_dtype,pool_dtype,operand", [
+        ("bfloat16", "bfloat16", "bfloat16"),
+        ("bfloat16", "int8", "bfloat16"),
+        ("float32", "bfloat16", "float32"),
+        ("float32", "int8", "float32"),
+        ("float32", "float32", "float32"),
+    ])
+    def test_dots_take_the_stored_dtype(self, q_dtype, pool_dtype, operand):
+        """The guard that the widening does not come back: every
+        `dot_general` inside the pallas_call takes both operands at the
+        wider of q's and the pools' dtype (q's for int8 pools), the
+        softmax weights among them, with a float32
+        `preferred_element_type`."""
+        rng = np.random.default_rng(7)
+        q, kp, vp, qs, ql, cl, bt = _case(rng)
+        quantized = pool_dtype == "int8"
+        scales = (jnp.ones(kp.shape[1:3], jnp.float32),) * 2 \
+            if quantized else (None, None)
+        jaxpr = jax.make_jaxpr(
+            lambda q, kp, vp, ks, vs: rpa_mod._ragged_pallas(
+                q, kp, vp, jnp.asarray(qs), jnp.asarray(ql),
+                jnp.asarray(cl), jnp.asarray(bt), 0.25, None, 4, True,
+                k_scale=ks, v_scale=vs))(
+            jnp.asarray(q, q_dtype), _pool(kp, pool_dtype),
+            _pool(vp, pool_dtype), *scales)
+        calls = [e for e in _all_eqns(jaxpr.jaxpr)
+                 if e.primitive.name == "pallas_call"]
+        assert len(calls) == 1
+        dots = [e for e in _all_eqns(calls[0].params["jaxpr"])
+                if e.primitive.name == "dot_general"]
+        hk = kp.shape[0]
+        assert len(dots) == 2 * hk           # a head: q . K^T and p @ V
+        for eqn in dots:
+            assert [v.aval.dtype.name for v in eqn.invars] == [operand] * 2
+            assert eqn.params["preferred_element_type"] == jnp.float32
+            assert eqn.outvars[0].aval.dtype == jnp.float32
 
 
 def _brute_walked(qs, ql, cl, n_rows, block_q, ps, window, block_pages,

@@ -248,19 +248,26 @@ class TestRaggedPagedAttentionCompile:
                                           v_scale=vs), q, kp, kp, ks, ks)
         assert np.isfinite(np.asarray(out, np.float32)).all()
 
-    @pytest.mark.parametrize("shape", [(16, 32, 128, 1), (16, 512, 128, 8),
-                                       (32, 32, 64, 1), (32, 256, 64, 8)])
-    @pytest.mark.parametrize("variant", ["bf16", "int8", "window"])
-    def test_cell_shapes_match_the_oracle(self, variant, shape):
-        """ISSUE 26: the in-kernel loop over KV blocks, executed at the
-        benchmark cells' shapes (Q heads, rows, table columns, block_q)
-        on contexts of a third of the table, against the XLA oracle.
-        The table's dead columns hold an id far outside the pool: a
-        DMA that read one would fault."""
-        from paddle_tpu.ops.ragged_paged_attention import (
-            pack_ragged_starts, ragged_paged_attention_values as rpa)
-        h, rows, pps, block_q = shape
-        n, hk, d = 32, 8, 128
+    # the benchmark's cells: (Q heads, KV heads, slots, rows, table
+    # columns, block_q), as tests/test_mosaic_lowering.py lists them
+    CELL_SHAPES = {
+        "batch.decode": (16, 8, 32, 32, 128, 1),
+        "batch.admit512": (16, 8, 32, 512, 128, 8),
+        "chat.decode": (32, 8, 32, 32, 64, 1),
+        "chat.admit256": (32, 8, 32, 256, 64, 8),
+        "reasoning.decode": (32, 2, 64, 64, 512, 1),
+        "reasoning.admit1024": (32, 2, 64, 1024, 512, 8),
+    }
+
+    def _cell_case(self, shape, variant="bf16"):
+        """A cell's shape on contexts of up to half the table, bf16 q:
+        (q, kp, vp, descriptors, the block tables, the same with dead
+        columns on page 0, keywords). The table's dead columns hold an
+        id far outside the pool: a DMA that read one would fault."""
+        from paddle_tpu.ops.ragged_paged_attention import \
+            pack_ragged_starts
+        h, hk, n, rows, pps, block_q = self.CELL_SHAPES[shape]
+        d = 128
         rng = np.random.default_rng(rows + pps)
         cl = rng.integers(self.PAGE, pps * self.PAGE // 2, n)
         if block_q == 1:
@@ -289,18 +296,90 @@ class TestRaggedPagedAttentionCompile:
                 v_scale=jnp.asarray(rng.uniform(0.002, 0.02, shp[:2]),
                                     jnp.float32))
         else:
-            kp, vp = (jnp.asarray(rng.standard_normal(shp), jnp.bfloat16)
-                      for _ in range(2))
+            kp, vp = (jnp.asarray(rng.standard_normal(shp, np.float32),
+                                  jnp.bfloat16) for _ in range(2))
+        return q, kp, vp, (qs, ql, cl), bt, safe, block_q, kw
+
+    @pytest.mark.parametrize("shape", sorted(CELL_SHAPES))
+    @pytest.mark.parametrize("variant", ["bf16", "int8", "window"])
+    def test_cell_shapes_match_the_oracle(self, variant, shape):
+        """ISSUE 26: the in-kernel loop over KV blocks, executed at the
+        benchmark cells' shapes against the XLA oracle."""
+        from paddle_tpu.ops.ragged_paged_attention import \
+            ragged_paged_attention_values as rpa
+        q, kp, vp, desc, bt, safe, block_q, kw = self._cell_case(
+            shape, variant)
         got = _compile(lambda q, kp, vp: rpa(
-            q, kp, vp, qs, ql, cl, bt, block_q=block_q, **kw), q, kp, vp)
+            q, kp, vp, *desc, bt, block_q=block_q, **kw), q, kp, vp)
         want = _compile(lambda q, kp, vp: rpa(
-            q, kp, vp, qs, ql, cl, safe, block_q=block_q,
+            q, kp, vp, *desc, safe, block_q=block_q,
             use_kernel=False, **kw), q, kp, vp)
-        # bf16 outputs of O(1) values; the oracle rounds its softmax
-        # weights to the pool's dtype, the kernel keeps them in float32
+        # bf16 outputs of O(1) values; the oracle rounds its normalised
+        # softmax weights to the pool's dtype, the kernel its running ones
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(want, np.float32),
                                    atol=3e-2)
+
+    # max |kernel - float64 NumPy| at `_cell_case`'s data of the PARENT's
+    # kernel (float32 MXU operands, one pass a head) on a TPU v5 lite,
+    # rounded up in the fourth digit (PERF.md section 6, PR 30): the
+    # bf16 output's rounding, and the softmax weights' that the MXU
+    # made of its float32 operand
+    PARENT_MAX_ERR = {
+        "batch.decode": 0.004160,
+        "batch.admit512": 0.007721,
+        "chat.decode": 0.004595,
+        "chat.admit256": 0.008602,
+        "reasoning.decode": 0.001721,
+        "reasoning.admit1024": 0.008350,
+    }
+
+    @staticmethod
+    def _oracle64(q, kp, vp, desc, bt, page):
+        """The attention of `_cell_case`'s bf16 data in float64 NumPy, a
+        sequence at a time (no window, no scales)."""
+        qs, ql, cl = desc
+        q = np.asarray(q.astype(jnp.float32), np.float64)
+        t, h, d = q.shape
+        hk = kp.shape[2] // d
+        out = np.zeros((t, h, d))
+        for s in range(len(ql)):
+            n, c = int(ql[s]), int(cl[s])
+            if not n:
+                continue
+            pg = jnp.asarray(bt[s, :-(-c // page)])
+            k, v = (np.asarray(x[pg].astype(jnp.float32), np.float64)
+                    .reshape(-1, hk, d)[:c] for x in (kp, vp))
+            qq = q[qs[s]:qs[s] + n].reshape(n, hk, h // hk, d)
+            lg = np.einsum("nkgd,ckd->nkgc", qq, k) / np.sqrt(d)
+            causal = np.arange(c)[None, :] <= (c - n + np.arange(n))[:, None]
+            lg = np.where(causal[:, None, None, :], lg, -np.inf)
+            p = np.exp(lg - lg.max(-1, keepdims=True))
+            p /= p.sum(-1, keepdims=True)
+            out[qs[s]:qs[s] + n] = np.einsum(
+                "nkgc,ckd->nkgd", p, v).reshape(n, h, d)
+        return out
+
+    def _cell_error(self, shape, rpa):
+        """max |rpa's output - float64| at a cell's shape; `rpa` is a
+        parameter so that a parent's kernel can be measured the same
+        way when `PARENT_MAX_ERR` is taken again."""
+        q, kp, vp, desc, bt, _, block_q, _ = self._cell_case(shape)
+        got = _compile(lambda q, kp, vp: rpa(
+            q, kp, vp, *desc, bt, block_q=block_q), q, kp, vp)
+        want = self._oracle64(q, kp, vp, desc, bt, self.PAGE)
+        return float(np.abs(np.asarray(got, np.float64) - want).max())
+
+    @pytest.mark.parametrize("shape", sorted(CELL_SHAPES))
+    def test_cell_shapes_error_no_larger_than_the_parents(self, shape):
+        """ISSUE 30: bf16 operands at the MXU (q, K and V as stored,
+        the softmax weights rounded to bf16 beside them) lose nothing
+        the float32 operands kept on the chip, where the MXU rounded
+        them the same way: the error against float64 at the cells'
+        shapes is no larger than the parent's kernel's."""
+        from paddle_tpu.ops.ragged_paged_attention import \
+            ragged_paged_attention_values as rpa
+        assert self._cell_error(shape, rpa) <= self.PARENT_MAX_ERR[shape]
 
     @pytest.mark.parametrize("block_q", [8, 1])
     def test_tp_shard_map_kernel(self, block_q):
