@@ -26,15 +26,23 @@ layer keeps between the tokens of a sequence.
 The forward pass takes the views as `past_key_values` (one a layer) and
 returns, in the same order, a layer's new view, a reporting layer's
 `(counts, records)`, or None.
+
+`model.generation_spec()` says how generation PROCEEDS, the way
+`cache_spec()` says what a layer keeps. Absent or None: one token a
+sequence a step, each from the logits of the token before it. A
+`BlockDiffusionSpec`: by diffusion over blocks (its docstring). The
+engine reads it once, at construction.
 """
 from __future__ import annotations
 
 import math
 from typing import NamedTuple, Tuple
 
+import jax
 import jax.numpy as jnp
 
-__all__ = ["KVSpec", "StateSpec", "ReportSpec", "RaggedStateView"]
+__all__ = ["KVSpec", "StateSpec", "ReportSpec", "RaggedStateView",
+           "BlockDiffusionSpec"]
 
 
 class KVSpec(NamedTuple):
@@ -55,6 +63,66 @@ class StateSpec(NamedTuple):
 class ReportSpec(NamedTuple):
     counters: Tuple[Tuple[object, str], ...]  # (telemetry counter, kind)
     row_record: Tuple[int, ...]               # int32, one a packed row
+
+
+class BlockDiffusionSpec(NamedTuple):
+    """Generation by diffusion over blocks. Positions are cut into
+    blocks of `block_length` from position 0; attention is causal over
+    blocks and full inside one (`ragged_paged_attention`'s
+    ``diffusion_block``). A block in flight holds its given tokens and
+    `mask_token_id` elsewhere; a PASS runs the model over the block's
+    rows against the stored context (row `i` predicts position `i`'s own
+    token) and `transfer` decides some of the masked positions. A block
+    that holds no mask takes one more pass, which leaves the keys and
+    values later blocks read, and the next block starts.
+
+    `remasking`: ``low_confidence_static`` decides the `block_length /
+    denoising_steps` masked positions of highest confidence a pass (the
+    remainder of the division goes to the first passes);
+    ``low_confidence_dynamic`` decides every masked position whose
+    confidence passes `threshold`, and the most confident one if none
+    does."""
+    block_length: int
+    mask_token_id: int
+    denoising_steps: int
+    remasking: str = "low_confidence_static"
+    threshold: float = 0.9
+
+    def check(self) -> None:
+        if self.remasking not in ("low_confidence_static",
+                                  "low_confidence_dynamic"):
+            raise ValueError(f"remasking {self.remasking!r}: "
+                             "low_confidence_static|low_confidence_dynamic")
+        if not 1 <= self.denoising_steps <= self.block_length:
+            raise ValueError(
+                f"denoising_steps {self.denoising_steps} outside "
+                f"[1, block_length {self.block_length}]")
+
+    def transfer(self, logits, ids, masked, passes):
+        """The transfer rule, inside the step program: a pure function
+        of a pass's logits (slots, block, vocab), the dispatched blocks'
+        `ids` and `masked` flags (slots, block) and the denoising passes
+        each slot's block has had (slots,). Greedy: a masked position's
+        candidate is its argmax, its confidence the candidate's softmax
+        probability; ties go to the lower position. Returns the next
+        blocks' (ids, masked). A block without a mask comes back as it
+        was."""
+        b = self.block_length
+        lg = logits.astype(jnp.float32)
+        x0 = jnp.argmax(lg, axis=-1).astype(ids.dtype)
+        conf = jnp.exp(jnp.max(lg, axis=-1)
+                       - jax.nn.logsumexp(lg, axis=-1))
+        conf = jnp.where(masked, conf, -jnp.inf)
+        # rank 0 = the most confident masked position of the block
+        order = jnp.argsort(-conf, axis=-1, stable=True)
+        rank = jnp.argsort(order, axis=-1, stable=True)
+        if self.remasking == "low_confidence_static":
+            base, rem = divmod(b, self.denoising_steps)
+            n = base + (passes < rem).astype(jnp.int32)
+            take = masked & (rank < n[:, None])
+        else:
+            take = masked & ((conf > self.threshold) | (rank == 0))
+        return jnp.where(take, x0, ids), masked & ~take
 
 
 class RaggedStateView:

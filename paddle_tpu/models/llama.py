@@ -221,7 +221,9 @@ class RaggedKVCacheView:
     `context_lens` are per sequence (N,); `block_q` is the static
     q-block size the packer aligned `query_start` to (decode batches
     pass 1); `pages_bound` is the static gather trim the XLA fallback
-    applies (None = full table).
+    applies (None = full table); `diffusion_block` (static) is the
+    block of a model that attends by blocks (`ragged_paged_attention`;
+    1, the causal mask, for every other model).
 
     The speculative engine mode (`serving.SpecConfig`) rides this
     view twice over: the VERIFY pass packs each slot as a multi-token
@@ -234,7 +236,7 @@ class RaggedKVCacheView:
     def __init__(self, k_pages, v_pages, block_tables, token_seq,
                  positions, query_start, query_len, context_lens,
                  block_q=1, pages_bound=None, tp=None, k_scale=None,
-                 v_scale=None):
+                 v_scale=None, diffusion_block=1):
         self.k_pages = k_pages if isinstance(k_pages, Tensor) \
             else Tensor(k_pages)
         self.v_pages = v_pages if isinstance(v_pages, Tensor) \
@@ -259,6 +261,7 @@ class RaggedKVCacheView:
         self.query_len = _i32(query_len)
         self.context_lens = _i32(context_lens)
         self.block_q = int(block_q)
+        self.diffusion_block = int(diffusion_block)
         self.pages_bound = None if pages_bound is None \
             else int(pages_bound)
         # tensor parallelism (serving/submesh.py): a (jax Mesh, axis)
@@ -266,6 +269,38 @@ class RaggedKVCacheView:
         # the pools arrive sharded on their KV-head axis, descriptors
         # and block tables stay replicated scalars
         self.tp = tp
+
+
+def ragged_write_attend(q, k, v, view: RaggedKVCacheView, window=None):
+    """The ragged path's two cache calls for full-width pools, as every
+    servable attention layer makes them: ONE scatter of the packed
+    batch's new K/V rows (1, T, HK, D) into the view's pages, then
+    ragged paged attention of q (1, T, H, D) over them under the
+    view's descriptors and mask. Returns (out (1, T, H, D), the view
+    over the written pools)."""
+    from paddle_tpu.core.tensor import apply as _apply
+    from paddle_tpu.ops.ragged_paged_attention import (
+        ragged_paged_attention_values, ragged_scatter_values)
+    bt = view.block_tables
+
+    def fn_scatter(kp, vp, kk, vv):
+        return ragged_scatter_values(kp, vp, kk[0], vv[0], bt,
+                                     view.token_seq, view.positions)
+    kp, vp = _apply("ragged_kv_scatter", fn_scatter,
+                    (view.k_pages, view.v_pages, k, v), multi_output=True)
+
+    def fn_attn(qq, kp_, vp_):
+        return ragged_paged_attention_values(
+            qq[0], kp_, vp_, view.query_start, view.query_len,
+            view.context_lens, bt, window=window, block_q=view.block_q,
+            pages_bound=view.pages_bound, tp=view.tp,
+            diffusion_block=view.diffusion_block)[None]
+    out = _apply("ragged_paged_attention", fn_attn, (q, kp, vp))
+    return out, RaggedKVCacheView(
+        kp, vp, bt, view.token_seq, view.positions, view.query_start,
+        view.query_len, view.context_lens, view.block_q,
+        view.pages_bound, tp=view.tp,
+        diffusion_block=view.diffusion_block)
 
 
 class LlamaAttention(nn.Layer):

@@ -32,31 +32,19 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu import nn
-from paddle_tpu import observability as telemetry
 from paddle_tpu.nn import functional as F
 from paddle_tpu.nn import initializer as I
 from paddle_tpu.core.tensor import Tensor, apply as _apply
 from paddle_tpu.models.cache_spec import (KVSpec, RaggedStateView,
                                           ReportSpec, StateSpec)
-from paddle_tpu.models.llama import RaggedKVCacheView
+from paddle_tpu.models.llama import (RaggedKVCacheView,
+                                     ragged_write_attend)
+from paddle_tpu.models.routed import (combine_rows, report_counts,
+                                      report_spec, route_rows)
 
 __all__ = ["NemotronHConfig", "NemotronHForCausalLM"]
 
 _F32 = jnp.float32
-
-# what an expert layer counts a dispatch (cache_spec.ReportSpec)
-_M_MOE_ASSIGNMENTS = telemetry.counter(
-    "pdt_serving_moe_assignments_total",
-    "Token-to-expert assignments of the dispatched live rows, summed "
-    "over the expert layers, by kind: local = on an expert this "
-    "program holds (computed), remote = on an expert held elsewhere "
-    "(dropped before the sort).", ("kind",))
-_M_MOE_EXPERTS = telemetry.counter(
-    "pdt_serving_moe_experts_total",
-    "Held experts a dispatch, summed over the expert layers, by kind: "
-    "hit = got at least one row (its weights were read), idle = got "
-    "none.", ("kind",))
-
 
 @dataclass
 class NemotronHConfig:
@@ -435,9 +423,8 @@ def latent_experts_values(a, live, w_r, b_corr, w_down, w1, w2, w_up, w1_s,
     width, latent) are the experts held here. Returns (out (T, hidden),
     counts int32 (4,) in `NemotronHExperts.cache_spec`'s order, chosen
     int32 (T, k): each row's experts)."""
-    from paddle_tpu.ops.grouped_matmul import (grouped_matmul_values,
-                                               row_block)
-    t, dtype = a.shape[0], a.dtype
+    from paddle_tpu.ops.grouped_matmul import grouped_matmul_values
+    dtype = a.dtype
     k, held = cfg.num_experts_per_tok, w1.shape[0]
     with jax.default_matmul_precision("highest"):
         s = jax.nn.sigmoid(a.astype(_F32) @ w_r.astype(_F32))   # (T, R)
@@ -447,37 +434,18 @@ def latent_experts_values(a, live, w_r, b_corr, w_down, w1, w2, w_up, w1_s,
         wts = wts / jnp.sum(wts, axis=1, keepdims=True)
     wts = wts * cfg.routed_scaling_factor
 
-    # assignments on experts held elsewhere are dropped BEFORE the sort:
-    # they take the group `held`, which sorts last and gets no rows
-    local = chosen - cfg.expert_offset
-    mine = (local >= 0) & (local < held) & live[:, None]
-    gid = jnp.where(mine, local, held).reshape(-1)              # (T k,)
-    counts = jnp.zeros(held + 1, jnp.int32).at[gid].add(1)[:held]
-    bm = row_block(t * k / cfg.n_routed_experts)
-    padded = -(-counts // bm) * bm
-    start_p = jnp.cumsum(padded) - padded
-    start_u = jnp.cumsum(counts) - counts
-    order = jnp.argsort(gid, stable=True)
-    sgid = gid[order]
-    sg = jnp.minimum(sgid, held - 1)
-    m_pad = -(-(t * k + held * (bm - 1)) // bm) * bm            # static
-    row = jnp.where(sgid < held,
-                    start_p[sg] + jnp.arange(t * k) - start_u[sg], m_pad)
-    src = jnp.zeros(m_pad, jnp.int32).at[row].set(order // k, mode="drop")
-    dest = jnp.zeros(t * k, jnp.int32).at[order].set(row)
-
+    # the one routed dispatch (models/routed.py): assignments on experts
+    # held elsewhere dropped before the sort, rows padded to the tile
+    r = route_rows(chosen, live, held=held, offset=cfg.expert_offset,
+                   n_experts=cfg.n_routed_experts)
     lat = a @ w_down                                            # (T, latent)
-    up = grouped_matmul_values(lat[src], w1, padded, bm)
-    down = grouped_matmul_values(_relu2(up).astype(dtype), w2, padded, bm)
-    rows = down[jnp.minimum(dest, m_pad - 1)].reshape(t, k, -1)
-    r = jnp.einsum("tkl,tk->tl", rows.astype(_F32),
-                   jnp.where(mine, wts, 0.0)).astype(dtype)
+    up = grouped_matmul_values(lat[r.src], w1, r.padded, r.block_m)
+    down = grouped_matmul_values(_relu2(up).astype(dtype), w2, r.padded,
+                                 r.block_m)
+    routed = combine_rows(down, wts, r).astype(dtype)
     shared = _relu2(a @ w1_s).astype(dtype) @ w2_s
-    n_local, n_hit = jnp.sum(counts), jnp.sum(counts > 0)
-    stats = jnp.stack([n_local, jnp.sum(live) * k - n_local,
-                       n_hit, held - n_hit])
-    return (r @ w_up + shared, stats.astype(jnp.int32),
-            chosen.astype(jnp.int32))
+    stats = report_counts(r, live, k)
+    return routed @ w_up + shared, stats, chosen.astype(jnp.int32)
 
 
 class NemotronHExperts(nn.Layer):
@@ -504,10 +472,7 @@ class NemotronHExperts(nn.Layer):
             cfg.moe_shared_expert_intermediate_size, h, bias_attr=False)
 
     def cache_spec(self) -> ReportSpec:
-        return ReportSpec(
-            ((_M_MOE_ASSIGNMENTS, "local"), (_M_MOE_ASSIGNMENTS, "remote"),
-             (_M_MOE_EXPERTS, "hit"), (_M_MOE_EXPERTS, "idle")),
-            (self.cfg.num_experts_per_tok,))
+        return report_spec(self.cfg.num_experts_per_tok)
 
     def forward(self, x, live):
         """x (1, T, hidden); live (T,) bool. Returns (out, (counts,
@@ -559,27 +524,8 @@ class NemotronHAttention(nn.Layer):
         if view is None:
             out = F.scaled_dot_product_attention(q, k, v, is_causal=True)
             return self.o_proj(out.reshape([b, s, -1])), None
-        from paddle_tpu.ops.ragged_paged_attention import (
-            ragged_paged_attention_values, ragged_scatter_values)
-        bt = view.block_tables
-
-        def fn_scatter(kp, vp, kk, vv):
-            return ragged_scatter_values(kp, vp, kk[0], vv[0], bt,
-                                         view.token_seq, view.positions)
-        kp, vp = _apply("ragged_kv_scatter", fn_scatter,
-                        (view.k_pages, view.v_pages, k, v),
-                        multi_output=True)
-
-        def fn_attn(qq, kp_, vp_):
-            return ragged_paged_attention_values(
-                qq[0], kp_, vp_, view.query_start, view.query_len,
-                view.context_lens, bt, block_q=view.block_q,
-                pages_bound=view.pages_bound)[None]
-        out = _apply("ragged_paged_attention", fn_attn, (q, kp, vp))
-        return self.o_proj(out.reshape([1, s, -1])), RaggedKVCacheView(
-            kp, vp, bt, view.token_seq, view.positions, view.query_start,
-            view.query_len, view.context_lens, view.block_q,
-            view.pages_bound)
+        out, view = ragged_write_attend(q, k, v, view)
+        return self.o_proj(out.reshape([1, s, -1])), view
 
 
 # -- the model ---------------------------------------------------------------

@@ -273,6 +273,22 @@ _M_STATE_BYTES = telemetry.gauge(
 _M_STATE_SLOTS = telemetry.gauge(
     "pdt_serving_state_slots_live",
     "Slots whose state arrays hold a running sequence's state.")
+# -- generation by diffusion over blocks (cache_spec.BlockDiffusionSpec)
+_M_BLOCK_PASSES = telemetry.counter(
+    "pdt_serving_block_passes_total",
+    "Passes over a live slot's block, one count a live slot a pass, by "
+    "kind: denoise = the dispatched block held a mask (the pass decides "
+    "positions), commit = it held none (the pass leaves the keys and "
+    "values later blocks read).", ("kind",))
+_M_BLOCK_TOKENS = telemetry.counter(
+    "pdt_serving_block_tokens_total",
+    "Tokens of committed blocks handed to requests (a first block's "
+    "given prompt tokens and what lies past a budget are not).")
+_M_BLOCK_SECONDS = telemetry.histogram(
+    "pdt_serving_block_seconds",
+    "A slot's block from the start of its first pass to its tokens, "
+    "engine clock.",
+    buckets=(0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5))
 # -- speculative decoding (spec_decode=SpecConfig(...), ISSUE 10) ------
 _M_SPEC_ROUNDS = telemetry.counter(
     "pdt_spec_rounds_total",
@@ -634,6 +650,26 @@ class ContinuousBatchingEngine:
                     ("harvest_every > 1", int(harvest_every) > 1)):
                 if asked:
                     self._refuse_state(feature)
+        # -- how generation proceeds (models/cache_spec.py): a token a
+        # sequence a step, or by diffusion over blocks. Read once, here;
+        # what takes a token to be a step refuses a block model by name.
+        gen = getattr(model, "generation_spec", None)
+        self._gen = gen() if gen is not None else None
+        self._dblock = 1 if self._gen is None else \
+            int(self._gen.block_length)
+        if self._gen is not None:
+            self._gen.check()
+            for feature, asked in (
+                    ("spec_decode", spec_decode is not None),
+                    ("harvest_every > 1", int(harvest_every) > 1),
+                    ("do_sample", do_sample),
+                    ("quant.kv", quant is not None and quant.kv),
+                    ("quant.weights", quant is not None and quant.weights),
+                    ("submesh tp > 1", submesh is not None
+                     and int(submesh.tp) > 1),
+                    ("a state layer", bool(self._state_spec))):
+                if asked:
+                    self._refuse_blocks(feature)
         # -- pipelined decode (ISSUE 18, docs/serving.md "Pipelined
         # decode"): harvest_every=k defers the D2H token sync — the
         # greedy-sampled token stays ON DEVICE and feeds step N+1's
@@ -724,6 +760,13 @@ class ContinuousBatchingEngine:
         self.pps = -(-self.S // self.page_size)
         # +1: page 0 is the reserved trash page
         self.num_pages = int(num_pages or self.B * self.pps + 1)
+        if self.page_size % self._dblock or self.S % self._dblock:
+            # a page then holds whole blocks (so a cached prompt page
+            # depends on no later token) and the last block fits
+            raise ValueError(
+                f"page_size {self.page_size} and max_seq_len {self.S} "
+                f"must be multiples of the model's block_length "
+                f"{self._dblock}")
         if self.num_pages < 2:
             raise ValueError("num_pages must be >= 2 (page 0 is "
                              "reserved)")
@@ -807,6 +850,16 @@ class ContinuousBatchingEngine:
         # host-side slot state
         self._pos = np.zeros(self.B, np.int32)        # next write position
         self._tok = np.zeros(self.B, np.int32)        # last emitted token
+        # a block model's slot holds a BLOCK in flight at positions
+        # [_pos, _pos + block): its ids, which of them are still masked
+        # (a flag, so a token that equals the mask id stays a token),
+        # how many lead tokens were given by the prompt, the denoising
+        # passes it has had and when its first pass began
+        self._blk_ids = np.zeros((self.B, self._dblock), np.int32)
+        self._blk_masked = np.zeros((self.B, self._dblock), bool)
+        self._blk_given = np.zeros(self.B, np.int32)
+        self._blk_passes = np.zeros(self.B, np.int32)
+        self._blk_t0 = np.full(self.B, np.nan)
         self._slot_req: List[Optional[Request]] = [None] * self.B
         self._queue: List[Request] = []
         self._next_rid = 0
@@ -988,6 +1041,14 @@ class ContinuousBatchingEngine:
             f"({type(self.model).__name__}): it takes a sequence's cache "
             "to be its pages, and this model also keeps a recurrent "
             "state per slot")
+
+    def _refuse_blocks(self, feature: str):
+        raise ValueError(
+            f"{feature} is not supported for a model that generates by "
+            f"diffusion over blocks ({type(self.model).__name__}): it "
+            "takes a decode step to give one token a sequence, and this "
+            f"model's step is a pass over a block of {self._dblock} "
+            "positions that may decide any of them")
 
     def _state_nbytes(self) -> int:
         return self.B * sum(s.nbytes() for s in self._state_spec)
@@ -1499,7 +1560,10 @@ class ContinuousBatchingEngine:
         every active slot, release finished slots. Returns the requests
         that reached a TERMINAL state this step (finished / timeout /
         failed / preempted-out — check `.status`). One monotonic-clock
-        tick per step drives deadline and queue-time expiry.
+        tick per step drives deadline and queue-time expiry. For a model
+        that generates by diffusion over blocks the decode is one PASS
+        over every active slot's block (`_decode_blocks`), and a slot
+        gives its block's tokens when the block holds no mask.
 
         Pipelined mode (harvest_every=k > 1): a due deferred window is
         harvested FIRST — before expiry, admission, and the next
@@ -1946,6 +2010,13 @@ class ContinuousBatchingEngine:
             raise
         self._pos[slot] = ctx
         self._tok[slot] = int(payload["last_token"])
+        if self._gen is not None:
+            # a block model's payload holds its COMMITTED blocks (the
+            # block in flight on the source is dropped, as a preemption
+            # drops it): generation goes on from there, the tokens past
+            # the committed context the first block's given head
+            self._start_block(slot,
+                              self._effective_prompt(req)[ctx:])
         if self._invariants_enabled():
             self.check_invariants()
         return req
@@ -2283,6 +2354,30 @@ class ContinuousBatchingEngine:
             if got != want or any(a.shape[0] != self.B for a in arrays):
                 errs.append(f"state layer {layer}: arrays {got} are not "
                             f"({self.B} slots of) {want}")
+        # a block model: a running slot's block lies on a block boundary
+        # inside the sequence, its given tokens are the request's own and
+        # never masked, a masked position holds the mask id; a free slot
+        # has no block in flight
+        for i, r in enumerate(self._slot_req if self._gen is not None
+                              else ()):
+            pos, given = int(self._pos[i]), int(self._blk_given[i])
+            if r is None:
+                if self._blk_masked[i].any() or given \
+                        or self._blk_passes[i]:
+                    errs.append(f"free slot {i} holds a block in flight")
+                continue
+            if pos % self._dblock or pos + self._dblock > self.S:
+                errs.append(f"slot {i}: block at {pos} is not a whole "
+                            f"block of {self._dblock} inside {self.S}")
+            if self._blk_masked[i, :given].any() or list(
+                    self._blk_ids[i, :given]) \
+                    != self._effective_prompt(r)[pos:pos + given]:
+                errs.append(f"slot {i}: the block's {given} given tokens "
+                            "are masked or not the request's")
+            if np.any(self._blk_ids[i][self._blk_masked[i]]
+                      != self._gen.mask_token_id):
+                errs.append(f"slot {i}: a masked position does not hold "
+                            f"the mask id {self._gen.mask_token_id}")
         if self._spec is not None:
             self._check_invariants_draft(errs)
         if self._tp is not None:
@@ -2435,6 +2530,11 @@ class ContinuousBatchingEngine:
         # the arrays keep the old state; the slot's next sequence
         # starts from zero by its descriptors (cache_spec.py)
         self._state_live[slot] = False
+        # a block in flight is dropped: a preempted request resumes
+        # from its last committed block
+        self._blk_masked[slot] = False
+        self._blk_given[slot] = self._blk_passes[slot] = 0
+        self._blk_t0[slot] = np.nan
         if self._prefix_enabled and req is not None and register:
             # register BEFORE the decrefs so the prompt pages never
             # transit through the free list
@@ -2550,10 +2650,14 @@ class ContinuousBatchingEngine:
         return len(shared) * self.page_size
 
     def _note_admitted(self, req: Request):
-        """An admission's prefill gave the request a token. The first
-        one is stamped once per request (a preempted request's
-        re-admission must not re-observe TTFT), telemetry on or off."""
+        """An admission's prefill gave the request a token."""
         _M_ADMISSIONS.inc()
+        self._note_first_token(req)
+
+    def _note_first_token(self, req: Request):
+        """The first token is stamped once per request (a preempted
+        request's re-admission must not re-observe TTFT), telemetry on
+        or off."""
         if req.first_token_time is not None:
             return
         req.first_token_time = self._clock()
@@ -2618,9 +2722,18 @@ class ContinuousBatchingEngine:
                 if shared:
                     self.prefix_hits += 1
                     self.prefix_tokens_reused += shared_len
+                # a block model prefills the prompt's whole blocks and
+                # samples nothing: what is left over is the given head
+                # of the first block it generates
+                whole = p_len // self._dblock * self._dblock \
+                    if self._gen is not None else p_len
+                if whole == shared_len:
+                    self._start_generation(slot, whole, prompt[whole:])
+                    continue
                 entries.append({"slot": slot, "req": req,
-                                "tokens": prompt[shared_len:],
-                                "offset": shared_len})
+                                "tokens": prompt[shared_len:whole],
+                                "offset": shared_len,
+                                "tail": prompt[whole:]})
             except PoolExhausted:
                 if self._admission_pool_exhausted(slot, req, free,
                                                   finished):
@@ -2652,7 +2765,8 @@ class ContinuousBatchingEngine:
                     else min(len(toks), budget - cur_tok)
                 cur.append({"slot": e["slot"], "req": e["req"],
                             "tokens": toks[:take], "offset": off,
-                            "sample": take == len(toks)})
+                            "sample": take == len(toks),
+                            "tail": e.get("tail")})
                 toks = toks[take:]
                 off += take
                 cur_tok += take
@@ -2668,11 +2782,13 @@ class ContinuousBatchingEngine:
         from ..ops.ragged_paged_attention import pack_ragged_batch
         bq = self._ragged_block_q
         grid = -(-self.pad // bq) * bq
+        blocks = self._gen is not None      # its prefill samples nothing
         pk = pack_ragged_batch(
             [{"seq": p["slot"], "tokens": p["tokens"],
-              "offset": p["offset"], "sample": p["sample"]}
+              "offset": p["offset"], "sample": p["sample"] and not blocks}
              for p in batch],
-            self.B, block_q=bq, pad_to=grid)
+            self.B, block_q=bq, pad_to=grid,
+            diffusion_block=self._dblock)
         t_pad = pk["t_pad"]
         # static gather trim for the XLA fallback: the batch's max page
         # demand, power-of-two bucketed so the (t_pad, bound) program
@@ -2721,7 +2837,7 @@ class ContinuousBatchingEngine:
             for p in batch:
                 self._state_live[p["slot"]] = True
         self._corrupt_kv_site()
-        if self._sentry is not None:
+        if self._sentry is not None and not blocks:
             rows = [p["slot"] for p in batch if p["sample"]]
             if rows:
                 self._sentry.observe_tokens(nxt[rows])
@@ -2730,6 +2846,11 @@ class ContinuousBatchingEngine:
             if not piece["sample"]:
                 continue
             req, s = piece["req"], piece["slot"]
+            if blocks:
+                self._start_generation(
+                    s, piece["offset"] + len(piece["tokens"]),
+                    piece["tail"])
+                continue
             self._pos[s] = piece["offset"] + len(piece["tokens"])
             tok = int(nxt[s])
             self._tok[s] = tok
@@ -2853,6 +2974,7 @@ class ContinuousBatchingEngine:
             query_start, query_len, context_len, n_rows,
             block_q=block_q, page_size=self.page_size,
             window=self._window, table_pages=self.pps,
+            diffusion_block=self._dblock,
             block_pages=kv_block_pages(
                 self.page_size, hd, hk,
                 1 if self._qkv else jnp.dtype(dt).itemsize, self.pps))
@@ -2880,7 +3002,8 @@ class ContinuousBatchingEngine:
     def _build_ragged_step(self, block_q: int, pages_bound=None,
                            draft: bool = False,
                            select_rows: bool = True,
-                           return_logits: bool = False):
+                           return_logits: bool = False,
+                           rule=None):
         """The one ragged program: packed ids -> per-token rope ->
         ONE KV scatter into the pages -> ragged paged attention with
         per-sequence descriptors -> sample each slot's designated row.
@@ -2895,7 +3018,11 @@ class ContinuousBatchingEngine:
         additionally returns the (selected) logit rows — the decode
         program's sentry variant, so the every-Nth-step numeric scan
         (serving/sentry.py) can pull them to host without a second
-        dispatch."""
+        dispatch. `rule` (a block model's pass, `_block_rule`) stands in
+        for the row select and the sampler: it is given every packed
+        row's logits, the ids, and what the caller passed in
+        `sample_rows`' place, and what it returns comes back where the
+        tokens do."""
         model = self._spec.draft_model if draft else self.model
         params = self._d_params if draft else self._params
         buffers = self._d_buffers if draft else self._buffers
@@ -2905,6 +3032,7 @@ class ContinuousBatchingEngine:
         qkv = bool(self._qkv)
         spec = _cache_spec(model) if draft else self._layer_spec
         stateful = bool(self._state_spec) and not draft
+        dblock = 1 if draft else self._dblock
 
         def run(pv, bv, kv, ids, tok_seq, qpos, qstart, qlen, ctx, bt,
                 sample_rows, key):
@@ -2923,7 +3051,8 @@ class ContinuousBatchingEngine:
                             e[0], e[1], bt, tok_seq, qpos, qstart, qlen,
                             ctx, block_q, pages_bound, tp=view_tp,
                             k_scale=e[2] if qkv else None,
-                            v_scale=e[3] if qkv else None))
+                            v_scale=e[3] if qkv else None,
+                            diffusion_block=dblock))
                     elif isinstance(s, StateSpec):
                         views.append(RaggedStateView(
                             next(states), tok_seq, qstart, qlen, ctx,
@@ -2934,10 +3063,13 @@ class ContinuousBatchingEngine:
                     Tensor(ids[None]), past_key_values=views,
                     use_cache=True)
                 rows = logits._value[0]
-                if select_rows:
-                    rows = rows[jnp.clip(sample_rows, 0,
-                                         rows.shape[0] - 1)]
-                nxt, _ = _sample_token(rows, key, strat, temp, tk, tp)
+                if rule is not None:
+                    nxt = rule(rows, ids, sample_rows)
+                else:
+                    if select_rows:
+                        rows = rows[jnp.clip(sample_rows, 0,
+                                             rows.shape[0] - 1)]
+                    nxt, _ = _sample_token(rows, key, strat, temp, tk, tp)
                 cache = [
                     (v.k_pages._value, v.v_pages._value,
                      v.k_scale._value, v.v_scale._value) if qkv
@@ -3197,6 +3329,8 @@ class ContinuousBatchingEngine:
         degrades (an armed `speculative.draft`/`speculative.verify`
         site fired) falls straight through to the plain path — the
         round still makes progress, the REQUEST never fails."""
+        if self._gen is not None:
+            return self._decode_blocks(finished)
         if self._spec is not None and self._spec_decode(finished):
             return True
         if self._decode_jit is None:
@@ -3359,6 +3493,183 @@ class ContinuousBatchingEngine:
                 self._tok[i] = nxt[i]
                 self._pos[i] += 1
         return False
+
+    # -- generation by diffusion over blocks ----------------------------
+    # (cache_spec.BlockDiffusionSpec says what a block and a pass are)
+    def _start_generation(self, slot: int, context: int, given):
+        """A block model's slot whose whole prompt blocks are in its
+        pages: it generates from position `context`, and its first block
+        starts with the prompt's left-over tokens `given`."""
+        self._pos[slot] = context
+        self._start_block(slot, given)
+        _M_ADMISSIONS.inc()
+
+    def _start_block(self, slot: int, given=()):
+        """A fresh block at the slot's position: `given` tokens, then
+        the mask."""
+        n = len(given)
+        self._blk_ids[slot] = self._gen.mask_token_id
+        self._blk_ids[slot, :n] = given
+        self._blk_masked[slot] = np.arange(self._dblock) >= n
+        self._blk_given[slot] = n
+        self._blk_passes[slot] = 0
+        self._blk_t0[slot] = np.nan
+
+    def _block_rule(self):
+        """The transfer rule as the step program runs it: every live
+        slot's next block, (slots, block + 1) int32, the ids and then
+        the masked flags as one bit a position: the pass's whole answer
+        to the host, not its (block, vocabulary) logits."""
+        gen, b = self._gen, self._dblock
+
+        def rule(rows, ids, aux):
+            masked, passes = aux
+            new_ids, new_masked = gen.transfer(
+                rows.reshape(-1, b, rows.shape[-1]), ids.reshape(-1, b),
+                masked.reshape(-1, b), passes)
+            bits = jnp.sum(new_masked.astype(jnp.int32)
+                           << jnp.arange(b, dtype=jnp.int32), axis=1)
+            return jnp.concatenate(
+                [new_ids.astype(jnp.int32), bits[:, None]], axis=1)
+
+        return rule
+
+    def _decode_blocks(self, finished: List[Request]) -> bool:
+        """One PASS over the block of every live slot: ONE dispatch of
+        the ragged program at `slots x block` rows, slot `i`'s block in
+        rows `[i block, (i + 1) block)` at positions `[pos, pos +
+        block)` with `context_len = pos + block`. The K/V scatter in
+        front of attention writes the block's rows on every pass, so the
+        last pass over a block (the one that finds no mask in it) leaves
+        what later blocks read. The program applies the transfer rule
+        (`_block_rule`); the host then advances the slots whose
+        dispatched block held no mask: their tokens go to the request,
+        their context grows by a block and a block of masks begins.
+        Slots in any pass of their block share the dispatch. Always
+        handles the step itself (returns True)."""
+        b = self._dblock
+        if self._decode_jit is None:
+            self._decode_logits = (self._sentry is not None
+                                   and self._sentry.wants_logits)
+            self._decode_jit = self._jit_singleton(
+                "decode", lambda: self._build_ragged_step(
+                    b, return_logits=self._decode_logits,
+                    rule=self._block_rule()))
+            self._decode_idx = jnp.arange(self.B, dtype=jnp.int32) * b
+        for i, r in enumerate(self._slot_req):
+            if r is not None:
+                # pages for the whole block, before the pass (within the
+                # admission reservation; a preempted slot just leaves)
+                self._grow_slot(i, finished, extra=b - 1)
+        live = [i for i, r in enumerate(self._slot_req) if r is not None]
+        if not live:
+            return True           # every slot preempted away
+        fault_point("serving.decode")
+        on = np.zeros(self.B, bool)
+        on[live] = True
+        pos = np.where(on, self._pos, 0).astype(np.int32)
+        masked = self._blk_masked & on[:, None]
+        # the slots this pass commits: their dispatched block holds no mask
+        commit = on & ~masked.any(axis=1)
+        n_commit = int(commit.sum())
+        self._blk_t0[on & np.isnan(self._blk_t0)] = self._clock()
+        rids = ([self._slot_req[i].request_id for i in live]
+                if telemetry.enabled() else ())
+        qlen = np.where(on, b, 0).astype(np.int32)
+        ctx = np.where(on, pos + b, 0).astype(np.int32)
+        if telemetry.enabled():
+            self._count_attn_pages(np.arange(self.B) * b, qlen, ctx,
+                                   self.B * b, b)
+        with telemetry.span("serving.decode_step", slots=len(live),
+                            rows=len(live) * b,
+                            commit_slots=n_commit, rids=rids):
+            # pdt-lint: disable=PDT001 decode_step_seconds measures the
+            # REAL wall time of one device dispatch incl. its D2H sync
+            t0 = time.perf_counter()
+            tok_seq = np.repeat(np.where(on, np.arange(self.B), -1), b)
+            qpos = (pos[:, None] + np.arange(b)).reshape(-1)
+            with self._tp_scope():
+                out = self._decode_jit(
+                    self._lora_pv(self._pv(),
+                                  np.repeat(self._slot_adapter, b)),
+                    self._bv(), self._cache(),
+                    jnp.asarray(self._blk_ids.reshape(-1)),
+                    jnp.asarray(tok_seq.astype(np.int32)),
+                    jnp.asarray(qpos.astype(np.int32)),
+                    self._decode_idx, jnp.asarray(qlen), jnp.asarray(ctx),
+                    jnp.asarray(self._bt),
+                    (jnp.asarray(masked.reshape(-1)),
+                     jnp.asarray(self._blk_passes)), self._next_keys())
+            nxt, lg_rows, reports = self._take_step(
+                out, self._decode_logits)
+            # pdt-lint: disable=PDT001 same real-wall measurement as t0
+            t1 = time.perf_counter()
+            _M_DECODE_DISPATCH.observe(t1 - t0)
+            nxt = self._harvest_sync(nxt)
+            # pdt-lint: disable=PDT001 same real-wall measurement
+            dt = time.perf_counter() - t0
+        # what the pass decided, and which slots' blocks are complete
+        handed = []
+        for i in live:
+            r = self._slot_req[i]
+            if not commit[i]:
+                self._blk_ids[i] = nxt[i, :b]
+                self._blk_masked[i] = (int(nxt[i, b]) >> np.arange(b)) & 1
+                self._blk_passes[i] += 1
+                continue
+            given = int(self._blk_given[i])
+            toks = [int(t) for t in self._blk_ids[i, given:]]
+            toks = toks[:r.max_new_tokens - len(r.output)]
+            if self.eos is not None and self.eos in toks:
+                toks = toks[:toks.index(self.eos) + 1]
+            r.output.extend(toks)
+            handed.extend(toks)
+            self._note_first_token(r)
+            self._pos[i] += b
+            if telemetry.enabled():
+                _M_BLOCK_SECONDS.observe(self._clock() - self._blk_t0[i])
+            if (self.eos is not None and toks and toks[-1] == self.eos) \
+                    or len(r.output) >= r.max_new_tokens \
+                    or int(self._pos[i]) + b > self.S:
+                self._finalize(r, RequestStatus.FINISHED, None, finished)
+                self._release_slot(i)
+            else:
+                self._start_block(i)
+        # counted after the step's timed part, telemetry on only
+        if telemetry.enabled():
+            _M_HARVEST.observe(dt - (t1 - t0))
+            _M_DECODE_STEP.observe(dt)
+            _M_DECODE_TOKENS.inc(len(handed))
+            _M_BLOCK_TOKENS.inc(len(handed))
+            _M_BLOCK_PASSES.inc(len(live) - n_commit, kind="denoise")
+            _M_BLOCK_PASSES.inc(n_commit, kind="commit")
+            if dt > 0:
+                _M_TOKENS_PER_SEC.set(len(handed) / dt)
+        rows = np.flatnonzero(tok_seq >= 0)
+        if reports is not None:
+            self._harvest_reports(reports, rows, tok_seq[rows], qpos[rows])
+        self._corrupt_kv_site()
+        if self._sentry is not None:
+            with telemetry.span("serving.sentry"):
+                # pdt-lint: disable=PDT001 sentry cost is REAL wall
+                s0 = time.perf_counter()
+                scan = self._sentry.step_tick()
+                # pdt-lint: disable=PDT001 same real-wall measurement
+                self._sentry.note_cost(time.perf_counter() - s0)
+                self._harvest_block_sentry(
+                    handed, lg_rows if scan else None, rows)
+        return True
+
+    def _harvest_block_sentry(self, handed, lg_rows, rows):
+        """Sentry checks over one pass: the tokens it handed out, and on
+        a scan the logits of every live row (`block` rows a live slot,
+        in slot order)."""
+        if handed:
+            self._sentry.observe_tokens(np.asarray(handed, np.int32))
+        if lg_rows is not None:
+            self._sentry.observe_logits(fault_value(
+                "serving.logits", np.asarray(lg_rows)[rows],
+                tag=self.fault_tag))
 
     # -- pipelined harvest seam (harvest_every=k, ISSUE 18) -------------
     # The _harvest_* family are the DESIGNATED host-sync functions of

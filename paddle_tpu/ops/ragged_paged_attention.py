@@ -130,7 +130,7 @@ def pack_ragged_starts(query_lens, block_q=DEFAULT_BLOCK_Q):
 
 
 def pack_ragged_batch(pieces, n_seqs, block_q=DEFAULT_BLOCK_Q,
-                      pad_to=None):
+                      pad_to=None, diffusion_block=1):
     """Pack a batch of admission/verify pieces into the descriptor +
     per-token arrays one ragged dispatch consumes. Each piece is a dict
     ``{"seq": owning sequence index, "tokens": [ids...], "offset":
@@ -152,8 +152,20 @@ def pack_ragged_batch(pieces, n_seqs, block_q=DEFAULT_BLOCK_Q,
     This is the ONE packer behind the engine's admission dispatch, the
     speculative-verify dispatch (each slot a ``query_len = k+1``
     multi-token row), and the draft-cache backfill prefills — the
-    descriptor format cannot drift between them."""
+    descriptor format cannot drift between them.
+
+    ``diffusion_block`` > 1 (a model that attends by blocks, below):
+    every piece must start and end on a multiple of it, or the call is
+    refused: a piece that ends inside a block would attend keys of its
+    block that are not written yet."""
     grid = int(pad_to) if pad_to else int(block_q)
+    for p in pieces if diffusion_block > 1 else ():
+        if p["offset"] % diffusion_block \
+                or len(p["tokens"]) % diffusion_block:
+            raise ValueError(
+                f"piece of {len(p['tokens'])} tokens at offset "
+                f"{p['offset']} does not start and end on a multiple "
+                f"of diffusion_block {diffusion_block}")
     cur = 0
     row0 = []
     for p in pieces:
@@ -271,8 +283,19 @@ def gather_pages(k_pages, v_pages, block_tables, kv_heads,
     return k_pages[bt].reshape(shape), v_pages[bt].reshape(shape)
 
 
+def block_frontier(pos, diffusion_block):
+    """The last key position a query at `pos` attends: itself under the
+    causal mask (`diffusion_block` 1, returned untouched), the end of
+    its block of `diffusion_block` positions (counted from 0) where
+    attention is by blocks: ``kpos // B <= qpos // B``. Scalars or
+    arrays, numpy or jax."""
+    if diffusion_block == 1:
+        return pos
+    return (pos // diffusion_block + 1) * diffusion_block - 1
+
+
 def masked_page_attention(q, kc, vc, q_positions, context_lens, scale,
-                          window=None):
+                          window=None, diffusion_block=1):
     """The ONE masked-attention core behind every paged XLA fallback.
 
     q: (T, HK, G, D) packed query tokens; kc/vc: (T, S, HK, D) — the
@@ -281,12 +304,15 @@ def masked_page_attention(q, kc, vc, q_positions, context_lens, scale,
     of each query token; context_lens: (T,) context length of the
     token's sequence. Token t attends keys ``k <= q_positions[t]``
     (and ``> q_positions[t] - window``), keys past the context are
-    masked, and tokens with no valid key output zero."""
+    masked, and tokens with no valid key output zero. With
+    ``diffusion_block`` B > 1 it attends every key of its own block of
+    B positions too (`block_frontier`)."""
     s_max = kc.shape[1]
     logits = jnp.einsum("tkgd,tskd->tkgs", q, kc,
                         preferred_element_type=jnp.float32) * scale
     kpos = jnp.arange(s_max)
-    valid = (kpos[None, :] <= q_positions[:, None]) \
+    frontier = block_frontier(q_positions, diffusion_block)
+    valid = (kpos[None, :] <= frontier[:, None]) \
         & (kpos[None, :] < context_lens[:, None])
     if window is not None:
         valid = valid & (kpos[None, :] > q_positions[:, None] - window)
@@ -299,7 +325,7 @@ def masked_page_attention(q, kc, vc, q_positions, context_lens, scale,
 
 def _ragged_xla(q, k_pages, v_pages, query_start, query_len, context_len,
                 block_tables, scale, window=None, pages_bound=None,
-                k_scale=None, v_scale=None):
+                k_scale=None, v_scale=None, diffusion_block=1):
     """Reference/CI path: bounded page gather + the shared masked core.
     Semantically identical to the kernel; padding rows output zero.
     ``pages_bound`` is the TRACED caller's static trim (the engine
@@ -339,7 +365,7 @@ def _ragged_xla(q, k_pages, v_pages, query_start, query_len, context_len,
     qh = q.reshape(t, hk, g, d)
     out = masked_page_attention(qh, kc[tok_seq], vc[tok_seq],
                                 jnp.where(live, tok_pos, -1), tok_ctx,
-                                scale, window)
+                                scale, window, diffusion_block)
     # quantized pools dequantized kc/vc to f32 above; match the kernel
     # path's contract (output in q's dtype) on every route
     return out.reshape(t, h, d).astype(q.dtype)
@@ -388,7 +414,7 @@ def qblock_seq(query_start, query_len, n_qblocks, block_q, xp=jnp):
 
 
 def live_kv_blocks(seq, qb_off, qlen, ctx, *, page_size, block_q,
-                   window, block_pages, xp=jnp):
+                   window, block_pages, xp=jnp, diffusion_block=1):
     """(first live page, last live page, first KV block, KV block count)
     of a q block whose rows start ``qb_off`` rows into sequence
     ``seq``'s query segment. Live pages run from the page that holds
@@ -403,7 +429,7 @@ def live_kv_blocks(seq, qb_off, qlen, ctx, *, page_size, block_q,
     last_q = ctx - qlen + xp.minimum(qb_off + block_q, qlen) - 1
     lo = 0 if window is None \
         else xp.maximum(first_q - window + 1, 0) // page_size
-    hi = last_q // page_size
+    hi = block_frontier(last_q, diffusion_block) // page_size
     live = (seq >= 0) & (qb_off < qlen)
     b0 = lo // block_pages
     return lo, hi, b0, xp.where(live, hi // block_pages - b0 + 1, 0)
@@ -411,7 +437,7 @@ def live_kv_blocks(seq, qb_off, qlen, ctx, *, page_size, block_q,
 
 def ragged_pages_walked(query_start, query_len, context_len, n_rows, *,
                         block_q, page_size, window, block_pages,
-                        table_pages):
+                        table_pages, diffusion_block=1):
     """Block-table columns the kernel's loops visit for one dispatch
     (host side, from the dispatch's own descriptors): per q block the
     columns of the KV blocks of its live page range (a last block that
@@ -426,7 +452,8 @@ def ragged_pages_walked(query_start, query_len, context_len, n_rows, *,
     qb_off = np.arange(nqb, dtype=np.int32) * block_q - qs[sc]
     _, _, b0, n_blocks = live_kv_blocks(
         seq, qb_off, ql[sc], cl[sc], page_size=page_size,
-        block_q=block_q, window=window, block_pages=block_pages, xp=np)
+        block_q=block_q, window=window, block_pages=block_pages, xp=np,
+        diffusion_block=diffusion_block)
     end = np.minimum((b0 + n_blocks) * block_pages, table_pages)
     return int(np.where(n_blocks > 0, end - b0 * block_pages, 0).sum())
 
@@ -442,7 +469,8 @@ def _operand_dtype(q_dtype, pool_dtype):
 
 def _ragged_kernel(qb_seq_ref, qstart_ref, qlen_ref, ctx_ref, bt_ref,
                    q_ref, k_hbm, v_hbm, *rest, scale, page_size,
-                   block_q, group, window, block_pages, quantized=False):
+                   block_q, group, window, block_pages, quantized=False,
+                   diffusion_block=1):
     # quantized page pools (int8 storage) add the two per-page-row
     # scale pools, gathered to one (1, keys) f32 row a KV block of each
     # sequence; the dequant folds into the existing multiplies — logits
@@ -468,7 +496,8 @@ def _ragged_kernel(qb_seq_ref, qstart_ref, qlen_ref, ctx_ref, bt_ref,
     first_q = ctx - qlen + qb_off                  # global pos of row 0
     lo_page, hi_page, block0, n_trips = live_kv_blocks(
         s, qb_off, qlen, ctx, page_size=page_size, block_q=block_q,
-        window=window, block_pages=block_pages)
+        window=window, block_pages=block_pages,
+        diffusion_block=diffusion_block)
 
     acc_ref[:] = jnp.zeros_like(acc_ref)
     m_ref[:] = jnp.full_like(m_ref, NEG_INF)
@@ -522,7 +551,12 @@ def _ragged_kernel(qb_seq_ref, qstart_ref, qlen_ref, ctx_ref, bt_ref,
             + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
         row = jax.lax.broadcasted_iota(jnp.int32, shape, 0) // group
         qpos = first_q + row
-        valid = (kpos <= qpos) & (qb_off + row < qlen)
+        # by blocks, a row attends to the end of its own block: keys
+        # the dispatch's own scatter wrote, never past the context
+        valid = (kpos <= block_frontier(qpos, diffusion_block)) \
+            & (qb_off + row < qlen)
+        if diffusion_block > 1:
+            valid = valid & (kpos < ctx)
         if window is not None:
             valid = valid & (kpos > qpos - window)
         if quantized:
@@ -600,10 +634,11 @@ def _block_scale_rows(scale_pool, block_tables, block_pages):
 # one function the layers call): traced and lowered a layer at a time
 # the kernel's body cost a 24-layer program 24 times its 0.4 s
 @functools.partial(jax.jit, static_argnames=(
-    "scale", "window", "block_q", "interpret"))
+    "scale", "window", "block_q", "interpret", "diffusion_block"))
 def _ragged_pallas(q, k_pages, v_pages, query_start, query_len,
                    context_len, block_tables, scale, window, block_q,
-                   interpret, k_scale=None, v_scale=None):
+                   interpret, k_scale=None, v_scale=None,
+                   diffusion_block=1):
     t, h, d = q.shape
     hk = k_pages.shape[2] // d
     if k_pages.shape[2] % LANES:
@@ -662,7 +697,9 @@ def _ragged_pallas(q, k_pages, v_pages, query_start, query_len,
         functools.partial(_ragged_kernel, scale=scale,
                           page_size=page_size, block_q=block_q, group=g,
                           window=window, block_pages=block_pages,
-                          quantized=quantized),
+                          quantized=quantized,
+                          **({"diffusion_block": diffusion_block}
+                             if diffusion_block > 1 else {})),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((hk, nqb, block_q * g, d),
                                        q.dtype),
@@ -678,7 +715,7 @@ def _ragged_pallas(q, k_pages, v_pages, query_start, query_len,
 def _ragged_tp_shard_map(q, k_pages, v_pages, query_start, query_len,
                          context_len, block_tables, scale, window,
                          block_q, interpret, tp, k_scale=None,
-                         v_scale=None):
+                         v_scale=None, diffusion_block=1):
     """The Pallas kernel under tensor parallelism (serving/submesh.py):
     heads are data-parallel in attention, so each TP shard runs the
     UNCHANGED kernel over its local (H/tp, HK/tp) heads via shard_map —
@@ -698,7 +735,8 @@ def _ragged_tp_shard_map(q, k_pages, v_pages, query_start, query_len,
         ks, vs = scales if quantized else (None, None)
         return _ragged_pallas(qq, kp, vp, qs, ql, cl, bt, scale,
                               window, block_q, interpret,
-                              k_scale=ks, v_scale=vs)
+                              k_scale=ks, v_scale=vs,
+                              diffusion_block=diffusion_block)
 
     # a shard's heads are a contiguous run of every stored row's lanes
     in_specs = (P(None, axis, None), P(None, None, axis),
@@ -729,7 +767,8 @@ def ragged_paged_attention_values(q, k_pages, v_pages, query_start,
                                   scale=None, window=None,
                                   block_q=DEFAULT_BLOCK_Q,
                                   use_kernel=None, pages_bound=None,
-                                  tp=None, k_scale=None, v_scale=None):
+                                  tp=None, k_scale=None, v_scale=None,
+                                  diffusion_block=1):
     """q: (T, H, D) packed ragged queries; k_pages/v_pages:
     (P, page_size, HK*D), token-major (the module docstring says why);
     query_start/query_len/context_len: (N,) int32 per-sequence
@@ -737,6 +776,14 @@ def ragged_paged_attention_values(q, k_pages, v_pages, query_start,
     ``context_len[s] - query_len[s] + j`` and attends its sequence's
     pages causally (band-limited by ``window`` when set).  Returns
     (T, H, D); padding rows (owned by no sequence) return zero.
+
+    ``diffusion_block`` B > 1 (static; a model that generates by
+    diffusion over blocks, `models/cache_spec.BlockDiffusionSpec`): the
+    mask is causal over BLOCKS of B positions counted from 0, a row
+    attends every key of its own block, ``kpos // B <= qpos // B``, and
+    a q block's walk ends at the block of its last row's frontier. Every
+    segment must start and end on a multiple of B (`pack_ragged_batch`
+    checks). At 1 every path is what it was, operation for operation.
 
     ``use_kernel``: None routes by platform (Pallas on TPU, the bounded
     XLA gather oracle elsewhere); True forces the Pallas kernel — in
@@ -788,7 +835,8 @@ def ragged_paged_attention_values(q, k_pages, v_pages, query_start,
         return _ragged_xla(q, k_pages, v_pages, query_start, query_len,
                            context_len, block_tables, sc, window,
                            pages_bound=pages_bound, k_scale=k_scale,
-                           v_scale=v_scale)
+                           v_scale=v_scale,
+                           diffusion_block=diffusion_block)
     if t % block_q:
         raise ValueError(f"packed length {t} not a multiple of "
                          f"block_q {block_q}")
@@ -797,11 +845,13 @@ def ragged_paged_attention_values(q, k_pages, v_pages, query_start,
                                     query_len, context_len,
                                     block_tables, sc, window, block_q,
                                     _interpret(), tp, k_scale=k_scale,
-                                    v_scale=v_scale)
+                                    v_scale=v_scale,
+                                    diffusion_block=diffusion_block)
     return _ragged_pallas(q, k_pages, v_pages, query_start, query_len,
                           context_len, block_tables, sc, window,
                           block_q, _interpret(), k_scale=k_scale,
-                          v_scale=v_scale)
+                          v_scale=v_scale,
+                          diffusion_block=diffusion_block)
 
 
 def _row_targets(k_pages, block_tables, token_seq, positions):
@@ -881,7 +931,8 @@ def ragged_scatter_quantized(k_pages, v_pages, k_scale, v_scale,
 def ragged_paged_attention(q: Tensor, k_pages: Tensor, v_pages: Tensor,
                            query_start, query_len, context_len,
                            block_tables, scale=None, window=None,
-                           block_q=DEFAULT_BLOCK_Q) -> Tensor:
+                           block_q=DEFAULT_BLOCK_Q,
+                           diffusion_block=1) -> Tensor:
     """Eager/tape entry. Serving-only: no grad path."""
     qs = query_start._value if isinstance(query_start, Tensor) \
         else jnp.asarray(query_start, jnp.int32)
@@ -893,6 +944,7 @@ def ragged_paged_attention(q: Tensor, k_pages: Tensor, v_pages: Tensor,
         else jnp.asarray(block_tables, jnp.int32)
 
     def fn(qq, kk, vv):
-        return ragged_paged_attention_values(qq, kk, vv, qs, ql, cl, bt,
-                                             scale, window, block_q)
+        return ragged_paged_attention_values(
+            qq, kk, vv, qs, ql, cl, bt, scale, window, block_q,
+            diffusion_block=diffusion_block)
     return apply("ragged_paged_attention", fn, (q, k_pages, v_pages))

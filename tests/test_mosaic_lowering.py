@@ -455,6 +455,33 @@ class TestQuantMatmulLowering:
         _lower(lambda x, qw, sc: dequant_matmul_values(x, qw, sc),
                x, qw, sc)
 
+    # the block-diffusion cell (ISSUE 31): 32 Q on 4 KV heads, 64 slots
+    # of 256 columns; a pass is 4 rows a slot at block_q 4, an admission
+    # 512 rows at block_q 8, both under the mask by blocks of 4
+    BLOCK_CELL_SHAPES = {
+        "generate.pass": (32, 4, 64, 256, 256, 4),
+        "generate.admit512": (32, 4, 64, 512, 256, 8),
+    }
+
+    @pytest.mark.parametrize("shape", sorted(BLOCK_CELL_SHAPES))
+    def test_block_cell_shapes(self, shape):
+        """`diffusion_block` is a static parameter of the one kernel:
+        the frontier of a row is the end of its block."""
+        from paddle_tpu.ops.ragged_paged_attention import \
+            ragged_paged_attention_values
+
+        h, hk, slots, rows, pps, block_q = self.BLOCK_CELL_SHAPES[shape]
+        d, page_size = 128, 16
+        q = jnp.zeros((rows, h, d), jnp.bfloat16)
+        kp = jnp.zeros((slots * pps + 1, page_size, hk * d), jnp.bfloat16)
+        i32 = jnp.zeros((slots,), jnp.int32)
+        bt = jnp.zeros((slots, pps), jnp.int32)
+        kernels = _lower(
+            lambda q, kp, vp, qs, ql, cl, bt: ragged_paged_attention_values(
+                q, kp, vp, qs, ql, cl, bt, block_q=block_q,
+                diffusion_block=4), q, kp, kp, i32, i32, i32, bt)
+        assert kernels.get("ragged_paged_attention") == 1, kernels
+
 
 class TestGroupedMatmulLowering:
     def test_grouped(self):
@@ -483,6 +510,26 @@ class TestGroupedMatmulLowering:
                                                    row_block)
         held, top_k = 128, 22
         bm = row_block(rows * top_k / 512)
+        m = -(-(rows * top_k + held * (bm - 1)) // bm) * bm
+        x = jnp.zeros((m, k), jnp.bfloat16)
+        w = jnp.zeros((held, k, n), jnp.bfloat16)
+        sizes = jnp.full((held,), bm, jnp.int32)
+        assert _lower(
+            lambda x, w, sizes: grouped_matmul_values(x, w, sizes, bm),
+            x, w, sizes) == {"grouped_matmul": 1}
+
+    @pytest.mark.parametrize("rows,k,n", [
+        (256, 2048, 1536), (256, 768, 2048),       # a pass: 64 slots x 4
+        (512, 2048, 1536), (512, 768, 2048)])      # an admission
+    def test_swiglu_expert_layer_shapes(self, rows, k, n):
+        """ISSUE 31: the SwiGLU expert layer of the block-diffusion
+        cell: all 128 experts held, 8 choices a row, gate and up one
+        operand of 2 x 768 columns; groups padded to `row_block` (16
+        rows at a pass, 32 at an admission)."""
+        from paddle_tpu.ops.grouped_matmul import (grouped_matmul_values,
+                                                   row_block)
+        held, top_k = 128, 8
+        bm = row_block(rows * top_k / held)
         m = -(-(rows * top_k + held * (bm - 1)) // bm) * bm
         x = jnp.zeros((m, k), jnp.bfloat16)
         w = jnp.zeros((held, k, n), jnp.bfloat16)
@@ -539,4 +586,3 @@ class TestKernelsUnderAMesh:
         assert set(found) == {"flash_fwd", "flash_bwd_dq",
                               "flash_bwd_dkv", "rms_norm_fwd",
                               "rms_norm_bwd"}
-
