@@ -421,7 +421,7 @@ def latent_experts_values(a, live, w_r, b_corr, w_down, w1, w2, w_up, w1_s,
     """The `E` mixer over packed rows a (T, hidden); `live` (T,) marks
     the rows that are tokens. w1 (held, latent, width) and w2 (held,
     width, latent) are the experts held here. Returns (out (T, hidden),
-    counts int32 (4,) in `NemotronHExperts.cache_spec`'s order, chosen
+    counts int32 (6,) in `NemotronHExperts.cache_spec`'s order, chosen
     int32 (T, k): each row's experts)."""
     from paddle_tpu.ops.grouped_matmul import grouped_matmul_values
     dtype = a.dtype

@@ -10,7 +10,7 @@ no rows), sorts the rest by expert and pads every expert's rows to the
 kernel's row tile; `combine_rows` gathers the experts' outputs back to
 their rows and adds them under the router's weights; `report_spec` /
 `report_counts` are the `cache_spec.ReportSpec` and the vector of counts
-both layers hand the engine, over the two counters defined here.
+both layers hand the engine, over the three counters defined here.
 """
 from __future__ import annotations
 
@@ -36,14 +36,23 @@ _M_MOE_EXPERTS = telemetry.counter(
     "Held experts a dispatch, summed over the expert layers, by kind: "
     "hit = got at least one row (its weights were read), idle = got "
     "none.", ("kind",))
+_M_MOE_ROW_TILES = telemetry.counter(
+    "pdt_serving_moe_row_tiles_total",
+    "Live row tiles of a dispatch's grouped matmuls (an expert's rows "
+    "padded to the kernel's row tile), summed over the expert layers, "
+    "by kind: first = the first tile of its expert (the tile that "
+    "fetches the expert's weights; equal to the experts hit), further "
+    "= a tile behind another of the same expert, which runs on the "
+    "weight block already fetched.", ("kind",))
 
 
 def report_spec(k: int) -> ReportSpec:
     """What a routed expert layer of `k` choices a row reports: the
-    four counts of `report_counts`, and each row's chosen experts."""
+    six counts of `report_counts`, and each row's chosen experts."""
     return ReportSpec(
         ((_M_MOE_ASSIGNMENTS, "local"), (_M_MOE_ASSIGNMENTS, "remote"),
-         (_M_MOE_EXPERTS, "hit"), (_M_MOE_EXPERTS, "idle")), (k,))
+         (_M_MOE_EXPERTS, "hit"), (_M_MOE_EXPERTS, "idle"),
+         (_M_MOE_ROW_TILES, "first"), (_M_MOE_ROW_TILES, "further")), (k,))
 
 
 class Routed(NamedTuple):
@@ -94,9 +103,12 @@ def combine_rows(out_rows, wts, r: Routed):
 
 
 def report_counts(r: Routed, live, k: int):
-    """int32 (4,): assignments local and remote, held experts hit and
-    idle, in `report_spec`'s order."""
+    """int32 (6,): assignments local and remote, held experts hit and
+    idle, live row tiles that are the first of their expert and those
+    behind another of the same expert, in `report_spec`'s order."""
     held = r.counts.shape[0]
     n_local, n_hit = jnp.sum(r.counts), jnp.sum(r.counts > 0)
     return jnp.stack([n_local, jnp.sum(live) * k - n_local,
-                      n_hit, held - n_hit]).astype(jnp.int32)
+                      n_hit, held - n_hit, n_hit,
+                      jnp.sum(r.padded // r.block_m) - n_hit]
+                     ).astype(jnp.int32)

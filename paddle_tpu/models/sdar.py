@@ -108,7 +108,7 @@ def swiglu_experts_values(a, live, w_r, w_gu, w_down, *,
     """The expert layer over packed rows a (T, hidden); `live` (T,)
     marks the rows that are tokens. w_gu (held, hidden, 2 width) holds
     each held expert's gate and up side by side, w_down (held, width,
-    hidden). Returns (out (T, hidden), counts int32 (4,) in
+    hidden). Returns (out (T, hidden), counts int32 (6,) in
     `SdarMoeExperts.cache_spec`'s order, chosen int32 (T, k))."""
     from paddle_tpu.ops.grouped_matmul import grouped_matmul_values
     dtype = a.dtype

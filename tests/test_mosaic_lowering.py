@@ -504,8 +504,8 @@ class TestGroupedMatmulLowering:
     def test_expert_layer_shapes(self, rows, k, n):
         """ISSUE 27: the latent expert layer of the reasoning cell: 128
         held experts of 512, 22 choices a row, groups padded to
-        `row_block` (16 rows at decode, 64 at an admission), tiles of
-        1024 and 896 that divide 2688."""
+        `row_block` (16 rows at decode, 64 at an admission), an expert's
+        whole (K, N) a weight block."""
         from paddle_tpu.ops.grouped_matmul import (grouped_matmul_values,
                                                    row_block)
         held, top_k = 128, 22
@@ -520,12 +520,13 @@ class TestGroupedMatmulLowering:
 
     @pytest.mark.parametrize("rows,k,n", [
         (256, 2048, 1536), (256, 768, 2048),       # a pass: 64 slots x 4
-        (512, 2048, 1536), (512, 768, 2048)])      # an admission
+        (512, 2048, 1536), (512, 768, 2048),       # an admission
+        (1024, 2048, 1536), (1024, 768, 2048)])    # two chunks of one
     def test_swiglu_expert_layer_shapes(self, rows, k, n):
         """ISSUE 31: the SwiGLU expert layer of the block-diffusion
         cell: all 128 experts held, 8 choices a row, gate and up one
         operand of 2 x 768 columns; groups padded to `row_block` (16
-        rows at a pass, 32 at an admission)."""
+        rows at a pass, 32 and 64 at an admission)."""
         from paddle_tpu.ops.grouped_matmul import (grouped_matmul_values,
                                                    row_block)
         held, top_k = 128, 8
@@ -537,6 +538,56 @@ class TestGroupedMatmulLowering:
         assert _lower(
             lambda x, w, sizes: grouped_matmul_values(x, w, sizes, bm),
             x, w, sizes) == {"grouped_matmul": 1}
+
+
+    # ISSUE 32: the stationary grid holds the whole of K and an n block
+    # of an expert's weights in VMEM, twice (the pipeline's two
+    # buffers). (K, N, dtype) -> the n block the kernel derives.
+    SERVED_WEIGHT_BLOCKS = [
+        (2048, 1536, "bfloat16", 1536),     # generate: gate and up
+        (768, 2048, "bfloat16", 2048),      # generate: down
+        (1024, 2688, "bfloat16", 2688),     # reasoning: up
+        (2688, 1024, "bfloat16", 1024),     # reasoning: down
+        (1536, 2048, "bfloat16", 2048),     # d(lhs) of gate and up
+        (2048, 1536, "float32", 768),       # float32 weights: half
+        (8192, 8192, "bfloat16", 256),
+    ]
+
+    @pytest.mark.parametrize("k,n,dtype,want", SERVED_WEIGHT_BLOCKS)
+    def test_weight_block_follows_k_n_and_dtype(self, k, n, dtype, want):
+        """The largest 128-multiple dividing N whose two buffers fit the
+        budget the kernel states, and the v5e compile takes it at a
+        16-row and at a 64-row tile."""
+        from paddle_tpu.ops import grouped_matmul as gm
+        size = jnp.dtype(dtype).itemsize
+        assert gm._weight_block_n(k, n, size) == want
+        assert 2 * k * want * size <= gm.WEIGHT_VMEM_BUDGET
+        assert all(2 * k * t * size > gm.WEIGHT_VMEM_BUDGET
+                   for t in range(want + 128, n + 1, 128) if n % t == 0)
+        for bm in (16, 64):
+            x = jnp.zeros((8 * bm, k), dtype)
+            w = jnp.zeros((4, k, n), dtype)
+            sizes = jnp.full((4,), bm, jnp.int32)
+            assert _lower(
+                lambda x, w, sizes: gm.grouped_matmul_values(
+                    x, w, sizes, bm), x, w, sizes) == {"grouped_matmul": 1}
+
+    @pytest.mark.parametrize("rows,k,n", [
+        (256, 2048, 1536), (256, 768, 2048), (512, 2048, 1536)])
+    def test_swiglu_input_gradient_shapes(self, rows, k, n):
+        """The custom vjp's d(lhs) at the served tile: the same kernel
+        on the transposed weights, one `grouped_matmul` call."""
+        from paddle_tpu.ops import grouped_matmul as gm
+        held, top_k = 128, 8
+        bm = gm.row_block(rows * top_k / held)
+        m = -(-(rows * top_k + held * (bm - 1)) // bm) * bm
+        dout = jnp.zeros((m, n), jnp.bfloat16)
+        w = jnp.zeros((held, k, n), jnp.bfloat16)
+        sizes = jnp.full((held,), bm, jnp.int32)
+        assert _lower(
+            lambda dout, w, sizes: gm._gmm(
+                dout, jnp.swapaxes(w, 1, 2), sizes, bm),
+            dout, w, sizes) == {"grouped_matmul": 1}
 
 
 class TestLoraEpilogueLowering:

@@ -127,7 +127,7 @@ def test_cache_spec_states_what_each_block_keeps():
                                        ReportSpec, StateSpec]
     assert spec[1].row_record == (cfg.num_experts_per_tok,)
     assert [kind for _, kind in spec[1].counters] == [
-        "local", "remote", "hit", "idle"]
+        "local", "remote", "hit", "idle", "first", "further"]
     assert spec[2] == KVSpec(2, 16)
     assert spec[0].shapes == ((3, 32 + 2 * 2 * 16), (4, 8, 16))
     assert spec[0].dtypes == ("float32", "float32")
@@ -241,7 +241,8 @@ def test_four_shares_add_up_to_the_uncut_layer():
     counters = np.stack(counters)
     assert counters[:, 0].sum() == 23 * cfg.num_experts_per_tok
     assert (counters[:, :2].sum(1) == 23 * cfg.num_experts_per_tok).all()
-    assert (counters[:, 2:].sum(1) == 4).all()
+    assert (counters[:, 2:4].sum(1) == 4).all()
+    assert (counters[:, 4] == counters[:, 2]).all()
 
 
 # -- through the engine ----------------------------------------------------
@@ -363,6 +364,11 @@ def test_expert_counters_and_state_gauges(monkeypatch):
     assert 0 < a['kind="local"'] < a['kind="remote"']
     # one admission and three decode dispatches of 8 held x 2 layers
     assert e['kind="hit"'] + e['kind="idle"'] == 4 * 8 * 2
+    # the engine adds the specification's every entry: a hit expert's
+    # first row tile, and none further (a handful of rows on 16-row tiles)
+    tiles = snap["counters"]["pdt_serving_moe_row_tiles_total"]
+    assert tiles['kind="first"'] == e['kind="hit"']
+    assert tiles.get('kind="further"', 0) == 0
     assert snap["gauges"]["pdt_serving_state_bytes"][""] == \
         2 * sum(s.nbytes() for s in eng._state_spec)
     assert snap["gauges"]["pdt_serving_state_slots_live"][""] == 0
@@ -434,32 +440,152 @@ def test_row_block_follows_the_rows_a_group_holds():
         [16, 16, 16, 32, 64, 128]
 
 
-@pytest.mark.parametrize("path", ["aligned", "kernel", "any_size"])
-def test_grouped_matmul_at_sixteen_rows_a_tile(path):
+# groups padded to 16 rows: tiles a group, empty groups, dead tiles
+_PADDED = [16, 0, 32, 0, 0, 16, 48, 0]
+_GMM_CASES = {
+    # case: (path, group sizes, rows, K, N, n block)
+    "aligned": ("aligned", _PADDED, 160, 128, 256, None),
+    "kernel": ("kernel", _PADDED, 160, 128, 256, None),
+    "any_size": ("any_size", [5, 0, 37, 0, 0, 16, 51, 3], 160, 128, 256,
+                 None),
+    # the stationary grid: more than one n block, K as the served
+    # widths have it (768 and 2688, an eighth of each), groups of one,
+    # two and three tiles with empty groups between them, no dead tile,
+    # nothing but dead tiles, and the 128-row grid at 16 rows for the
+    # same answer
+    "kernel_two_n_blocks": ("kernel", _PADDED, 160, 128, 256, 128),
+    "kernel_k_768": ("kernel", _PADDED, 160, 96, 256, 128),
+    "kernel_k_2688": ("kernel", _PADDED, 160, 336, 384, 128),
+    "kernel_last_group_live": ("kernel", [0, 48, 0, 16, 32], 96, 128, 256,
+                               128),
+    "kernel_one_group": ("kernel", [0, 0, 32, 0], 64, 128, 128, None),
+    "kernel_all_dead": ("kernel", [0, 0, 0], 32, 128, 256, 128),
+    "kernel_row_tile_outermost": ("outer", _PADDED, 160, 128, 256, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_GMM_CASES))
+def test_grouped_matmul_at_sixteen_rows_a_tile(case):
     """Against a per-group oracle: groups padded to 16 rows, some
     empty, dead tiles at the end, through the XLA walk and through the
-    kernel (interpret mode); and groups of any size, through the walk
+    kernels (interpret mode); and groups of any size, through the walk
     at block_m 0."""
     from paddle_tpu.ops import grouped_matmul as gm
+    path, sizes, m, k, n, block_n = _GMM_CASES[case]
     rng = np.random.default_rng(0)
-    sizes = np.array([5, 0, 37, 0, 0, 16, 51, 3] if path == "any_size"
-                     else [16, 0, 32, 0, 0, 16, 48, 0], np.int32)
-    m, k, n = 160, 128, 256
+    sizes = np.array(sizes, np.int32)
     lhs = rng.standard_normal((m, k)).astype(np.float32)
-    rhs = rng.standard_normal((8, k, n)).astype(np.float32)
+    rhs = rng.standard_normal((len(sizes), k, n)).astype(np.float32)
     want, row = np.zeros((m, n), np.float32), 0
     for g, size in enumerate(sizes):
         want[row:row + size] = lhs[row:row + size] @ rhs[g]
         row += size
+    args = jnp.asarray(lhs), jnp.asarray(rhs), jnp.asarray(sizes)
     if path == "kernel":
-        got = gm.gmm_pallas(jnp.asarray(lhs), jnp.asarray(rhs),
-                            jnp.asarray(sizes), block_m=16, interpret=True)
+        got = gm.gmm_stationary(*args, block_m=16, block_n=block_n,
+                                interpret=True)
+    elif path == "outer":
+        got = gm.gmm_pallas(*args, block_m=16, interpret=True)
     else:
-        got = gm.grouped_matmul_values(
-            jnp.asarray(lhs), jnp.asarray(rhs), jnp.asarray(sizes),
-            16 if path == "aligned" else 0)
+        got = gm.grouped_matmul_values(*args, 16 if path == "aligned" else 0)
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-3)
     assert not np.asarray(got[row:]).any()
+
+
+def test_grouped_matmul_input_gradient_at_sixteen_rows_a_tile(monkeypatch):
+    """The custom vjp's d(lhs) goes through the kernel at the tile it
+    was given: `_gmm` on the transposed weights. The kernel path (what
+    a TPU takes, here in interpret mode) against the XLA walk's."""
+    import functools
+    from paddle_tpu.ops import grouped_matmul as gm
+    rng = np.random.default_rng(1)
+    sizes = jnp.asarray(_PADDED, jnp.int32)
+    lhs = jnp.asarray(rng.standard_normal((160, 128)), jnp.float32)
+    rhs = jnp.asarray(rng.standard_normal((8, 128, 256)), jnp.float32)
+
+    def dlhs():
+        return jax.grad(lambda x: jnp.sum(
+            gm.grouped_matmul_values(x, rhs, sizes, 16) ** 2))(lhs)
+
+    want = dlhs()
+    calls = []
+
+    def kernel(*args, **kw):
+        calls.append(args[1].shape)
+        return gm_stationary(*args, interpret=True, **kw)
+
+    gm_stationary = gm.gmm_stationary
+    monkeypatch.setattr(gm, "on_tpu", lambda: True)
+    monkeypatch.setattr(gm, "gmm_stationary", kernel)
+    got = dlhs()
+    # forward on (E, K, N), then d(lhs) on (E, N, K)
+    assert calls == [(8, 128, 256), (8, 256, 128)]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-2)
+    assert not np.asarray(got[112:]).any()
+
+
+def _weight_fetches(counts, block_m, n_blocks, tiles):
+    """Walk `gmm_stationary`'s grid in order through the kernel's own
+    `_weight_copies` and return the experts whose block a sweep
+    copies, in order. Checked on the way: a tile computes from a buffer
+    that holds its expert's block, waited for, and no copy lands in a
+    buffer whose run is not over."""
+    from paddle_tpu.ops import grouped_matmul as gm
+    padded = -(-np.asarray(counts) // block_m) * block_m
+    te, first, slot, ahead, live = (np.asarray(a) for a in gm._run_map(
+        jnp.asarray(padded, jnp.int32), tiles, block_m))
+    assert live[0] == padded.sum() // block_m <= tiles
+    assert first.sum() == (padded > 0).sum()
+    sweeps = []
+    for _ in range(n_blocks):
+        copied, holds, landed = [], [None, None], [False, False]
+        for i in range(tiles):
+            starts, (waits, expert, buffer) = gm._weight_copies(
+                i, te, first, slot, ahead, live)
+            for on, e, b in starts:
+                if on:
+                    # the run that computed from this buffer is over
+                    assert i == 0 or slot[i] != b
+                    copied.append(int(e))
+                    holds[b], landed[b] = int(e), False
+            if waits:
+                assert holds[buffer] == expert and not landed[buffer]
+                landed[buffer] = True
+            if i < live[0]:
+                assert holds[slot[i]] == te[i] and landed[slot[i]]
+        assert landed == [h is not None for h in holds]   # none in flight
+        sweeps.append(copied)
+    return sweeps
+
+
+@pytest.mark.parametrize("draw", ["even", "skewed", "last_expert_idle",
+                                  "one_expert", "none"])
+def test_an_experts_weights_are_copied_once_an_n_block(draw):
+    """The property the stationary grid is for: over a sweep of the
+    row tiles each HIT expert's weight block is copied exactly once,
+    in order, however many row tiles its rows take; dead tiles and
+    idle experts copy nothing."""
+    rng = np.random.default_rng(3)
+    experts, block_m = 128, 16
+    p = {"even": np.full(experts, 1 / experts),
+         "skewed": (lambda z: z / z.sum())(
+             1 / np.arange(1, experts + 1) ** 1.2)}.get(draw)
+    if p is not None:
+        counts = rng.multinomial(2048, p)
+    elif draw == "last_expert_idle":
+        counts = np.r_[rng.integers(1, 60, experts - 3), 0, 0, 0]
+    elif draw == "one_expert":
+        counts = np.r_[np.zeros(40, int), 100, np.zeros(experts - 41, int)]
+    else:
+        counts = np.zeros(experts, int)
+    tiles = (int(counts.sum()) + experts * (block_m - 1)) // block_m + 1
+    hit = np.nonzero(counts)[0].tolist()
+    further = int((-(-counts // block_m)).sum()) - len(hit)
+    assert further > 0 or draw == "none"
+    for n_blocks in (1, 2, 3):
+        assert _weight_fetches(counts, block_m, n_blocks, tiles) \
+            == [hit] * n_blocks
 
 
 # -- the benchmark's arithmetic for this model ---------------------------------

@@ -5,6 +5,7 @@ model that generates by diffusion over blocks (models/cache_spec.py
 slot, a slot advances when its block holds no mask. Tiny widths, CPU,
 float32. conftest runs the engine cases with PDT_CHECK_INVARIANTS=1."""
 import hashlib
+import json
 import os
 import sys
 import types
@@ -414,7 +415,8 @@ def test_four_shares_add_up_to_the_uncut_layer():
     counters = np.stack(counters)
     assert counters[:, 0].sum() == 23 * cfg.num_experts_per_tok
     assert (counters[:, :2].sum(1) == 23 * cfg.num_experts_per_tok).all()
-    assert (counters[:, 2:].sum(1) == 4).all()
+    assert (counters[:, 2:4].sum(1) == 4).all()
+    assert (counters[:, 4] == counters[:, 2]).all()
 
 
 def test_both_expert_layers_share_one_routed_dispatch():
@@ -435,9 +437,45 @@ def test_both_expert_layers_share_one_routed_dispatch():
     assert (src[dest[rows, ks]] == rows).all()
     stats = np.asarray(routed.report_counts(r, live, 4))
     assert stats.tolist() == [counts.sum(), 20 * 4 - counts.sum(),
-                              (counts > 0).sum(), 8 - (counts > 0).sum()]
+                              (counts > 0).sum(), 8 - (counts > 0).sum(),
+                              (counts > 0).sum(),
+                              (-(-counts // r.block_m)).sum()
+                              - (counts > 0).sum()]
     for fn in (nh.latent_experts_values, sdar.swiglu_experts_values):
         assert "route_rows" in fn.__code__.co_names
+
+
+@pytest.mark.parametrize("draw", ["even", "skewed"])
+def test_row_tile_counts_against_numpy(draw):
+    """`report_counts`' row tiles: `first` = live row tiles that are
+    the first of their expert (the experts hit), `further` = live row
+    tiles behind another of the same expert; rows held elsewhere and
+    dead rows take no tile."""
+    from paddle_tpu.models import routed
+    rng = np.random.default_rng(5)
+    t, k, n_experts, held, offset = 256, 8, 128, 96, 16
+    p = np.full(n_experts, 1 / n_experts) if draw == "even" else \
+        (lambda z: z / z.sum())(1 / np.arange(1, n_experts + 1.0))
+    chosen = np.stack([rng.choice(n_experts, k, replace=False, p=p)
+                       for _ in range(t)])
+    live = np.arange(t) < 230
+    r = routed.route_rows(jnp.asarray(chosen), jnp.asarray(live),
+                          held=held, offset=offset, n_experts=n_experts)
+    assert r.block_m == 16
+    local = chosen[live] - offset
+    counts = np.bincount(local[(local >= 0) & (local < held)],
+                         minlength=held)
+    tiles = -(-counts // 16)
+    stats = np.asarray(routed.report_counts(r, jnp.asarray(live), k))
+    assert stats[4] == (counts > 0).sum() == stats[2]
+    assert stats[5] == tiles.sum() - (counts > 0).sum()
+    assert stats[5] > 0 and stats[4] + stats[5] == np.asarray(
+        r.padded).sum() // r.block_m
+    spec = routed.report_spec(k)
+    assert [(c.name, kind) for c, kind in spec.counters[4:]] == [
+        ("pdt_serving_moe_row_tiles_total", "first"),
+        ("pdt_serving_moe_row_tiles_total", "further")]
+    assert len(spec.counters) == stats.shape[0]
 
 
 # -- (e) preemption and migration mid-block --------------------------------
@@ -537,6 +575,7 @@ def test_block_counters(monkeypatch):
     model, cfg = _model()
     monkeypatch.setenv("PDT_TELEMETRY", "1")     # as conftest's fixture
     telemetry.reset()
+    telemetry.clear_events()     # an earlier file's spans, same worker
     eng, _ = _engine(model, max_batch_size=2)
     for p in _prompts(cfg, (12, 8)):
         eng.add_request(p, max_new_tokens=8)
@@ -562,6 +601,8 @@ def test_block_counters(monkeypatch):
     e = snap["counters"]["pdt_serving_moe_experts_total"]
     # (two admission dispatches: 20 prompt rows at a chunk of 16)
     assert e['kind="hit"'] + e['kind="idle"'] == (2 + 10) * 16 * 2
+    tiles = snap["counters"]["pdt_serving_moe_row_tiles_total"]
+    assert tiles['kind="first"'] == e['kind="hit"']
 
 
 # -- (h) the configuration, the pass's bytes, the readers, the check --------
@@ -657,6 +698,19 @@ def test_block_readers_on_a_hand_made_window():
     assert counter_ratio.read(
         obs, counter="pdt_serving_block_passes_total",
         numerator=['kind="commit"'], scale=100.0) == pytest.approx(20.0)
+    # moe_tile_reuse_share, through its own file: the row tiles that
+    # ran on a weight block already fetched, of all live row tiles
+    with open(os.path.join(ROOT, "benchmark", "metrics",
+                           "moe_tile_reuse_share.json")) as f:
+        reuse = json.load(f)
+    assert reuse["reader"] == "counter_ratio"
+    for when, first, further in (("before", 7000.0, 3000.0),
+                                 ("after", 83000.0, 36000.0)):
+        obs["telemetry"][when]["counters"][
+            "pdt_serving_moe_row_tiles_total"] = {
+                'kind="first"': first, 'kind="further"': further}
+    assert counter_ratio.read(obs, **reuse["args"]) == pytest.approx(
+        100 * 33000 / (76000 + 33000))
     # a program without the counters (the parent): nothing to read
     empty = {"telemetry": {"before": {}, "after": {}}, "steps": [],
              "window_s": 1.0}
@@ -665,6 +719,7 @@ def test_block_readers_on_a_hand_made_window():
     assert gmm_roofline_blocks.read(empty, **gmm) is None
     assert counter_over_counter.read(empty, numerator="a",
                                      denominator="b") is None
+    assert counter_ratio.read(empty, **reuse["args"]) is None
 
 
 def _blocks_check(seed=2, model_kw=None, **spec):

@@ -542,6 +542,46 @@ class TestGroupedMatmulCompile:
         sizes = jnp.full((8,), 512, jnp.int32)
         _compile(gmm_pallas, lhs, rhs, sizes)
 
+    # (assignments, K, N) on 128 held experts: the block cell's pass
+    # (256 rows x 8: tile 16) and admission (512 x 8: tile 32), the
+    # hybrid's decode step (64 x 22 / 4: tile 16)
+    SERVED = {
+        "generate.pass.gate_up": (2048, 2048, 1536),
+        "generate.pass.down": (2048, 768, 2048),
+        "generate.admit512.gate_up": (4096, 2048, 1536),
+        "generate.admit512.down": (4096, 768, 2048),
+        "reasoning.decode.up": (352, 1024, 2688),
+        "reasoning.decode.down": (352, 2688, 1024),
+    }
+
+    @pytest.mark.parametrize("shape", sorted(SERVED))
+    def test_served_shapes_run_and_match_the_xla_walk(self, shape):
+        """ISSUE 32: the stationary grid at the served widths, rows
+        drawn unevenly so that experts take one, two and three tiles,
+        some none, with dead tiles behind them."""
+        from paddle_tpu.ops import grouped_matmul as gm
+        held = 128
+        a, k, n = self.SERVED[shape]
+        bm = gm.row_block(a / held)
+        rng = np.random.default_rng(4)
+        p = 1 / np.arange(1.0, held + 1)
+        counts = rng.multinomial(a, rng.permutation(p / p.sum()))
+        counts[rng.integers(0, held, 6)] = 0
+        padded = -(-counts // bm) * bm
+        m = -(-(a + held * (bm - 1)) // bm) * bm
+        assert (padded // bm).max() >= 3 and padded.sum() < m
+        x = jnp.asarray(rng.standard_normal((m, k)), jnp.bfloat16)
+        w = jnp.asarray(rng.standard_normal((held, k, n)) * k ** -0.5,
+                        jnp.bfloat16)
+        sizes = jnp.asarray(padded, jnp.int32)
+        got = _compile(lambda *args: gm.grouped_matmul_values(*args, bm),
+                       x, w, sizes)
+        want = _compile(lambda *args: gm._gmm_xla(*args, bm), x, w, sizes)
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=2e-2, atol=2e-2)
+        assert not np.asarray(got[int(padded.sum()):], np.float32).any()
+
 
 class TestInt8MXUCompile:
     """Round-4: the W8A8 path must hit the MXU's native int8 mode on
