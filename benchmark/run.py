@@ -12,8 +12,9 @@ metric is `benchmark/metrics/<name>.json`, which names its reader under
 as files of its own.
 
 The last line of standard output is one JSON object: `correct`,
-`attempted`, `failed`, `metrics`, `device`, and with `--trace 1`
-`breakdown`. The line before it, `[summary] {...}`, holds whatever else
+`attempted`, `failed`, `metrics`, `device`, with `--trace 1`
+`breakdown`, and last `compared`: each number that decided `correct`
+beside its limit. The line before it, `[summary] {...}`, holds whatever else
 the run learned. Without a TPU the measurement path exits non-zero and
 prints no result; `--dry` rehearses a cell on the CPU at the tiny sizes
 of `benchmark/tests/dry.json` and prints counts, never a time or a rate.
@@ -55,10 +56,21 @@ def _apply_dry(cell: dict, sizes: dict) -> None:
     cut = dry["lengths_divided_by"]
     cell["traffic"] = traffic.scaled(cell["traffic"], cut)
     eng = cell["engine"]
+    page = sizes["engine"]["page_size"]
+
+    def default_pool():
+        return eng["max_batch_size"] * -(-eng["max_seq_len"] // page)
+
+    full = default_pool()
     for key in ("max_seq_len", "prefill_chunk", "prompt_pad"):
         if eng.get(key):
             eng[key] = max(16, int(eng[key] / cut))
     eng["max_batch_size"] = min(eng["max_batch_size"], dry["max_slots"])
+    if eng.get("num_pages"):
+        # an explicit pool is cut with the slots and the contexts: the
+        # same share of slots x pages a sequence (page 0 is spare)
+        eng["num_pages"] = 1 + -(-(eng["num_pages"] - 1) * default_pool()
+                                 // full)
     arr = cell["traffic"]["arrivals"]
     if "clients" in arr:
         arr["clients"] = min(arr["clients"], dry["max_slots"] * 3 // 2)
@@ -69,7 +81,21 @@ def _apply_dry(cell: dict, sizes: dict) -> None:
     lc["prompt_tokens"] = max(8, int(lc["prompt_tokens"] / cut))
 
 
-def _device(dry: bool, chips: int) -> dict:
+def load_cell(workload: str):
+    """`(bench, entry, conf, cell, sizes)` of a workload's name: its
+    entries in `BENCHMARK.json`, its file and its configuration's."""
+    bench = _load("BENCHMARK.json")
+    entry = next((w for w in bench["workloads"]
+                  if w["name"] == workload), None)
+    if entry is None:
+        raise SystemExit(f"no workload {workload!r} in BENCHMARK.json")
+    conf = next(c for c in bench["configs"] if c["name"] == entry["config"])
+    return (bench, entry, conf,
+            _load("benchmark", "workloads", entry["name"] + ".json"),
+            _load(conf["file"]))
+
+
+def _device(dry: bool, chips: int, peak=None) -> dict:
     import jax
     if dry:
         from paddle_tpu.device import describe_devices
@@ -80,10 +106,11 @@ def _device(dry: bool, chips: int) -> dict:
         if info["count"] < chips:
             raise RuntimeError(f"the cell asks for {chips} chips, JAX "
                                f"found {info['count']}")
-    peak = 0
-    for d in jax.devices():
-        st = d.memory_stats() or {}
-        peak = max(peak, int(st.get("peak_bytes_in_use", 0)))
+    if peak is None:
+        peak = 0
+        for d in jax.devices():
+            st = d.memory_stats() or {}
+            peak = max(peak, int(st.get("peak_bytes_in_use", 0)))
     return {"platform": info["platform"], "kind": info["kind"],
             "count": info["count"], "memory_peak_bytes": peak}
 
@@ -113,14 +140,7 @@ def main() -> int:
                     help="also write the run's details to this JSON file")
     args = ap.parse_args()
 
-    bench = _load("BENCHMARK.json")
-    entry = next((w for w in bench["workloads"]
-                  if w["name"] == args.workload), None)
-    if entry is None:
-        raise SystemExit(f"no workload {args.workload!r} in BENCHMARK.json")
-    conf = next(c for c in bench["configs"] if c["name"] == entry["config"])
-    cell = _load("benchmark", "workloads", entry["name"] + ".json")
-    sizes = _load(conf["file"])
+    bench, entry, _, cell, sizes = load_cell(args.workload)
     seconds = args.seconds if args.seconds is not None \
         else float(bench["run_seconds"])
 
@@ -157,7 +177,9 @@ def main() -> int:
                 json.dump(rows, f, indent=1)
         return 0
     res = runner.run(ctx)
-    device = _device(args.dry, entry["chips"])      # the peak, afterwards
+    # the peak: as the runner read it when its window had closed, before
+    # a reference ran; else as it is now
+    device = _device(args.dry, entry["chips"], res.get("memory_peak_bytes"))
     obs = res.pop("obs")
 
     e2e = {m["name"]: m for m in _mine(bench["end_to_end"], entry["name"])}
@@ -184,6 +206,9 @@ def main() -> int:
     if args.trace and obs.get("trace"):
         line["breakdown"] = {"device_ops": obs["trace"]["device_ops"],
                              "idle_gaps": obs["trace"]["idle_gaps"]}
+    # each number `correct` compared beside its limit: last in the line,
+    # and (below) the last lines of standard error
+    line["compared"] = res.get("compared", {})
     summary = {"workload": entry["name"], "seed": args.seed,
                "seconds": seconds, "trace": args.trace, "dry": args.dry,
                "compile_cache": cache_dir,
@@ -201,6 +226,9 @@ def main() -> int:
             json.dump({"line": line, "summary": summary,
                        "trace_planes": obs.get("trace_planes"),
                        "steps": obs.get("steps")}, f, default=str)
+    for name, c in line["compared"].items():
+        print(f"compared {name} {c['value']} limit {c['limit']}",
+              file=sys.stderr, flush=True)
     print(json.dumps(line), flush=True)
     return 0
 

@@ -10,9 +10,11 @@ the client's side by one thread.
              token the moment a `router.step()` shows it
     drain    follow what the window started, up to `drain_s`
     check    every request ended FINISHED on its budget, nothing was
-             healed, the expected kernels are in the programs, and one
+             healed, the expected kernels are in the programs, one
              prompt's logits through the paged cache agree with the
-             plain reference
+             plain reference, and (a cell with a `served_check` block)
+             the tokens the window SERVED are the plain reference's
+             best, to a gap the cell's file bounds
 
 The loop is synchronous, as the router is: submit what is due, step,
 look. An open loop's request is timed from the moment it was DUE.
@@ -21,10 +23,16 @@ from __future__ import annotations
 
 import gc
 import importlib
+import os
 import re
 import shutil
+import sys
 import tempfile
 import time
+
+if __name__ == "__main__":      # run as a script: the repo on the path
+    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))))
 
 import jax
 import numpy as np
@@ -136,8 +144,10 @@ def logits_check(model, sizes: dict, engine_kw: dict, spec: dict,
     n, steps = int(spec["prompt_tokens"]), int(spec["steps"])
     rng = np.random.default_rng(int(seed) + 1)
     prompt = rng.integers(1, sizes["vocab_size"], n).tolist()
+    # two slots and their default pool, whatever pool the cell names
     eng = ContinuousBatchingEngine(model, **{**engine_kw,
-                                             "max_batch_size": 2})
+                                             "max_batch_size": 2,
+                                             "num_pages": None})
     rec = _LogitRecorder()
     eng.attach_sentry(rec)
     rid = eng.add_request(prompt, max_new_tokens=steps + 1)
@@ -161,6 +171,100 @@ def logits_check(model, sizes: dict, engine_kw: dict, spec: dict,
            "tolerance": float(spec["tolerance"])}
     out["ok"] = err <= out["tolerance"]
     return out
+
+
+class _Rounded(dict):
+    """The same weights with every matrix rounded to `dtype` and back,
+    one at a time as it is read (the control's reference)."""
+
+    def __init__(self, values, dtype):
+        super().__init__(values)
+        self.dtype = jax.numpy.dtype(dtype)
+
+    def __getitem__(self, name):
+        v = super().__getitem__(name)
+        return v.astype(self.dtype).astype(v.dtype) if v.ndim >= 2 else v
+
+
+def served_sample(ended, k: int, seed: int) -> list:
+    """`k` of the requests the run finished: the longest, and the rest
+    drawn from the seed."""
+    done = [r for r in ended if not r["failed"] and r.get("tokens")]
+    if not done:
+        return []
+    longest = max(done, key=lambda r: len(r["prompt"]) + len(r["tokens"]))
+    rest = [r for r in done if r is not longest]
+    rng = np.random.default_rng([int(seed), 11])
+    pick = rng.choice(len(rest), size=min(k - 1, len(rest)), replace=False)
+    return [longest] + [rest[i] for i in sorted(pick.tolist())]
+
+
+def served_check(values, sizes: dict, sample: list, spec: dict,
+                 control=None) -> dict:
+    """What the timed path produced, against the plain reference: the
+    reference runs once over each sampled request's prompt with the
+    tokens it was SERVED (by the window's own programs, in its own
+    batches), and for every served token reads how far its logit lies
+    below the reference's best at that position, over the standard
+    deviation of the reference's logits. A greedy token of a sound bf16
+    program is the reference's best or a near tie (the gap is bounded
+    by twice the logit error); a wrong page, position or slot, a token
+    altered on its way out, or arithmetic a precision lower serves
+    tokens the reference holds well below its best. Two numbers, each
+    with a limit in the cell's file: the widest gap, and the mean gap
+    over all compared tokens, which grows with the SQUARE of the logit
+    error (how often a tie flips times how far) and swings far less
+    from seed to seed.
+
+    With `control` (a dtype) the reference itself, its matrices rounded
+    to that precision, stands in the program's place: at each position
+    of the same prompts and tokens, the gap of the token IT puts first.
+    Sequences are padded to a multiple of `pad_to` (causal: the padding
+    changes no compared row), so the reference compiles few shapes."""
+    ref = importlib.import_module(f"benchmark.reference.{sizes['reference']}")
+    pad = int(spec.get("pad_to", 256))
+    served, low = [], []
+    for r in sample:
+        n, toks = len(r["prompt"]), np.asarray(r["tokens"])
+        ids = list(r["prompt"]) + list(r["tokens"])
+        ids += [1] * (-len(ids) % pad)
+        rows = slice(n - 1, n - 1 + len(toks))
+        want = ref.forward_logits(values, sizes, ids)[rows]
+        if not np.isfinite(want).all():
+            raise Unsound("served check: non-finite reference logits")
+        best, scale, at = want.max(-1), np.std(want), np.arange(len(toks))
+        served.append((best - want[at, toks]) / scale)
+        if control:
+            first = ref.forward_logits(_Rounded(values, control), sizes,
+                                       ids)[rows].argmax(-1)
+            low.append((best - want[at, first]) / scale)
+    if not served:
+        return {"requests": 0, "ok": False}
+
+    def numbers(gaps):
+        gap = np.concatenate(gaps)
+        return {"served_gap_max": float(gap.max()),
+                "served_gap_mean": float(gap.mean()),
+                "not_the_reference_best": int((gap > 0).sum())}
+
+    out = {"requests": len(sample), "tokens": sum(len(g) for g in served),
+           "longest": len(sample[0]["prompt"]) + len(sample[0]["tokens"]),
+           **numbers(low if control else served),
+           "gap_max_limit": float(spec["gap_max_limit"]),
+           "gap_mean_limit": float(spec["gap_mean_limit"]),
+           "control": control}
+    if control:       # the program's own reading, beside the control's
+        out["program"] = numbers(served)
+    out["ok"] = out["served_gap_max"] <= out["gap_max_limit"] \
+        and out["served_gap_mean"] <= out["gap_mean_limit"]
+    return out
+
+
+def memory_peak_bytes() -> int:
+    """The peak on the fullest chip so far (0 where the backend does
+    not say)."""
+    return max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+               for d in jax.devices())
 
 
 def _kernels_by_family(snap: dict) -> dict:
@@ -195,6 +299,7 @@ class Fleet:
                                request_id=rid)
         live[rid] = {"id": rid, "due": due, "submit": CLOCK(),
                      "prompt_tokens": len(prompt), "budget": budget,
+                     "prompt": prompt, "tokens": None,
                      "token_times": [], "counted": counted,
                      "failed": False, "end": None, "status": None}
         return live[rid]
@@ -213,6 +318,7 @@ class Fleet:
             n = len(rec.tokens)
             r["token_times"] += [now] * (n - len(r["token_times"]))
             r["end"], r["status"] = now, rec.status
+            r["tokens"] = list(rec.tokens)
             r["failed"] = (rec.status != RequestStatus.FINISHED
                            or bool(rec.failovers) or n != r["budget"])
             if r["failed"]:
@@ -410,6 +516,12 @@ def _set_up(ctx: dict, telemetry_stays_on: bool):
     else off from the end of the first crafted set."""
     cell, sizes, seed = ctx["cell"], ctx["sizes"], ctx["seed"]
     engine_kw = {**sizes["engine"], **cell["engine"]}
+    if ctx.get("quant"):
+        # the control that is the program's own path: weights and pages
+        # held in 8 bits (`main` below; never in a benchmark run)
+        from paddle_tpu.models.serving import QuantServingConfig
+        engine_kw["quant"] = QuantServingConfig(weights=ctx["quant"],
+                                                kv="int8")
     notes = {}
     _watch_lowering()
     t0 = CLOCK()
@@ -473,20 +585,51 @@ def run(ctx: dict) -> dict:
     telemetry.disable()
 
     # -- correctness, outside the window ----------------------------
-    problems = healed_failures(fleet.router)
+    # the peak is the fleet's: read before any reference runs, and the
+    # fleet's pools are freed before one does
+    peak = memory_peak_bytes()
+    healed = healed_failures(fleet.router)
+    slots = fleet.slots
+    del fleet
+    gc.collect()
     failed = [r for r in obs["ended"] if r["failed"]]
-    problems += [f"request {r['id']}: {r.get('error')}" for r in failed[:5]]
+    problems = healed \
+        + [f"request {r['id']}: {r.get('error')}" for r in failed[:5]]
     missing = _kernels_ok(warmed["kernels"], cell.get("expect_kernels", {})) \
         if not dry else []
     if missing:
         problems.append(f"Mosaic kernels missing from the programs: "
                         f"{missing} (found {warmed['kernels']})")
+    compared = {"failed_requests": (len(failed), 0),
+                "healed_failures": (len(healed), 0)}
     t0 = CLOCK()
     check = logits_check(model, sizes, engine_kw, cell["logits_check"], seed)
     notes["logits_check_s"] = CLOCK() - t0
     notes["logits_check"] = check
+    compared["logits_err"] = (check["max_err_over_ref_std"],
+                              check["tolerance"])
+    if "route_gap_max" in check:
+        compared["route_gap"] = (check["route_gap_max"],
+                                 check["route_margin"])
     if not check["ok"]:
         problems.append(f"logits outside the tolerance: {check}")
+    if cell.get("served_check"):
+        spec = cell["served_check"]
+        t0 = CLOCK()
+        served = served_check(
+            weights.named_values(model), sizes,
+            served_sample(obs["ended"], int(spec["requests"]), seed),
+            spec, control=ctx.get("control"))
+        notes["served_check_s"] = CLOCK() - t0
+        notes["served_check"] = served
+        if served["requests"]:
+            compared["served_gap_max"] = (served["served_gap_max"],
+                                          served["gap_max_limit"])
+            compared["served_gap_mean"] = (served["served_gap_mean"],
+                                           served["gap_mean_limit"])
+        if not served["ok"]:
+            problems.append(f"served tokens below the reference's best: "
+                            f"{served}")
     notes["problems"] = problems
     notes["in_flight_at_close"] = obs["in_flight_at_close"]
     notes["lowered_in_window"] = obs["lowered_in_window"]
@@ -508,13 +651,37 @@ def run(ctx: dict) -> dict:
     notes["samples"] = {"token_gaps": len(gaps), "ttft": len(first),
                         "steps": len(obs["steps"]),
                         "window_s": obs["window_s"]}
-    obs["engine"] = {"slots": fleet.slots, **engine_kw}
+    if not dry:
+        # what a later reader of one run's line needs to tell a run
+        # that was slow throughout from one that stalled, and a 95th
+        # percentile that reads a plain decode step from one that reads
+        # a step plus an admission (none of it is an end-to-end value)
+        live = [s["running_slots"] for s in obs["steps"]]
+        notes["tokens_per_s_thirds"] = stats.rate_by_thirds(
+            reqs, t_open, t_close)
+        notes["gaps_with_admission_share"] = \
+            stats.gaps_with_admission_share(reqs, t_open, t_close)
+        notes["running_slots_mean"] = sum(live) / max(len(live), 1)
+        notes["itl_p92_p98_s"] = [stats.percentile(gaps, q) if gaps
+                                  else None for q in (92, 98)]
+        # how far the 95th percentile sits from the next kind of gap on
+        # either side: the shares of gaps over 0.8 and over 1.25 times
+        # it. Where one of them lies near 5 %, it is on an edge
+        p95 = e2e["itl_p95_s"]
+        notes["gaps_over_p95_bracket_share"] = [
+            100.0 * sum(g > f * p95 for g in gaps) / len(gaps)
+            for f in (0.8, 1.25)] if gaps else None
+        notes["step_durations"] = stats.step_durations(obs["steps"], reqs)
+    obs["engine"] = {"slots": slots, **engine_kw}
     obs["model"] = sizes
     counted = [r for r in obs["ended"] if r["counted"]]
     return {"correct": not problems,
             "attempted": len(counted),
             "failed": sum(1 for r in counted if r["failed"]),
-            "end_to_end": e2e, "obs": obs, "notes": notes}
+            "end_to_end": e2e, "obs": obs, "notes": notes,
+            "memory_peak_bytes": peak,
+            "compared": {k: {"value": v, "limit": lim}
+                         for k, (v, lim) in compared.items()}}
 
 
 def sweep(ctx: dict, rates) -> list:
@@ -550,6 +717,12 @@ def sweep(ctx: dict, rates) -> list:
             "ttft_p50_s": stats.finite(stats.percentile(first, 50)),
             "ttft_p90_s": stats.finite(stats.percentile(first, 90)),
             "itl_p95_s": stats.percentile(gaps, 95) if gaps else None,
+            # a 95th percentile over "a step plus k admission dispatches"
+            # is steady where its neighbours read the same k
+            "itl_p92_s": stats.percentile(gaps, 92) if gaps else None,
+            "itl_p98_s": stats.percentile(gaps, 98) if gaps else None,
+            "gaps_with_admission_share": stats.gaps_with_admission_share(
+                obs["requests"], t_open, t_close),
             "tokens_per_s": stats.tokens_in_window(
                 obs["requests"], t_open, t_close) / obs["window_s"],
             "lowered_in_window": obs["lowered_in_window"],
@@ -558,3 +731,54 @@ def sweep(ctx: dict, rates) -> list:
         if not ctx["dry"]:
             print("[sweep] " + repr(rows[-1]), flush=True)
     return rows
+
+
+def main() -> int:
+    """`python3 benchmark/runners/serve.py --workload <cell> --seeds a,b,c
+    --seconds 15 [--control float8_e4m3fn | --quant int8]`: a short
+    window at the cell's own load a seed, all in one process, and the
+    numbers `correct` compares, one line a seed. `--control`: the plain
+    reference rounded to that precision in the program's place.
+    `--quant`: the program with its own 8-bit path switched on. Exit
+    code 1 unless every seed comes out correct (without a control) or
+    NOT correct (with one)."""
+    import argparse
+    import json
+
+    from benchmark import run as harness
+    ap = argparse.ArgumentParser(description=main.__doc__)
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seeds", required=True)
+    ap.add_argument("--seconds", type=float, default=15.0)
+    ap.add_argument("--control", default=None)
+    ap.add_argument("--quant", default=None)
+    ap.add_argument("--dry", action="store_true")
+    args = ap.parse_args()
+    if not args.dry:
+        from paddle_tpu.device import enable_compile_cache, require_tpu
+        require_tpu()
+        enable_compile_cache()
+    seeds = [int(x) for x in args.seeds.split(",")]
+    as_expected = 0
+    for seed in seeds:
+        _, entry, _, cell, sizes = harness.load_cell(args.workload)
+        if args.dry:
+            harness._apply_dry(cell, sizes)
+        res = run({"cell": cell, "sizes": sizes, "seed": seed,
+                   "seconds": args.seconds, "trace": False, "dry": args.dry,
+                   "t_start": CLOCK(), "chips": entry["chips"],
+                   "control": args.control, "quant": args.quant})
+        print(json.dumps({"seed": seed, "correct": res["correct"],
+                          "compared": res["compared"],
+                          "served_check": res["notes"].get("served_check"),
+                          "logits_check": res["notes"]["logits_check"]}),
+              flush=True)
+        as_expected += res["correct"] == (
+            args.control is None and args.quant is None)
+        del res
+        gc.collect()
+    return 0 if as_expected == len(seeds) else 1
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())

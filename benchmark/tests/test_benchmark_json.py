@@ -10,6 +10,11 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+# what `reduced` may never name: a hidden, intermediate, latent, state or
+# projection size, a head size, an expansion factor, experts per token
+WIDTH = re.compile(
+    r"(_dim|_rank)$|(hidden|intermediate|latent|state|proj\w*|head)_size$"
+    r"|head_dim|expan|per_tok|top_k")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 
 
@@ -52,8 +57,9 @@ def test_configs(bench):
         assert sizes["reduced"] == c["reduced"]
         for key in c["reduced"]:
             assert NAME.match(key)
-            # never a width
-            assert not re.search(r"(_dim|_rank|_size)$|head|expert", key)
+            # never a width; a count (layers, experts held, vocabulary
+            # rows a chip's share keeps) may be cut
+            assert not WIDTH.search(key), key
 
 
 def test_workloads(bench):
@@ -73,6 +79,30 @@ def test_workloads(bench):
         assert cell["why"] == w["why"]
         assert os.path.exists(os.path.join(
             ROOT, "benchmark", "runners", cell["runner"] + ".py"))
+        # an explicit pool holds a slot-full of the traffic's median
+        # sequences (page 0 is never handed out), or admission waits
+        # for pages and the slots the cell names stay empty
+        conf = next(c for c in bench["configs"] if c["name"] == w["config"])
+        with open(os.path.join(ROOT, conf["file"])) as f:
+            eng = {**json.load(f)["engine"], **cell["engine"]}
+        t = cell["traffic"]
+        if eng.get("num_pages"):
+            median = t["prompt_tokens"]["median"] \
+                + t["output_tokens"]["median"]
+            pages = -(-median // eng["page_size"])
+            assert eng["num_pages"] - 1 >= eng["max_batch_size"] * pages
+
+
+@pytest.mark.parametrize("key,width", [
+    ("num_hidden_layers", False), ("experts_held", False),
+    ("vocab_size", False), ("num_experts", False),
+    ("hidden_size", True), ("intermediate_size", True),
+    ("moe_latent_size", True), ("ssm_state_size", True),
+    ("head_dim", True), ("kv_lora_rank", True), ("mamba_head_dim", True),
+    ("num_experts_per_tok", True), ("expand", True),
+    ("moe_shared_expert_intermediate_size", True)])
+def test_reduced_never_names_a_width(key, width):
+    assert bool(WIDTH.search(key)) is width
 
 
 def test_metrics(bench):
