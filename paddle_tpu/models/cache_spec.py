@@ -3,9 +3,19 @@
 `model.cache_spec()` returns one entry a layer, in layer order: what the
 layer keeps between the tokens of a sequence.
 
-* `KVSpec(num_kv_heads, head_dim)`: keys and values of every context
-  token. The engine gives the layer a pair of page pools and hands it a
-  `llama.RaggedKVCacheView` over them and the block table.
+* `KVSpec(num_kv_heads, head_dim, window=None)`: keys and values of
+  every context token, or with `window` of the last `window` positions
+  only (a query at position p attends keys p - window < k <= p). The
+  engine gives the layer a pair of page pools and hands it a
+  `llama.RaggedKVCacheView` over them and a block table. Layers of one
+  `KVSpec` form a PAGE GROUP: one block table, one free list, one
+  reservation, and in a window group the pages that slide below the
+  window go back to the free list after every dispatch.
+* `SharedKVSpec(source_layer)`: the layer keeps nothing and READS the
+  keys and values layer `source_layer` (an earlier `KVSpec` layer)
+  stored. It owns no pool and is handed None: the forward pass gives
+  it the view `source_layer` returned in this same dispatch, and it
+  attends without writing (`llama.ragged_write_attend` with no k, v).
 * `StateSpec(shapes, dtypes)`: arrays of FIXED size a sequence, however
   long it is (a recurrent state). The engine allocates one
   `(slots, *shape)` array per entry, indexed by SLOT, donates them
@@ -27,6 +37,15 @@ The forward pass takes the views as `past_key_values` (one a layer) and
 returns, in the same order, a layer's new view, a reporting layer's
 `(counts, records)`, or None.
 
+`model.rows_leave_after()` (optional) names the last layer that KEEPS
+anything. Behind it a packed row that samples nothing is work thrown
+away, so an admission program hands the forward pass `sample_rows`
+(one packed row a slot, a row count or more where the slot samples
+nothing) and the forward pass carries only those rows past that layer:
+it returns logits `(1, slots, vocab)`, a sampled row's equal to what
+the whole batch would have given it. A decode step samples every row
+and passes no `sample_rows`.
+
 `model.generation_spec()` says how generation PROCEEDS, the way
 `cache_spec()` says what a layer keeps. Absent or None: one token a
 sequence a step, each from the logits of the token before it. A
@@ -36,18 +55,23 @@ engine reads it once, at construction.
 from __future__ import annotations
 
 import math
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["KVSpec", "StateSpec", "ReportSpec", "RaggedStateView",
-           "BlockDiffusionSpec"]
+__all__ = ["KVSpec", "SharedKVSpec", "StateSpec", "ReportSpec",
+           "RaggedStateView", "BlockDiffusionSpec", "conv_inputs"]
 
 
 class KVSpec(NamedTuple):
     num_kv_heads: int
     head_dim: int
+    window: Optional[int] = None
+
+
+class SharedKVSpec(NamedTuple):
+    source_layer: int
 
 
 class StateSpec(NamedTuple):
@@ -163,3 +187,27 @@ class RaggedStateView:
         return RaggedStateView(
             arrays, jnp.repeat(jnp.arange(batch, dtype=jnp.int32), seq_len),
             jnp.arange(batch, dtype=jnp.int32) * seq_len, lens, lens)
+
+
+def conv_inputs(xbc, tail, seq, idx, fresh, qstart, qlen):
+    """A causal depthwise convolution's inputs over a packed batch of
+    pieces (what every state layer with a convolution in front of its
+    scan needs): the K-1 rows before each packed row in ITS sequence's
+    stream (`prev[k-1]` is the row k back): earlier rows of the piece,
+    then the slot's stored tail, zeros for a piece that starts its
+    sequence. And the tails to store: the last K-1 rows of every slot's
+    stream."""
+    t = xbc.shape[0]
+    k1 = tail.shape[1]                                    # K - 1
+    seq_c = jnp.maximum(seq, 0)
+    tail = jnp.where(fresh[:, None, None], 0, tail)
+    prev = []
+    for k in range(1, k1 + 1):
+        stored = tail[seq_c, jnp.clip(k1 - k + idx, 0, k1 - 1)]
+        prev.append(jnp.where((idx >= k)[:, None],
+                              jnp.roll(xbc, k, axis=0), stored))
+    # stream = [tail ; piece]; the new tail is stream[qlen : qlen + K-1]
+    j = qlen[:, None] + jnp.arange(k1)[None, :]           # (S, K-1)
+    piece = xbc[jnp.clip(qstart[:, None] + j - k1, 0, t - 1)]
+    old = jnp.take_along_axis(tail, jnp.clip(j, 0, k1 - 1)[..., None], 1)
+    return prev, jnp.where((j >= k1)[..., None], piece, old)

@@ -271,31 +271,38 @@ class RaggedKVCacheView:
         self.tp = tp
 
 
-def ragged_write_attend(q, k, v, view: RaggedKVCacheView, window=None):
+def ragged_write_attend(q, k, v, view: RaggedKVCacheView, window=None,
+                        scale=None):
     """The ragged path's two cache calls for full-width pools, as every
     servable attention layer makes them: ONE scatter of the packed
     batch's new K/V rows (1, T, HK, D) into the view's pages, then
     ragged paged attention of q (1, T, H, D) over them under the
     view's descriptors and mask. Returns (out (1, T, H, D), the view
-    over the written pools)."""
+    over the written pools). With `k` and `v` None it only ATTENDS: a
+    layer that reads the keys and values another layer stored
+    (`cache_spec.SharedKVSpec`) passes that layer's returned view, and
+    gets (out, None)."""
     from paddle_tpu.core.tensor import apply as _apply
     from paddle_tpu.ops.ragged_paged_attention import (
         ragged_paged_attention_values, ragged_scatter_values)
     bt = view.block_tables
-
-    def fn_scatter(kp, vp, kk, vv):
-        return ragged_scatter_values(kp, vp, kk[0], vv[0], bt,
-                                     view.token_seq, view.positions)
-    kp, vp = _apply("ragged_kv_scatter", fn_scatter,
-                    (view.k_pages, view.v_pages, k, v), multi_output=True)
+    kp, vp = view.k_pages, view.v_pages
+    if k is not None:
+        def fn_scatter(kp, vp, kk, vv):
+            return ragged_scatter_values(kp, vp, kk[0], vv[0], bt,
+                                         view.token_seq, view.positions)
+        kp, vp = _apply("ragged_kv_scatter", fn_scatter, (kp, vp, k, v),
+                        multi_output=True)
 
     def fn_attn(qq, kp_, vp_):
         return ragged_paged_attention_values(
             qq[0], kp_, vp_, view.query_start, view.query_len,
-            view.context_lens, bt, window=window, block_q=view.block_q,
-            pages_bound=view.pages_bound, tp=view.tp,
-            diffusion_block=view.diffusion_block)[None]
+            view.context_lens, bt, scale=scale, window=window,
+            block_q=view.block_q, pages_bound=view.pages_bound,
+            tp=view.tp, diffusion_block=view.diffusion_block)[None]
     out = _apply("ragged_paged_attention", fn_attn, (q, kp, vp))
+    if k is None:
+        return out, None
     return out, RaggedKVCacheView(
         kp, vp, bt, view.token_seq, view.positions, view.query_start,
         view.query_len, view.context_lens, view.block_q,
@@ -634,10 +641,12 @@ class LlamaForCausalLM(nn.Layer, GenerationMixin):
 
     def cache_spec(self) -> list:
         """What each layer keeps (models/cache_spec.py): keys and
-        values, in every layer."""
+        values, in every layer, of the last `sliding_window` positions
+        where the config has one."""
         from paddle_tpu.models.cache_spec import KVSpec
         cfg = self.config
-        return [KVSpec(cfg.num_key_value_heads, cfg.head_dim)] \
+        return [KVSpec(cfg.num_key_value_heads, cfg.head_dim,
+                       getattr(cfg, "sliding_window", None))] \
             * cfg.num_hidden_layers
 
     def _logits(self, hidden):

@@ -35,8 +35,9 @@ from paddle_tpu import nn
 from paddle_tpu.nn import functional as F
 from paddle_tpu.nn import initializer as I
 from paddle_tpu.core.tensor import Tensor, apply as _apply
-from paddle_tpu.models.cache_spec import (KVSpec, RaggedStateView,
-                                          ReportSpec, StateSpec)
+from paddle_tpu.models.cache_spec import (
+    KVSpec, RaggedStateView, ReportSpec, StateSpec,
+    conv_inputs as _conv_inputs)
 from paddle_tpu.models.llama import (RaggedKVCacheView,
                                      ragged_write_attend)
 from paddle_tpu.models.routed import (combine_rows, report_counts,
@@ -135,27 +136,6 @@ def _segment_cumsum(a, starts):
         rf, rv = right
         return lf | rf, jnp.where(rf[:, None], rv, lv + rv)
     return jax.lax.associative_scan(combine, (starts, a))[1]
-
-
-def _conv_inputs(xbc, tail, seq, idx, fresh, qstart, qlen):
-    """The K-1 rows before each packed row in ITS sequence's stream
-    (`prev[k-1]` is the row k back): earlier rows of the piece, then the
-    slot's stored tail, zeros for a piece that starts its sequence. And
-    the tails to store: the last K-1 rows of every slot's stream."""
-    t = xbc.shape[0]
-    k1 = tail.shape[1]                                    # K - 1
-    seq_c = jnp.maximum(seq, 0)
-    tail = jnp.where(fresh[:, None, None], 0, tail)
-    prev = []
-    for k in range(1, k1 + 1):
-        stored = tail[seq_c, jnp.clip(k1 - k + idx, 0, k1 - 1)]
-        prev.append(jnp.where((idx >= k)[:, None],
-                              jnp.roll(xbc, k, axis=0), stored))
-    # stream = [tail ; piece]; the new tail is stream[qlen : qlen + K-1]
-    j = qlen[:, None] + jnp.arange(k1)[None, :]           # (S, K-1)
-    piece = xbc[jnp.clip(qstart[:, None] + j - k1, 0, t - 1)]
-    old = jnp.take_along_axis(tail, jnp.clip(j, 0, k1 - 1)[..., None], 1)
-    return prev, jnp.where((j >= k1)[..., None], piece, old)
 
 
 def _scan_one_token(x, b, c, dt, a, d_skip, ssm, fresh):

@@ -87,7 +87,8 @@ from ..utils.faults import (FaultError, fault_point, fault_value,
                             value_armed)
 from .. import observability as telemetry
 from ..observability import profile as _profile
-from .cache_spec import KVSpec, RaggedStateView, ReportSpec, StateSpec
+from .cache_spec import (KVSpec, RaggedStateView, ReportSpec, SharedKVSpec,
+                         StateSpec)
 from .generation import RequestStatus
 
 _NULL_SCOPE = contextlib.nullcontext()
@@ -262,6 +263,30 @@ _M_PAGES_IN_USE = telemetry.gauge(
 _M_PAGE_OCCUPANCY = telemetry.gauge(
     "pdt_serving_page_occupancy",
     "Fraction of usable KV pages allocated.")
+_M_GROUP_OCCUPANCY = telemetry.gauge(
+    "pdt_serving_group_page_occupancy",
+    "Fraction of a page group's usable KV pages allocated, a series a "
+    "group of a model with several (full, w<window>); "
+    "pdt_serving_page_occupancy stays the first group's.", ("group",))
+_M_KV_PAGES = telemetry.counter(
+    "pdt_serving_kv_pages_total",
+    "KV pages of a page group, by kind: allocated = taken from the "
+    "group's free list for a slot, reclaimed = given back because they "
+    "slid wholly below the group's window (a slot's release is not "
+    "counted).", ("group", "kind"))
+_M_ATTN_KV_ROWS = telemetry.counter(
+    "pdt_serving_attn_kv_rows_total",
+    "Stored K and V rows the attention calls of a dispatch must read, "
+    "each row once a layer that reads it (a sequence's rows from its "
+    "first query row's window edge to its context's end, times the "
+    "layers that read the group's pools), by page group and by phase "
+    "(decode or admit).", ("group", "phase"))
+_M_PREFILL_LAYER_ROWS = telemetry.counter(
+    "pdt_serving_prefill_layer_rows_total",
+    "Rows x layers of the admission dispatches, by kind: run = what a "
+    "dispatch ran, skipped = what it did not because the rows that "
+    "sample nothing left after the model's last keeping layer "
+    "(cache_spec: rows_leave_after).", ("kind",))
 _M_INVARIANT_SECONDS = telemetry.histogram(
     "pdt_serving_invariant_check_seconds",
     "Duration of check_invariants() page-accounting sweeps.")
@@ -395,12 +420,53 @@ def _name_program(jitted, family: str, key=None):
 
 def _cache_spec(model) -> list:
     """What each layer of `model` keeps (models/cache_spec.py). A model
-    that does not say keeps keys and values in every layer."""
+    that does not say keeps keys and values in every layer, of the
+    last `sliding_window` positions where its config has one."""
     if hasattr(model, "cache_spec"):
         return list(model.cache_spec())
     cfg = model.config
-    return [KVSpec(cfg.num_key_value_heads, cfg.head_dim)] \
+    return [KVSpec(cfg.num_key_value_heads, cfg.head_dim,
+                   getattr(cfg, "sliding_window", None))] \
         * cfg.num_hidden_layers
+
+
+class _PageGroup:
+    """The allocator's state for the KV layers of ONE `KVSpec`: layers
+    that keep the same heads for the same window share a block table, a
+    free list, reference counts and a reservation, and their pools have
+    `num_pages` pages. `pools` are the indices (into the engine's pool
+    list) of the layers that own a pool here; `readers_before` /
+    `readers_after` count the layers whose attention call reads these
+    pools (the owners and the `SharedKVSpec` layers on them), split at
+    the model's `rows_leave_after()`. A `derived` group (a window group
+    of a model with several groups) allocates a dispatch at a time and
+    its pool follows from slots, window and chunk; every other group is
+    sized by `num_pages` and reserves a sequence's worst case."""
+
+    def __init__(self, spec: KVSpec, num_pages: int, slots: int, pps: int,
+                 derived: bool = False, steady: int = 0):
+        self.spec = spec
+        self.window = spec.window
+        self.name = "full" if spec.window is None else f"w{spec.window}"
+        self.num_pages = int(num_pages)
+        self.derived = derived
+        self.steady = int(steady)     # a derived group's pages a slot
+        self.pools: List[int] = []
+        self.readers_before = self.readers_after = 0
+        self.bt = np.zeros((slots, pps), np.int32)
+        self.free: List[int] = list(range(1, self.num_pages))
+        self.page_rc = np.zeros(self.num_pages, np.int32)
+        self.slot_pages: List[List[int]] = [[] for _ in range(slots)]
+        self.slot_reserved = np.zeros(slots, np.int64)
+        # pages ever attached (shared + allocated) — the next block-
+        # table index to fill; stays monotonic even after window
+        # reclamation frees leading pages
+        self.slot_next_idx = np.zeros(slots, np.int64)
+        self.slot_freed = np.zeros(slots, np.int64)
+        # pages taken from the free list / slid out below the window,
+        # and what of each the telemetry counter has been told
+        self.allocated = self.reclaimed = 0
+        self.told = [0, 0]
 
 
 class EngineOverloaded(RuntimeError):
@@ -714,8 +780,30 @@ class ContinuousBatchingEngine:
                 f"max_seq_len {self.S} exceeds the model's rope table "
                 f"(max_position_embeddings="
                 f"{cfg.max_position_embeddings})")
-        self._window = getattr(cfg, "sliding_window", None)
-        if self._window is not None and enable_prefix_caching:
+        # -- page groups (models/cache_spec.py): the KV layers of one
+        # (heads, head size, window) share an allocator. The engine
+        # learns windows and sharing from the specification and from
+        # nowhere else. The window-less group comes first: `num_pages`
+        # sizes it, and `_bt`, `_free`, ... below are ITS arrays.
+        kv_spec = [s for s in self._layer_spec if isinstance(s, KVSpec)]
+        # a model without a KV layer keeps the page bookkeeping (block
+        # tables, reservations) over zero pools
+        group_specs = sorted(dict.fromkeys(kv_spec or [KVSpec(1, 1)]),
+                             key=lambda g: g.window is not None)
+        shares = any(isinstance(s, SharedKVSpec) for s in self._layer_spec)
+        self._grouped = len(group_specs) > 1 or shares
+        if self._grouped:
+            for feature, asked in (
+                    ("enable_prefix_caching", enable_prefix_caching),
+                    ("spec_decode", spec_decode is not None),
+                    ("harvest_every > 1", int(harvest_every) > 1),
+                    ("quant.kv", quant is not None and quant.kv),
+                    ("submesh tp > 1", submesh is not None
+                     and int(submesh.tp) > 1)):
+                if asked:
+                    self._refuse_groups(feature)
+        windowed = any(g.window is not None for g in group_specs)
+        if windowed and enable_prefix_caching:
             # slid-out pages are reclaimed and their block-table entries
             # trash-routed, so a window model's prompt pages are not
             # stable shareable KV
@@ -739,17 +827,7 @@ class ContinuousBatchingEngine:
             # different submeshes share one model object
             self._tp_pv, self._tp_bv = \
                 self._tp.shard_model_values(model)
-        kv_spec = [s for s in self._layer_spec if isinstance(s, KVSpec)]
-        if len(set(kv_spec)) > 1:
-            raise ValueError(
-                f"KV layers of different shapes {sorted(set(kv_spec))}: "
-                "the engine keeps one pool geometry")
-        # a model without a KV layer keeps the page bookkeeping (block
-        # tables, reservations) over zero pools
-        hk, hd = kv_spec[0] if kv_spec else (1, 1)
-        L = len(kv_spec)
         dt = self._params[0]._value.dtype
-        self._kv_shape = (L, hk, hd, dt)
         self._state = [
             tuple(jnp.zeros((int(max_batch_size),) + tuple(shape), d)
                   for shape, d in zip(s.shapes, s.dtypes))
@@ -770,20 +848,68 @@ class ContinuousBatchingEngine:
         if self.num_pages < 2:
             raise ValueError("num_pages must be >= 2 (page 0 is "
                              "reserved)")
+        self._groups: List[_PageGroup] = []
+        for g in group_specs:
+            derived = g.window is not None and len(group_specs) > 1
+            pages, steady = self.num_pages, 0
+            if derived:
+                # a slot between dispatches holds the window's pages,
+                # the one that straddles its edge and the one being
+                # written; a dispatch adds its rows' pages, and slots x
+                # a sequence's pages is the most there can ever be
+                steady = -(-g.window // self.page_size) + 2
+                rows = int(prefill_chunk) if prefill_chunk else self.S
+                pages = 1 + min(self.B * self.pps, self.B * steady
+                                + -(-(g.window + rows) // self.page_size))
+            self._groups.append(_PageGroup(g, pages, self.B, self.pps,
+                                           derived, steady))
+        self._window_groups = [g for g in self._groups
+                               if g.window is not None]
+        # which layers read which group, and which group a pool is in
+        leave = getattr(model, "rows_leave_after", None)
+        self._leave_after = None if leave is None else int(leave())
+        self._pool_group: List[int] = []
+        layer_group = {}
+        for i, s in enumerate(self._layer_spec):
+            if isinstance(s, KVSpec):
+                layer_group[i] = group_specs.index(s)
+                self._groups[layer_group[i]].pools.append(
+                    len(self._pool_group))
+                self._pool_group.append(layer_group[i])
+            elif isinstance(s, SharedKVSpec):
+                if s.source_layer not in layer_group \
+                        or s.source_layer >= i:
+                    raise ValueError(
+                        f"layer {i} shares the keys and values of layer "
+                        f"{s.source_layer}, which is not an earlier "
+                        "KVSpec layer")
+                layer_group[i] = layer_group[s.source_layer]
+            else:
+                continue
+            g = self._groups[layer_group[i]]
+            if self._leave_after is not None and i > self._leave_after:
+                g.readers_after += 1
+            else:
+                g.readers_before += 1
+        primary = self._groups[0]
+        L = len(primary.pools)
+        hk, hd = primary.spec.num_kv_heads, primary.spec.head_dim
+        self._kv_shape = (L, hk, hd, dt)
 
-        def _pool():
+        def _pool(g: _PageGroup):
             # stored token-major, the layout the row scatter writes
             # (ops/ragged_paged_attention.py): a token's row over all
             # KV heads is contiguous, so the write updates the donated
             # pool in place
             pool_dt = jnp.int8 if self._qkv else dt
-            z = jnp.zeros((self.num_pages, self.page_size, hk * hd),
-                          pool_dt)
+            z = jnp.zeros((g.num_pages, self.page_size,
+                           g.spec.num_kv_heads * g.spec.head_dim), pool_dt)
             if self._tp is None:
                 return z
             # sharded allocator contract: the pool splits its rows
             # by KV head, so every page id names tp local shards
-            return jax.device_put(z, self._tp.kv_sharding(hk))
+            return jax.device_put(
+                z, self._tp.kv_sharding(g.spec.num_kv_heads))
 
         def _spool():
             # per-page-row dequant scales of a QUANTIZED pool:
@@ -796,20 +922,20 @@ class ContinuousBatchingEngine:
                 return z
             return jax.device_put(z, self._tp.replicated())
 
+        pool_groups = [self._groups[i] for i in self._pool_group]
         if self._qkv:
-            self._kv = [(_pool(), _pool(), _spool(), _spool())
-                        for _ in range(L)]
+            self._kv = [(_pool(g), _pool(g), _spool(), _spool())
+                        for g in pool_groups]
         else:
-            self._kv = [(_pool(), _pool()) for _ in range(L)]
-        self._bt = np.zeros((self.B, self.pps), np.int32)
-        self._free: List[int] = list(range(1, self.num_pages))
-        self._slot_pages: List[List[int]] = [[] for _ in range(self.B)]
-        self._slot_reserved = np.zeros(self.B, np.int64)
-        # pages ever attached (shared + allocated) — the next block-
-        # table index to fill; stays monotonic even after window
-        # reclamation frees leading pages
-        self._slot_next_idx = np.zeros(self.B, np.int64)
-        self._slot_freed = np.zeros(self.B, np.int64)
+            self._kv = [(_pool(g), _pool(g)) for g in pool_groups]
+        # the first group's arrays under the names the engine has always
+        # had for them: one and the same objects, never rebound
+        self._bt = primary.bt
+        self._free = primary.free
+        self._slot_pages = primary.slot_pages
+        self._slot_reserved = primary.slot_reserved
+        self._slot_next_idx = primary.slot_next_idx
+        self._slot_freed = primary.slot_freed
         # -- automatic prefix caching (vLLM-style, opt-in) ---------
         # Full pages are immutable once written (decode only appends
         # past them), so a finished request's full-page prompt KV can
@@ -824,7 +950,7 @@ class ContinuousBatchingEngine:
         # nodes; childless LRU nodes are evicted under pool pressure.
         self._prefix_enabled = bool(enable_prefix_caching)
         self._max_prefix_entries = int(max_prefix_entries)
-        self._page_rc = np.zeros(self.num_pages, np.int32)
+        self._page_rc = primary.page_rc
         # node key -> {"page": id, "parent": key|None, "children": n}
         self._prefix_nodes: "OrderedDict[tuple, dict]" = OrderedDict()
         self._slot_shared_pages: List[List[int]] = \
@@ -938,7 +1064,7 @@ class ContinuousBatchingEngine:
                     "spec_decode is greedy-only (bit-identical to the "
                     "plain engine); for sampling use "
                     "models.speculative.speculative_generate")
-            if self._window is not None:
+            if windowed:
                 raise ValueError(
                     "spec_decode does not compose with sliding_window "
                     "models (window page reclamation would race the "
@@ -1041,6 +1167,14 @@ class ContinuousBatchingEngine:
             f"({type(self.model).__name__}): it takes a sequence's cache "
             "to be its pages, and this model also keeps a recurrent "
             "state per slot")
+
+    def _refuse_groups(self, feature: str):
+        raise ValueError(
+            f"{feature} is not supported for a model whose layers keep "
+            f"their keys and values differently "
+            f"({type(self.model).__name__}: window, full or shared "
+            "layers in page groups): it takes a sequence's cache to be "
+            "one list of pages that every layer fills (ROADMAP M2)")
 
     def _refuse_blocks(self, feature: str):
         raise ValueError(
@@ -1653,6 +1787,14 @@ class ContinuousBatchingEngine:
         in_use = usable - len(self._free)
         _M_PAGES_IN_USE.set(in_use)
         _M_PAGE_OCCUPANCY.set(in_use / max(usable, 1))
+        for g in self._groups if len(self._groups) > 1 \
+                else self._window_groups:
+            _M_GROUP_OCCUPANCY.set(
+                1.0 - len(g.free) / max(g.num_pages - 1, 1), group=g.name)
+            for i, (kind, n) in enumerate((("allocated", g.allocated),
+                                           ("reclaimed", g.reclaimed))):
+                _M_KV_PAGES.inc(n - g.told[i], group=g.name, kind=kind)
+                g.told[i] = n
         if self._state_spec:
             _M_STATE_BYTES.set(self._state_nbytes())
             _M_STATE_SLOTS.set(int(self._state_live.sum()))
@@ -1712,7 +1854,10 @@ class ContinuousBatchingEngine:
         in free/trash pages would drill nothing."""
         if not value_armed("serving.kv_page", self.fault_tag):
             return
-        live = sorted({p for pages in self._slot_pages for p in pages})
+        if not self._kv:
+            return
+        group = self._groups[self._pool_group[0]]
+        live = sorted({p for pages in group.slot_pages for p in pages})
         if not live:
             return
         entry = self._kv[0]
@@ -1754,6 +1899,8 @@ class ContinuousBatchingEngine:
         plane's serialize cost."""
         if self._state_spec:
             self._refuse_state("export_pages")
+        if self._grouped:
+            self._refuse_groups("export_pages")
         # pipelined decode: the payload serializes host slot state
         # (ctx/last_token/output) — drain the deferred window first so
         # it reflects every token the device produced (quiesce seam,
@@ -1858,6 +2005,8 @@ class ContinuousBatchingEngine:
         capacity deferrals, distinct from transfer failures."""
         if self._state_spec:
             self._refuse_state("import_pages")
+        if self._grouped:
+            self._refuse_groups("import_pages")
         # pipelined decode: the active set must be CONSTANT within a
         # deferred window (the device token ring carries no entry for
         # a slot installed mid-window) — drain the window before the
@@ -2065,6 +2214,8 @@ class ContinuousBatchingEngine:
         the spilled bytes are only interpretable in their own mode."""
         if self._state_spec:
             self._refuse_state("import_prefix")
+        if self._grouped:
+            self._refuse_groups("import_prefix")
         if not self._prefix_enabled:
             return 0
         if (kv_scales is None) == bool(self._qkv):
@@ -2229,6 +2380,14 @@ class ContinuousBatchingEngine:
                 "bytes_pool": self.num_pages * page_bytes,
                 "bytes_in_use": in_use * page_bytes,
                 "utilization": in_use / max(usable, 1)}
+        if len(self._groups) > 1:
+            # the numbers above are the first group's; every group's here
+            info["groups"] = {g.name: {
+                "page_bytes": 2 * len(g.pools) * self.page_size * itemsize
+                * g.spec.num_kv_heads * g.spec.head_dim,
+                "total_pages": g.num_pages - 1,
+                "pages_in_use": g.num_pages - 1 - len(g.free)}
+                for g in self._groups}
         if self._prefix_enabled:
             cached = {n["page"] for n in self._prefix_nodes.values()}
             info.update(prefix_entries=len(self._prefix_nodes),
@@ -2251,59 +2410,74 @@ class ContinuousBatchingEngine:
                 _M_INVARIANT_SECONDS.time():
             self._check_invariants_paged()
 
-    def _check_invariants_paged(self):
-        errs: List[str] = []
-        free = list(self._free)
+    def _check_invariants_group(self, g: _PageGroup, errs: List[str]):
+        """One page group's accounting (`check_invariants`); the shared
+        prefix pages and the trie's nodes are the first group's."""
+        first = g is self._groups[0]
+        tag = f"group {g.name}: " if len(self._groups) > 1 else ""
+        free = list(g.free)
         free_set = set(free)
         if len(free_set) != len(free):
-            errs.append(f"free list has duplicates: {sorted(free)}")
+            errs.append(f"{tag}free list has duplicates: {sorted(free)}")
         if 0 in free_set:
-            errs.append("reserved trash page 0 is on the free list")
-        expected = np.zeros(self.num_pages, np.int64)
+            errs.append(f"{tag}reserved trash page 0 is on the free list")
+        expected = np.zeros(g.num_pages, np.int64)
         for i, r in enumerate(self._slot_req):
-            if r is None and (self._slot_pages[i]
-                              or self._slot_shared_pages[i]
-                              or np.any(self._bt[i] != 0)):
+            shared = self._slot_shared_pages[i] if first else []
+            if r is None and (g.slot_pages[i] or shared
+                              or np.any(g.bt[i] != 0)):
                 errs.append(
-                    f"released slot {i} still holds pages "
-                    f"{self._slot_pages[i]} shared "
-                    f"{self._slot_shared_pages[i]} or a nonzero "
+                    f"{tag}released slot {i} still holds pages "
+                    f"{g.slot_pages[i]} shared {shared} or a nonzero "
                     "block-table row")
-            for p in self._slot_pages[i]:
+            for p in g.slot_pages[i]:
                 expected[p] += 1
-            for p in self._slot_shared_pages[i]:
+            for p in shared:
                 expected[p] += 1
-        for node in self._prefix_nodes.values():
+        for node in self._prefix_nodes.values() if first else ():
             expected[node["page"]] += 1
-        for p in range(1, self.num_pages):
-            rc = int(self._page_rc[p])
+        for p in range(1, g.num_pages):
+            rc = int(g.page_rc[p])
             if rc != int(expected[p]):
-                errs.append(f"page {p}: refcount {rc} != "
+                errs.append(f"{tag}page {p}: refcount {rc} != "
                             f"{int(expected[p])} holders "
                             "(slots + prefix nodes)")
             if rc == 0 and p not in free_set:
-                errs.append(f"page {p} LEAKED: refcount 0 but absent "
-                            "from the free list")
+                errs.append(f"{tag}page {p} LEAKED: refcount 0 but "
+                            "absent from the free list")
             if rc > 0 and p in free_set:
-                errs.append(f"page {p} on the free list with refcount "
-                            f"{rc}")
+                errs.append(f"{tag}page {p} on the free list with "
+                            f"refcount {rc}")
         for i, r in enumerate(self._slot_req):
             if r is None:
                 continue
-            lo = int(self._slot_freed[i])
-            hi = int(self._slot_next_idx[i])
+            lo = int(g.slot_freed[i])
+            hi = int(g.slot_next_idx[i])
             for j in range(self.pps):
-                p = int(self._bt[i, j])
+                p = int(g.bt[i, j])
                 if lo <= j < hi:
-                    if p == 0 or int(self._page_rc[p]) < 1:
+                    if p == 0 or int(g.page_rc[p]) < 1:
                         errs.append(
-                            f"slot {i} block-table[{j}] -> page {p} is "
-                            "not an allocated page")
+                            f"{tag}slot {i} block-table[{j}] -> page {p} "
+                            "is not an allocated page")
                 elif p != 0:
                     errs.append(
-                        f"slot {i} block-table[{j}] = {p} outside the "
-                        f"live window [{lo}, {hi}) must trash-route "
+                        f"{tag}slot {i} block-table[{j}] = {p} outside "
+                        f"the live window [{lo}, {hi}) must trash-route "
                         "to 0")
+            # a step ends with every page that lies wholly below the
+            # window of the slot's LAST dispatched position given back
+            if g.window is not None and lo < hi and \
+                    (lo + 1) * self.page_size <= int(self._pos[i]) - g.window:
+                errs.append(
+                    f"{tag}slot {i} block-table[{lo}] is still allocated "
+                    f"wholly below the window of {g.window} at position "
+                    f"{int(self._pos[i])}")
+
+    def _check_invariants_paged(self):
+        errs: List[str] = []
+        for g in self._groups:
+            self._check_invariants_group(g, errs)
         # multi-model (ISSUE 17): the slot -> adapter-row map must
         # mirror slot ownership exactly — a stale row would gather
         # ANOTHER adapter's delta into this slot's stream, silent
@@ -2539,18 +2713,19 @@ class ContinuousBatchingEngine:
             # register BEFORE the decrefs so the prompt pages never
             # transit through the free list
             self._register_prefix(slot, req)
-        for p in self._slot_pages[slot]:
-            self._decref(p)
         for p in self._slot_shared_pages[slot]:
             self._decref(p)
-        self._slot_pages[slot] = []
         self._slot_shared_pages[slot] = []
-        self._slot_reserved[slot] = 0
-        self._slot_next_idx[slot] = 0
-        self._slot_freed[slot] = 0
-        # inactive slots keep decoding garbage; their block-table row
-        # must point at the trash page, not at reclaimed pages
-        self._bt[slot] = 0
+        for g in self._groups:
+            for p in g.slot_pages[slot]:
+                self._decref(p, g)
+            g.slot_pages[slot] = []
+            g.slot_reserved[slot] = 0
+            g.slot_next_idx[slot] = 0
+            g.slot_freed[slot] = 0
+            # inactive slots keep decoding garbage; their block-table
+            # row must point at the trash page, not at reclaimed pages
+            g.bt[slot] = 0
         if self._spec is not None:
             # the draft cache dies with the slot: preemption
             # re-prefills, failover re-dispatch, and migration all
@@ -2783,6 +2958,13 @@ class ContinuousBatchingEngine:
         bq = self._ragged_block_q
         grid = -(-self.pad // bq) * bq
         blocks = self._gen is not None      # its prefill samples nothing
+        for g in self._window_groups:
+            if not g.derived:
+                continue        # its prompt's pages were taken at the claim
+            for p in batch:
+                end = p["offset"] + len(p["tokens"])
+                while g.slot_next_idx[p["slot"]] * self.page_size < end:
+                    self._alloc_page(p["slot"], g)
         pk = pack_ragged_batch(
             [{"seq": p["slot"], "tokens": p["tokens"],
               "offset": p["offset"], "sample": p["sample"] and not blocks}
@@ -2805,8 +2987,23 @@ class ContinuousBatchingEngine:
         if telemetry.enabled():
             self._count_attn_pages(pk["query_start"], pk["query_len"],
                                    pk["context_len"], t_pad, bq)
+            sampled = (pk["sample_rows"] < t_pad).astype(np.int32)
+            self._count_attn_kv_rows(
+                "admit", pk["query_len"], pk["context_len"],
+                sampled if self._leave_after is not None
+                else pk["query_len"])
+            layers = len(self._layer_spec)
+            behind = 0 if self._leave_after is None or blocks \
+                else layers - 1 - self._leave_after
+            gone = max(int(t_pad) - self.B, 0) * behind
+            _M_PREFILL_LAYER_ROWS.inc(int(t_pad) * layers - gone,
+                                      kind="run")
+            _M_PREFILL_LAYER_ROWS.inc(gone, kind="skipped")
         with telemetry.span("serving.ragged_prefill", tokens=tokens,
-                            t_pad=int(t_pad), rids=rids), \
+                            t_pad=int(t_pad), rids=rids,
+                            rows_sampled=sum(
+                                p["sample"] and not blocks
+                                for p in batch)), \
                 self._tp_scope():
             jit = self._get_ragged_prefill(t_pad, bound)
             # multi-LoRA: each packed row gathers its OWNING slot's
@@ -2825,9 +3022,12 @@ class ContinuousBatchingEngine:
                 jnp.asarray(pk["query_start"]),
                 jnp.asarray(pk["query_len"]),
                 jnp.asarray(pk["context_len"]),
-                jnp.asarray(self._bt), jnp.asarray(pk["sample_rows"]),
+                self._tables(), jnp.asarray(pk["sample_rows"]),
                 self._next_keys()))
             nxt = np.asarray(nxt)
+        for p in batch if self._window_groups else ():
+            self._reclaim_below_window(
+                p["slot"], p["offset"] + len(p["tokens"]))
         if reports is not None:
             seq = np.asarray(pk["token_seq"])
             live = np.flatnonzero(seq >= 0)
@@ -2965,22 +3165,54 @@ class ContinuousBatchingEngine:
         with telemetry on only."""
         from ..ops.ragged_paged_attention import (kv_block_pages,
                                                   ragged_pages_walked)
-        layers, hk, hd, dt = self._kv_shape
-        if not layers:
-            return                      # no KV layer: no attention call
-        if self._view_tp() is not None:
-            hk //= self._tp.tp                  # a shard's local heads
-        walked = ragged_pages_walked(
-            query_start, query_len, context_len, n_rows,
-            block_q=block_q, page_size=self.page_size,
-            window=self._window, table_pages=self.pps,
-            diffusion_block=self._dblock,
-            block_pages=kv_block_pages(
-                self.page_size, hd, hk,
-                1 if self._qkv else jnp.dtype(dt).itemsize, self.pps))
-        _M_ATTN_PAGES.inc(walked, kind="walked")
-        _M_ATTN_PAGES.inc(int(n_rows) // block_q * self.pps - walked,
-                          kind="skipped")
+        dt = self._kv_shape[3]
+        for g in self._groups:
+            if not g.pools:
+                continue                # no KV layer: no attention call
+            hk, hd = g.spec.num_kv_heads, g.spec.head_dim
+            if self._view_tp() is not None:
+                hk //= self._tp.tp              # a shard's local heads
+            walked = ragged_pages_walked(
+                query_start, query_len, context_len, n_rows,
+                block_q=block_q, page_size=self.page_size,
+                window=g.window, table_pages=self.pps,
+                diffusion_block=self._dblock,
+                block_pages=kv_block_pages(
+                    self.page_size, hd, hk,
+                    1 if self._qkv else jnp.dtype(dt).itemsize, self.pps))
+            _M_ATTN_PAGES.inc(walked, kind="walked")
+            _M_ATTN_PAGES.inc(int(n_rows) // block_q * self.pps - walked,
+                              kind="skipped")
+
+    def _count_attn_kv_rows(self, phase, query_len, context_len,
+                            sampled_len):
+        """`pdt_serving_attn_kv_rows_total` for one dispatch, from its
+        descriptors: a group's layers each read a sequence's stored rows
+        from the window edge of its first query row (row 0 without a
+        window) to its context's end, the bounds of the kernel's walk
+        (`live_kv_blocks`) in rows. The layers behind the model's
+        `rows_leave_after()` run on the sampled rows alone, one query a
+        slot at its context's end (`sampled_len`: 1 where the slot
+        samples). Called with telemetry on only."""
+        ctx = np.asarray(context_len)
+
+        def rows(n, window):
+            lo = 0 if window is None else np.maximum(ctx - n - window + 1, 0)
+            return int(np.where(n > 0, ctx - lo, 0).sum())
+
+        ql, sl = np.asarray(query_len), np.asarray(sampled_len)
+        for g in self._groups:
+            n = g.readers_before * rows(ql, g.window) \
+                + g.readers_after * rows(sl, g.window)
+            if n:
+                _M_ATTN_KV_ROWS.inc(n, group=g.name, phase=phase)
+
+    def _tables(self):
+        """The block tables a step program takes: the one table, or one
+        a page group in the groups' order."""
+        if len(self._groups) == 1:
+            return jnp.asarray(self._bt)
+        return tuple(jnp.asarray(g.bt) for g in self._groups)
 
     def _pages_bound(self, contexts) -> int:
         """Power-of-two-bucketed static gather trim for a dispatch
@@ -3033,6 +3265,12 @@ class ContinuousBatchingEngine:
         spec = _cache_spec(model) if draft else self._layer_spec
         stateful = bool(self._state_spec) and not draft
         dblock = 1 if draft else self._dblock
+        pool_group = None if draft or len(self._groups) == 1 \
+            else self._pool_group
+        # an admission program of a model whose last layers keep
+        # nothing carries only the sampled rows through them
+        leave = self._leave_after is not None and not draft \
+            and rule is None and select_rows and block_q != 1
 
         def run(pv, bv, kv, ids, tok_seq, qpos, qstart, qlen, ctx, bt,
                 sample_rows, key):
@@ -3041,14 +3279,17 @@ class ContinuousBatchingEngine:
             state = ()
             if stateful:
                 kv, state = kv
-            pools, states = iter(kv), iter(state)
+            pools, states = enumerate(kv), iter(state)
             with bind_state(params, buffers, pv, bv), no_grad():
                 views = []
                 for s in spec:
                     if isinstance(s, KVSpec):
-                        e = next(pools)
+                        n, e = next(pools)
+                        # its group's table, where there are several
+                        table = bt if pool_group is None \
+                            else bt[pool_group[n]]
                         views.append(RaggedKVCacheView(
-                            e[0], e[1], bt, tok_seq, qpos, qstart, qlen,
+                            e[0], e[1], table, tok_seq, qpos, qstart, qlen,
                             ctx, block_q, pages_bound, tp=view_tp,
                             k_scale=e[2] if qkv else None,
                             v_scale=e[3] if qkv else None,
@@ -3061,12 +3302,13 @@ class ContinuousBatchingEngine:
                         views.append(None)      # ReportSpec or nothing
                 logits, new = model.forward(
                     Tensor(ids[None]), past_key_values=views,
-                    use_cache=True)
+                    use_cache=True,
+                    **({"sample_rows": sample_rows} if leave else {}))
                 rows = logits._value[0]
                 if rule is not None:
                     nxt = rule(rows, ids, sample_rows)
                 else:
-                    if select_rows:
+                    if select_rows and not leave:
                         rows = rows[jnp.clip(sample_rows, 0,
                                              rows.shape[0] - 1)]
                     nxt, _ = _sample_token(rows, key, strat, temp, tk, tp)
@@ -3090,32 +3332,48 @@ class ContinuousBatchingEngine:
         return jax.jit(run, donate_argnums=(2,))
 
     # -- page accounting ------------------------------------------------
-    def _worst_pages(self, req: Request) -> int:
+    def _worst_pages(self, req: Request,
+                     g: Optional[_PageGroup] = None) -> int:
+        """Pages a request reserves in a group: its longest possible
+        sequence's, or in a derived window group what a slot holds
+        between dispatches (a dispatch's own rows come out of the slack
+        the group's pool was sized with, never out of a reservation)."""
         worst_len = min(len(req.prompt) + req.max_new_tokens, self.S)
-        return -(-worst_len // self.page_size)
+        worst = -(-worst_len // self.page_size)
+        return min(worst, g.steady) if g is not None and g.derived \
+            else worst
 
     def _reserve_ok(self, req: Request, shared_pages: int = 0) -> bool:
         """Admit only if the request's worst-case page demand (net of any
         shared prefix pages it attaches) fits the pool net of other
         slots' outstanding (reserved-but-unallocated) pages — lazy
-        growth can then never fail mid-flight. Evicts LRU prefix-cache
-        entries when that frees enough."""
-        outstanding = int(sum(
-            self._slot_reserved[i] - self._slot_next_idx[i]
-            for i, r in enumerate(self._slot_req) if r is not None))
-        need = self._worst_pages(req) - shared_pages + outstanding
-        if len(self._free) >= need:
-            return True
-        return self._ensure_free(need)
+        growth can then never fail mid-flight — in every page group.
+        Evicts LRU prefix-cache entries when that frees enough (the
+        first group's: a model with several has no prefix cache)."""
+        for g in self._groups:
+            held = g.slot_next_idx - g.slot_freed if g.derived \
+                else g.slot_next_idx
+            outstanding = int(sum(
+                g.slot_reserved[i] - held[i]
+                for i, r in enumerate(self._slot_req) if r is not None))
+            need = self._worst_pages(req, g) + outstanding
+            if g is self._groups[0]:
+                need -= shared_pages
+                if len(g.free) < need and not self._ensure_free(need):
+                    return False
+            elif len(g.free) < need:
+                return False
+        return True
 
     # -- prefix cache ---------------------------------------------------
     def _incref(self, page: int):
         self._page_rc[page] += 1
 
-    def _decref(self, page: int):
-        self._page_rc[page] -= 1
-        if self._page_rc[page] == 0:
-            self._free.append(page)
+    def _decref(self, page: int, g: Optional[_PageGroup] = None):
+        g = g or self._groups[0]
+        g.page_rc[page] -= 1
+        if g.page_rc[page] == 0:
+            g.free.append(page)
 
     def _evict_one(self) -> bool:
         """Evict the least-recently-used CHILDLESS trie node (leaves
@@ -3196,30 +3454,53 @@ class ContinuousBatchingEngine:
             if not self._evict_one():
                 break
 
-    def _alloc_page(self, slot: int) -> int:
+    def _alloc_page(self, slot: int,
+                    g: Optional[_PageGroup] = None) -> int:
         # chaos tests arm this site (exc=PoolExhausted) to force the
         # preemption path that reservation accounting makes unreachable
         fault_point("serving.alloc_page")
-        if not self._free:
+        g = g or self._groups[0]
+        if not g.free and g is self._groups[0]:
             self._ensure_free(1)
-        if not self._free:
+        if not g.free:
             raise PoolExhausted(
-                f"KV page pool exhausted ({self.num_pages - 1} usable "
+                f"KV page pool exhausted ({g.num_pages - 1} usable "
                 "pages, none free after prefix-cache eviction)")
-        page = self._free.pop()
-        self._page_rc[page] = 1
-        self._slot_pages[slot].append(page)
-        self._bt[slot, self._slot_next_idx[slot]] = page
-        self._slot_next_idx[slot] += 1
+        page = g.free.pop()
+        g.page_rc[page] = 1
+        g.slot_pages[slot].append(page)
+        g.bt[slot, g.slot_next_idx[slot]] = page
+        g.slot_next_idx[slot] += 1
+        g.allocated += 1
         return page
 
     def _reserve_and_alloc(self, slot: int, req: Request, p_len: int):
         """Record the slot's worst-case reservation and allocate pages
         covering the prompt — the common preamble of every paged
-        admission path."""
-        self._slot_reserved[slot] = self._worst_pages(req)
-        while self._slot_next_idx[slot] * self.page_size < p_len:
-            self._alloc_page(slot)
+        admission path. A derived window group allocates a dispatch at
+        a time instead (`_dispatch_ragged`): a long prompt never holds
+        more of its pages than a dispatch's rows and the window."""
+        for g in self._groups:
+            g.slot_reserved[slot] = self._worst_pages(req, g)
+            while not g.derived \
+                    and g.slot_next_idx[slot] * self.page_size < p_len:
+                self._alloc_page(slot, g)
+
+    def _reclaim_below_window(self, slot: int, pos: int):
+        """Give back `slot`'s pages that slid wholly below a group's
+        attention window [pos + 1 - w, pos] of a query at `pos`, the
+        slot's next position: no later call reads them."""
+        for g in self._window_groups:
+            ws = pos + 1 - g.window
+            while (g.slot_freed[slot] + 1) * self.page_size <= ws:
+                j = int(g.slot_freed[slot])
+                page = int(g.bt[slot, j])
+                if page != 0:
+                    g.slot_pages[slot].remove(page)
+                    self._decref(page, g)
+                    g.bt[slot, j] = 0      # trash-route
+                    g.reclaimed += 1
+                g.slot_freed[slot] += 1
 
     # -- decode --------------------------------------------------------
     def _decode_query_lens(self):
@@ -3296,10 +3577,14 @@ class ContinuousBatchingEngine:
         injection or an accounting bug — admission reserves worst-case
         demand) preempt the youngest running request and retry.
         Returns False if `slot` itself was preempted away."""
-        while self._slot_next_idx[slot] * self.page_size \
-                <= int(self._pos[slot]) + extra:
+        while True:
+            g = next((g for g in self._groups
+                      if g.slot_next_idx[slot] * self.page_size
+                      <= int(self._pos[slot]) + extra), None)
+            if g is None:
+                return True
             try:
-                self._alloc_page(slot)
+                self._alloc_page(slot, g)
             except PoolExhausted:
                 if self._pending:
                     # pipelined window: commit the in-flight dispatches
@@ -3316,7 +3601,6 @@ class ContinuousBatchingEngine:
                     raise
                 if victim == slot:
                     return False
-        return True
 
     def _decode(self, finished: List[Request]) -> bool:
         """One batched decode step for every active slot. Starvation-
@@ -3359,22 +3643,14 @@ class ContinuousBatchingEngine:
                 continue
             if not self._grow_slot(i, finished):
                 continue          # slot i itself was preempted
-            if self._window is not None:
+            if self._window_groups:
                 # reclaim pages that slid wholly below the attention
                 # window [ctx - w, ctx): the kernel never reads them
-                ws = int(self._pos[i]) + 1 - self._window
-                while (self._slot_freed[i] + 1) * self.page_size <= ws:
-                    j = int(self._slot_freed[i])
-                    page = int(self._bt[i, j])
-                    if page != 0:
-                        self._slot_pages[i].remove(page)
-                        self._decref(page)
-                        self._bt[i, j] = 0      # trash-route
-                    self._slot_freed[i] += 1
+                self._reclaim_below_window(i, int(self._pos[i]))
         if not any(r is not None for r in self._slot_req):
             return False          # every slot preempted away
         kv = self._cache()
-        bt = jnp.asarray(self._bt)
+        bt = self._tables()
         # fault BEFORE the dispatch (and before the PRNG key advances):
         # a retried step replays an identical sampling stream
         fault_point("serving.decode")
@@ -3390,6 +3666,9 @@ class ContinuousBatchingEngine:
             self._count_attn_pages(
                 np.arange(self.B), np.ones(self.B, np.int32), pos + 1,
                 self.B, 1)
+            live = np.fromiter((r is not None for r in self._slot_req),
+                               np.int32, self.B)
+            self._count_attn_kv_rows("decode", live, pos + 1, live)
         with telemetry.span("serving.decode_step", slots=n_active,
                             rids=rids):
             # pdt-lint: disable=PDT001 decode_step_seconds measures the

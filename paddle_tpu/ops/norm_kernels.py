@@ -193,6 +193,15 @@ def _ln(x2, w, b, eps, block_rows):
 
 
 def _ln_fwd(x2, w, b, eps, block_rows):
+    return _ln_fwd_call(x2, w, b, eps, block_rows, _interpret())
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("eps", "block_rows", "interpret"))
+def _ln_fwd_call(x2, w, b, eps, block_rows, interpret):
+    """Under `jax.jit` so that a program with a norm a layer traces and
+    lowers the kernel once a shape, not once a call site (PR 26 did the
+    same for the ragged kernel)."""
     n, h = x2.shape
     o, mean, rstd = pl.pallas_call(
         functools.partial(_ln_fwd_kernel, eps=eps),
@@ -206,7 +215,7 @@ def _ln_fwd(x2, w, b, eps, block_rows):
         out_shape=[jax.ShapeDtypeStruct((n, h), x2.dtype),
                    jax.ShapeDtypeStruct((n, LANES), jnp.float32),
                    jax.ShapeDtypeStruct((n, LANES), jnp.float32)],
-        interpret=_interpret(),
+        interpret=interpret,
     )(x2, w, b)
     return o, mean, rstd
 

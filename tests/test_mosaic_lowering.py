@@ -637,3 +637,67 @@ class TestKernelsUnderAMesh:
         assert set(found) == {"flash_fwd", "flash_bwd_dq",
                               "flash_bwd_dkv", "rms_norm_fwd",
                               "rms_norm_bwd"}
+
+
+class TestPageGroupPrograms:
+    """ISSUE 35: the engine's two step programs for a model whose layers
+    keep their keys and values differently (models/phi4flash.py: window
+    layers, a full layer, layers that share it, Mamba-1 state), at the
+    published widths (heads of 64 paired into the kernel's 128 lanes)
+    and 8 of the 32 layers, compiled for a v5e as the engine builds
+    them: a block table a page group, the attend-only call of a sharing
+    layer, the sampled rows leaving after the full layer."""
+
+    @pytest.mark.parametrize("program", ["decode", "admit"])
+    def test_engine_programs_compile(self, program):
+        from paddle_tpu.models.phi4flash import (Phi4FlashConfig,
+                                                 Phi4FlashForCausalLM)
+        from paddle_tpu.models.serving import ContinuousBatchingEngine
+        from paddle_tpu.ops import mosaic_kernels
+        one = jax.sharding.SingleDeviceSharding(_v5e()[0])
+        box = {}
+
+        def build():
+            model = Phi4FlashForCausalLM(Phi4FlashConfig(
+                num_hidden_layers=8, vocab_size=8192))
+            model.to(dtype="bfloat16")
+            model.eval()
+            box["eng"] = ContinuousBatchingEngine(
+                model, max_batch_size=8, max_seq_len=2048,
+                prefill_chunk=256, prompt_pad=256, num_pages=257)
+            return 0
+
+        jax.eval_shape(build)          # shapes only: nothing is allocated
+        eng = box["eng"]
+        assert [g.name for g in eng._groups] == ["full", "w512"]
+
+        def sds(x):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)
+
+        def i32(*s):
+            return jax.ShapeDtypeStruct(s, jnp.int32, sharding=one)
+
+        block_q, rows, bound = (1, eng.B, None) if program == "decode" \
+            else (8, 256, 128)
+        tree = jax.tree_util.tree_map
+        lowered = eng._build_ragged_step(block_q, bound).lower(
+            tree(sds, eng._pv()), tree(sds, eng._bv()),
+            tree(sds, eng._cache()), i32(rows), i32(rows), i32(rows),
+            i32(eng.B), i32(eng.B), i32(eng.B),
+            tuple(i32(eng.B, eng.pps) for _ in eng._groups), i32(eng.B),
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one))
+        compiled = lowered.compile()
+        # the attention kernel in the text (layers of one trace share a
+        # function): with the window, and without it for the full layer
+        # and the layer that shares it, which in an admission is a
+        # third, one query a slot over the sampled rows alone; the
+        # LayerNorm kernel
+        kernels = mosaic_kernels(lowered.as_text())
+        assert kernels.pop("ragged_paged_attention") \
+            == (2 if program == "decode" else 3)
+        assert set(kernels) == {"_ln_fwd_kernel"}
+        mem = compiled.memory_analysis()
+        # the pools and the state are donated and updated in place
+        assert mem.alias_size_in_bytes >= 0.99 * sum(
+            a.size * a.dtype.itemsize
+            for a in jax.tree_util.tree_leaves(eng._cache()))
