@@ -265,13 +265,16 @@ class SdarMoeForCausalLM(nn.Layer):
                                   cfg.denoising_steps, cfg.remasking,
                                   cfg.threshold)
 
-    def forward(self, input_ids, past_key_values=None, use_cache=False):
+    def forward(self, input_ids, past_key_values=None, use_cache=False,
+                sample_rows=None):
         """Logits of `input_ids`. With `past_key_values` (as
         `cache_spec` orders them: a layer's view, then None for its
         expert layer) the ids are ONE packed ragged batch (1, T) and the
         result is `(logits, new)`, `new` holding a layer's new view and
-        its expert layer's report. Without, (B, S) whole sequences from
-        nothing, every row of a block attending its whole block."""
+        its expert layer's report; with `sample_rows` (R,) too the head
+        runs over those packed rows only and the logits are theirs, (1,
+        R, vocab). Without, (B, S) whole sequences from nothing, every
+        row of a block attending its whole block."""
         x = self.model.embed_tokens(input_ids)
         b, s = x.shape[0], x.shape[1]
         views = past_key_values
@@ -292,6 +295,8 @@ class SdarMoeForCausalLM(nn.Layer):
                 live)
             x = x + out.reshape([b, s, -1])
             new += [got, report]
+        if views is not None and sample_rows is not None:
+            x = _apply("sampled_rows", lambda v: v[:, sample_rows], (x,))
         logits = self.lm_head(self.model.norm(x))
         if use_cache and views is not None:
             return logits, new

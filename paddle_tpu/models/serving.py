@@ -302,9 +302,16 @@ _M_STATE_SLOTS = telemetry.gauge(
 _M_BLOCK_PASSES = telemetry.counter(
     "pdt_serving_block_passes_total",
     "Passes over a live slot's block, one count a live slot a pass, by "
-    "kind: denoise = the dispatched block held a mask (the pass decides "
-    "positions), commit = it held none (the pass leaves the keys and "
-    "values later blocks read).", ("kind",))
+    "kind: denoise = the dispatched block it samples held a mask (the "
+    "pass decides positions; a finished block committed beside it counts "
+    "here), commit = it held none (a block that ends its request, "
+    "committed alone: the pass only leaves its keys and values).",
+    ("kind",))
+_M_BLOCK_COMMITS = telemetry.counter(
+    "pdt_serving_block_commits_total",
+    "Finished blocks whose keys and values a pass wrote, by kind: fused "
+    "= in the first pass of the request's next block, alone = in a pass "
+    "of its own (the block ends its request).", ("kind",))
 _M_BLOCK_TOKENS = telemetry.counter(
     "pdt_serving_block_tokens_total",
     "Tokens of committed blocks handed to requests (a first block's "
@@ -1213,11 +1220,20 @@ class ContinuousBatchingEngine:
         dispatch's live packed rows `rows` (of `slots`, at `positions`)
         go to a sentry that takes them. Each is one more D2H pull, so
         neither happens unasked."""
-        counts, records = reports
+        self._count_reports(reports[0])
+        self._give_layer_rows(reports[1], rows, slots, positions)
+
+    def _count_reports(self, counts):
+        """The reporting layers' counts of one dispatch into the
+        counters the specification names, with telemetry on only."""
         if telemetry.enabled():
             names = [c for s in self._report_spec for c in s.counters]
             for (counter, kind), n in zip(names, np.asarray(counts)):
                 counter.inc(int(n), kind=kind)
+
+    def _give_layer_rows(self, records, rows, slots, positions):
+        """The reporting layers' records of packed rows `rows` (of
+        `slots`, at `positions`), to a sentry that takes them."""
         take = getattr(self._sentry, "observe_layer_rows", None)
         if take is not None:
             take(np.asarray(slots), np.asarray(positions),
@@ -3251,10 +3267,11 @@ class ContinuousBatchingEngine:
         program's sentry variant, so the every-Nth-step numeric scan
         (serving/sentry.py) can pull them to host without a second
         dispatch. `rule` (a block model's pass, `_block_rule`) stands in
-        for the row select and the sampler: it is given every packed
-        row's logits, the ids, and what the caller passed in
-        `sample_rows`' place, and what it returns comes back where the
-        tokens do."""
+        for the row select and the sampler: the caller passes `(read,
+        *aux)` in `sample_rows`' place, the head runs over the packed
+        rows `read` alone (over every row where `return_logits` asks
+        for them), the rule is given those rows' logits, their ids and
+        `aux`, and what it returns comes back where the tokens do."""
         model = self._spec.draft_model if draft else self.model
         params = self._d_params if draft else self._params
         buffers = self._d_buffers if draft else self._buffers
@@ -3300,13 +3317,19 @@ class ContinuousBatchingEngine:
                             one_token=block_q == 1))
                     else:
                         views.append(None)      # ReportSpec or nothing
+                head = {}
+                if leave:
+                    head = {"sample_rows": sample_rows}
+                elif rule is not None and not return_logits:
+                    head = {"sample_rows": sample_rows[0]}
                 logits, new = model.forward(
                     Tensor(ids[None]), past_key_values=views,
-                    use_cache=True,
-                    **({"sample_rows": sample_rows} if leave else {}))
+                    use_cache=True, **head)
                 rows = logits._value[0]
                 if rule is not None:
-                    nxt = rule(rows, ids, sample_rows)
+                    read = sample_rows[0]
+                    nxt = rule(rows[read] if return_logits else rows,
+                               ids[read], sample_rows[1:])
                 else:
                     if select_rows and not leave:
                         rows = rows[jnp.clip(sample_rows, 0,
@@ -3813,72 +3836,128 @@ class ContinuousBatchingEngine:
 
         return rule
 
+    def _block_end(self, slot: int):
+        """What slot `slot`'s finished block (one that holds no mask)
+        hands its request: (tokens past the given head, within the
+        budget and up to EOS; whether the request ends with them, by
+        EOS, budget or a next block past `max_seq_len`)."""
+        r, b = self._slot_req[slot], self._dblock
+        toks = [int(t) for t in
+                self._blk_ids[slot, int(self._blk_given[slot]):]]
+        toks = toks[:r.max_new_tokens - len(r.output)]
+        if self.eos is not None and self.eos in toks:
+            toks = toks[:toks.index(self.eos) + 1]
+        ends = (self.eos is not None and toks and toks[-1] == self.eos) \
+            or len(r.output) + len(toks) >= r.max_new_tokens \
+            or int(self._pos[slot]) + 2 * b > self.S
+        return toks, bool(ends)
+
     def _decode_blocks(self, finished: List[Request]) -> bool:
         """One PASS over the block of every live slot: ONE dispatch of
-        the ragged program at `slots x block` rows, slot `i`'s block in
-        rows `[i block, (i + 1) block)` at positions `[pos, pos +
-        block)` with `context_len = pos + block`. The K/V scatter in
-        front of attention writes the block's rows on every pass, so the
-        last pass over a block (the one that finds no mask in it) leaves
-        what later blocks read. The program applies the transfer rule
-        (`_block_rule`); the host then advances the slots whose
-        dispatched block held no mask: their tokens go to the request,
-        their context grows by a block and a block of masks begins.
+        the ragged program at `slots x 2 block` rows, slot `i`'s segment
+        in rows `[2 i block, 2 (i + 1) block)`, one q block of the
+        attention kernel. The block the pass samples is at positions
+        `[p, p + block)` with `context_len = p + block`; the transfer
+        rule (`_block_rule`) reads its rows alone. A block that holds no
+        mask is finished and its keys and values (the last pass wrote
+        them from ids that were not final yet) must be written once
+        more. Where its request goes on, the finished block fills the
+        segment's first half at its own positions and the next block's
+        first pass the second (`p` a block further on): the K/V scatter
+        in front of attention writes both halves and the mask by blocks
+        (`ragged_paged_attention.block_frontier`) shows each half what a
+        pass of its own would have shown it, so the same numbers come
+        out of one pass fewer, and the q block walks the slot's pages
+        once for both. Any other slot's block fills the first half and
+        the second is padding; a block that ends its request has a pass
+        of its own there (its keys may still feed the prefix cache).
+        After the dispatch the host hands finished blocks' tokens to
+        their requests and advances the slots by what the rule decided.
         Slots in any pass of their block share the dispatch. Always
         handles the step itself (returns True)."""
-        b = self._dblock
+        b, seg = self._dblock, 2 * self._dblock
         if self._decode_jit is None:
             self._decode_logits = (self._sentry is not None
                                    and self._sentry.wants_logits)
             self._decode_jit = self._jit_singleton(
                 "decode", lambda: self._build_ragged_step(
-                    b, return_logits=self._decode_logits,
+                    seg, return_logits=self._decode_logits,
                     rule=self._block_rule()))
-            self._decode_idx = jnp.arange(self.B, dtype=jnp.int32) * b
+            self._decode_idx = jnp.arange(self.B, dtype=jnp.int32) * seg
+        done = {i: self._block_end(i)
+                for i, r in enumerate(self._slot_req)
+                if r is not None and not self._blk_masked[i].any()}
         for i, r in enumerate(self._slot_req):
             if r is not None:
-                # pages for the whole block, before the pass (within the
-                # admission reservation; a preempted slot just leaves)
-                self._grow_slot(i, finished, extra=b - 1)
+                # pages for what the pass writes, before it (within the
+                # admission reservation: a next block is begun only for
+                # a request that goes on; a preempted slot just leaves)
+                fused = i in done and not done[i][1]
+                self._grow_slot(i, finished,
+                                extra=(seg if fused else b) - 1)
         live = [i for i, r in enumerate(self._slot_req) if r is not None]
         if not live:
             return True           # every slot preempted away
         fault_point("serving.decode")
         on = np.zeros(self.B, bool)
         on[live] = True
+        fuse = np.zeros(self.B, bool)
+        fuse[[i for i in live if i in done and not done[i][1]]] = True
+        n_fuse = int(fuse.sum())
+        n_alone = sum(1 for i in live if i in done) - n_fuse
         pos = np.where(on, self._pos, 0).astype(np.int32)
-        masked = self._blk_masked & on[:, None]
-        # the slots this pass commits: their dispatched block holds no mask
-        commit = on & ~masked.any(axis=1)
-        n_commit = int(commit.sum())
-        self._blk_t0[on & np.isnan(self._blk_t0)] = self._clock()
+        # the block a segment's second half holds: a fused slot's next
+        # block, all masks, in its first pass (padding elsewhere, at the
+        # first half's positions)
+        at = pos + np.where(fuse, b, 0)
+        masked = np.where(fuse[:, None], True,
+                          self._blk_masked) & on[:, None]
+        passes = np.where(fuse, 0, self._blk_passes).astype(np.int32)
+        now = self._clock()
+        self._blk_t0[on & np.isnan(self._blk_t0)] = now
         rids = ([self._slot_req[i].request_id for i in live]
                 if telemetry.enabled() else ())
-        qlen = np.where(on, b, 0).astype(np.int32)
-        ctx = np.where(on, pos + b, 0).astype(np.int32)
+        qlen = (np.where(on, b, 0) + np.where(fuse, b, 0)).astype(np.int32)
+        ctx = np.where(on, pos + qlen, 0).astype(np.int32)
         if telemetry.enabled():
-            self._count_attn_pages(np.arange(self.B) * b, qlen, ctx,
-                                   self.B * b, b)
+            self._count_attn_pages(np.arange(self.B) * seg, qlen, ctx,
+                                   self.B * seg, seg)
         with telemetry.span("serving.decode_step", slots=len(live),
-                            rows=len(live) * b,
-                            commit_slots=n_commit, rids=rids):
+                            rows=int(qlen.sum()),
+                            commit_slots=n_fuse + n_alone,
+                            fused_slots=n_fuse, rids=rids):
             # pdt-lint: disable=PDT001 decode_step_seconds measures the
             # REAL wall time of one device dispatch incl. its D2H sync
             t0 = time.perf_counter()
-            tok_seq = np.repeat(np.where(on, np.arange(self.B), -1), b)
-            qpos = (pos[:, None] + np.arange(b)).reshape(-1)
+            slot = np.arange(self.B)[:, None]
+            tok_seq = np.concatenate(
+                [np.where(on[:, None], slot, -1).repeat(b, 1),
+                 np.where(fuse[:, None], slot, -1).repeat(b, 1)],
+                axis=1).reshape(-1)
+            qpos = np.concatenate([pos[:, None] + np.arange(b),
+                                   at[:, None] + np.arange(b)],
+                                  axis=1).reshape(-1)
+            ids = np.concatenate(
+                [self._blk_ids,
+                 np.full_like(self._blk_ids, self._gen.mask_token_id)],
+                axis=1).reshape(-1)
+            # the rows the rule reads: a fused segment's second half,
+            # any other's first
+            read = (slot * seg + np.where(fuse, b, 0)[:, None]
+                    + np.arange(b)).reshape(-1)
             with self._tp_scope():
                 out = self._decode_jit(
                     self._lora_pv(self._pv(),
-                                  np.repeat(self._slot_adapter, b)),
+                                  np.repeat(self._slot_adapter, seg)),
                     self._bv(), self._cache(),
-                    jnp.asarray(self._blk_ids.reshape(-1)),
+                    jnp.asarray(ids.astype(np.int32)),
                     jnp.asarray(tok_seq.astype(np.int32)),
                     jnp.asarray(qpos.astype(np.int32)),
-                    self._decode_idx, jnp.asarray(qlen), jnp.asarray(ctx),
-                    jnp.asarray(self._bt),
-                    (jnp.asarray(masked.reshape(-1)),
-                     jnp.asarray(self._blk_passes)), self._next_keys())
+                    self._decode_idx, jnp.asarray(qlen),
+                    jnp.asarray(ctx), jnp.asarray(self._bt),
+                    (jnp.asarray(read.astype(np.int32)),
+                     jnp.asarray(masked.reshape(-1)),
+                     jnp.asarray(passes)), self._next_keys())
             nxt, lg_rows, reports = self._take_step(
                 out, self._decode_logits)
             # pdt-lint: disable=PDT001 same real-wall measurement as t0
@@ -3887,46 +3966,43 @@ class ContinuousBatchingEngine:
             nxt = self._harvest_sync(nxt)
             # pdt-lint: disable=PDT001 same real-wall measurement
             dt = time.perf_counter() - t0
-        # what the pass decided, and which slots' blocks are complete
+        # the finished blocks' tokens, then what the pass decided
         handed = []
         for i in live:
             r = self._slot_req[i]
-            if not commit[i]:
-                self._blk_ids[i] = nxt[i, :b]
-                self._blk_masked[i] = (int(nxt[i, b]) >> np.arange(b)) & 1
-                self._blk_passes[i] += 1
-                continue
-            given = int(self._blk_given[i])
-            toks = [int(t) for t in self._blk_ids[i, given:]]
-            toks = toks[:r.max_new_tokens - len(r.output)]
-            if self.eos is not None and self.eos in toks:
-                toks = toks[:toks.index(self.eos) + 1]
-            r.output.extend(toks)
-            handed.extend(toks)
-            self._note_first_token(r)
-            self._pos[i] += b
-            if telemetry.enabled():
-                _M_BLOCK_SECONDS.observe(self._clock() - self._blk_t0[i])
-            if (self.eos is not None and toks and toks[-1] == self.eos) \
-                    or len(r.output) >= r.max_new_tokens \
-                    or int(self._pos[i]) + b > self.S:
-                self._finalize(r, RequestStatus.FINISHED, None, finished)
-                self._release_slot(i)
-            else:
+            if i in done:
+                toks, ends = done[i]
+                r.output.extend(toks)
+                handed.extend(toks)
+                self._note_first_token(r)
+                self._pos[i] += b
+                if telemetry.enabled():
+                    _M_BLOCK_SECONDS.observe(self._clock()
+                                             - self._blk_t0[i])
+                if ends:
+                    self._finalize(r, RequestStatus.FINISHED, None,
+                                   finished)
+                    self._release_slot(i)
+                    continue
                 self._start_block(i)
+                self._blk_t0[i] = now
+            self._blk_ids[i] = nxt[i, :b]
+            self._blk_masked[i] = (int(nxt[i, b]) >> np.arange(b)) & 1
+            self._blk_passes[i] += 1
         # counted after the step's timed part, telemetry on only
         if telemetry.enabled():
             _M_HARVEST.observe(dt - (t1 - t0))
             _M_DECODE_STEP.observe(dt)
             _M_DECODE_TOKENS.inc(len(handed))
             _M_BLOCK_TOKENS.inc(len(handed))
-            _M_BLOCK_PASSES.inc(len(live) - n_commit, kind="denoise")
-            _M_BLOCK_PASSES.inc(n_commit, kind="commit")
+            _M_BLOCK_PASSES.inc(len(live) - n_alone, kind="denoise")
+            _M_BLOCK_PASSES.inc(n_alone, kind="commit")
+            _M_BLOCK_COMMITS.inc(n_fuse, kind="fused")
+            _M_BLOCK_COMMITS.inc(n_alone, kind="alone")
             if dt > 0:
                 _M_TOKENS_PER_SEC.set(len(handed) / dt)
-        rows = np.flatnonzero(tok_seq >= 0)
         if reports is not None:
-            self._harvest_reports(reports, rows, tok_seq[rows], qpos[rows])
+            self._count_reports(reports[0])
         self._corrupt_kv_site()
         if self._sentry is not None:
             with telemetry.span("serving.sentry"):
@@ -3936,19 +4012,36 @@ class ContinuousBatchingEngine:
                 # pdt-lint: disable=PDT001 same real-wall measurement
                 self._sentry.note_cost(time.perf_counter() - s0)
                 self._harvest_block_sentry(
-                    handed, lg_rows if scan else None, rows)
+                    handed, lg_rows if scan else None,
+                    None if reports is None else reports[1], tok_seq,
+                    qpos, np.repeat(fuse, seg) & (np.arange(self.B * seg)
+                                                  % seg < b))
         return True
 
-    def _harvest_block_sentry(self, handed, lg_rows, rows):
-        """Sentry checks over one pass: the tokens it handed out, and on
-        a scan the logits of every live row (`block` rows a live slot,
-        in slot order)."""
+    def _harvest_block_sentry(self, handed, lg_rows, records, tok_seq,
+                              qpos, committed):
+        """Sentry checks over one pass: the tokens it handed out, then
+        the reporting layers' records and, on a scan, the logits of its
+        live rows. A pass that commits finished blocks beside their next
+        blocks (packed rows `committed`) is given as the two passes it
+        stands for: those blocks' rows, then the sampled blocks' rows,
+        each in slot order."""
         if handed:
             self._sentry.observe_tokens(np.asarray(handed, np.int32))
+        rows = np.flatnonzero(tok_seq >= 0)
         if lg_rows is not None:
-            self._sentry.observe_logits(fault_value(
-                "serving.logits", np.asarray(lg_rows)[rows],
-                tag=self.fault_tag))
+            lg_rows = fault_value("serving.logits",
+                                  np.asarray(lg_rows)[rows],
+                                  tag=self.fault_tag)
+        first = committed[rows]
+        for part in (first, ~first):
+            if not part.any():
+                continue
+            if records is not None:
+                self._give_layer_rows(records, rows[part],
+                                      tok_seq[rows[part]], qpos[rows[part]])
+            if lg_rows is not None:
+                self._sentry.observe_logits(lg_rows[part])
 
     # -- pipelined harvest seam (harvest_every=k, ISSUE 18) -------------
     # The _harvest_* family are the DESIGNATED host-sync functions of

@@ -457,7 +457,9 @@ class TestQuantMatmulLowering:
 
     # the block-diffusion cell (ISSUE 31): 32 Q on 4 KV heads, 64 slots
     # of 256 columns; a pass is 4 rows a slot at block_q 4, an admission
-    # 512 rows at block_q 8, both under the mask by blocks of 4
+    # 512 rows at block_q 8, both under the mask by blocks of 4; since
+    # PR 36 the cell's pass lowers at the admission's shape (a segment
+    # of 2 x 4 rows a slot, one q block of 8)
     BLOCK_CELL_SHAPES = {
         "generate.pass": (32, 4, 64, 256, 256, 4),
         "generate.admit512": (32, 4, 64, 512, 256, 8),

@@ -243,6 +243,8 @@ def test_group_counters_and_the_sampled_rows_attribute(monkeypatch):
 def _one_group_models():
     from paddle_tpu.models.nemotron_h import (NemotronHConfig,
                                               NemotronHForCausalLM)
+    from paddle_tpu.models.phi4flash import (Phi4FlashConfig,
+                                             Phi4FlashForCausalLM)
     from paddle_tpu.models.sdar import SdarMoeConfig, SdarMoeForCausalLM
     paddle.seed(0)
     yield "llama", LlamaForCausalLM(LlamaConfig.tiny())
@@ -251,11 +253,17 @@ def _one_group_models():
     yield "llama_window", LlamaForCausalLM(cfg)
     yield "nemotron_h", NemotronHForCausalLM(NemotronHConfig.tiny())
     yield "sdar", SdarMoeForCausalLM(SdarMoeConfig.tiny())
+    # two more whose step programs the block pass must leave alone
+    # (PR 36): the causal SDAR model and a model of several groups
+    yield "sdar_block_1", SdarMoeForCausalLM(
+        SdarMoeConfig.tiny(block_length=1))
+    yield "phi4flash", Phi4FlashForCausalLM(Phi4FlashConfig.tiny())
 
 
-# sha256 of the step programs' jaxprs, taken on the parent tree (PR 34)
-# under `default_matmul_precision("highest")`, as tests/conftest.py sets
-# it (the precision is part of a jaxpr's text)
+# sha256 of the step programs' jaxprs, taken on the parent tree (PR 34;
+# PR 35 for sdar_block_1 and phi4flash) under
+# `default_matmul_precision("highest")`, as tests/conftest.py sets it
+# (the precision is part of a jaxpr's text)
 JAXPR_OF_THE_PARENT = {
     ("llama", "decode"):
     "a329855bdd4f8ba2a4122d16112556596951d3224b6a0bbc0cd1bf1ba81d5760",
@@ -273,24 +281,35 @@ JAXPR_OF_THE_PARENT = {
     "c62ca46338479f5563ddb78fcddeb961a78df4bca874930e38e3b0d499e9d0f9",
     ("sdar", "admit"):
     "20fb16ec1ccd687b52cad130cc8ef14ff95a5fb5a7104c5edf0127c5cdc80557",
+    ("sdar_block_1", "decode"):
+    "d87ad40a3a2e045ee5781b370f6320c25972234b1ccbc3e4d3b052419083210f",
+    ("sdar_block_1", "admit"):
+    "6977054492e83da3ddd9ec99ad8141607513408ba651ecba28d6fbadcfe1e6fa",
+    ("phi4flash", "decode"):
+    "f09209bb53f489c0e7d0c650669c68e3c343d3483f522ad2115538cf68d86a41",
+    ("phi4flash", "admit"):
+    "de486aeae66e3707fac1ed4616cead5d0d7edaa0d2031e8397f496bb52d7279c",
 }
 
 
 @pytest.mark.parametrize("which", ["llama", "llama_window", "nemotron_h",
-                                   "sdar"])
+                                   "sdar", "sdar_block_1", "phi4flash"])
 def test_one_group_models_trace_to_the_parents_programs(which):
     """The decode and admission programs of every model the benchmark
     already runs (and of a one-window model) trace to the jaxpr they had
     before page groups, operation for operation; the engine's own names
-    for its one group's arrays are the group's arrays."""
+    for its one group's arrays are the group's arrays. Since PR 36 also
+    the causal SDAR model's and Phi-4-flash's (several groups): the
+    fused commit changes a block model's pass alone."""
     model = dict(_one_group_models())[which]
     model.eval()
     eng = ContinuousBatchingEngine(model, max_batch_size=2, max_seq_len=32,
                                    page_size=8, prompt_pad=8)
-    (group,) = eng._groups
-    assert eng._bt is group.bt and eng._free is group.free \
-        and eng._page_rc is group.page_rc and not group.derived
-    assert isinstance(eng._tables(), jax.Array)
+    if which != "phi4flash":
+        (group,) = eng._groups
+        assert eng._bt is group.bt and eng._free is group.free \
+            and eng._page_rc is group.page_rc and not group.derived
+        assert isinstance(eng._tables(), jax.Array)
     for name, bq, t, bound in (("decode", 1, eng.B, None),
                                ("admit", 8, 16, 2)):
         with jax.default_matmul_precision("highest"):

@@ -28,7 +28,7 @@ from paddle_tpu.models.cache_spec import (BlockDiffusionSpec,  # noqa: E402
                                           KVSpec, ReportSpec)
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM  # noqa: E402
 from paddle_tpu.models.serving import (ContinuousBatchingEngine,  # noqa: E402
-                                       EngineInvariantError,
+                                       EngineInvariantError, PoolExhausted,
                                        QuantServingConfig, SpecConfig)
 from paddle_tpu.ops import ragged_paged_attention as rpa      # noqa: E402
 from benchmark.reference import sdar_moe as ref                # noqa: E402
@@ -61,14 +61,18 @@ def _prompts(cfg, lens, seed=0):
 
 class _PassRecorder:
     """Sentry-shaped (`attach_sentry`'s contract): every pass's live
-    rows (slots, positions, chosen experts) and their logits."""
+    rows (slots, positions, chosen experts) and their logits, and beside
+    each the dispatch it came from (`ticks`: a fused dispatch gives two
+    passes)."""
     wants_logits = True
     trips = 0
 
     def __init__(self):
         self.passes, self.tokens, self._rows = [], [], None
+        self.ticks, self.dispatches = [], 0
 
     def step_tick(self):
+        self.dispatches += 1
         return True
 
     def note_cost(self, seconds):
@@ -82,6 +86,7 @@ class _PassRecorder:
 
     def observe_logits(self, lg):
         self.passes.append(self._rows + (np.asarray(lg, np.float32),))
+        self.ticks.append(self.dispatches)
 
 
 def _engine(model, **kw):
@@ -202,6 +207,17 @@ def test_engine_generates_the_references_blocks(rule):
                                  by_rid, rids)
     live = {len(s) // 4 for s, _, _, _ in rec.passes}
     assert live >= {2, 3}          # passes shared by slots out of phase
+    # a dispatch that commits finished blocks is given as two passes:
+    # those blocks, then every live slot's sampled block (a block
+    # further on for a fused slot), here beside slots that denoise
+    fused = [(a, b) for a, b, ta, tb in zip(rec.passes, rec.passes[1:],
+                                            rec.ticks, rec.ticks[1:])
+             if ta == tb]
+    assert fused and any(len(b[0]) > len(a[0]) for a, b in fused)
+    for (slots, positions, _, _), (later, at, _, _) in fused:
+        for j in range(0, len(slots), 4):
+            k = list(later).index(slots[j])
+            assert at[k] == positions[j] + 4
     n_passes = [len(by_rid[r]) for r in rids]
     blocks = [-(-(p % 4 + n) // 4) for p, n in zip(lens, budgets)]
     if rule == "static_4_steps":
@@ -215,6 +231,115 @@ def test_engine_generates_the_references_blocks(rule):
         assert 2 < per_block < 5, per_block   # the threshold fires, not always
     assert rec.tokens and len(rec.tokens) == sum(budgets)
     eng.check_invariants()
+
+
+@pytest.mark.parametrize("n", [1, 2, 3])
+def test_a_request_of_n_blocks_takes_4n_plus_1_passes(n):
+    """4 denoising passes a block; a finished block is committed in the
+    next block's first pass, and the last one, which ends the request,
+    in a pass of its own."""
+    model, cfg = _model()
+    eng, rec = _engine(model, max_batch_size=1)
+    prompt = _prompts(cfg, (12,), seed=4)[0]
+    rid = eng.add_request(prompt, max_new_tokens=4 * n)
+    done, by_rid = _run(eng, rec, 4)
+    assert rec.dispatches == 4 * n + 1
+    assert len(by_rid[rid]) == 5 * n       # the passes the reference lists
+    _check_against_the_reference(model, cfg, [prompt], [4 * n], done,
+                                 by_rid, [rid])
+
+
+
+# (engine settings, budget, tokens handed): 1 given token, then blocks
+ENDS = {
+    "budget": ({}, 7, lambda want: 7),       # a second block cut short
+    "eos": (lambda want: dict(eos_token_id=want[4]), 40,
+            lambda want: want.index(want[4]) + 1),
+    # blocks at 12, 16, 20: no room for a next one past 24
+    "max_seq_len": (dict(max_seq_len=24, prefill_chunk=8), 40,
+                    lambda want: 11),
+}
+
+
+@pytest.mark.parametrize("end", sorted(ENDS))
+def test_a_block_that_ends_its_request_commits_alone(end, monkeypatch):
+    """A request that ends by its budget, by EOS or because its next
+    block would pass `max_seq_len` commits its last block in a pass of
+    its own (the blocks before it fused), and hands the reference's
+    tokens."""
+    monkeypatch.setenv("PDT_TELEMETRY", "1")
+    telemetry.reset()
+    model, cfg = _model()
+    prompt = _prompts(cfg, (13,), seed=12)[0]
+    want = ref.generate_blocks(_weights(model), dict(vars(cfg)), prompt, 12)
+    kw, budget, handed = ENDS[end]
+    eng, rec = _engine(model, max_batch_size=1,
+                       **(kw(want) if callable(kw) else kw))
+    rid = eng.add_request(prompt, max_new_tokens=budget)
+    done, _ = _run(eng, rec, 4)
+    n = handed(want)
+    assert done[rid] == want[:n]
+    blocks = -(-(1 + n) // 4)
+    commits = telemetry.snapshot()["counters"][
+        "pdt_serving_block_commits_total"]
+    assert (commits.get('kind="fused"', 0), commits['kind="alone"']) \
+        == (blocks - 1, 1)
+    # 3 masks in the first block, 4 in the others, one pass alone
+    assert rec.dispatches == 3 + 4 * (blocks - 1) + 1
+
+
+def test_the_pages_hold_the_references_keys_and_values():
+    """After a run every position of a request's prompt and output holds
+    the keys and values of the reference's forward over prompt + output:
+    the fused pass wrote a finished block's rows from its final ids."""
+    model, cfg = _model()
+    eng, rec = _engine(model)
+    lens, budgets = (13, 16, 21), (11, 12, 7)   # each ends on a block
+    prompts = _prompts(cfg, lens, seed=13)
+    rids = [eng.add_request(p, max_new_tokens=n)
+            for p, n in zip(prompts, budgets)]
+    kept, release = {}, eng._release_slot
+
+    def keep(slot, register=True):
+        r = eng._slot_req[slot]
+        kept[r.rid] = (eng._bt[slot].copy(), int(eng._pos[slot]),
+                       [(np.asarray(k), np.asarray(v)) for k, v in eng._kv])
+        release(slot, register)
+
+    eng._release_slot = keep
+    done, _ = _run(eng, rec, 4)
+    w, sizes = _weights(model), dict(vars(cfg))
+    hk, d, eps = cfg.num_key_value_heads, cfg.head_dim, cfg.rms_norm_eps
+    for rid, p in zip(rids, prompts):
+        bt, pos, pools = kept[rid]
+        ids = p + done[rid]
+        assert pos == len(ids) and pos % 4 == 0
+        t = len(ids)
+        cos, sin = ref.rope_tables(d, t, cfg.rope_theta)
+        with jax.default_matmul_precision("highest"):
+            h = w["model.embed_tokens.weight"][jnp.asarray(ids)]
+            for layer, (kp, vp) in enumerate(pools):
+                pre = f"model.layers.{layer}."
+                lw = {k[len(pre):]: v for k, v in w.items()
+                      if k.startswith(pre)}
+                a = ref._rmsnorm(h, lw["input_layernorm.weight"], eps)
+                k = ref._rope(ref._rmsnorm(
+                    (a @ lw["self_attn.k_proj.weight"]).reshape(t, hk, d),
+                    lw["self_attn.k_norm.weight"], eps), cos, sin)
+                v = a @ lw["self_attn.v_proj.weight"]
+                rows = bt[np.arange(t) // 8], np.arange(t) % 8
+                assert _err(kp[rows].reshape(t, hk, d), np.asarray(k)) < TIGHT
+                assert _err(vp[rows], np.asarray(v)) < TIGHT
+                h = ref._attention(
+                    h, {k_: v_ for k_, v_ in lw.items()
+                        if not k_.startswith("mlp.")}, cos, sin,
+                    heads=cfg.num_attention_heads, kv_heads=hk,
+                    block=4, eps=eps)
+                h = ref._experts(
+                    h, {k_: v_ for k_, v_ in lw.items()
+                        if k_.startswith(("mlp.", "post_"))},
+                    top_k=cfg.num_experts_per_tok, offset=0,
+                    renorm=cfg.norm_topk_prob, eps=eps)[0]
 
 
 def test_a_token_equal_to_the_mask_id_stays_a_token():
@@ -486,12 +611,46 @@ def test_preempt_mid_block_and_resume():
     eng, rec = _engine(model, max_batch_size=2)
     prompts = _prompts(cfg, (21, 12), seed=9)
     rids = [eng.add_request(p, max_new_tokens=11) for p in prompts]
-    for _ in range(8):
+    for _ in range(7):
         eng.step()
     victim = eng._slot_req[1]
     assert eng._blk_passes[1] == 3 and len(victim.output) == 4
     eng._preempt_youngest([])
     assert eng.num_preemptions == 1 and not eng._blk_masked[1].any()
+    done, _ = _run(eng, rec, 4)
+    w, sizes = _weights(model), dict(vars(cfg))
+    for rid, p in zip(rids, prompts):
+        assert done[rid] == ref.generate_blocks(w, sizes, p, 11)
+    eng.check_invariants()
+
+
+def test_preempted_in_a_fused_pass_resumes_to_the_same_tokens(monkeypatch):
+    """A slot whose finished block would be committed in the next
+    block's first pass is preempted by that pass's page growth (a page
+    a block): it leaves with the tokens of the blocks committed before
+    it, its finished block is begun anew, and the tokens are the
+    reference's."""
+    model, cfg = _model()
+    eng, rec = _engine(model, max_batch_size=2, page_size=4)
+    prompts = _prompts(cfg, (21, 12), seed=9)
+    rids = [eng.add_request(p, max_new_tokens=11) for p in prompts]
+    for _ in range(8):
+        eng.step()
+    victim = eng._slot_req[1]
+    # its second block holds no mask: the next pass would fuse
+    assert not eng._blk_masked[1].any() and len(victim.output) == 4
+    real = eng._alloc_page
+
+    def exhausted(slot, g=None):
+        if slot == 1:
+            raise PoolExhausted("forced")
+        return real(slot, g)
+
+    monkeypatch.setattr(eng, "_alloc_page", exhausted)
+    eng.step()
+    monkeypatch.setattr(eng, "_alloc_page", real)
+    assert eng.num_preemptions == 1 and eng._slot_req[1] is not victim
+    assert len(victim.output) == 4
     done, _ = _run(eng, rec, 4)
     w, sizes = _weights(model), dict(vars(cfg))
     for rid, p in zip(rids, prompts):
@@ -582,25 +741,32 @@ def test_block_counters(monkeypatch):
     eng.run()
     snap = telemetry.snapshot()
     passes = snap["counters"]["pdt_serving_block_passes_total"]
-    # 2 requests x 2 blocks of 4 masks: 4 denoising passes and 1 commit
-    assert passes['kind="denoise"'] == 16 and passes['kind="commit"'] == 4
+    # 2 requests x 2 blocks of 4 masks: 4 denoising passes a block; the
+    # first block is committed in the second's first pass, the second
+    # (the request's last) in a pass of its own
+    assert passes['kind="denoise"'] == 16 and passes['kind="commit"'] == 2
+    commits = snap["counters"]["pdt_serving_block_commits_total"]
+    assert commits['kind="fused"'] == 2 and commits['kind="alone"'] == 2
     assert snap["counters"]["pdt_serving_block_tokens_total"][""] == 16
     assert snap["counters"]["pdt_serving_decode_tokens_total"][""] == 16
     assert snap["histograms"]["pdt_serving_block_seconds"][""]["count"] \
         == 4
     steps = [e for e in telemetry.events()
              if e.get("name") == "serving.decode_step"]
-    assert len(steps) == 10
-    assert {e["attrs"]["rows"] for e in steps} == {8}
+    assert len(steps) == 9
+    # both slots in phase: the fused pass runs both blocks of each
+    assert sorted(e["attrs"]["rows"] for e in steps) == [8] * 8 + [16]
     assert sum(e["attrs"]["commit_slots"] for e in steps) == 4
+    assert sum(e["attrs"]["fused_slots"] for e in steps) == 2
     # the expert layer reports through the counters Nemotron's does:
-    # 20 prompt rows and 10 passes x 8 rows, 4 choices, 2 layers
+    # 20 prompt rows and 8 passes x 8 rows + 1 x 16, 4 choices, 2
+    # layers (the padding rows of a pass take no expert)
     a = snap["counters"]["pdt_serving_moe_assignments_total"]
     assert a['kind="local"'] == (20 + 80) * 4 * 2 and \
         a.get('kind="remote"', 0) == 0
     e = snap["counters"]["pdt_serving_moe_experts_total"]
     # (two admission dispatches: 20 prompt rows at a chunk of 16)
-    assert e['kind="hit"'] + e['kind="idle"'] == (2 + 10) * 16 * 2
+    assert e['kind="hit"'] + e['kind="idle"'] == (2 + 9) * 16 * 2
     tiles = snap["counters"]["pdt_serving_moe_row_tiles_total"]
     assert tiles['kind="first"'] == e['kind="hit"']
 
@@ -698,6 +864,19 @@ def test_block_readers_on_a_hand_made_window():
     assert counter_ratio.read(
         obs, counter="pdt_serving_block_passes_total",
         numerator=['kind="commit"'], scale=100.0) == pytest.approx(20.0)
+    # commit_fused_share, through its own file: finished blocks committed
+    # in the next block's first pass, of all committed
+    with open(os.path.join(ROOT, "benchmark", "metrics",
+                           "commit_fused_share.json")) as f:
+        fused = json.load(f)
+    assert fused["reader"] == "counter_ratio"
+    for when, n_fused, alone in (("before", 100.0, 1.0),
+                                 ("after", 1507.0, 12.0)):
+        obs["telemetry"][when]["counters"][
+            "pdt_serving_block_commits_total"] = {
+                'kind="fused"': n_fused, 'kind="alone"': alone}
+    assert counter_ratio.read(obs, **fused["args"]) == pytest.approx(
+        100 * 1407 / 1418)
     # moe_tile_reuse_share, through its own file: the row tiles that
     # ran on a weight block already fetched, of all live row tiles
     with open(os.path.join(ROOT, "benchmark", "metrics",
@@ -720,6 +899,7 @@ def test_block_readers_on_a_hand_made_window():
     assert counter_over_counter.read(empty, numerator="a",
                                      denominator="b") is None
     assert counter_ratio.read(empty, **reuse["args"]) is None
+    assert counter_ratio.read(empty, **fused["args"]) is None
 
 
 def _blocks_check(seed=2, model_kw=None, **spec):
