@@ -235,17 +235,6 @@ _M_DECODE_STEP = telemetry.histogram(
     "pdt_serving_decode_step_seconds",
     "Wall time of one batched decode dispatch incl. its D2H sync "
     "(the synchronous harvest_every=1 path).")
-# pipelined decode (harvest_every=k, ISSUE 18): dispatch wall and
-# harvest/D2H wall are SEPARATE histograms — the single step histogram
-# conflates exactly the two costs the overlap window trades off
-_M_DECODE_DISPATCH = telemetry.histogram(
-    "pdt_serving_decode_dispatch_seconds",
-    "Wall time of one batched decode dispatch WITHOUT its D2H sync "
-    "(the device-feedback half of the pipelined hot loop).")
-_M_HARVEST = telemetry.histogram(
-    "pdt_serving_harvest_seconds",
-    "Wall time of one batched harvest: the D2H sync over a whole "
-    "deferred window (harvest_every dispatches) plus token commits.")
 _M_DECODE_TOKENS = telemetry.counter(
     "pdt_serving_decode_tokens_total",
     "Tokens emitted by decode steps (excludes prefill first tokens).")
@@ -3021,25 +3010,30 @@ class ContinuousBatchingEngine:
                                 p["sample"] and not blocks
                                 for p in batch)), \
                 self._tp_scope():
-            jit = self._get_ragged_prefill(t_pad, bound)
-            # multi-LoRA: each packed row gathers its OWNING slot's
-            # adapter row (padding rows gather slot 0's — inert, their
-            # outputs are never read and the epilogue has no
-            # cross-token reduction)
-            pv = self._lora_pv(
-                self._pv(),
-                self._slot_adapter[np.asarray(pk["token_seq"],
-                                              np.int32)])
-            nxt, _, reports = self._take_step(jit(
-                pv, self._bv(),
-                self._cache(), jnp.asarray(pk["ids"]),
-                jnp.asarray(pk["token_seq"]),
-                jnp.asarray(pk["positions"]),
-                jnp.asarray(pk["query_start"]),
-                jnp.asarray(pk["query_len"]),
-                jnp.asarray(pk["context_len"]),
-                self._tables(), jnp.asarray(pk["sample_rows"]),
-                self._next_keys()))
+            # the dispatch's three phases: its operands (inputs), the
+            # call until it returns (launch), and in the span's own time
+            # the wait for its result
+            with telemetry.span("serving.inputs"):
+                jit = self._get_ragged_prefill(t_pad, bound)
+                # multi-LoRA: each packed row gathers its OWNING slot's
+                # adapter row (padding rows gather slot 0's — inert,
+                # their outputs are never read and the epilogue has no
+                # cross-token reduction)
+                pv = self._lora_pv(
+                    self._pv(),
+                    self._slot_adapter[np.asarray(pk["token_seq"],
+                                                  np.int32)])
+                args = (pv, self._bv(),
+                        self._cache(), jnp.asarray(pk["ids"]),
+                        jnp.asarray(pk["token_seq"]),
+                        jnp.asarray(pk["positions"]),
+                        jnp.asarray(pk["query_start"]),
+                        jnp.asarray(pk["query_len"]),
+                        jnp.asarray(pk["context_len"]),
+                        self._tables(), jnp.asarray(pk["sample_rows"]),
+                        self._next_keys())
+            with telemetry.span("serving.launch"):
+                nxt, _, reports = self._take_step(jit(*args))
             nxt = np.asarray(nxt)
         for p in batch if self._window_groups else ():
             self._reclaim_below_window(
@@ -3699,29 +3693,31 @@ class ContinuousBatchingEngine:
             # (tokens/sec derives from it) — a fake clock here would
             # fabricate hardware throughput, not make tests exact
             t0 = time.perf_counter()
-            bidx = self._decode_idx
-            qlen = self._decode_query_lens()
-            # pipelined mode: mid-window the token input is the
-            # PREVIOUS dispatch's on-device output — the greedy
-            # feedback needs no host round-trip (the whole point)
-            tok_in = (self._tok_dev if self._tok_dev is not None
-                      else jnp.asarray(self._tok))
+            # inputs and launch as in _dispatch_ragged; the block
+            # tables were uploaded above, in serving.decode's own time
             with self._tp_scope():
-                # multi-LoRA: decode packs one row per slot in slot
-                # order, so the gather vector IS the slot-adapter map
-                out = self._decode_jit(
-                    self._lora_pv(self._pv(), self._slot_adapter),
-                    self._bv(),
-                    kv, tok_in, bidx,
-                    jnp.asarray(pos.astype(np.int32)), bidx,
-                    qlen,
-                    jnp.asarray((pos + 1).astype(np.int32)), bt,
-                    bidx, self._next_keys())
-            nxt, lg_rows, reports = self._take_step(
-                out, self._decode_logits)
+                with telemetry.span("serving.inputs"):
+                    bidx = self._decode_idx
+                    qlen = self._decode_query_lens()
+                    # pipelined mode: mid-window the token input is the
+                    # PREVIOUS dispatch's on-device output — the greedy
+                    # feedback needs no host round-trip (the whole point)
+                    tok_in = (self._tok_dev if self._tok_dev is not None
+                              else jnp.asarray(self._tok))
+                    # multi-LoRA: decode packs one row per slot in slot
+                    # order, so the gather vector IS the slot-adapter map
+                    args = (self._lora_pv(self._pv(), self._slot_adapter),
+                            self._bv(),
+                            kv, tok_in, bidx,
+                            jnp.asarray(pos.astype(np.int32)), bidx,
+                            qlen,
+                            jnp.asarray((pos + 1).astype(np.int32)), bt,
+                            bidx, self._next_keys())
+                with telemetry.span("serving.launch"):
+                    nxt, lg_rows, reports = self._take_step(
+                        self._decode_jit(*args), self._decode_logits)
             # pdt-lint: disable=PDT001 same real-wall measurement as t0
             t1 = time.perf_counter()
-            _M_DECODE_DISPATCH.observe(t1 - t0)
             if self.harvest_every > 1:
                 # deferred-harvest path: the token vector stays on
                 # device; defer the sync, commits, and sentry checks to
@@ -3760,9 +3756,6 @@ class ContinuousBatchingEngine:
             # pdt-lint: disable=PDT001 same real-wall measurement
             dt = time.perf_counter() - t0
         if telemetry.enabled():
-            # the D2H sync wait is the device-side remainder of the
-            # step (dispatch returned before the device finished)
-            _M_HARVEST.observe(dt - (t1 - t0))
             _M_DECODE_STEP.observe(dt)
             _M_DECODE_TOKENS.inc(n_active)
             if dt > 0:
@@ -3929,40 +3922,41 @@ class ContinuousBatchingEngine:
             # pdt-lint: disable=PDT001 decode_step_seconds measures the
             # REAL wall time of one device dispatch incl. its D2H sync
             t0 = time.perf_counter()
-            slot = np.arange(self.B)[:, None]
-            tok_seq = np.concatenate(
-                [np.where(on[:, None], slot, -1).repeat(b, 1),
-                 np.where(fuse[:, None], slot, -1).repeat(b, 1)],
-                axis=1).reshape(-1)
-            qpos = np.concatenate([pos[:, None] + np.arange(b),
-                                   at[:, None] + np.arange(b)],
-                                  axis=1).reshape(-1)
-            ids = np.concatenate(
-                [self._blk_ids,
-                 np.full_like(self._blk_ids, self._gen.mask_token_id)],
-                axis=1).reshape(-1)
-            # the rows the rule reads: a fused segment's second half,
-            # any other's first
-            read = (slot * seg + np.where(fuse, b, 0)[:, None]
-                    + np.arange(b)).reshape(-1)
+            # inputs and launch as in _dispatch_ragged
             with self._tp_scope():
-                out = self._decode_jit(
-                    self._lora_pv(self._pv(),
-                                  np.repeat(self._slot_adapter, seg)),
-                    self._bv(), self._cache(),
-                    jnp.asarray(ids.astype(np.int32)),
-                    jnp.asarray(tok_seq.astype(np.int32)),
-                    jnp.asarray(qpos.astype(np.int32)),
-                    self._decode_idx, jnp.asarray(qlen),
-                    jnp.asarray(ctx), jnp.asarray(self._bt),
-                    (jnp.asarray(read.astype(np.int32)),
-                     jnp.asarray(masked.reshape(-1)),
-                     jnp.asarray(passes)), self._next_keys())
-            nxt, lg_rows, reports = self._take_step(
-                out, self._decode_logits)
-            # pdt-lint: disable=PDT001 same real-wall measurement as t0
-            t1 = time.perf_counter()
-            _M_DECODE_DISPATCH.observe(t1 - t0)
+                with telemetry.span("serving.inputs"):
+                    slot = np.arange(self.B)[:, None]
+                    tok_seq = np.concatenate(
+                        [np.where(on[:, None], slot, -1).repeat(b, 1),
+                         np.where(fuse[:, None], slot, -1).repeat(b, 1)],
+                        axis=1).reshape(-1)
+                    qpos = np.concatenate([pos[:, None] + np.arange(b),
+                                           at[:, None] + np.arange(b)],
+                                          axis=1).reshape(-1)
+                    ids = np.concatenate(
+                        [self._blk_ids,
+                         np.full_like(self._blk_ids,
+                                      self._gen.mask_token_id)],
+                        axis=1).reshape(-1)
+                    # the rows the rule reads: a fused segment's second
+                    # half, any other's first
+                    read = (slot * seg + np.where(fuse, b, 0)[:, None]
+                            + np.arange(b)).reshape(-1)
+                    args = (self._lora_pv(self._pv(),
+                                          np.repeat(self._slot_adapter,
+                                                    seg)),
+                            self._bv(), self._cache(),
+                            jnp.asarray(ids.astype(np.int32)),
+                            jnp.asarray(tok_seq.astype(np.int32)),
+                            jnp.asarray(qpos.astype(np.int32)),
+                            self._decode_idx, jnp.asarray(qlen),
+                            jnp.asarray(ctx), jnp.asarray(self._bt),
+                            (jnp.asarray(read.astype(np.int32)),
+                             jnp.asarray(masked.reshape(-1)),
+                             jnp.asarray(passes)), self._next_keys())
+                with telemetry.span("serving.launch"):
+                    nxt, lg_rows, reports = self._take_step(
+                        self._decode_jit(*args), self._decode_logits)
             nxt = self._harvest_sync(nxt)
             # pdt-lint: disable=PDT001 same real-wall measurement
             dt = time.perf_counter() - t0
@@ -3991,7 +3985,6 @@ class ContinuousBatchingEngine:
             self._blk_passes[i] += 1
         # counted after the step's timed part, telemetry on only
         if telemetry.enabled():
-            _M_HARVEST.observe(dt - (t1 - t0))
             _M_DECODE_STEP.observe(dt)
             _M_DECODE_TOKENS.inc(len(handed))
             _M_BLOCK_TOKENS.inc(len(handed))
@@ -4123,15 +4116,12 @@ class ContinuousBatchingEngine:
             return
         with telemetry.span("serving.harvest",
                             window=len(entries)):
-            # pdt-lint: disable=PDT001 harvest_seconds is REAL wall,
+            # pdt-lint: disable=PDT001 the window's sync is REAL wall,
             # like decode_step_seconds (hardware-honesty throughput)
             t0 = time.perf_counter()
             stacked = np.asarray(jnp.stack([e["nxt"] for e in entries]))
             # pdt-lint: disable=PDT001 same real-wall measurement
             harvest_dt = time.perf_counter() - t0
-        # the window's one batched D2H sync is where the deferred
-        # rounds' device time surfaces on the host clock
-        _M_HARVEST.observe(harvest_dt)
         with telemetry.span("serving.commit", window=len(entries)):
             n_committed = self._commit_window(entries, stacked,
                                               finished)

@@ -55,13 +55,23 @@ lands in the XLA timeline (TraceAnnotation) and in
 fault-tolerant — the ring buffer works in processes that never import
 jax.
 
+GARBAGE COLLECTION is named where it happens: one `gc.callbacks` hook,
+installed once at import, times every collection into
+`pdt_host_gc_seconds{generation}`; a generation-2 collection also
+leaves one `host.gc` record, shaped as an `interval()` record, and
+enters a `RecordEvent` so that it sits in the device trace's host plane
+beside the spans. It is no span: it runs inside whatever span is open,
+and that span's self time holds it.
+
 Like the metrics registry, spans are a guaranteed no-op while telemetry
 is disabled: `span()` returns a singleton null context manager,
-`event()` / `start_trace()` return immediately.
+`event()` / `start_trace()` return immediately, and the collection hook
+returns at its first line.
 """
 from __future__ import annotations
 
 import contextlib
+import gc
 import itertools
 import json
 import os
@@ -84,13 +94,18 @@ _SEQ = itertools.count()
 _TLS = threading.local()
 
 # host slices from microseconds up to multi-second cold dispatches
+_HOST_BUCKETS = (1e-5, 2.5e-5, 5e-5, 1e-4, 2.5e-4, 5e-4, 0.001, 0.0025,
+                 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0)
 _M_SELF = histogram(
     "pdt_span_self_seconds",
     "Self time of a span: its duration minus its direct children's, "
     "by span name. Self times of one tree are disjoint and add up to "
-    "the root's duration.", ("name",),
-    buckets=(1e-5, 2.5e-5, 5e-5, 1e-4, 2.5e-4, 5e-4, 0.001, 0.0025,
-             0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0))
+    "the root's duration.", ("name",), buckets=_HOST_BUCKETS)
+_M_GC = histogram(
+    "pdt_host_gc_seconds",
+    "One garbage collection of the interpreter, from its start to its "
+    "stop, by generation. It runs inside whatever span is open.",
+    ("generation",), buckets=_HOST_BUCKETS)
 
 # -- the one clock ----------------------------------------------------
 # Every stamp is perf_counter; wall time is DERIVED from this base pair
@@ -169,11 +184,13 @@ def _emit(ev: dict):
 
 def events() -> List[dict]:
     """Snapshot of the ring buffer, oldest first."""
+    _emit_gc_records()
     with _LOCK:
         return list(_RING)
 
 
 def clear():
+    _GC_DONE.clear()
     with _LOCK:
         _RING.clear()
     with _CARRIER_LOCK:
@@ -338,6 +355,8 @@ class _Span:
             ev["attrs"] = dict(self.attrs,
                                error=f"{exc_type.__name__}: {exc}")
         _emit(ev)
+        if _GC_DONE:
+            _emit_gc_records()
         return False
 
 
@@ -363,13 +382,22 @@ def interval(name: str, seconds: float, /, **attrs):
     wait was not time spent inside it. No-op while disabled."""
     if not enabled():
         return
+    _emit(_interval_record(name, seconds, _CLOCK(), attrs))
+    _emit_gc_records()
+
+
+def _interval_record(name: str, seconds: float, end: float,
+                     attrs: dict) -> dict:
+    """An `interval()` record of a duration that ended at `end` on the
+    one clock. Takes no lock of this module (the collection hook builds
+    its records with it)."""
     stack = getattr(_TLS, "stack", None) or []
     parent, trace, depth = _resolve_links(stack, attrs)
     dur = float(seconds)
-    t = _CLOCK() - dur
-    _emit({"name": name, "attrs": attrs, "ts": _wall(t), "ts_mono": t,
-           "dur_s": dur, "seq": next(_SEQ), "depth": depth,
-           "parent": parent, "trace": trace})
+    t = end - dur
+    return {"name": name, "attrs": attrs, "ts": _wall(t), "ts_mono": t,
+            "dur_s": dur, "seq": next(_SEQ), "depth": depth,
+            "parent": parent, "trace": trace}
 
 
 def event(name: str, **attrs):
@@ -377,6 +405,70 @@ def event(name: str, **attrs):
     membership changes. A `request_id=` attr joins the request's
     distributed trace. No-op while disabled."""
     interval(name, 0.0, **attrs)
+
+
+# -- garbage collection --------------------------------------------------
+# A collection starts at whichever allocation crosses a threshold, one
+# inside `_emit` too (json.dumps allocates while `_LOCK` is held; the
+# sink's write may be mid-call). So the hook takes no lock of this
+# module and writes nothing: a `host.gc` record waits in `_GC_DONE`
+# (a deque's append and popleft need no lock) until the next span exit,
+# `interval()` or `events()` emits it. Collections do not overlap, and
+# a collection's start and stop run in one thread: one open slot.
+_GC_DONE: "deque[dict]" = deque()
+_GC_OPEN: list = [None, None]       # perf_counter at start, RecordEvent
+
+
+def _emit_gc_records():
+    while _GC_DONE:
+        try:
+            ev = _GC_DONE.popleft()
+        except IndexError:          # another thread took the last one
+            return
+        _emit(ev)
+
+
+def _on_gc(phase: str, info: dict):
+    """The `gc.callbacks` hook (module docstring)."""
+    if not enabled() and _GC_OPEN[0] is None:
+        return
+    if phase == "start":
+        rec = None
+        # the RecordEvent class only once a span has resolved it: an
+        # import inside a collection is no place to resolve it
+        if info["generation"] == 2 and _RECORD_EVENT:
+            try:
+                rec = _RECORD_EVENT("host.gc")
+                rec.begin()
+            except Exception:
+                rec = None          # profiler backend unavailable
+        _GC_OPEN[1] = rec
+        _GC_OPEN[0] = _CLOCK()
+        return
+    t0, rec = _GC_OPEN
+    if t0 is None:
+        return                      # telemetry came on mid-collection
+    t1 = _CLOCK()
+    _GC_OPEN[0] = _GC_OPEN[1] = None
+    if rec is not None:
+        try:
+            rec.end()
+        except Exception:
+            pass
+    gen = info["generation"]
+    _M_GC.observe(t1 - t0, generation=gen)
+    if gen == 2 and enabled():
+        _GC_DONE.append(_interval_record(
+            "host.gc", t1 - t0, t1,
+            {"generation": gen, "collected": info["collected"],
+             "uncollectable": info["uncollectable"]}))
+
+
+_on_gc.pdt_trace_hook = True
+# once a process, however often this file is executed (a reload, a copy
+# imported under another name)
+if not any(getattr(cb, "pdt_trace_hook", False) for cb in gc.callbacks):
+    gc.callbacks.append(_on_gc)
 
 
 # -- offline tooling ---------------------------------------------------

@@ -104,6 +104,66 @@ def test_span_self_reads_window_deltas():
         "before": _snap(), "after": _snap()}}) is None
 
 
+def test_dispatch_phases_split_the_window():
+    """`dispatch_host_share` reads the two phases' self time, and
+    `device_wait_share` the dispatch spans' own. `host_self_time_share`
+    holds the phases (they are host time), so it and the waits (and
+    `jit.compile`) are the whole of the window's self time, and the
+    phases are a part of it. A parent without the phases reads 0.0."""
+    phases = {"serving.inputs": (0.5, 10), "serving.launch": (1.0, 10)}
+    after = _self(**{"router.step": (1.5, 20), "serving.admit": (2.25, 20),
+                     "serving.commit": (0.25, 10),
+                     "serving.ragged_prefill": (6.0, 8),
+                     "serving.decode_step": (14.0, 20),
+                     "jit.compile": (3.0, 1), **phases})
+    before = _self(**{"router.step": (1.0, 10), "serving.admit": (2.0, 10),
+                      "serving.ragged_prefill": (4.0, 4),
+                      "serving.decode_step": (8.0, 10)})
+    obs = {"window_s": 10.0, "telemetry": {"before": _snap(before),
+                                           "after": _snap(after)}}
+    shares = {}
+    for name in ("dispatch_host_share", "device_wait_share",
+                 "host_self_time_share"):
+        reader, args = _metric(name)
+        shares[name] = reader.read(obs, **args)
+    # (0.5 + 1.0) of phases; (2.0 + 6.0) of waits; 1.0 of host before
+    # and 1.5 more now that the phases are spans
+    assert shares == pytest.approx({"dispatch_host_share": 15.0,
+                                    "device_wait_share": 80.0,
+                                    "host_self_time_share": 25.0})
+    total = sum(s["sum"] for s in after["pdt_span_self_seconds"].values()) \
+        - sum(s["sum"] for s in before["pdt_span_self_seconds"].values())
+    jit = 3.0 / 10.0 * 100.0
+    assert shares["host_self_time_share"] + shares["device_wait_share"] \
+        + jit == pytest.approx(total / 10.0 * 100.0)
+    reader, args = _metric("dispatch_host_share")
+    assert reader.read(OBS, **args) == 0.0
+
+
+def test_gc_time_share_reads_the_histograms_window_delta():
+    reader, args = _metric("gc_time_share")
+
+    def gc_hist(**sums):
+        return {"pdt_host_gc_seconds": {f'generation="{g}"': {
+            "sum": s, "count": 1} for g, s in sums.items()}}
+
+    def obs(before, after):
+        return {"window_s": 2.0, "telemetry": {"before": _snap(before),
+                                               "after": _snap(after)}}
+    # (0.04 - 0.01) + (0.06 - 0.0) s of collections in a 2 s window
+    assert reader.read(obs(gc_hist(**{"0": 0.01}),
+                           gc_hist(**{"0": 0.04, "2": 0.06})),
+                       **args) == pytest.approx(4.5)
+    assert reader.read(obs(gc_hist(**{"0": 0.01}), gc_hist(**{"0": 0.01})),
+                       **args) == 0.0
+    # a program without the histogram (the parent): nothing to read
+    assert reader.read(obs({}, _self(**{"router.step": (1.0, 1)})),
+                       **args) is None
+    assert reader.read({"telemetry": None}, **args) is None
+    from paddle_tpu.observability import trace
+    assert trace._M_GC.name == args["histogram"]
+
+
 def test_counter_ratio_reads_window_deltas():
     reader, args = _metric("prefill_pad_share")
     assert reader.read(OBS, **args) == pytest.approx(25.0)

@@ -15,8 +15,11 @@ The two acceptance gates pinned here:
 conftest runs this file with PDT_TELEMETRY=1 and
 PDT_CHECK_INVARIANTS=1 and attaches the profile report to failing
 reports."""
+import gc
+import importlib
 import json
 import re
+import threading
 import time
 
 import pytest
@@ -120,6 +123,27 @@ def _spans():
     return [e for e in telemetry.events() if "self_s" in e]
 
 
+DISPATCH = ("serving.decode_step", "serving.ragged_prefill")
+
+
+def _dispatches(spans):
+    """Each dispatch span with its direct children in start order."""
+    kids = {}
+    for e in spans:
+        kids.setdefault(e["parent"], []).append(e)
+    return [(e, sorted(kids.get(e["seq"], []), key=lambda c: c["ts_mono"]))
+            for e in spans if e["name"] in DISPATCH]
+
+
+def _assert_split(spans):
+    for e, kids in _dispatches(spans):
+        assert [c["name"] for c in kids] == [
+            "serving.inputs", "serving.launch"], (e["name"], kids)
+        assert e["self_s"] == pytest.approx(
+            e["dur_s"] - (kids[0]["dur_s"] + kids[1]["dur_s"]),
+            rel=1e-12, abs=1e-15)
+
+
 def _tree_of(root, spans):
     """`root` and every span below it."""
     kids = {}
@@ -155,9 +179,11 @@ class TestStepTree:
                 ("serving.admit", "serving.step"),
                 ("serving.prefill", "serving.admit"),
                 ("serving.ragged_prefill", "serving.admit"),
-                ("jit.compile", "serving.ragged_prefill"),
+                ("serving.inputs", "serving.ragged_prefill"),
+                ("jit.compile", "serving.launch"),
                 ("serving.decode", "serving.step"),
                 ("serving.decode_step", "serving.decode"),
+                ("serving.launch", "serving.decode_step"),
                 ("serving.commit", "serving.step"),
                 ("serving.invariants", "serving.step")} <= edges, edges
         roots = {e["name"] for e in spans if e["parent"] is None}
@@ -191,6 +217,7 @@ class TestStepTree:
         names = {lbl.split('"')[1] for lbl in series}
         assert {"router.step", "serving.step", "serving.admit",
                 "serving.decode", "serving.decode_step",
+                "serving.inputs", "serving.launch",
                 "serving.commit"} <= names
         if k > 1:
             assert "serving.harvest" in names
@@ -217,6 +244,62 @@ class TestStepTree:
         ragged = next(e for e in tree
                       if e["name"] == "serving.ragged_prefill")
         assert admit["self_s"] <= admit["dur_s"] - ragged["dur_s"] + 1e-9
+
+    @pytest.mark.parametrize("k", [1, 4])
+    def test_token_dispatches_hold_inputs_then_launch(self, model, k):
+        """Every admission and decode dispatch: one `serving.inputs`,
+        then one `serving.launch`, and the rest (the wait for the
+        result) is the dispatch span's own time. At k 4 the window's
+        sync is `serving.harvest`, which has no children."""
+        eng = _engine(model, k)
+        for p, n in JOBS:
+            eng.add_request(list(p), 6)
+        eng.run()
+        spans = _spans()
+        assert {e["name"] for e, _ in _dispatches(spans)} == set(DISPATCH)
+        _assert_split(spans)
+        harvests = [e for e in spans if e["name"] == "serving.harvest"]
+        assert bool(harvests) == (k > 1)
+        assert not any(e["parent"] in {h["seq"] for h in harvests}
+                       for e in spans)
+
+    def test_block_pass_holds_inputs_then_launch(self):
+        """The same split on a block model's pass (the numpy
+        descriptors of the pass are the inputs) and its admissions."""
+        from paddle_tpu.models import sdar
+        paddle.seed(0)
+        m = sdar.SdarMoeForCausalLM(sdar.SdarMoeConfig.tiny())
+        m.eval()
+        eng = ContinuousBatchingEngine(m, max_batch_size=3,
+                                       max_seq_len=128, page_size=8,
+                                       prefill_chunk=16, prompt_pad=8)
+        for n in (5, 11, 20):
+            eng.add_request(list(range(1, n + 1)), 9)
+        eng.run()
+        spans = _spans()
+        passes = [e for e, _ in _dispatches(spans)
+                  if e["name"] == "serving.decode_step"]
+        assert passes and all("rows" in e["attrs"] for e in passes)
+        assert any(e["name"] == "serving.ragged_prefill"
+                   for e, _ in _dispatches(spans))
+        _assert_split(spans)
+
+    def test_decode_step_seconds_times_the_dispatch_and_its_sync(
+            self, model):
+        """`pdt_serving_decode_step_seconds` keeps its points: one
+        observation a synchronous dispatch, taken inside the dispatch
+        span, around both phases and the sync."""
+        eng = _engine(model)
+        for p, n in JOBS:
+            eng.add_request(list(p), 6)
+        eng.run()
+        steps = [(e, kids) for e, kids in _dispatches(_spans())
+                 if e["name"] == "serving.decode_step"]
+        hist = telemetry.snapshot()["histograms"][
+            "pdt_serving_decode_step_seconds"][""]
+        assert hist["count"] == len(steps) > 0
+        phases = sum(c["dur_s"] for _, kids in steps for c in kids)
+        assert phases < hist["sum"] <= sum(e["dur_s"] for e, _ in steps)
 
     def test_queue_wait_once_a_claim_on_the_engine_clock(self, model):
         """`serving.queue_wait` at each claim of a slot, `dur_s` = the
@@ -297,6 +380,126 @@ class TestStepTree:
         assert modules["decode"] == "jit_pdt_decode"
         assert re.fullmatch(r"jit_pdt_ragged_t\d+",
                             modules["ragged"]), modules
+
+
+# -- garbage collection --------------------------------------------------
+class TestGcHook:
+    def test_collection_in_a_span_is_timed_and_recorded(self):
+        """One forced collection inside a span: one observation of
+        generation 2, one `host.gc` record inside the span and parented
+        under it, and the spans' self times still sum exactly."""
+        was = gc.isenabled()
+        gc.disable()                   # no automatic collection besides
+        try:
+            with telemetry.span("t.outer"):
+                with telemetry.span("t.inner"):
+                    gc.collect()
+        finally:
+            if was:
+                gc.enable()
+        hist = telemetry.snapshot()["histograms"]["pdt_host_gc_seconds"]
+        assert hist['generation="2"']["count"] == 1
+        evts = telemetry.events()
+        recs = [e for e in evts if e["name"] == "host.gc"]
+        assert len(recs) == 1
+        rec = recs[0]
+        assert set(rec["attrs"]) == {"generation", "collected",
+                                     "uncollectable"}
+        assert rec["attrs"]["generation"] == 2 and "self_s" not in rec
+        assert rec["dur_s"] == hist['generation="2"']["sum"]
+        outer, inner = (next(e for e in evts if e["name"] == n)
+                        for n in ("t.outer", "t.inner"))
+        assert rec["parent"] == inner["seq"]
+        assert inner["ts_mono"] <= rec["ts_mono"]
+        assert rec["ts_mono"] + rec["dur_s"] \
+            <= inner["ts_mono"] + inner["dur_s"]
+        assert outer["self_s"] + inner["self_s"] == pytest.approx(
+            outer["dur_s"], rel=1e-12)
+        series = telemetry.snapshot()["histograms"]["pdt_span_self_seconds"]
+        assert sum(v["sum"] for v in series.values()) == pytest.approx(
+            outer["dur_s"], rel=1e-12)
+
+    def test_telemetry_off_records_nothing(self, monkeypatch):
+        monkeypatch.delenv("PDT_TELEMETRY", raising=False)
+        telemetry.disable()
+        telemetry.reset()
+        try:
+            with telemetry.span("t.off"):
+                gc.collect()
+            snap = telemetry.snapshot()
+        finally:
+            telemetry.disable(clear_override=True)
+        assert "pdt_host_gc_seconds" not in snap["histograms"]
+        assert telemetry.events() == []
+
+    def test_collections_inside_emit_neither_deadlock_nor_lose_a_line(
+            self, tmp_path):
+        """A collection at nearly every allocation (threshold 1), json
+        and the sink's write among them, while a burst of spans writes
+        to a file; some spans force a generation-2 collection in their
+        body and the sink forces one inside `_emit`, with the module's
+        lock held, at every tenth span line: the burst ends, and the
+        file holds every record the ring holds."""
+        path = tmp_path / "t.jsonl"
+        telemetry.set_trace_file(str(path))
+
+        class CollectingSink:
+            def __init__(self, f):
+                self.f, self.lines = f, 0
+
+            def write(self, line):
+                if '"host.gc"' not in line:
+                    self.lines += 1
+                    if self.lines % 10 == 0:
+                        gc.collect()
+                self.f.write(line)
+
+            def close(self):
+                self.f.close()
+
+        trace = importlib.import_module("paddle_tpu.observability.trace")
+        trace._SINK_FILE = CollectingSink(open(path, "a", buffering=1))
+
+        def burst():
+            for i in range(200):
+                with telemetry.span("t.burst", i=i,
+                                    pad=[{"k": j} for j in range(8)]):
+                    if i % 20 == 0:
+                        gc.collect()
+
+        old = gc.get_threshold()
+        th = threading.Thread(target=burst, daemon=True)
+        gc.set_threshold(1)
+        try:
+            th.start()
+            th.join(timeout=120)
+        finally:
+            gc.set_threshold(*old)
+        alive = th.is_alive()
+        if not alive:                  # the sink's lock is free
+            ring = [e for e in telemetry.events()
+                    if e["name"] in ("t.burst", "host.gc")]
+            telemetry.set_trace_file(None)
+        assert not alive, "the burst deadlocked"
+        lines = [json.loads(ln) for ln in path.read_text().splitlines()]
+        assert sorted(e["attrs"]["i"] for e in lines
+                      if e["name"] == "t.burst") == list(range(200))
+        assert sum(e["name"] == "host.gc" for e in lines) >= 30
+        assert [e["seq"] for e in ring] == [e["seq"] for e in lines]
+
+    def test_importing_the_module_twice_installs_one_hook(self):
+        import importlib.util
+
+        def hooks():
+            return [cb for cb in gc.callbacks
+                    if getattr(cb, "pdt_trace_hook", False)]
+
+        trace = importlib.import_module("paddle_tpu.observability.trace")
+        assert hooks() == [trace._on_gc]
+        spec = importlib.util.spec_from_file_location(
+            "paddle_tpu.observability._trace_again", trace.__file__)
+        spec.loader.exec_module(importlib.util.module_from_spec(spec))
+        assert hooks() == [trace._on_gc]
 
 
 # -- compile-cache observability ---------------------------------------
