@@ -104,6 +104,54 @@ def precompute_rope(head_dim: int, max_len: int, theta: float):
             paddle.to_tensor(np.sin(freqs).astype(np.float32)))
 
 
+def yarn_ramp(head_dim: int, params: dict) -> np.ndarray:
+    """YaRN's share of interpolation a rotary pair (head_dim / 2,): 0 up
+    to dim `low`, 1 from dim `high`, linear between; the edges are where
+    a pair turns `beta_fast` and `beta_slow` times over the pretrained
+    context, floored and ceiled (`truncate`, the transformers
+    `_compute_yarn_parameters` default)."""
+    theta = float(params["rope_theta"])
+    ctx = float(params["original_max_position_embeddings"])
+
+    def dim(turns):
+        return head_dim * math.log(ctx / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(dim(float(params.get("beta_fast", 32)))), 0)
+    high = min(math.ceil(dim(float(params.get("beta_slow", 1)))),
+               head_dim - 1)
+    if high == low:
+        high += 0.001
+    return np.clip((np.arange(head_dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+
+
+def precompute_rope_scaled(head_dim: int, max_len: int, params: dict):
+    """`precompute_rope` of one entry of a config's `rope_parameters`:
+    `default` is plain RoPE at `rope_theta`; `yarn` divides the
+    frequency of the pairs above `yarn_ramp`'s edge by `factor`, blends
+    the pairs between, and scales cos and sin by `attention_factor`
+    (0.1 ln factor + 1 where absent), so that q.k is scaled by its
+    square. Any other `rope_type` is refused by name."""
+    kind = params.get("rope_type", "default")
+    if kind not in ("default", "yarn"):
+        raise ValueError(f"rope_type {kind!r}: only 'default' and 'yarn' "
+                         "are implemented")
+    theta = float(params["rope_theta"])
+    inv = 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float64)
+                           / head_dim))
+    scale = 1.0
+    if kind == "yarn":
+        factor = float(params["factor"])
+        ramp = yarn_ramp(head_dim, params)
+        inv = inv / factor * ramp + inv * (1.0 - ramp)
+        scale = float(params.get("attention_factor")
+                      or 0.1 * math.log(factor) + 1.0)
+    freqs = np.outer(np.arange(max_len, dtype=np.float64), inv)
+    return (paddle.to_tensor((scale * np.cos(freqs)).astype(np.float32)),
+            paddle.to_tensor((scale * np.sin(freqs)).astype(np.float32)))
+
+
 def apply_rope(x: Tensor, cos: Tensor, sin: Tensor, position_offset=0):
     """x: (B, S, H, D) — Pallas fused rope kernel (custom VJP = inverse
     rotation). ≙ fused_rotary_position_embedding

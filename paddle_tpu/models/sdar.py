@@ -22,6 +22,9 @@ docstring; this file computes them for the serving engine:
 `block_length` 1 is the causal model: `generation_spec()` is None and
 the engine serves it a token a step. The forward pass without a cache
 (tests, the logits of whole sequences) applies the same mask densely.
+An attention layer may keep a window (`SdarMoeAttention(cfg, window)`),
+and the model chooses each layer's rotary table (`layer_rope`):
+`models/mellum.py` is the causal model with both.
 """
 from __future__ import annotations
 
@@ -164,13 +167,16 @@ class SdarMoeExperts(nn.Layer):
 
 class SdarMoeAttention(nn.Layer):
     """GQA, no bias, an RMSNorm over every q and k head before the
-    rotary embedding, causal over blocks."""
+    rotary embedding, causal over blocks; with `window`, a query at
+    position p sees the keys p - window < k <= p only. The rotary table
+    is the caller's: the model chooses it a layer."""
 
-    def __init__(self, cfg: SdarMoeConfig):
+    def __init__(self, cfg: SdarMoeConfig, window: Optional[int] = None):
         super().__init__()
         self.heads, self.kv_heads = cfg.num_attention_heads, \
             cfg.num_key_value_heads
         self.head_dim, self.block = cfg.head_dim, cfg.block_length
+        self.window = window
         h = cfg.hidden_size
         self.q_proj = nn.Linear(h, self.heads * self.head_dim,
                                 bias_attr=False)
@@ -184,7 +190,7 @@ class SdarMoeAttention(nn.Layer):
         self.k_norm = nn.RMSNorm(self.head_dim, cfg.rms_norm_eps)
 
     def cache_spec(self) -> KVSpec:
-        return KVSpec(self.kv_heads, self.head_dim)
+        return KVSpec(self.kv_heads, self.head_dim, self.window)
 
     def forward(self, x, cos, sin, view: Optional[RaggedKVCacheView]):
         b, s = x.shape[0], x.shape[1]
@@ -195,10 +201,13 @@ class SdarMoeAttention(nn.Layer):
         v = self.v_proj(x).reshape([b, s, self.kv_heads, self.head_dim])
         if view is None:
             q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
-            blk = np.arange(s) // self.block
+            pos = np.arange(s)
+            blk = pos // self.block
+            ok = blk[None, :] <= blk[:, None]
+            if self.window is not None:
+                ok &= pos[None, :] > pos[:, None] - self.window
             out = F.scaled_dot_product_attention(
-                q, k, v, attn_mask=Tensor(jnp.asarray(
-                    blk[None, :] <= blk[:, None])[None, None]))
+                q, k, v, attn_mask=Tensor(jnp.asarray(ok)[None, None]))
             return self.o_proj(out.reshape([b, s, -1])), None
         from paddle_tpu.ops.rope import rope_rotate_values
         pos = view.positions
@@ -209,15 +218,15 @@ class SdarMoeAttention(nn.Layer):
                 s_[pos].astype(_F32)[None, :, None, :])
         q = _apply("rope_ragged", fn_rope, (q, cos, sin))
         k = _apply("rope_ragged", fn_rope, (k, cos, sin))
-        out, view = ragged_write_attend(q, k, v, view)
+        out, view = ragged_write_attend(q, k, v, view, window=self.window)
         return self.o_proj(out.reshape([1, s, -1])), view
 
 
 class SdarMoeDecoderLayer(nn.Layer):
-    def __init__(self, cfg: SdarMoeConfig):
+    def __init__(self, cfg: SdarMoeConfig, window: Optional[int] = None):
         super().__init__()
         self.input_layernorm = nn.RMSNorm(cfg.hidden_size, cfg.rms_norm_eps)
-        self.self_attn = SdarMoeAttention(cfg)
+        self.self_attn = SdarMoeAttention(cfg, window)
         self.post_attention_layernorm = nn.RMSNorm(cfg.hidden_size,
                                                    cfg.rms_norm_eps)
         self.mlp = SdarMoeExperts(cfg)
@@ -239,11 +248,13 @@ class SdarMoeModel(nn.Layer):
 
 
 class SdarMoeForCausalLM(nn.Layer):
+    body = SdarMoeModel           # the decoder: embedding, layers, norm
+
     def __init__(self, cfg: SdarMoeConfig | None = None):
         super().__init__()
         cfg = cfg or SdarMoeConfig()
         self.config = cfg
-        self.model = SdarMoeModel(cfg)
+        self.model = self.body(cfg)
         self.lm_head = nn.Linear(cfg.hidden_size, cfg.vocab_size,
                                  bias_attr=False)
 
@@ -254,6 +265,10 @@ class SdarMoeForCausalLM(nn.Layer):
         return [spec for layer in self.model.layers
                 for spec in (layer.self_attn.cache_spec(),
                              layer.mlp.cache_spec())]
+
+    def layer_rope(self, i: int):
+        """The (cos, sin) table layer `i` rotates its q and k by."""
+        return self.model.rope_cos, self.model.rope_sin
 
     def generation_spec(self) -> Optional[BlockDiffusionSpec]:
         """How generation proceeds: by diffusion over blocks, or (block
@@ -278,7 +293,6 @@ class SdarMoeForCausalLM(nn.Layer):
         x = self.model.embed_tokens(input_ids)
         b, s = x.shape[0], x.shape[1]
         views = past_key_values
-        cos, sin = self.model.rope_cos, self.model.rope_sin
         if views is None:
             live = jnp.ones((b * s,), bool)
         else:
@@ -287,7 +301,7 @@ class SdarMoeForCausalLM(nn.Layer):
         new = []
         for i, layer in enumerate(self.model.layers):
             out, got = layer.self_attn(
-                layer.input_layernorm(x), cos, sin,
+                layer.input_layernorm(x), *self.layer_rope(i),
                 None if views is None else views[2 * i])
             x = x + out
             out, report = layer.mlp(

@@ -524,14 +524,14 @@ class TestGroupedMatmulLowering:
         (256, 2048, 1536), (256, 768, 2048),       # a pass: 64 slots x 4
         (512, 2048, 1536), (512, 768, 2048),       # an admission
         (1024, 2048, 1536), (1024, 768, 2048)])    # two chunks of one
-    def test_swiglu_expert_layer_shapes(self, rows, k, n):
+    def test_swiglu_expert_layer_shapes(self, rows, k, n, held=128):
         """ISSUE 31: the SwiGLU expert layer of the block-diffusion
         cell: all 128 experts held, 8 choices a row, gate and up one
         operand of 2 x 768 columns; groups padded to `row_block` (16
         rows at a pass, 32 and 64 at an admission)."""
         from paddle_tpu.ops.grouped_matmul import (grouped_matmul_values,
                                                    row_block)
-        held, top_k = 128, 8
+        top_k = 8
         bm = row_block(rows * top_k / held)
         m = -(-(rows * top_k + held * (bm - 1)) // bm) * bm
         x = jnp.zeros((m, k), jnp.bfloat16)
@@ -540,6 +540,16 @@ class TestGroupedMatmulLowering:
         assert _lower(
             lambda x, w, sizes: grouped_matmul_values(x, w, sizes, bm),
             x, w, sizes) == {"grouped_matmul": 1}
+
+    @pytest.mark.parametrize("rows,k,n", [
+        (64, 2304, 1792), (64, 896, 2304),         # a decode step
+        (1024, 2304, 1792), (1024, 896, 2304)])    # an admission chunk
+    def test_codemix_expert_layer_shapes(self, rows, k, n):
+        """The codemix cell's expert layer, 64 experts of 896
+        held, 8 choices a row: a decode step of 64 slots gives an expert
+        8 rows (16-row tiles, the stationary grid), an admission of 1024
+        rows 128 (`gmm_pallas`, the 128-row grid)."""
+        self.test_swiglu_expert_layer_shapes(rows, k, n, held=64)
 
 
     # ISSUE 32: the stationary grid holds the whole of K and an n block
@@ -550,6 +560,8 @@ class TestGroupedMatmulLowering:
         (768, 2048, "bfloat16", 2048),      # generate: down
         (1024, 2688, "bfloat16", 2688),     # reasoning: up
         (2688, 1024, "bfloat16", 1024),     # reasoning: down
+        (2304, 1792, "bfloat16", 896),      # codemix: gate and up
+        (896, 2304, "bfloat16", 2304),      # codemix: down
         (1536, 2048, "bfloat16", 2048),     # d(lhs) of gate and up
         (2048, 1536, "float32", 768),       # float32 weights: half
         (8192, 8192, "bfloat16", 256),
@@ -700,6 +712,68 @@ class TestPageGroupPrograms:
         assert set(kernels) == {"_ln_fwd_kernel"}
         mem = compiled.memory_analysis()
         # the pools and the state are donated and updated in place
+        assert mem.alias_size_in_bytes >= 0.99 * sum(
+            a.size * a.dtype.itemsize
+            for a in jax.tree_util.tree_leaves(eng._cache()))
+
+
+class TestMixedWindowPrograms:
+    """The engine's two step programs for a model of window
+    and full layers over routed experts (models/mellum.py), at the
+    published widths (heads of 128, 8 q heads a KV head, a window of
+    1024, 64 SwiGLU experts of 896) and the last 4 of the 28 layers
+    (S S S F), a vocabulary of 8192: a block table a page group, the
+    window on three layers' calls and not on the fourth's, the grouped
+    matmul twice a layer."""
+
+    @pytest.mark.parametrize("program", ["decode", "admit"])
+    def test_engine_programs_compile(self, program):
+        from paddle_tpu.models.mellum import MellumConfig, MellumForCausalLM
+        from paddle_tpu.models.serving import ContinuousBatchingEngine
+        from paddle_tpu.ops import mosaic_kernels
+        one = jax.sharding.SingleDeviceSharding(_v5e()[0])
+        box = {}
+
+        def build():
+            model = MellumForCausalLM(MellumConfig(
+                num_hidden_layers=4, vocab_size=8192,
+                max_position_embeddings=4096))
+            model.to(dtype="bfloat16")
+            model.eval()
+            box["eng"] = ContinuousBatchingEngine(
+                model, max_batch_size=8, max_seq_len=4096,
+                prefill_chunk=256, prompt_pad=256, num_pages=257)
+            return 0
+
+        jax.eval_shape(build)          # shapes only: nothing is allocated
+        eng = box["eng"]
+        assert [g.name for g in eng._groups] == ["full", "w1024"]
+
+        def sds(x):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)
+
+        def i32(*s):
+            return jax.ShapeDtypeStruct(s, jnp.int32, sharding=one)
+
+        block_q, rows, bound = (1, eng.B, None) if program == "decode" \
+            else (8, 256, 256)
+        tree = jax.tree_util.tree_map
+        lowered = eng._build_ragged_step(block_q, bound).lower(
+            tree(sds, eng._pv()), tree(sds, eng._bv()),
+            tree(sds, eng._cache()), i32(rows), i32(rows), i32(rows),
+            i32(eng.B), i32(eng.B), i32(eng.B),
+            tuple(i32(eng.B, eng.pps) for _ in eng._groups), i32(eng.B),
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one))
+        compiled = lowered.compile()
+        # the attention kernel with the window and without it (layers of
+        # one trace share a function), the grouped matmul, the norms
+        kernels = mosaic_kernels(lowered.as_text())
+        assert kernels["ragged_paged_attention"] == 2
+        assert kernels["grouped_matmul"] >= 2
+        assert set(kernels) == {"ragged_paged_attention", "grouped_matmul",
+                                "rms_norm_fwd"}
+        mem = compiled.memory_analysis()
+        # the pools of both groups are donated and updated in place
         assert mem.alias_size_in_bytes >= 0.99 * sum(
             a.size * a.dtype.itemsize
             for a in jax.tree_util.tree_leaves(eng._cache()))
